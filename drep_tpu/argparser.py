@@ -176,13 +176,12 @@ def build_parser() -> argparse.ArgumentParser:
                               "(ops/pallas_ring.py — the neighbor transfer "
                               "rides a Pallas async remote DMA hidden behind "
                               "the tile compute); 'ppermute' is the shard_map "
-                              "reference. 'auto' (default) picks pallas_dma "
-                              "only on a real TPU after a one-time on-device "
-                              "self-check proves bit-equality — block tiles, "
-                              "checkpoints, and elastic fallback are identical "
-                              "either way. Env DREP_TPU_RING_COMM also "
-                              "accepted (plus 'pallas_interpret', the CPU "
-                              "equality oracle for tests/bench — never a "
+                              "ring. 'auto' (default) is ppermute: the fused "
+                              "kernel does not compile on the supported "
+                              "toolchain, and an explicit pallas_dma raises "
+                              "what the compiler says. Env DREP_TPU_RING_COMM "
+                              "also accepted (plus 'pallas_interpret', the "
+                              "CPU equality oracle for tests — never a "
                               "performance mode)")
         tpu.add_argument("--ring_vmem_mb", type=int, default=None,
                          help="VMEM budget (MB) the gridded fused ring sizes "

@@ -143,10 +143,9 @@ def mash_distance_matrix(
 
     if estimator in ("auto", "sort") and pallas_mash_supported(packed.sketch_size):
         # single-chip TPU: the VMEM-resident Pallas kernel computes the
-        # reference-faithful sort estimator faster than the MXU matmul
-        # family (BENCH_r02 end-to-end: 2.70 vs 2.18 M pairs/s/chip at
-        # width 1024, n=2048; the raw-kernel gap is larger — host
-        # thresholding amortizes it)
+        # reference-faithful sort estimator, and was faster end to end
+        # than the MXU matmul family in an earlier chip run (not
+        # re-measured — ROADMAP D3)
         dist, _jac = all_vs_all_mash_pallas(packed, k=k)
         return dist
     if estimator == "matmul" or (estimator == "auto" and packed.n >= MATMUL_MIN_GENOMES):
@@ -184,22 +183,13 @@ def primary_jax_mash(
 # columns/pair) with this penalty on the merge side; the merge only wins
 # when the vocabulary outgrows ~47x the merge units (very diverse
 # clusters).
-# Source: BENCH_r04 `dispatch_crossover` (real v5e, healthy link,
-# 2026-07-31) — both kernels measured at 4 vocab/merge-unit ratios
-# (8x/20x/40x/100x, equal=true at every point); fitted_elem_cost = 47.06
-# (median of per-shape ratios 11.97/32.94/75.65/61.19). The measured
-# winners flip between ratio 40 (matmul, 3.39 s vs 6.41 s) and ratio 100
-# (pallas, 9.47 s vs 5.66 s); 47.0 predicts all four winners, while the
-# previous single-measurement value (15.0, r3 session note) mispredicted
-# pallas at ratios 20 and 40. bench.py::bench_dispatch_crossover
-# re-derives this constant every run and reports `fitted_elem_cost` +
-# `shipped_matches_measured` — update again when a recorded crossover
-# table disagrees by >2x.
-# NB: the triangle-only refactor (ISSUE 1) cut the chunked-matmul side's
-# FLOPs ~1.8x while the pallas self path was already half-grid, so the
-# next on-hardware crossover run is expected to fit a LOWER constant;
-# until it lands, 47.0 conservatively over-favors the (now cheaper)
-# matmul side only near the boundary.
+# The value is from an earlier chip run, not re-measured: both kernels
+# timed at four vocabulary/merge-unit ratios (8x/20x/40x/100x), the
+# winner flipping between ratio 40 (matmul) and ratio 100 (merge). The
+# triangle-only refactor (ISSUE 1) has since cut the chunked-matmul
+# side's FLOPs ~1.8x, so a fresh fit is expected to land LOWER. ROADMAP
+# D2 re-measures both sides and keeps the choice only if a cell sits on
+# each side of it.
 MERGE_VS_MATMUL_ELEM_COST = 47.0
 
 
@@ -216,15 +206,14 @@ def beyond_budget_secondary_path(sketch_width: int, v_pad: int) -> str:
     return "matmul_chunked"
 
 
-# observability: how many containment_matrices calls each kernel path
-# served this process — bench_e2e diffs it around a run to PROVE which
-# regime (one-shot vs beyond-budget) an end-to-end measurement exercised,
-# instead of inferring it from planted-vocabulary arithmetic
-SECONDARY_PATH_COUNTS: dict[str, int] = {}
-
-
 def _count_path(path: str) -> None:
-    SECONDARY_PATH_COUNTS[path] = SECONDARY_PATH_COUNTS.get(path, 0) + 1
+    """Book which kernel path served this containment call into the run
+    record (perf_counters.json `secondary_paths`): a measurement must be
+    able to PROVE which regime (one-shot vs beyond-budget, device vs CPU)
+    it exercised, not infer it from planted-vocabulary arithmetic."""
+    from drep_tpu.utils.profiling import counters
+
+    counters.add_path(path)
 
 
 def containment_matrices(
@@ -286,24 +275,10 @@ def containment_matrices(
         if beyond_budget_secondary_path(packed.sketch_size, v_pad) == "pallas_range":
             from drep_tpu.ops.pallas_merge import all_vs_all_containment_pallas
 
-            try:
-                _count_path("pallas_range")
-                return all_vs_all_containment_pallas(packed, k=k)
-            except Exception:
-                # a Mosaic rejection of the fused stacked grid on some
-                # TPU generation must degrade a production run to the
-                # (always-valid) chunked matmul, not kill it — same
-                # self-deploying stance as the pallas indicator gate
-                from drep_tpu.utils.logger import get_logger
-
-                get_logger().warning(
-                    "pallas_range kernel failed to compile/run — falling "
-                    "back to the chunked MXU path for this cluster",
-                    exc_info=True,
-                )
-                _count_path("pallas_range_fallback")
-        else:
-            _count_path("matmul_chunked")
+            # no fallback: a kernel that fails to compile or run raises
+            _count_path("pallas_range")
+            return all_vs_all_containment_pallas(packed, k=k)
+        _count_path("matmul_chunked")
         return all_vs_all_containment_matmul_chunked(packed, k=k)
     _count_path("cpu_tiles")
     return all_vs_all_containment(packed, k=k, tile=tile)
@@ -344,9 +319,8 @@ def secondary_jax_ani_batched(
     joint vocabulary extent is the max single-cluster vocabulary, not the
     union — at production sketch depth (20k-wide sketches, mostly private
     hash space across unrelated clusters) the union pack measured 8.4M
-    ids and forced the chunked kernels (BENCH_r04 `e2e_prod`:
-    matmul_chunked x9, 0.756x), while the cluster-local pack stays in the
-    one-shot indicator regime. The cluster-local one-shot is preferred
+    ids and forced the chunked kernels, while the cluster-local pack stays
+    in the one-shot indicator regime. The cluster-local one-shot is preferred
     even when a mesh is available: a <=512-row batch over a cluster-max
     vocabulary is a single small matmul, and sharding it over a ring is
     collective-latency-dominated for zero compute win — the mesh earns

@@ -4,7 +4,8 @@ This is the host side of the sketching pipeline (SURVEY.md §7 step 2). It
 plays the role of the reference's `mash sketch` fan-out plus
 d_filter.calc_fasta_stats (reference mount empty; upstream layout), but
 produces device-ready packed arrays instead of .msh files. Results are
-cached in the work directory (``data/arrays/sketches.npz``) keyed on the
+cached in the work directory (``data/arrays/sketches.npz`` plus its
+size-bounded ``sketches.<key>.NNNN.npz`` parts, workdir.py) keyed on the
 sketching arguments, giving sub-stage resume like the reference's cached
 sketch files under ``<wd>/data/``.
 
@@ -176,6 +177,20 @@ pack_ragged = _pack_ragged
 unpack_ragged = _unpack_ragged
 
 
+def _note_ingest_path() -> None:
+    """Record which per-genome kernel sketched this run's genomes — the
+    native C++ library or the numpy path (native/__init__.py) — in the
+    run record (perf_counters.json note ``ingest_path``). The pool
+    workers load the very library this process loads, so asking here
+    answers for them."""
+    from drep_tpu import native
+    from drep_tpu.utils.profiling import counters
+
+    counters.set_note(
+        "ingest_path", "native" if native.get_library() is not None else "numpy"
+    )
+
+
 def sketch_paths(
     bdb: pd.DataFrame,
     k: int,
@@ -205,6 +220,7 @@ def sketch_paths(
         for job in jobs:
             name, res = _sketch_one(job)
             results[name] = res
+    _note_ingest_path()
     bad = sorted(g for g, r in results.items() if r["n_kmers"] == 0)
     if bad:
         shown = ", ".join(bad[:10]) + (" ..." if len(bad) > 10 else "")
@@ -414,6 +430,8 @@ def sketch_genomes(
         for job in todo:
             collect(*_sketch_one(job))
     flush(force=True)
+    if todo:
+        _note_ingest_path()
 
     if nproc > 1:
         from drep_tpu.utils.ckptmeta import atomic_write_bytes

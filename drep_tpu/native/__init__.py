@@ -3,8 +3,11 @@
 The hot host-side loop (FASTA -> canonical k-mers -> sketches; SURVEY.md §7
 step 2 / hard part (f)) has a C++ implementation in ingest.cc, built lazily
 with g++ into a content-addressed shared library cached next to the source.
-Everything degrades transparently to the numpy path (ops/kmers.py) when a
-compiler is unavailable, so the framework never *requires* the native path.
+Without a compiler everything degrades to the numpy path (ops/kmers.py),
+so the framework never *requires* the native path — but never silently:
+a failed build is logged with the compiler's output, and every run
+records which path served (perf_counters.json note ``ingest_path``; the
+numpy path is more than an order of magnitude slower).
 
 DREP_TPU_NO_NATIVE=1 disables the native path entirely (used by the
 equivalence tests to pin the numpy oracle).
@@ -46,9 +49,9 @@ class _DrepSketch(ctypes.Structure):
 def _build_library() -> str | None:
     """Compile ingest.cc -> cached .so keyed on source hash; None on failure.
 
-    EVERYTHING here may fail benignly — including makedirs when the package
-    sits in a read-only site-packages — and must degrade to the numpy path,
-    never abort ingest (the module contract)."""
+    EVERYTHING here may fail — including makedirs when the package sits in
+    a read-only site-packages — and then degrades to the numpy path, never
+    aborting ingest (the module contract); the reason is logged."""
     tmp = None
     try:
         h = hashlib.sha256()
@@ -65,13 +68,13 @@ def _build_library() -> str | None:
         cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", *_SOURCES, "-o", tmp, "-lz"]
         res = subprocess.run(cmd, capture_output=True, text=True, timeout=300)
         if res.returncode != 0:
-            get_logger().debug("native build failed: %s", res.stderr[-1000:])
+            get_logger().warning("native build failed: %s", res.stderr[-1000:])
             return None
         # drep-lint: allow[durable-funnel] — local build artifact: g++ wrote the tmp; the rename IS the atomic publish (no shared-FS payload, no crc story)
         os.replace(tmp, so_path)  # atomic: concurrent builders race safely
         return so_path
-    except Exception as e:
-        get_logger().debug("native build unavailable: %s", e)
+    except Exception as e:  # noqa: BLE001 — no compiler, read-only package dir, ...
+        get_logger().warning("native build unavailable: %s", e)
         return None
     finally:
         if tmp is not None and os.path.exists(tmp):
@@ -95,7 +98,7 @@ def get_library() -> ctypes.CDLL | None:
         so_path = _build_library()
         if so_path is None:
             _lib_failed = True
-            get_logger().info("native ingest unavailable — using the numpy path")
+            get_logger().warning("native ingest unavailable — using the numpy path")
             return None
         lib = ctypes.CDLL(so_path)
         lib.drep_sketch_fasta.restype = ctypes.c_int

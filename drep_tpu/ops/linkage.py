@@ -109,8 +109,8 @@ def sparse_average_linkage(
     keep: float,
 ) -> tuple[np.ndarray, int]:
     """Average-linkage (UPGMA) flat clusters at `cutoff` from a SPARSE edge
-    set — the streaming primary's linkage (VERDICT r2 item 5: the 30k+
-    regime previously fell back to single-linkage silently).
+    set — the streaming primary's linkage (so the 30k+ regime never falls
+    back to single-linkage silently).
 
     Edges (ii[e], jj[e], dd[e]) are every pair with distance <= `keep`
     (the streaming retention bound, max(1-P_ani, warn_dist)); any pair NOT

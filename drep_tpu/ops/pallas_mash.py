@@ -125,8 +125,8 @@ def _mash_shared_kernel(s_orig: int, r_iter: int, a_rev_ref, na_ref, b_ref, nb_r
     def body_r(i, _):
         # Per-row dynamic loads/stores, not a [R, S2] block at offset
         # i*r_iter: Mosaic requires multi-row vector loads/stores to start
-        # at a sublane multiple of 8, and i*{2,4} is not provably one
-        # (BENCH_r04 attempt 1 recorded the compile failure). Single-row
+        # at a sublane multiple of 8, and i*{2,4} is not provably one.
+        # Single-row
         # dynamic indexing is the supported pattern (it is what the
         # r_iter==1 path compiles to); the batched [R, TB, 2*S2] merge —
         # the point of the knob — is unchanged.
@@ -168,7 +168,12 @@ def _mash_shared_grid(a_rev, na, b, nb, *, s_orig: int, r_iter: int, interpret: 
         out_specs=pl.BlockSpec(
             (TILE, TILE), lambda i, j: (i, j), memory_space=pltpu.VMEM
         ),
-        out_shape=jax.ShapeDtypeStruct((ta_n, tb_n), jnp.int32),
+        # vma: inside a shard_map (the mesh ring's tile) the result varies
+        # over whatever axes the operands vary over; empty outside one
+        out_shape=jax.ShapeDtypeStruct(
+            (ta_n, tb_n), jnp.int32,
+            vma=jax.typeof(a_rev).vma | jax.typeof(b).vma,
+        ),
         interpret=interpret,
     )(a_rev, na, b, nb)
 
@@ -208,10 +213,10 @@ def _mash_shared_grid_symmetric(a_rev, na, b, nb, *, s_orig: int, r_iter: int, i
 
 def all_vs_all_mash_pallas(packed, k: int = 21) -> tuple[np.ndarray, np.ndarray]:
     """Full [N, N] (distance, jaccard) for one packed sketch set — the
-    single-chip TPU primary engine (BENCH_r02 end-to-end: 2.70 M
-    pairs/s/chip at width 1024, n=2048, vs 2.18 M for the MXU
-    common-threshold estimator, AND it computes the reference-faithful
-    union-bottom-s estimator, not an alternative family). Same output
+    single-chip TPU primary engine (faster end to end than the MXU
+    common-threshold estimator in an earlier chip run, not re-measured —
+    ROADMAP D3 — AND it computes the reference-faithful union-bottom-s
+    estimator, not an alternative family). Same output
     contract as ops/minhash.py::all_vs_all_mash."""
     from drep_tpu.ops.pallas_merge import _unwrap_symmetric
     from drep_tpu.utils.profiling import counters
@@ -282,6 +287,36 @@ def pallas_mash_supported(sketch_width: int) -> bool:
         not _use_interpret()
         and max(128, next_pow2(sketch_width)) <= PALLAS_MAX_WIDTH
     )
+
+
+def mash_distance_tile_device(a_ids, a_counts, b_ids, b_counts, *, k: int = 21):
+    """Traceable twin of :func:`mash_distance_tile_pallas`: [Ta, Tb] float32
+    Mash distances from device operands, every step on the device — rows
+    padded to TILE multiples, widths to the kernel's power of two, the A
+    side reversed, the raw shared counts turned into distances by THE
+    shared transform. For callers that are themselves traced (the mesh
+    ring's per-block tile, parallel/allpairs.py): the kernel keeps each
+    merge in VMEM, where the jnp tile (ops/minhash.mash_distance_tile)
+    materializes [Ta, Tb, 2*S2] temporaries in HBM."""
+    ta, s_orig = a_ids.shape
+    tb = b_ids.shape[0]
+    s2 = max(128, next_pow2(s_orig))
+
+    def _pad(ids, counts):
+        rows = -(-ids.shape[0] // TILE) * TILE
+        ids = jnp.pad(
+            ids, ((0, rows - ids.shape[0]), (0, s2 - s_orig)), constant_values=PAD_ID
+        )
+        return ids, jnp.pad(counts, (0, rows - counts.shape[0]))[:, None]
+
+    a, na_col = _pad(a_ids, a_counts)
+    b, nb_col = _pad(b_ids, b_counts)
+    shared = _mash_shared_grid(
+        jnp.flip(a, axis=1), na_col, b, nb_col,
+        s_orig=s_orig, r_iter=rows_per_iter(s2), interpret=_use_interpret(),
+    )[:ta, :tb]
+    dist, _j = shared_counts_to_distance(shared, a_counts, b_counts, s_orig, k, xp=jnp)
+    return dist
 
 
 def mash_distance_tile_pallas(a_ids, a_counts, b_ids, b_counts, *, k: int = 21):

@@ -110,8 +110,8 @@ def _intersect_kernel_stacked(a_ref, b_ref, out_ref):
     the same output tile — zeroed at bucket 0, added to after (the
     standard Mosaic reduction-dimension pattern, cf. a matmul K loop).
     One launch + one stacked operand transfer replaces R separate
-    launches/transfers (BENCH_r04 `secondary_production.pallas_range`:
-    vpu_frac 0.026 — overhead-bound, not compute-bound)."""
+    launches/transfers (overhead-bound, not compute-bound, in an earlier
+    chip run; not re-measured)."""
     ta = a_ref.shape[1]
     tb, s2 = b_ref.shape[1], b_ref.shape[2]
     length = 2 * s2
@@ -382,9 +382,8 @@ def intersect_counts_pallas(
         from drep_tpu.ops.rangepart import stacked_range_buckets
 
         # ONE stacked [R, n, W] tensor per side, one transfer, one fused
-        # launch with bucket accumulation inside the grid — per-bucket
-        # repack/transfer/launch loops measured overhead-bound
-        # (BENCH_r04 secondary_production.pallas_range vpu_frac 0.026)
+        # launch with bucket accumulation inside the grid (per-bucket
+        # repack/transfer/launch loops were overhead-bound)
         a_st, b_st = stacked_range_buckets([a, b], PALLAS_MAX_WIDTH)
         if a_st.shape[0] == 0:
             return np.zeros((na, nb), dtype=np.int32)

@@ -279,8 +279,8 @@ def stacked_range_buckets(
     (ops/pallas_merge.py): all buckets cross the host->device link in one
     transfer and run in one kernel launch with an innermost
     bucket-accumulation grid dimension, instead of R separate repacks +
-    transfers + launches (BENCH_r04 `secondary_production.pallas_range`
-    measured vpu_frac 0.026 — launch/transfer overhead, not compute).
+    transfers + launches (which an earlier chip run, not re-measured, found
+    overhead-bound rather than compute-bound).
 
     Buckets empty across ALL inputs are dropped (R counts kept buckets
     only). Two dtype plans are compared by actual byte size and the

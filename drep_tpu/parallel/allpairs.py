@@ -51,14 +51,15 @@ ring is kept behind ``monolithic=True`` / ``--ring_monolithic`` /
 Fused DMA rotation (ISSUE 8): each rotating step's shard_map program can
 be swapped for the fused Pallas kernel (ops/pallas_ring.py) that starts
 the ICI transfer of the B operand to the ring neighbor and computes the
-tile WHILE it flies — recovering the ~19% multi-chip loss MULTICHIP_r05
-measured against non-overlapped ppermute rotation. Backend selection
-(``--ring_comm`` / ``DREP_TPU_RING_COMM`` / :func:`resolve_ring_comm`)
-is auto-gated on a one-time on-device self-check; block tiles are
+tile WHILE it flies. Backend selection is
+explicit (``--ring_comm`` / ``DREP_TPU_RING_COMM`` /
+:func:`resolve_ring_comm`; the default is ppermute); block tiles are
 bit-identical across backends (pinned in tests), so checkpoint shards,
 resume, per-block recovery, and the elastic death protocol are all
-backend-agnostic — a degraded or failed fused step falls into the SAME
-per-block (collective-free) recovery path as a failed ppermute step.
+backend-agnostic — a fused step that FAULTS at run time falls into the
+SAME per-block (collective-free) recovery path as a faulted ppermute
+step, while a step program that does not BUILD ends the run
+(parallel/faulttol.py).
 """
 
 from __future__ import annotations
@@ -78,15 +79,13 @@ from drep_tpu.ops.containment import ani_cov_from_intersections, containment_int
 from drep_tpu.ops.minhash import PackedSketches, mash_distance_tile, pad_packed_rows
 from drep_tpu.parallel.mesh import AXIS, make_mesh
 from drep_tpu.utils import envknobs, telemetry
-from drep_tpu.utils.jaxcompat import pcast, shard_map
 from drep_tpu.utils.logger import get_logger
 
 # monolithic-reference opt-in: explicit argument > configure_ring() >
 # env var > step-wise default
 RING_MONOLITHIC_ENV = "DREP_TPU_RING_MONOLITHIC"
 # ring comm backend request: explicit argument > configure_ring() > env >
-# "auto" (auto-select the fused pallas ring iff the on-device self-check
-# passes — ops/pallas_ring.py; otherwise lax.ppermute)
+# "auto" (= lax.ppermute; resolve_ring_comm)
 RING_COMM_ENV = "DREP_TPU_RING_COMM"
 RING_COMM_CHOICES = ("auto", "ppermute", "pallas_dma", "pallas_interpret")
 
@@ -154,64 +153,26 @@ def ring_comm_requested() -> str:
     return req
 
 
-def resolve_ring_comm(
-    mesh, requested: str | None = None,
-    n_local: int = 0, sketch_width: int = 0, n_outputs: int = 1,
-    kind: str = "",
-) -> str:
-    """The comm backend a step-wise ring over `mesh` actually RUNS:
-    'pallas_dma' (the gridded fused rotate+compare kernel,
-    ops/pallas_ring.py), 'pallas_interpret' (the same kernel discharged
-    on the host backend — the CPU equality oracle, never a perf claim),
-    or 'ppermute' (the shard_map reference).
+def resolve_ring_comm(mesh, requested: str | None = None) -> str:
+    """The comm backend a step-wise ring over `mesh` RUNS: 'ppermute' (the
+    shard_map ring), 'pallas_dma' (the gridded fused rotate+compare
+    kernel, ops/pallas_ring.py) or 'pallas_interpret' (the same kernel
+    discharged on the host backend — the CPU equality oracle, never a
+    perf claim).
 
-    'auto' selects pallas_dma only when the one-time on-device self-check
-    passed (real TPU backend, bit-equal numerics — the
-    pallas_indicator_ok gating pattern). There is NO block-size gate any
-    more (ISSUE 16): the gridded kernel streams ANY block through VMEM
-    in `DREP_TPU_RING_VMEM_MB`-sized row tiles, so `n_local` /
-    `sketch_width` no longer influence the verdict (kept in the
-    signature for callers that still pass them). When only the matmul
-    variant survived the self-check, kinds it cannot express (`kind`
-    outside MATMUL_TILE_KINDS) still resolve to ppermute. An explicit
-    'pallas_dma' that cannot be honored falls back to ppermute with a
-    warning naming the reason — a comm knob must never turn into a wedge
-    or a wrong answer."""
-    del n_local, sketch_width, n_outputs  # gridding removed the fits-check
+    'auto' is 'ppermute': the fused kernel does not compile on the
+    supported toolchain (ops/pallas_ring.py has the compiler's words), so
+    it is off the default dispatch. An explicit 'pallas_dma' is honored
+    as asked — the ring builds the kernel before its first step and
+    raises whatever the compiler says; nothing falls back silently."""
     req = requested if requested is not None else ring_comm_requested()
     if req not in RING_COMM_CHOICES:
         raise ValueError(
             f"ring comm backend {req!r}: expected one of {RING_COMM_CHOICES}"
         )
-    if req == "ppermute" or mesh.devices.size < 2:
+    if req == "auto" or mesh.devices.size < 2:
         return "ppermute"
-    from drep_tpu.ops.pallas_ring import (
-        fused_ring_kind_ok,
-        pallas_ring_ok,
-        pallas_ring_unavailable_reason,
-    )
-
-    if req == "pallas_interpret":
-        # the interpret oracle has no VMEM to overflow — always honored
-        return "pallas_interpret"
-    if not kind and pallas_ring_ok():
-        return "pallas_dma"
-    if kind and fused_ring_kind_ok(kind):
-        return "pallas_dma"
-    if pallas_ring_ok():
-        reason = (
-            f"only the matmul tile variant passed the self-check and kind "
-            f"{kind!r} needs the merge network"
-        )
-    else:
-        reason = pallas_ring_unavailable_reason()
-    if req == "pallas_dma":
-        get_logger().warning(
-            "dense ring: --ring_comm pallas_dma requested but unavailable "
-            "(%s) — falling back to ppermute",
-            reason,
-        )
-    return "ppermute"
+    return req
 
 
 def half_ring_steps(n_devices: int) -> int:
@@ -247,7 +208,7 @@ def _ring_allpairs_shard(a_ids, a_counts, tile_fn, n_outputs: int, half: bool):
     # mark the accumulators as device-varying so the scan carry type is
     # stable (the updates are derived from axis_index and vary over the mesh)
     outs = [
-        pcast(jnp.zeros((n_local, n_local * n_devices), jnp.float32), (AXIS,), to="varying")
+        lax.pcast(jnp.zeros((n_local, n_local * n_devices), jnp.float32), (AXIS,), to="varying")
         for _ in range(n_outputs)
     ]
     perm = [(j, (j + 1) % n_devices) for j in range(n_devices)]
@@ -291,6 +252,18 @@ def _ring_allpairs_shard(a_ids, a_counts, tile_fn, n_outputs: int, half: bool):
 
 def _mash_tile(k: int):
     def tile(a_ids, a_counts, b_ids, b_counts):
+        from drep_tpu.ops.pallas_mash import (
+            mash_distance_tile_device,
+            pallas_mash_supported,
+        )
+
+        if pallas_mash_supported(a_ids.shape[1]):
+            # on a TPU a ring block goes through the VMEM-resident kernel
+            # (same estimator, bit-equal shared counts): the jnp merge
+            # below materializes [Ta, Tb, 2*S2] temporaries in HBM — 12 GB
+            # apiece for a 1250-row block of 1000-wide sketches, which XLA
+            # refuses to compile for a 16 GB chip
+            return mash_distance_tile_device(a_ids, a_counts, b_ids, b_counts, k=k)
         d, _j = mash_distance_tile(a_ids, a_counts, b_ids, b_counts, k=k)
         return d
 
@@ -385,7 +358,7 @@ def _ring_fn(kind: str, k: int, mesh, half: bool) -> tuple[Callable, int]:
     compile-free."""
     make_tile, n_outputs = _TILE_KINDS[kind]
     fn = jax.jit(
-        shard_map(
+        jax.shard_map(
             functools.partial(
                 _ring_allpairs_shard,
                 tile_fn=make_tile(k),
@@ -448,7 +421,7 @@ def _ring_step_fn(kind: str, k: int, mesh, rotate: bool) -> tuple[Callable, int]
     ICI hop, same optimization as the monolithic program's lax.cond)."""
     make_tile, n_outputs = _TILE_KINDS[kind]
     fn = jax.jit(
-        shard_map(
+        jax.shard_map(
             functools.partial(
                 _ring_step_shard,
                 tile_fn=make_tile(k),
@@ -620,8 +593,9 @@ def _ring_allpairs_monolithic(packed, kind, k, mesh, half):
     # (see its docstring); multi-host live failures abort loudly via the
     # collective timeouts instead. The step-wise default has a redoable
     # unit and survives those deaths — this reference path does not.
-    from drep_tpu.parallel.faulttol import retrying_call
+    from drep_tpu.parallel.faulttol import build_program, retrying_call
 
+    build_program(fn, ids_d, counts_d)  # a compile error is not a device fault
     outs = retrying_call(
         lambda: jax.block_until_ready(fn(ids_d, counts_d)),
         site="ring_dispatch",
@@ -728,9 +702,11 @@ def _ring_allpairs_stepwise(
         TileExecutor,
         WatchdogTimeout,
         _wait_ready,
+        build_program,
         collective_timeout_s,
         drain_requested,
         heartbeat_cadence_s,
+        is_device_fault,
         join_elastic_pod,
         join_requested,
         wait_elastic,
@@ -952,6 +928,15 @@ def _ring_allpairs_stepwise(
         nonlocal ex, n_computed
         n_computed += 1
         if ex is None:
+            # the per-block program is built once, outside the retry
+            # envelope: a tile that does not compile ends the run
+            # (parallel/faulttol.py), it is never "recovered" on the CPU
+            from jax.sharding import SingleDeviceSharding
+
+            sh = SingleDeviceSharding(devices[0])
+            blk_ids = jax.ShapeDtypeStruct((n_local, ids.shape[1]), ids.dtype, sharding=sh)
+            blk_cts = jax.ShapeDtypeStruct((n_local,), counts.dtype, sharding=sh)
+            build_program(tile_jit, blk_ids, blk_cts, blk_ids, blk_cts)
             ex = TileExecutor(devices, cfg, fault_site="ring_dispatch")
         a, b = blk
         if tail_step is not None:
@@ -1004,45 +989,73 @@ def _ring_allpairs_stepwise(
             and not joining
         )
         aborted = None
-        # honest backend gauge: 0.0 unless a fused pallas step actually
-        # runs this call — a resume/recovery-only call (run_ring False)
-        # executes no rotation at all and must not inherit a previous
-        # call's 1.0
+        # honest backend gauge: 1.0 only once a fused pallas step has RUN
+        # this call (set after its wait below, never from the resolution)
+        # — a resume/recovery-only call (run_ring False) executes no
+        # rotation at all and must not inherit a previous call's 1.0
         counters.set_gauge("ring_comm_pallas", 0.0)
+        fused_ran = False
         if run_ring:
-            # rotation backend for THIS schedule: the gridded fused pallas
-            # kernel (ICI rotation hidden behind the tile sweep) when the
-            # resolve gate admits it, the shard_map ppermute otherwise.
-            # Block tiles are bit-identical either way (pinned in tests),
-            # so the choice never touches the checkpoint/recovery story.
-            comm = resolve_ring_comm(
-                mesh, ring_comm, kind=kind
-            ) if n_steps > 1 else "ppermute"
-            if comm != "ppermute":
-                counters.set_gauge("ring_comm_pallas", 1.0)
-            else:
-                # observability (ISSUE 16): WHY the fused path is off,
-                # beside the gauge in perf_counters.json — a 0.0 gauge
-                # alone cannot distinguish a pinned fallback from a
-                # failed self-check from a one-step schedule
-                from drep_tpu.ops.pallas_ring import (
-                    pallas_ring_unavailable_reason,
-                )
-
-                counters.set_note(
-                    "ring_comm_fallback_reason",
-                    "single-step schedule (nothing to rotate)"
-                    if n_steps <= 1
-                    else pallas_ring_unavailable_reason()
-                    or "ppermute requested or fused path refused for this kind",
-                )
+            # rotation backend for THIS schedule (resolve_ring_comm: the
+            # shard_map ppermute unless the fused pallas kernel was asked
+            # for). Block tiles are bit-identical either way (pinned in
+            # tests), so the choice never touches checkpoint/recovery.
+            req = ring_comm if ring_comm is not None else ring_comm_requested()
+            comm = resolve_ring_comm(mesh, req) if n_steps > 1 else "ppermute"
+            # which backend ran and why, beside the gauge
+            counters.set_note("ring_comm", comm)
+            counters.set_note(
+                "ring_comm_reason",
+                "single-step schedule (nothing to rotate)" if n_steps <= 1
+                else f"requested {req!r}" if req != "auto"
+                else "auto = ppermute: the fused pallas_dma step does not "
+                "compile on this toolchain (ops/pallas_ring.py)",
+            )
             ids_d = put_global(ids, NamedSharding(mesh, P(AXIS, None)))
             counts_d = put_global(counts, NamedSharding(mesh, P(AXIS)))
-            # the fused step's cold profile differs from the warm steps
-            # (the Mosaic/XLA compile lands on the first step's wait):
-            # exclude exactly that first step from the rolling median —
-            # the TileExecutor-style warmup exclusion, sized for a ring
-            # whose whole schedule is only half_ring_steps(D) samples
+
+            def _step_fn(i: int):
+                """(program, fused?) of ring step `i`."""
+                rotate = i < n_steps - 1
+                if rotate and comm != "ppermute":
+                    from drep_tpu.ops.pallas_ring import (
+                        fused_ring_step_fn,
+                        fused_ring_variant,
+                        matmul_ring_vocab_pad,
+                    )
+
+                    variant = fused_ring_variant(kind)
+                    fn, _ = fused_ring_step_fn(
+                        kind, k, mesh,
+                        interpret=comm == "pallas_interpret",
+                        variant=variant,
+                        # static dense-id extent, from the host copy the
+                        # driver already holds (matmul tiles only)
+                        v_pad=matmul_ring_vocab_pad(ids)
+                        if variant == "matmul"
+                        else 0,
+                        vmem_mb=ring_vmem_mb_override(),
+                    )
+                    return fn, True
+                # the final step has no rotation to overlap — the plain
+                # program (which skips the dead hop) is the right one
+                # under EVERY comm backend
+                return _ring_step_fn(kind, k, mesh, rotate)[0], False
+
+            steps = [_step_fn(i) for i in range(n_steps)]
+            # build every distinct step program BEFORE the first dispatch,
+            # outside the recovery envelope (parallel/faulttol.py): a step
+            # that does not trace, lower or compile ends the run with the
+            # compiler's message — it must never read as a failed step
+            # whose blocks get "recovered" one by one. Every step's B
+            # operand has the A operand's shape and sharding.
+            for fn in {id(f): f for f, _ in steps}.values():
+                build_program(fn, ids_d, counts_d, ids_d, counts_d)
+            # only the first step's wait still absorbs anything cold
+            # (executable load, first DMA): exclude exactly that one from
+            # the rolling median — the TileExecutor-style warmup
+            # exclusion, sized for a ring whose whole schedule is only
+            # half_ring_steps(D) samples
             auto = AutoTimeout(cfg, warmup=RING_STEP_WARMUP)
             # dispatch every step up front: JAX dispatch is async and each
             # step consumes the previous step's device-resident B operand,
@@ -1052,38 +1065,27 @@ def _ring_allpairs_stepwise(
             def _dispatch_all() -> list[tuple[int, list]]:
                 out_pending: list[tuple[int, list]] = []
                 b_ids, b_counts = ids_d, counts_d
-                for i in range(n_steps):
-                    rotate = i < n_steps - 1
-                    if rotate and comm != "ppermute":
-                        from drep_tpu.ops.pallas_ring import (
-                            fused_ring_step_fn,
-                            fused_ring_variant,
-                            matmul_ring_vocab_pad,
-                        )
-
-                        variant = fused_ring_variant(kind)
-                        fn, _ = fused_ring_step_fn(
-                            kind, k, mesh,
-                            interpret=comm == "pallas_interpret",
-                            variant=variant,
-                            # static dense-id extent, from the host copy
-                            # the driver already holds (matmul tiles only)
-                            v_pad=matmul_ring_vocab_pad(ids)
-                            if variant == "matmul"
-                            else 0,
-                            vmem_mb=ring_vmem_mb_override(),
-                        )
-                    else:
-                        # the final step has no rotation to overlap — the
-                        # plain program (which skips the dead hop) is the
-                        # right one under EVERY comm backend
-                        fn, _ = _ring_step_fn(kind, k, mesh, rotate)
+                for i, (fn, _fused) in enumerate(steps):
                     *outs, b_ids, b_counts = fn(ids_d, counts_d, b_ids, b_counts)
                     out_pending.append((i, outs))
                 return out_pending
 
+            def _member_left() -> bool:
+                """One cadence-gated liveness look between waits. A wait
+                that completes inside wait_elastic's first poll never
+                reaches its own check — and with the step programs built
+                up front every wait can be that fast — so the loop also
+                looks before the dispatch and at each step boundary: a
+                join request is admitted (and adopted), a death or drain
+                noticed, however fast the steps are. True when a member
+                LEFT; a pure join keeps the schedule."""
+                gone = (len(hb.dead), len(hb.drained))
+                return hb.maybe_check() and (len(hb.dead), len(hb.drained)) != gone
+
             pending: list[tuple[int, list]] = []
-            if elastic:
+            if elastic and _member_left():
+                aborted = "pod membership changed before the first step"
+            elif elastic:
                 # the enqueue itself can block inside the collective
                 # transport when a peer dies mid-rendezvous (observed:
                 # a survivor wedged INSIDE dispatch, never reaching the
@@ -1109,6 +1111,8 @@ def _ring_allpairs_stepwise(
                 try:
                     pending = _dispatch_all()
                 except Exception as e:  # noqa: BLE001 — recovery recomputes
+                    if not is_device_fault(e):
+                        raise
                     aborted = e
             for i, outs in pending:
                 if aborted is not None:
@@ -1151,6 +1155,8 @@ def _ring_allpairs_stepwise(
                     except (CollectiveTimeout, FaultTolError):
                         raise  # wedged peer / max_dead exceeded: abort loudly
                     except Exception as e:  # noqa: BLE001 — per-block recovery
+                        if not is_device_fault(e):
+                            raise
                         counters.add_fault("ring_step_failures")
                         logger.warning(
                             "dense ring: step %d/%d failed (%s) — recomputing "
@@ -1160,11 +1166,17 @@ def _ring_allpairs_stepwise(
                         break
                     auto.note(time.perf_counter() - t0)
                     _store_step(i, outs)
+                    if steps[i][1] and not fused_ran:
+                        fused_ran = True
+                        counters.set_gauge("ring_comm_pallas", 1.0)
                     # a drain request is honored at the step boundary: this
                     # step's blocks are durable, the departure note goes
                     # out, and the peers re-deal the rest with no
                     # staleness wait
                     _maybe_drain()
+                    if elastic and _member_left():
+                        aborted = "pod membership changed"
+                        break
                 if aborted is None and _join_covered_tail(i):
                     # ring-phase JOIN shortcut (ISSUE 15): admitted
                     # joiner(s) eat whole steps from the schedule TAIL
@@ -1226,6 +1238,7 @@ def _ring_allpairs_stepwise(
             last_deal_epoch = -1
             while True:
                 _maybe_drain()
+                hb.maybe_check()  # adopt what the fast step loop may have missed
                 live = list(hb.live)
                 missing = _missing_blocks()
                 if hb.epoch != last_deal_epoch:

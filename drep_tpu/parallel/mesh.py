@@ -9,6 +9,8 @@ tile grid is sharded (parallel/allpairs.py).
 
 from __future__ import annotations
 
+import os
+
 import jax
 from jax.sharding import Mesh
 
@@ -50,28 +52,30 @@ def make_local_mesh() -> Mesh:
 
 
 def initialize_distributed(coordinator: str | None = None, num_processes: int | None = None, process_id: int | None = None) -> None:
-    """Multi-host bring-up (v5e-64-style pods; SURVEY.md §5.8).
+    """Multi-host bring-up (v5e-64-style pods; SURVEY.md §5.8), only when
+    the operator configured one: explicit arguments, or
+    ``JAX_COORDINATOR_ADDRESS`` in the environment (JAX's cluster
+    detection then fills in whatever the arguments leave out).
 
-    On single-host runs this is a no-op. On multi-host, either rely on the
-    TPU environment auto-detection (no arguments) or pass explicit
-    coordinator/process counts.
+    Anything else is a single-host run, and it returns at once: no network
+    call, no coordination service. JAX's own argument-less auto-detect is
+    deliberately NOT the default — on a TPU VM it queries the cloud
+    metadata server, which a sealed machine cannot reach.
     """
+    if (
+        coordinator is None
+        and num_processes is None
+        and not os.environ.get("JAX_COORDINATOR_ADDRESS")
+    ):
+        return
     # must run BEFORE any backend use (jax.devices()/process_count() would
     # initialize the local backend and make distributed init impossible)
     try:
-        if coordinator is None and num_processes is None:
-            jax.distributed.initialize()
-        else:
-            jax.distributed.initialize(
-                coordinator_address=coordinator,
-                num_processes=num_processes,
-                process_id=process_id,
-            )
-    except ValueError:
-        # auto-detect found no cluster environment (single-host run):
-        # "coordinator_address should be defined" — expected, proceed local
-        if coordinator is not None or num_processes is not None:
-            raise  # explicit multi-host args were wrong — surface it
+        jax.distributed.initialize(
+            coordinator_address=coordinator,
+            num_processes=num_processes,
+            process_id=process_id,
+        )
     except RuntimeError as e:
         # tolerable: (a) distributed already initialized (idempotent
         # re-entry), (b) local backend already up in this process (library

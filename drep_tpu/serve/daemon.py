@@ -206,6 +206,7 @@ class IndexServer:
                     "generation": int(self._resident.generation),
                     "n_genomes": self._resident.n,
                     "pid": os.getpid(),
+                    **self._device_fields(),
                 },
                 separators=(",", ":"),
             ),
@@ -218,6 +219,15 @@ class IndexServer:
             self.stats.requests_total, self.stats.batches_total,
         )
         return 0
+
+    def _device_fields(self) -> dict:
+        """platform / device_kind / n_devices of the backend this daemon
+        computes on — carried by the ready line and every status
+        snapshot, so a loadgen or an orchestrator reads what served from
+        the daemon itself (never from its own view of the machine)."""
+        from drep_tpu.utils.profiling import device_record
+
+        return device_record()
 
     def request_drain(self) -> None:
         """The programmatic SIGTERM: refuse new admissions, let the
@@ -462,6 +472,7 @@ class IndexServer:
             "batches_total": self.stats.batches_total,
             "generation_swaps": self.stats.swaps_total,
             "latency_ms": hists,
+            **self._device_fields(),
         }
         out["partial_refusals"] = self.stats.partial_refusals
         out["deadline_shed"] = self.stats.deadline_shed

@@ -586,6 +586,11 @@ class RouterServer(IndexServer):
         self._sketch_lock = threading.Lock()
         self._sketch_cache: OrderedDict[tuple, dict] = OrderedDict()
 
+    def _device_fields(self) -> dict:
+        # the router computes nothing on a device and must never open a
+        # backend: on a chip machine the chip belongs to its replicas
+        return {}
+
     # ---- lifecycle -------------------------------------------------------
     def start(self) -> str:
         address = super().start()

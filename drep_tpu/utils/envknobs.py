@@ -92,7 +92,7 @@ _declare(
 _declare(
     "DREP_TPU_RING_COMM", "str", "",
     "Ring comm backend: auto|ppermute|pallas_dma|pallas_interpret "
-    "(parallel/allpairs.resolve_ring_comm). Empty = auto.",
+    "(parallel/allpairs.resolve_ring_comm). Empty = auto = ppermute.",
 )
 _declare(
     "DREP_TPU_RING_MONOLITHIC", "bool", False,
@@ -100,15 +100,10 @@ _declare(
     "reference) instead of host-stepped redoable units.",
 )
 _declare(
-    "DREP_TPU_PALLAS_RING", "bool", True,
-    "Set 0 to pin the fused Pallas DMA ring off (auto-gate reference "
-    "fallback is ppermute).",
-)
-_declare(
     "DREP_TPU_RING_VARIANT", "str", "",
-    "Fused-ring tile variant: auto|merge|matmul "
-    "(ops/pallas_ring.fused_ring_variant). Empty = auto (self-check "
-    "picks; matmul only ever applies to count-free |A∩B| kinds).",
+    "Fused-ring tile variant: merge|matmul "
+    "(ops/pallas_ring.fused_ring_variant). Empty = merge; matmul only "
+    "ever applies to count-free |A∩B| kinds.",
 )
 _declare(
     "DREP_TPU_RING_VMEM_MB", "int", 12,
@@ -117,10 +112,6 @@ _declare(
     "block streams through VMEM in tiles that fit. --ring_vmem_mb mirrors it.",
 )
 # -- single-chip kernels -----------------------------------------------------
-_declare(
-    "DREP_TPU_PALLAS_INDICATOR", "bool", True,
-    "Set 0 to pin the Pallas indicator kernel off (ops/pallas_indicator.py).",
-)
 _declare(
     "DREP_TPU_INDICATOR_DTYPE", "str", None,
     "Force the indicator matmul accumulator dtype (ops/containment.py); "

@@ -93,6 +93,23 @@ class Histogram:
         }
 
 
+def device_record() -> dict[str, Any]:
+    """What THIS process's JAX backend runs on — the three fields every
+    run record carries (perf_counters.json, the serve daemon's ready line
+    and status), so that a run can be judged from its own record: a
+    compare that quietly ran on the CPU must not read like a chip run.
+    Initializes the backend if nothing has yet; a backend that cannot
+    initialize raises (it is the run's error, not a default)."""
+    import jax
+
+    devices = jax.devices()
+    return {
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "n_devices": len(devices),
+    }
+
+
 @dataclass
 class Counters:
     """Per-stage pair/time accounting. One process-global instance (the
@@ -135,6 +152,11 @@ class Counters:
     # metric is the TAIL — p50/p99 over a bounded recent window, per
     # named series (serve_request_ms, serve_batch_ms, ...).
     hists: dict[str, Histogram] = field(default_factory=dict)
+    # which kernel path served each secondary compare call (one_shot,
+    # one_shot_clusterlocal, mesh_ring, matmul_chunked, pallas_range,
+    # cpu_tiles — cluster/engines.py): a run's record must say which
+    # regime it exercised, not leave it to be inferred from shapes
+    paths: dict[str, int] = field(default_factory=dict)
 
     @contextlib.contextmanager
     def stage(self, name: str, pairs: int = 0) -> Iterator[None]:
@@ -179,6 +201,10 @@ class Counters:
         self.faults[kind] = self.faults.get(kind, 0) + int(n)
         telemetry.event("fault", kind=kind, n=int(n))
 
+    def add_path(self, name: str) -> None:
+        """Count one secondary compare call served by kernel path `name`."""
+        self.paths[name] = self.paths.get(name, 0) + 1
+
     def set_gauge(self, name: str, value: float) -> None:
         """Record a derived operational value (last write wins)."""
         self.gauges[name] = float(value)
@@ -214,22 +240,22 @@ class Counters:
         telemetry.set_epoch(int(epoch))
         telemetry.event("epoch", epoch=int(epoch), reason=str(reason))
 
-    def report(self) -> dict[str, Any]:
-        # host-side tooling (tools/trace_report.py, the scrubber's
-        # neighbors) must be able to render a counter report WITHOUT a
-        # JAX runtime: fall back to n_chips=1 with a provenance note when
-        # jax is absent or its backend refuses to initialize
-        n_chips_source = None
-        try:
-            import jax
-
-            n_chips = max(1, len(jax.devices()))
-        except Exception as e:  # noqa: BLE001 — ImportError OR backend-init failure
-            n_chips = 1
-            n_chips_source = f"default (jax unavailable: {type(e).__name__})"
-        out: dict[str, Any] = {"n_chips": n_chips, "stages": {}}
-        if n_chips_source is not None:
-            out["n_chips_source"] = n_chips_source
+    def report(self, device: bool = True) -> dict[str, Any]:
+        """The run record. It names the device the process ran on
+        (:func:`device_record`; ``n_chips`` is its device count, the
+        divisor of the per-chip rates). ``device=False`` is for the
+        control-plane processes that never touch JAX (`index route`,
+        `index supervise`): on a chip machine a record written at their
+        exit must not open the backend — the chip belongs to the replica
+        processes — so theirs names no device."""
+        out: dict[str, Any] = (
+            device_record() if device
+            else {"platform": None, "device_kind": None, "n_devices": 0}
+        )
+        n_chips = max(1, out["n_devices"])
+        if device:
+            out["n_chips"] = n_chips
+        out["stages"] = {}
         total_pairs, total_seconds = 0, 0.0
         for name, st in self.stages.items():
             rate = st.pairs / st.seconds if st.seconds > 0 else 0.0
@@ -275,9 +301,11 @@ class Counters:
             out["histograms"] = {
                 name: h.summary() for name, h in sorted(self.hists.items())
             }
+        if self.paths:
+            out["secondary_paths"] = dict(sorted(self.paths.items()))
         return out
 
-    def write(self, log_dir: str) -> str:
+    def write(self, log_dir: str, device: bool = True) -> str:
         # atomic (utils/durableio.py): a SIGKILL mid-write must not leave
         # a torn perf_counters.json that poisons the next run's tooling —
         # the counters are the honesty record, they get the same
@@ -286,7 +314,8 @@ class Counters:
 
         path = os.path.join(log_dir, "perf_counters.json")
         atomic_write_bytes(
-            path, json.dumps(self.report(), indent=1, sort_keys=True).encode()
+            path,
+            json.dumps(self.report(device), indent=1, sort_keys=True).encode(),
         )
         return path
 
@@ -297,6 +326,7 @@ class Counters:
         self.notes.clear()
         self.epoch_history.clear()
         self.hists.clear()
+        self.paths.clear()
 
 
 counters = Counters()  # the process-global instance used by the pipeline

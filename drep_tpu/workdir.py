@@ -15,6 +15,8 @@ compute, and sharded tile results can be checkpointed per-shard.
 
 from __future__ import annotations
 
+import contextlib
+import glob
 import json
 import os
 from typing import Any
@@ -33,6 +35,13 @@ _SUBDIRS = ["data", "data_tables", "figures", "log", "dereplicated_genomes", os.
 LEGACY_SNAPSHOT_DEFAULTS: dict[str, Any] = {
     "hash": "splitmix64",
 }
+
+
+# No single file of the array store grows past this. Machines cap file size
+# (RLIMIT_FSIZE, an object store's part limit), and the sketch cache is the
+# one GB-scale payload: ~200 KB per genome at production width.
+ARRAY_PART_BYTES = 16 << 20
+_PARTS_PREFIX = "__parts__"
 
 
 def _atomic_write(loc: str, write_fn) -> None:
@@ -95,6 +104,9 @@ class WorkDirectory:
     def _array_loc(self, name: str) -> str:
         return os.path.join(self.location, "data", "arrays", f"{name}.npz")
 
+    def _part_loc(self, name: str, key: str, i: int) -> str:
+        return os.path.join(self.location, "data", "arrays", f"{name}.{key}.{i:04d}.npz")
+
     def store_arrays(self, name: str, compressed: bool = True, **arrays: np.ndarray) -> None:
         """`compressed=False` for high-entropy payloads (the MinHash sketch
         cache: uniform 64-bit hashes are incompressible, and zlib over the
@@ -103,17 +115,73 @@ class WorkDirectory:
         the in-band ``__crc__`` (utils/durableio.py) so a bit-rotted cache
         is detected at load, never silently trusted; the write streams to
         the tmp file directly (no in-memory serialize — the sketch cache
-        is ~GB at 100k genomes)."""
+        is ~GB at 100k genomes).
+
+        No file grows past ARRAY_PART_BYTES: an array larger than that is
+        cut along its first axis into ``<name>.<key>.NNNN.npz`` parts (each
+        a checked payload of its own) and the head ``<name>.npz`` records
+        the parts' lengths under ``__parts__<key>``. The head is the commit
+        point — removed first, published last — so a kill mid-save leaves
+        an absent cache, never a head over another save's parts."""
         from drep_tpu.utils.durableio import with_checksum
 
-        arrays = with_checksum(arrays)
         writer = np.savez_compressed if compressed else np.savez
-        _atomic_write(self._array_loc(name), lambda tmp: writer(tmp, **arrays))
+
+        def publish(loc: str, payload: dict) -> None:
+            payload = with_checksum(payload)
+            _atomic_write(loc, lambda tmp: writer(tmp, **payload))
+
+        head_loc = self._array_loc(name)
+        stale_parts = glob.glob(
+            os.path.join(glob.escape(os.path.dirname(head_loc)), f"{name}.*.{'[0-9]' * 4}.npz")
+        )
+        for loc in (head_loc, *stale_parts):  # the head first
+            with contextlib.suppress(FileNotFoundError):
+                os.remove(loc)
+        head: dict[str, np.ndarray] = {}
+        for key, arr in arrays.items():
+            arr = np.asarray(arr)
+            if arr.ndim == 0 or len(arr) < 2 or arr.nbytes <= ARRAY_PART_BYTES:
+                head[key] = arr
+                continue
+            rows = max(1, ARRAY_PART_BYTES // (arr.nbytes // len(arr)))
+            lengths = []
+            for i, lo in enumerate(range(0, len(arr), rows)):
+                part = arr[lo : lo + rows]
+                publish(self._part_loc(name, key, i), {"part": part})
+                lengths.append(len(part))
+            head[_PARTS_PREFIX + key] = np.asarray(lengths, dtype=np.int64)
+        publish(head_loc, head)
 
     def get_arrays(self, name: str) -> dict[str, np.ndarray]:
-        from drep_tpu.utils.durableio import load_npz_checked
+        from drep_tpu.utils.durableio import CorruptPayloadError, load_npz_checked
 
-        return load_npz_checked(self._array_loc(name), what=f"workdir array {name}")
+        out = load_npz_checked(self._array_loc(name), what=f"workdir array {name}")
+        for pkey in [k for k in out if k.startswith(_PARTS_PREFIX)]:
+            lengths = out.pop(pkey).tolist()
+            key = pkey[len(_PARTS_PREFIX) :]
+            whole, lo = None, 0
+            for i, n in enumerate(lengths):
+                loc = self._part_loc(name, key, i)
+                try:
+                    part = load_npz_checked(loc, what=f"workdir array {name} part")["part"]
+                except (FileNotFoundError, KeyError) as e:
+                    raise CorruptPayloadError(
+                        f"workdir array {name}: part {loc} is missing ({e!r}) — "
+                        f"delete {self._array_loc(name)} to recompute the cache"
+                    ) from e
+                if len(part) != n:
+                    raise CorruptPayloadError(
+                        f"workdir array {name}: part {loc} holds {len(part)} rows, "
+                        f"its head says {n} — delete {self._array_loc(name)} to "
+                        f"recompute the cache"
+                    )
+                if whole is None:  # one allocation, filled part by part
+                    whole = np.empty((sum(lengths), *part.shape[1:]), dtype=part.dtype)
+                whole[lo : lo + n] = part
+                lo += n
+            out[key] = whole
+        return out
 
     def has_arrays(self, name: str) -> bool:
         return os.path.exists(self._array_loc(name))

@@ -45,10 +45,11 @@ def _init(
     start_metrics_flush(wd.get_dir("log"))
     # fresh per-run state (library users may call several workflows per process)
     from drep_tpu.cluster.anim import reset_run_state
-    from drep_tpu.utils.profiling import counters
+    from drep_tpu.utils.profiling import counters, device_record
 
     counters.reset()
     reset_run_state()
+    get_logger().info("device: %s", device_record())
     if genomes:
         bdb = make_bdb(genomes)
         wd.store_db(bdb, "Bdb")
@@ -108,13 +109,14 @@ def compare_wrapper(wd_loc: str, genomes: list[str] | None = None, **kwargs) -> 
     return cdb
 
 
-def _init_index(index_loc: str, write_logs: bool = True) -> None:
+def _init_index(index_loc: str, write_logs: bool = True) -> str | None:
     """Service-mode session setup: logging under the index's own log dir,
     persistent compile cache, fresh counters — the index equivalents of
     `_init`, minus workdir/Bdb machinery (the store IS the state).
     `write_logs=False` (classify) keeps logging console-only: classify is
     read-only by contract, and even a log line under the index dir would
-    violate the nothing-written assertion its tests pin."""
+    violate the nothing-written assertion its tests pin. Returns the log
+    dir (None when console-only) for :func:`_finish_index`."""
     import os
 
     from drep_tpu.utils.xla_cache import enable_persistent_cache
@@ -138,6 +140,24 @@ def _init_index(index_loc: str, write_logs: bool = True) -> None:
     else:
         stop_metrics_flush()
     counters.reset()
+    return log_dir
+
+
+def _finish_index(log_dir: str | None) -> None:
+    """The index verbs' run record (the `_finish_counters` twin): which
+    device the verb ran on, its fault-tolerance counters and kernel
+    paths — ``<index>/log/perf_counters.json``, or ONE console log line
+    for the read-only classify, which may write nothing."""
+    import json
+
+    from drep_tpu.utils.profiling import counters
+
+    if log_dir is not None:
+        counters.write(log_dir)
+    else:
+        get_logger().info(
+            "perf_counters: %s", json.dumps(counters.report(), sort_keys=True)
+        )
 
 
 def index_build_wrapper(
@@ -151,7 +171,7 @@ def index_build_wrapper(
     meta-manifest, the whole input admitted as federation generation 0."""
     from drep_tpu.index import build_federated, build_from_paths, build_from_workdir
 
-    _init_index(index_loc)
+    log_dir = _init_index(index_loc)
     if work_directory and genomes:
         raise UserInputError(
             "index build takes --work_directory OR -g genomes, not both"
@@ -164,21 +184,24 @@ def index_build_wrapper(
                 "workdir snapshot has no per-genome routing pass — build "
                 "federated from the FASTAs instead"
             )
-        return build_from_workdir(index_loc, work_directory)
-    if genomes:
-        if partitions:
-            return build_federated(
-                index_loc, genomes, partitions,
-                processes=kwargs.pop("processes", 1) or 1, **kwargs,
-            )
-        return build_from_paths(
+        summary = build_from_workdir(index_loc, work_directory)
+    elif genomes and partitions:
+        summary = build_federated(
+            index_loc, genomes, partitions,
+            processes=kwargs.pop("processes", 1) or 1, **kwargs,
+        )
+    elif genomes:
+        summary = build_from_paths(
             index_loc, genomes,
             processes=kwargs.pop("processes", 1) or 1, **kwargs,
         )
-    raise UserInputError(
-        "index build needs a source: --work_directory <completed run> or "
-        "-g <genome FASTAs>"
-    )
+    else:
+        raise UserInputError(
+            "index build needs a source: --work_directory <completed run> or "
+            "-g <genome FASTAs>"
+        )
+    _finish_index(log_dir)
+    return summary
 
 
 def index_update_wrapper(
@@ -189,8 +212,8 @@ def index_update_wrapper(
     independent units (``--fed_pods`` for concurrent subprocess pods)."""
     from drep_tpu.index import index_update
 
-    _init_index(index_loc)
-    return index_update(
+    log_dir = _init_index(index_loc)
+    summary = index_update(
         index_loc, genomes, processes=kwargs.get("processes", 1) or 1,
         primary_prune=kwargs.get("primary_prune", "off") or "off",
         prune_bands=kwargs.get("prune_bands", 0) or 0,
@@ -199,6 +222,8 @@ def index_update_wrapper(
         fed_pods=kwargs.get("fed_pods"),
         params_file=kwargs.get("params_file"),
     )
+    _finish_index(log_dir)
+    return summary
 
 
 def index_maintenance_wrapper(index_loc: str, *, op: str, **kwargs) -> dict:
@@ -209,7 +234,7 @@ def index_maintenance_wrapper(index_loc: str, *, op: str, **kwargs) -> dict:
     from drep_tpu.index import fed_compact, fed_merge, fed_split
     from drep_tpu.utils import envknobs
 
-    _init_index(index_loc)
+    log_dir = _init_index(index_loc)
     processes = kwargs.get("processes", 1) or 1
     if op == "split":
         summary = fed_split(index_loc, int(kwargs["pid"]), processes=processes)
@@ -227,6 +252,7 @@ def index_maintenance_wrapper(index_loc: str, *, op: str, **kwargs) -> dict:
             min_generations=int(min_gens),
         )
     get_logger().info("index %s summary: %s", op, summary)
+    _finish_index(log_dir)
     return summary
 
 
@@ -239,14 +265,16 @@ def index_classify_wrapper(
 
     if not genomes:
         raise UserInputError("index classify needs -g <genome FASTAs>")
-    _init_index(index_loc, write_logs=False)
-    return index_classify(
+    log_dir = _init_index(index_loc, write_logs=False)
+    verdicts = index_classify(
         index_loc, genomes, processes=kwargs.get("processes", 1) or 1,
         primary_prune=kwargs.get("primary_prune", "off") or "off",
         prune_bands=kwargs.get("prune_bands", 0) or 0,
         prune_min_shared=kwargs.get("prune_min_shared", 0) or 0,
         prune_join_chunk=kwargs.get("prune_join_chunk", 0) or 0,
     )
+    _finish_index(log_dir)
+    return verdicts
 
 
 def index_serve_wrapper(index_loc: str, genomes: list[str] | None = None, **kwargs) -> int:
@@ -413,7 +441,7 @@ def index_route_wrapper(index_loc: str, genomes: list[str] | None = None, **kwar
     finally:
         stop_metrics_flush(final=bool(log_dir))
         if log_dir:
-            counters.write(log_dir)
+            counters.write(log_dir, device=False)  # control plane: never opens a backend
         telemetry.close()
 
 
@@ -536,7 +564,7 @@ def index_supervise_wrapper(index_loc: str, **kwargs) -> int:
     finally:
         stop_metrics_flush(final=bool(log_dir))
         if log_dir:
-            counters.write(log_dir)
+            counters.write(log_dir, device=False)  # control plane: never opens a backend
         telemetry.close()
 
 

@@ -26,36 +26,19 @@ def main() -> None:
 
     from drep_tpu.utils import envknobs
 
-    # jax 0.9: the forced-host XLA_FLAGS route no longer multiplies CPU
-    # devices; the config knob does, and must be set pre-backend-init.
-    # Older releases within the pyproject pin (e.g. 0.4.37) lack the knob
-    # and rely on the XLA_FLAGS the parent test already exported.
+    # the config knob multiplies CPU devices, and must be set before the
+    # backend initializes
     ndev = envknobs.env_int("DREP_TPU_TEST_CPU_DEVICES")
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", ndev)
-    except AttributeError:
-        pass
+    jax.config.update("jax_num_cpu_devices", ndev)
     if mode in ("join_streaming", "join_ring"):
         # mid-run JOINER (ISSUE 9): NOT a member of the jax.distributed
         # pod at all — a separate single-process jax runtime that joins
         # the pod's elastic stage through the checkpoint-dir protocol
-        # alone (DREP_TPU_POD_JOIN set by the parent test). Dispatched
-        # BEFORE the gloo collectives config below: gloo backend init
-        # needs the distributed client this process deliberately never
-        # creates.
+        # alone (DREP_TPU_POD_JOIN set by the parent test).
         _joiner_case(outdir, mode, sys.argv[6])
         return
 
-    try:
-        # pre-0.5 jaxlib implements cross-process CPU collectives only
-        # through gloo, and the default ("none") makes every multiprocess
-        # computation fail with "Multiprocess computations aren't
-        # implemented on the CPU backend"; newer releases dropped the knob
-        # (gloo became the default)
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (AttributeError, ValueError):
-        pass
     init_kwargs = {}
     if mode in ("elastic", "elastic_prebarrier", "ring", "secondary_retry"):
         # these cases kill (or early-exit) a pod member ON PURPOSE: the jax
@@ -64,19 +47,11 @@ def main() -> None:
         # and the client layer abort()s the very survivors under test
         # (client.h: "Terminating process..."). The repo's heartbeat
         # protocol is the detector being exercised, not jax's.
-        init_kwargs = dict(
-            service_heartbeat_interval_seconds=10,
-            service_max_missing_heartbeats=600,
-        )
-    try:
-        jax.distributed.initialize(
-            coordinator_address=coord, num_processes=nproc, process_id=pid,
-            **init_kwargs,
-        )
-    except TypeError:  # newer jax dropped the heartbeat kwargs
-        jax.distributed.initialize(
-            coordinator_address=coord, num_processes=nproc, process_id=pid
-        )
+        init_kwargs = dict(heartbeat_timeout_seconds=6000)
+    jax.distributed.initialize(
+        coordinator_address=coord, num_processes=nproc, process_id=pid,
+        **init_kwargs,
+    )
     assert jax.process_count() == nproc, jax.process_count()
     assert len(jax.devices()) == ndev * nproc, jax.devices()
     assert len(jax.local_devices()) == ndev

@@ -94,8 +94,6 @@ def test_scale_workdir_survives_sigkill_and_warm_starts(tmp_path):
     out_json = str(tmp_path / "r.json")
     script = (
         "import json, sys\n"
-        "from drep_tpu.controller import _honor_jax_platforms_env\n"
-        "_honor_jax_platforms_env()\n"
         "import bench\n"
         f"r = bench.bench_e2e(1200, workdir={wdp!r})\n"
         f"json.dump(r, open({out_json!r}, 'w'))\n"

@@ -377,6 +377,96 @@ def test_retrying_call_exhaustion_raises_faulttol_error():
     assert counters.faults.get("retries", 0) >= 1
 
 
+# --- build errors are bugs, not device faults (ISSUE 21) ------------------
+
+
+def _assert_nothing_hidden():
+    for k in ("retries", "cpu_fallback_tiles", "quarantined_devices",
+              "ring_step_failures", "ring_blocks_recovered"):
+        assert counters.faults.get(k, 0) == 0, counters.faults
+
+
+def test_envelope_propagates_build_errors_and_still_absorbs_device_faults():
+    """TileExecutor / retrying_call: a `compute` that raises while being
+    BUILT (anything that is not a device fault) propagates at once — no
+    retry, no CPU fallback — while an injected run-time fault still
+    retries and recovers as before."""
+    import jax.numpy as jnp
+
+    from drep_tpu.parallel.faulttol import TileExecutor, is_device_fault, retrying_call
+
+    assert not is_device_fault(TypeError("bad trace"))
+    assert not is_device_fault(NotImplementedError("Unimplemented primitive"))
+    assert is_device_fault(faults.InjectedFault("x"))
+    assert is_device_fault(faulttol.WatchdogTimeout("x"))
+
+    def broken(slot):
+        raise TypeError("cannot trace this")
+
+    cfg = FaultTolConfig(max_retries=2, backoff_s=0.0)
+    ft = TileExecutor([object(), object()], cfg)
+    fell_back = []
+    with pytest.raises(TypeError, match="cannot trace"):
+        ft.finalize(ft.submit(broken), cpu_fallback=lambda: fell_back.append(1))
+    with pytest.raises(TypeError, match="cannot trace"):
+        retrying_call(lambda: broken(0), site="secondary_batch", config=cfg)
+    assert not fell_back
+    _assert_nothing_hidden()
+
+    # a device fault on the same executor: retried on the other slot
+    calls = []
+
+    def flaky(slot):
+        calls.append(slot)
+        if len(calls) == 1:
+            raise faults.InjectedFault("transient")
+        return jnp.zeros(())
+
+    ft.finalize(ft.submit(flaky), cpu_fallback=lambda: fell_back.append(1))
+    assert len(calls) == 2 and not fell_back
+    assert counters.faults["retries"] == 1
+
+
+def test_tile_program_that_fails_to_trace_raises_from_streaming_and_ring(monkeypatch):
+    """The acceptance pin: a tile program that raises while tracing makes
+    `streaming_mash_edges` AND the dense ring raise; no retries, no
+    cpu_fallback_tiles, no ring_blocks_recovered is booked. (Before, both
+    finished "successfully" on the CPU-recompute / per-block recovery
+    paths with bit-equal output.)"""
+    import jax
+
+    from drep_tpu.ops import minhash
+    from drep_tpu.parallel import allpairs, streaming
+    from drep_tpu.parallel.mesh import make_mesh
+
+    def bad_pair(a, b, na, nb):
+        raise TypeError("tile body cannot be traced")
+
+    # fresh jit caches: an earlier test may have compiled these signatures
+    jax.clear_caches()
+    allpairs._ring_step_fn.cache_clear()
+    allpairs._block_tile_fn.cache_clear()
+    monkeypatch.setattr(minhash, "_pair_shared", bad_pair)
+    packed = _packed(n=48, s=32)
+    try:
+        with pytest.raises(TypeError, match="cannot be traced"):
+            streaming.streaming_mash_edges(packed, 21, 0.2, block=16, use_pallas=False)
+        with pytest.raises(TypeError, match="cannot be traced"):
+            allpairs.sharded_mash_allpairs(packed, k=21, mesh=make_mesh(3))
+        _assert_nothing_hidden()
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+        allpairs._ring_step_fn.cache_clear()
+        allpairs._block_tile_fn.cache_clear()
+    # and with the program intact, an injected RUN-TIME fault still recovers
+    clean = streaming_mash_edges(packed, 21, 0.2, block=16, use_pallas=False)
+    faults.configure("streaming_tile:raise:1.0:max=1")
+    got = streaming_mash_edges(packed, 21, 0.2, block=16, use_pallas=False)
+    _assert_edges_equal(got, clean)
+    assert counters.faults["retries"] == 1
+
+
 # --- stripe->process balance (ROADMAP open item) -------------------------
 
 
@@ -656,7 +746,7 @@ def test_quarantine_invokes_free_callback():
 
     def compute(slot):
         if slot == 0:
-            raise RuntimeError("boom")
+            raise faults.InjectedFault("boom")  # a device fault, not a bug
         return jnp.zeros(())
 
     ft = TileExecutor(

@@ -5,8 +5,11 @@ for the step-wise ring's rotating steps: block tiles BIT-IDENTICAL to
 the lax.ppermute schedule at odd and even D (the even-D half ring has
 the split middle step and the rotate-last-skip), double-buffer rotation
 correct across chained steps, checkpoint shards byte-compatible across
-comm backends, and the auto-gate refusing the compiled path on CPU
-(interpret mode is the only off-TPU mode, and never auto-selected).
+comm backends. The compiled kernel is off the default dispatch ('auto'
+is ppermute); an explicit 'pallas_dma' is honored and raises what the
+compiler says. Every equality pin also requires that NO ring step failed
+and NO block was recovered: the per-block recovery path produces the same
+bits, so without that a kernel that never ran would still pass.
 """
 
 import os
@@ -44,8 +47,17 @@ def _sketch_set(rng, n, s):
 @pytest.fixture(autouse=True)
 def _hermetic_ring_config():
     configure_ring()
+    counters.reset()
     yield
     configure_ring()
+
+
+def _assert_fused_ran_clean():
+    """The fused kernel itself produced the blocks: the gauge is set from
+    a step that RAN, and nothing went through per-block recovery."""
+    assert counters.gauges.get("ring_comm_pallas") == 1.0
+    assert counters.faults.get("ring_step_failures", 0) == 0
+    assert counters.faults.get("ring_blocks_recovered", 0) == 0
 
 
 # odd and even device counts: even D exercises the split middle step and
@@ -60,9 +72,7 @@ def test_fused_mash_ring_bit_equals_ppermute(rng, n_dev):
     want = sharded_mash_allpairs(packed, k=21, mesh=mesh, ring_comm="ppermute")
     got = sharded_mash_allpairs(packed, k=21, mesh=mesh, ring_comm="pallas_interpret")
     assert got.tobytes() == want.tobytes(), "fused pallas ring != ppermute ring"
-    # honest accounting is backend-agnostic: the comm choice must not
-    # change what the schedule books
-    assert counters.gauges.get("ring_comm_pallas") == 1.0
+    _assert_fused_ran_clean()
 
 
 @pytest.mark.parametrize("n_dev", [3, 8])
@@ -78,6 +88,7 @@ def test_fused_containment_ring_bit_equals_ppermute(rng, n_dev):
     )
     assert a_g.tobytes() == a_w.tobytes()
     assert c_g.tobytes() == c_w.tobytes()
+    _assert_fused_ran_clean()
 
 
 def test_double_buffer_rotation_across_chained_steps(rng):
@@ -142,7 +153,7 @@ def test_checkpoint_shards_are_comm_backend_agnostic(rng, tmp_path):
     )
     shards = sorted(f for f in os.listdir(ckpt) if f.startswith("blk_"))
     assert len(shards) == 3 * 4 // 2, shards
-    assert counters.gauges.get("ring_comm_pallas") == 1.0
+    _assert_fused_ran_clean()
     tc0 = counters.stages["primary_compare"].tiles_computed
     got = sharded_mash_allpairs(
         packed, k=21, mesh=mesh, checkpoint_dir=ckpt, ring_comm="ppermute"
@@ -155,40 +166,32 @@ def test_checkpoint_shards_are_comm_backend_agnostic(rng, tmp_path):
     assert counters.gauges.get("ring_comm_pallas") == 0.0
 
 
-def test_auto_gate_refuses_pallas_on_cpu():
-    """The compiled fused path must never engage off-TPU: 'auto' resolves
-    to ppermute, a forced 'pallas_dma' falls back (warning, not a wedge),
-    and the gate's reason names the backend."""
-    from drep_tpu.ops.pallas_ring import (
-        pallas_ring_ok,
-        pallas_ring_unavailable_reason,
-        reset_selftest_for_tests,
-    )
-
-    reset_selftest_for_tests()
-    try:
-        mesh = make_mesh(3)
-        assert pallas_ring_ok() is False
-        assert "tpu" in (pallas_ring_unavailable_reason() or "")
-        assert resolve_ring_comm(mesh, "auto") == "ppermute"
-        assert resolve_ring_comm(mesh, "pallas_dma") == "ppermute"
-        # the interpret oracle is the ONLY off-TPU pallas mode, and only
-        # ever by explicit request
-        assert resolve_ring_comm(mesh, "pallas_interpret") == "pallas_interpret"
-    finally:
-        reset_selftest_for_tests()
+def test_auto_is_ppermute_and_explicit_requests_are_honored():
+    """The compiled fused kernel is off the default dispatch: 'auto'
+    resolves to ppermute. Explicit requests resolve to themselves — no
+    self-check swaps them for another backend."""
+    mesh = make_mesh(3)
+    assert resolve_ring_comm(mesh, "auto") == "ppermute"
+    assert resolve_ring_comm(mesh, "ppermute") == "ppermute"
+    assert resolve_ring_comm(mesh, "pallas_dma") == "pallas_dma"
+    assert resolve_ring_comm(mesh, "pallas_interpret") == "pallas_interpret"
+    assert resolve_ring_comm(make_mesh(1), "pallas_dma") == "ppermute"  # nothing to rotate
 
 
-def test_env_pin_and_bad_comm_validation(monkeypatch):
-    from drep_tpu.ops.pallas_ring import pallas_ring_ok, reset_selftest_for_tests
+def test_explicit_pallas_dma_that_cannot_build_raises(rng):
+    """A step program that does not build ends the run: the compiled
+    kernel cannot compile off-TPU, and asking for it must raise the
+    compiler's error — not run ppermute, not "recover" every block."""
+    mesh = make_mesh(3)
+    n, s = 12, 32
+    packed = pack_sketches(_sketch_set(rng, n, s), [f"g{i}" for i in range(n)], s)
+    with pytest.raises(Exception, match="(?i)interpret|cpu|pallas"):
+        sharded_mash_allpairs(packed, k=21, mesh=mesh, ring_comm="pallas_dma")
+    assert counters.gauges.get("ring_comm_pallas") == 0.0
+    assert not counters.faults, counters.faults
 
-    monkeypatch.setenv("DREP_TPU_PALLAS_RING", "0")
-    reset_selftest_for_tests()
-    try:
-        assert pallas_ring_ok() is False
-    finally:
-        reset_selftest_for_tests()
 
+def test_bad_comm_validation(monkeypatch):
     monkeypatch.setenv("DREP_TPU_RING_COMM", "warp_drive")
     with pytest.raises(ValueError, match="warp_drive"):
         ring_comm_requested()
@@ -219,22 +222,6 @@ def test_fused_ring_tile_sizing():
     assert fused_ring_tile(1, 64) == 1  # single-row block
 
 
-def test_resolve_ring_comm_has_no_fits_check():
-    """`resolve_ring_comm` must not consult any block-size gate: the
-    verdict for a production-size block equals the verdict for a tiny
-    one (here both ppermute, CPU backend — the point is the shape args
-    no longer matter), and the gridded interpret oracle is honored at
-    any size."""
-    mesh = make_mesh(3)
-    assert resolve_ring_comm(mesh, "auto", 6250, 1024) == resolve_ring_comm(
-        mesh, "auto", 8, 64
-    )
-    assert (
-        resolve_ring_comm(mesh, "pallas_interpret", 100_000, 4096)
-        == "pallas_interpret"
-    )
-
-
 @pytest.mark.parametrize("n_dev", [3, 8])
 def test_gridded_fused_ring_nondivisible_and_single_row(rng, n_dev, monkeypatch):
     """Grid-edge shapes (ISSUE 16): a VMEM budget small enough to force
@@ -248,6 +235,7 @@ def test_gridded_fused_ring_nondivisible_and_single_row(rng, n_dev, monkeypatch)
     want = sharded_mash_allpairs(packed, k=21, mesh=mesh, ring_comm="ppermute")
     got = sharded_mash_allpairs(packed, k=21, mesh=mesh, ring_comm="pallas_interpret")
     assert got.tobytes() == want.tobytes(), "gridded fused ring != ppermute ring"
+    _assert_fused_ran_clean()
     # single-row blocks: exactly D genomes -> n_local == 1
     small = pack_sketches(
         _sketch_set(rng, n_dev, 32), [f"s{i}" for i in range(n_dev)], 32
@@ -255,6 +243,7 @@ def test_gridded_fused_ring_nondivisible_and_single_row(rng, n_dev, monkeypatch)
     want1 = sharded_mash_allpairs(small, k=21, mesh=mesh, ring_comm="ppermute")
     got1 = sharded_mash_allpairs(small, k=21, mesh=mesh, ring_comm="pallas_interpret")
     assert got1.tobytes() == want1.tobytes()
+    _assert_fused_ran_clean()
 
 
 @pytest.mark.parametrize("n_dev", [3, 8])
@@ -285,7 +274,7 @@ def test_gridded_fused_ring_past_old_vmem_cap(rng, n_dev):
     want = sharded_mash_allpairs(packed, k=21, mesh=mesh, ring_comm="ppermute")
     got = sharded_mash_allpairs(packed, k=21, mesh=mesh, ring_comm="pallas_interpret")
     assert got.tobytes() == want.tobytes(), "past-cap gridded ring != ppermute"
-    assert counters.gauges.get("ring_comm_pallas") == 1.0
+    _assert_fused_ran_clean()
 
 
 @pytest.mark.parametrize("n_dev", [3, 8])
@@ -307,8 +296,7 @@ def test_mxu_matmul_variant_ring_bit_equals_ppermute(rng, n_dev, monkeypatch):
     )
     assert a_g.tobytes() == a_w.tobytes(), "matmul-variant ring != ppermute"
     assert c_g.tobytes() == c_w.tobytes()
-    # the fused path really ran (recovery/fallback would zero this gauge)
-    assert counters.gauges.get("ring_comm_pallas") == 1.0
+    _assert_fused_ran_clean()
 
 
 def test_mxu_matmul_tile_equals_merge_tile(rng):
@@ -349,18 +337,11 @@ def test_mxu_matmul_tile_equals_merge_tile(rng):
         assert np.asarray(bc_x).tobytes() == np.asarray(bc_m).tobytes(), case
 
 
-def test_matmul_variant_validation_and_kind_gating():
+def test_matmul_variant_validation(monkeypatch):
     """The matmul variant is containment-only (mash's tile counts shared
     ids within the union bottom-s, not plain |A∩B|) and demands a static
-    pow2 v_pad; `fused_ring_kind_ok` refuses merge-only kinds when only
-    the matmul escape hatch survived the self-check."""
-    from drep_tpu.ops.pallas_ring import (
-        _SELFTEST,
-        fused_ring_kind_ok,
-        fused_ring_step_fn,
-        fused_ring_variant,
-        reset_selftest_for_tests,
-    )
+    pow2 v_pad; the variant pin never reaches merge-only kinds."""
+    from drep_tpu.ops.pallas_ring import fused_ring_step_fn, fused_ring_variant
 
     mesh = make_mesh(2)
     with pytest.raises(ValueError, match="matmul ring variant supports"):
@@ -369,19 +350,13 @@ def test_matmul_variant_validation_and_kind_gating():
         fused_ring_step_fn(
             "containment", 21, mesh, interpret=True, variant="matmul", v_pad=0
         )
+    assert fused_ring_variant("containment") == "merge"  # the default
+    monkeypatch.setenv("DREP_TPU_RING_VARIANT", "matmul")
+    assert fused_ring_variant("containment") == "matmul"
     assert fused_ring_variant("mash") == "merge"  # never matmul, any pin
-    reset_selftest_for_tests()
-    try:
-        # simulate: merge rejected by Mosaic, matmul survived
-        _SELFTEST.update(ok=True, reason=None, variant="matmul")
-        assert fused_ring_kind_ok("containment") is True
-        assert fused_ring_kind_ok("mash") is False
-        assert fused_ring_variant("containment") == "matmul"
-        mesh3 = make_mesh(3)
-        assert resolve_ring_comm(mesh3, "auto", kind="containment") == "pallas_dma"
-        assert resolve_ring_comm(mesh3, "auto", kind="mash") == "ppermute"
-    finally:
-        reset_selftest_for_tests()
+    monkeypatch.setenv("DREP_TPU_RING_VARIANT", "auto")
+    with pytest.raises(ValueError, match="DREP_TPU_RING_VARIANT"):
+        fused_ring_variant("containment")
 
 
 def test_ring_comm_gauge_reports_ppermute(rng):

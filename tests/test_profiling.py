@@ -3,6 +3,8 @@
 import json
 import os
 
+import pytest
+
 from drep_tpu.utils.profiling import Counters, trace
 
 
@@ -82,21 +84,30 @@ def test_epoch_history_ordering_and_pod_epoch_gauge():
     assert c.report().get("epoch_history") is None
 
 
-def test_report_renders_without_jax(monkeypatch):
-    """Host-side tooling (tools/trace_report.py) renders counter reports
-    with no JAX runtime: a failing jax.devices() falls back to n_chips=1
-    with an n_chips_source note instead of propagating."""
+def test_report_names_the_device_and_a_dead_backend_is_an_error(monkeypatch):
+    """The record names what the process ran on; a backend that cannot
+    initialize raises instead of defaulting to one chip. Control-plane
+    processes (route, supervise) never touch JAX: their record names no
+    device and asks for none."""
     import jax
+
+    c = Counters()
+    c.add("primary_compare", pairs=100, seconds=0.5)
+    rep = c.report()
+    dev = jax.devices()
+    assert (rep["platform"], rep["device_kind"], rep["n_devices"]) == (
+        dev[0].platform, dev[0].device_kind, len(dev)
+    )
+    assert rep["n_chips"] == len(dev)
 
     def boom():
         raise RuntimeError("no backend")
 
     monkeypatch.setattr(jax, "devices", boom)
-    c = Counters()
-    c.add("primary_compare", pairs=100, seconds=0.5)
-    rep = c.report()
-    assert rep["n_chips"] == 1
-    assert "jax unavailable" in rep["n_chips_source"]
+    with pytest.raises(RuntimeError, match="no backend"):
+        c.report()
+    rep = c.report(device=False)  # must not even ask
+    assert rep["platform"] is None and rep["n_devices"] == 0
     assert rep["stages"]["primary_compare"]["pairs_per_sec_per_chip"] == 200.0
 
 

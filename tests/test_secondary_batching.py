@@ -41,19 +41,17 @@ def test_batched_equals_per_cluster(gs_many_small):
 
 def test_batched_uses_clusterlocal_one_shot(gs_many_small):
     """Single-chip batched calls must ride the cluster-local pack (max
-    single-cluster vocab, one-shot indicator) — the production-depth fix
-    for BENCH_r04 e2e_prod's 9 beyond-budget chunked mega-calls."""
-    from drep_tpu.cluster.engines import SECONDARY_PATH_COUNTS
+    single-cluster vocab, one-shot indicator) instead of beyond-budget
+    chunked mega-calls over the union vocabulary — and the run record
+    says so."""
+    from drep_tpu.utils.profiling import counters
 
     gs = gs_many_small
     clusters = [list(range(c * 4, c * 4 + 4)) for c in range(12)]
-    before = dict(SECONDARY_PATH_COUNTS)
+    before = counters.paths.get("one_shot_clusterlocal", 0)
     secondary_jax_ani_batched(gs, clusters)
-    assert (
-        SECONDARY_PATH_COUNTS.get("one_shot_clusterlocal", 0)
-        - before.get("one_shot_clusterlocal", 0)
-        == 1
-    )
+    assert counters.paths.get("one_shot_clusterlocal", 0) - before == 1
+    assert counters.report()["secondary_paths"]["one_shot_clusterlocal"] >= 1
 
 
 def test_batched_falls_back_when_local_vocab_beyond_budget(gs_many_small, monkeypatch):

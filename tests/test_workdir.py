@@ -33,6 +33,46 @@ def test_arrays_roundtrip(tmp_path):
     assert np.array_equal(out["b"], b)
 
 
+def test_large_arrays_split_into_bounded_parts(tmp_path, monkeypatch):
+    """No file of the array store grows past ARRAY_PART_BYTES (a machine may
+    cap file size — the chip-check machine did, and the one-file sketch
+    cache died there with EFBIG): a large array round-trips through parts,
+    small ones stay in the head, a shorter re-save drops the longer save's
+    tail, and a lost part is corruption, not a short array."""
+    import glob
+    import os
+
+    from drep_tpu import workdir
+    from drep_tpu.utils.durableio import CorruptPayloadError
+
+    monkeypatch.setattr(workdir, "ARRAY_PART_BYTES", 4096)
+    wd = WorkDirectory(str(tmp_path / "wd"))
+    rng = np.random.default_rng(0)
+    big = rng.integers(0, 2**63, size=5000, dtype=np.uint64)  # 40 KB -> 10 parts
+    mat = rng.integers(0, 255, size=(100, 300), dtype=np.uint8)  # rows kept whole
+    small = np.arange(7, dtype=np.int64)
+    names = np.array(["a", "b"])
+    wd.store_arrays("sketches", compressed=False, big=big, mat=mat, small=small, names=names)
+    files = glob.glob(os.path.join(wd.location, "data", "arrays", "*"))
+    assert len(files) > 10
+    # zip + npy framing is a few hundred bytes on top of the payload
+    assert max(os.path.getsize(f) for f in files) < 4096 + 1024
+    out = wd.get_arrays("sketches")
+    assert sorted(out) == ["big", "mat", "names", "small"]
+    for key, want in (("big", big), ("mat", mat), ("small", small), ("names", names)):
+        assert out[key].dtype == want.dtype and np.array_equal(out[key], want)
+
+    wd.store_arrays("sketches", compressed=False, big=big[:1500])
+    assert np.array_equal(wd.get_arrays("sketches")["big"], big[:1500])
+    parts = sorted(glob.glob(os.path.join(wd.location, "data", "arrays", "sketches.big.*")))
+    assert len(parts) == 3
+    assert not glob.glob(os.path.join(wd.location, "data", "arrays", "sketches.mat.*"))
+
+    os.remove(parts[1])
+    with pytest.raises(CorruptPayloadError, match="missing"):
+        wd.get_arrays("sketches")
+
+
 def test_arguments_match(tmp_path):
     wd = WorkDirectory(str(tmp_path / "wd"))
     args = {"P_ani": 0.9, "S_ani": 0.95, "genomes": ["a", "b"]}

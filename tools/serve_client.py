@@ -24,10 +24,14 @@ pins the two claims the tentpole makes —
   recorded and must exceed ``--amortization``.
 
 The record (``--out``, default SERVE_BENCH.json) is stamped
-``proxy_metrics: true`` + the actual backend: CPU loadgen numbers
-characterize the batching/admission layers and are REFUSED as hardware
-claims by tools/missing_stages.py exactly like every other proxy
-record. Guards exit 1 on miss (``--no_guard`` records without judging).
+``proxy_metrics: true`` + the backend the DAEMON reports in its ready
+line: closed-loop loadgen numbers characterize the batching/admission
+layers and are never hardware claims. The parent never opens a JAX
+backend (one process per chip): the daemons run on whatever
+``JAX_PLATFORMS`` says — run the guard as ``JAX_PLATFORMS=cpu python
+tools/serve_client.py --bench``; ``--fleet`` starts two replicas, which
+cannot share one chip. Guards exit 1 on miss (``--no_guard`` records
+without judging).
 ``--deadline_ms`` stamps every loadgen request with an end-to-end
 budget (ISSUE 19); the record then carries the honest deadline-miss
 rate, the clients' wire-damage tallies, and the daemon's own
@@ -121,10 +125,31 @@ def _plant_genomes(out_dir: str, n: int, length: int = 4000, seed: int = 0) -> l
     return paths
 
 
-def _spawn_daemon(index_loc: str, max_batch: int, extra: list[str] | None = None):
+def _child_env() -> dict:
+    """The children's environment: the caller's, untouched (a daemon runs
+    on whatever JAX_PLATFORMS says — set JAX_PLATFORMS=cpu for the CPU
+    proxy guard), plus the repo on PYTHONPATH."""
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+    return env
+
+
+def _build_index(index_loc: str, genomes: list[str], partitions: int = 0) -> None:
+    """Bootstrap the bench index in a CHILD: this parent never opens a JAX
+    backend — on a chip machine the chip must be free for the daemons it
+    spawns (one process per chip), and the record's backend comes from
+    the daemon that served, never from this process's view."""
+    argv = [sys.executable, "-m", "drep_tpu", "index", "build", index_loc,
+            "--length", "0", "-g", *genomes]
+    if partitions:
+        argv += ["--partitions", str(partitions)]
+    subprocess.run(argv, check=True, cwd=REPO, env=_child_env(),
+                   stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL)
+
+
+def _spawn_daemon(index_loc: str, max_batch: int, extra: list[str] | None = None):
+    """Start one `index serve` child; returns (proc, address, ready line)."""
+    env = _child_env()
     proc = subprocess.Popen(
         [sys.executable, "-m", "drep_tpu", "index", "serve", index_loc,
          "--max_batch", str(max_batch), "--batch_window_ms", "10",
@@ -137,7 +162,7 @@ def _spawn_daemon(index_loc: str, max_batch: int, extra: list[str] | None = None
         proc.kill()
         raise RuntimeError("daemon died before its ready line")
     ready = json.loads(line)
-    return proc, ready["serving"]
+    return proc, ready["serving"], ready
 
 
 def _loadgen(
@@ -232,9 +257,7 @@ def _loadgen(
 
 
 def _spawn_router(index_loc: str, replicas: list[str], max_batch: int):
-    env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+    env = _child_env()
     argv = [sys.executable, "-m", "drep_tpu", "index", "route", index_loc,
             "--max_batch", str(max_batch), "--batch_window_ms", "10",
             "--probe_interval_s", "0.5"]
@@ -254,16 +277,12 @@ def _spawn_router(index_loc: str, replicas: list[str], max_batch: int):
 def run_fleet_bench(args) -> int:
     """The router perf guard: one daemon vs two replicas behind the
     front door, same federated index, same loadgen."""
-    import numpy as np  # noqa: F401 — _plant_genomes needs it anyway
-
     tmp = tempfile.mkdtemp(prefix="drep_fleet_bench_")
     print(f"fleet bench: planting {args.n_genomes} synthetic genomes...",
           file=sys.stderr)
     planted = _plant_genomes(os.path.join(tmp, "g"), args.n_genomes)
-    from drep_tpu.index import build_federated
-
     index_loc = os.path.join(tmp, "idx")
-    build_federated(index_loc, planted, args.partitions, length=0)
+    _build_index(index_loc, planted, partitions=args.partitions)
     # a WIDE disjoint hot set: the single daemon's identical-request
     # coalescing must not trivialize the workload, or the ratio would
     # measure framing overhead instead of compute parallelism
@@ -278,18 +297,15 @@ def run_fleet_bench(args) -> int:
         "n_replicas": 2,
         "configs": {},
     }
-    try:
-        import jax
-
-        record["backend"] = jax.default_backend()
-    except Exception:  # noqa: BLE001
-        record["backend"] = "unknown"
 
     procs: list = []
     try:
         # -- single daemon reference --------------------------------------
-        proc, addr = _spawn_daemon(index_loc, args.max_batch)
+        proc, addr, ready = _spawn_daemon(index_loc, args.max_batch)
         procs.append(proc)
+        # what served, as the daemon itself reports it
+        record["backend"] = ready["platform"]
+        record["device_kind"] = ready["device_kind"]
         single = _loadgen(
             addr, genomes, clients=args.clients,
             requests_per_client=args.requests_per_client,
@@ -306,8 +322,8 @@ def run_fleet_bench(args) -> int:
         proc.wait(60)
 
         # -- two replicas behind the router -------------------------------
-        r1, a1 = _spawn_daemon(index_loc, args.max_batch)
-        r2, a2 = _spawn_daemon(index_loc, args.max_batch)
+        r1, a1, _ = _spawn_daemon(index_loc, args.max_batch)
+        r2, a2, _ = _spawn_daemon(index_loc, args.max_batch)
         procs += [r1, r2]
         router, raddr = _spawn_router(index_loc, [a1, a2], args.max_batch)
         procs.append(router)
@@ -378,10 +394,8 @@ def run_bench(args) -> int:
     else:
         print(f"bench: planting {args.n_genomes} synthetic genomes...", file=sys.stderr)
         planted = _plant_genomes(os.path.join(tmp, "g"), args.n_genomes)
-        from drep_tpu.index import build_from_paths
-
         index_loc = os.path.join(tmp, "idx")
-        build_from_paths(index_loc, planted, length=0)
+        _build_index(index_loc, planted)
         # queries: a disjoint synthetic HOT SET (novel + near-family mix).
         # Small on purpose — the serving scenario is many concurrent
         # users asking about a working set of genomes, which is exactly
@@ -396,19 +410,16 @@ def run_bench(args) -> int:
         "n_query_hot_set": len(genomes),
         "configs": {},
     }
-    try:
-        import jax
-
-        record["backend"] = jax.default_backend()
-    except Exception:  # noqa: BLE001
-        record["backend"] = "unknown"
 
     rpc = args.requests_per_client
     daemons: list = []
     try:
         for max_batch in (1, 16, 256):
-            proc, addr = _spawn_daemon(index_loc, max_batch)
+            proc, addr, ready = _spawn_daemon(index_loc, max_batch)
             daemons.append(proc)
+            # what served, as the daemon itself reports it
+            record["backend"] = ready["platform"]
+            record["device_kind"] = ready["device_kind"]
             with ServeClient(addr, timeout_s=600) as c:
                 st = c.status()
                 record["n_indexed"] = st["n_genomes"]
