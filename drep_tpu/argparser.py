@@ -219,9 +219,13 @@ def build_parser() -> argparse.ArgumentParser:
                               "DREP_TPU_EVENTS=on is equivalent; an explicit "
                               "flag wins over the env")
         tpu.add_argument("--profile", nargs="?", const="auto", default=None,
-                         help="record a jax.profiler trace of the compare stage "
+                         help="record a jax.profiler trace of the whole job "
                               "(optionally to the given directory; default "
-                              "<wd>/log/jax_trace). perf_counters.json is always written")
+                              "<wd>/log/jax_trace): device operations, and the "
+                              "program's own spans as drep:<span> events on the "
+                              "host plane, on one clock. Python frames are off. "
+                              "The benchmark (benchmark/tracered.py) reduces "
+                              "the same trace. perf_counters.json is always written")
 
         if with_filter:
             tax = p.add_argument_group("TAXONOMY")

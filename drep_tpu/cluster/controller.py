@@ -39,6 +39,7 @@ from drep_tpu.ingest import (
 from drep_tpu.ops.kmers import DEFAULT_K
 from drep_tpu.ops.linkage import cluster_hierarchical, single_linkage_device
 from drep_tpu.utils.logger import get_logger
+from drep_tpu.utils.profiling import counters
 from drep_tpu.workdir import WorkDirectory
 
 CLUSTER_DEFAULTS: dict[str, Any] = {
@@ -298,7 +299,8 @@ def _primary_clusters(
                 kw["primary_estimator"],
             )
         ckpt = wd.get_dir(os.path.join("data", "streaming_primary")) if wd is not None else None
-        packed = pack_sketches(gs.bottom, gs.names, gs.sketch_size)
+        with counters.span("primary/pack"):
+            packed = pack_sketches(gs.bottom, gs.names, gs.sketch_size)
         # --clusterAlg carries into the streaming path: average (default)
         # runs sparse UPGMA over the retained edge graph, single runs
         # connected components; anything else raises with guidance — no
@@ -321,7 +323,9 @@ def _primary_clusters(
             prune_min_shared=kw["prune_min_shared"],
             prune_join_chunk=kw["prune_join_chunk"],
         )
-        return labels, None, np.empty((0, 4)), _streaming_mdb(edges, gs.names), pairs_computed
+        with counters.span("mdb_build"):
+            sparse_mdb = _streaming_mdb(edges, gs.names)
+        return labels, None, np.empty((0, 4)), sparse_mdb, pairs_computed
     if kw["primary_prune"] != "off":
         # the dense engines materialize every tile by design — pruning
         # only exists on the streaming schedule (and the index's rect
@@ -341,11 +345,12 @@ def _primary_clusters(
         primary_estimator=kw["primary_estimator"],
     )
     cutoff = 1.0 - kw["P_ani"]
-    if kw["clusterAlg"] == "single" and n > 64:
-        labels = single_linkage_device(dist, cutoff)
-        link = np.empty((0, 4))
-    else:
-        labels, link = cluster_hierarchical(dist, cutoff, method=kw["clusterAlg"])
+    with counters.span("primary/linkage"):
+        if kw["clusterAlg"] == "single" and n > 64:
+            labels = single_linkage_device(dist, cutoff)
+            link = np.empty((0, 4))
+        else:
+            labels, link = cluster_hierarchical(dist, cutoff, method=kw["clusterAlg"])
     return labels, dist, link, None, n * (n - 1) // 2
 
 
@@ -381,7 +386,8 @@ def _secondary_for_cluster(
     """One primary cluster -> (Ndb rows, secondary labels 1.., linkage)."""
     engine = dispatch.get_secondary(kw["S_algorithm"])
     ani, cov = engine(gs, indices, bdb=bdb, processes=kw["processes"], mesh_shape=kw["mesh_shape"])
-    return _secondary_postprocess(gs, indices, pc, kw, ani, cov)
+    with counters.span("secondary/post"):
+        return _secondary_postprocess(gs, indices, pc, kw, ani, cov)
 
 
 # the incremental genome index (drep_tpu/index/update.py) re-runs the
@@ -391,6 +397,181 @@ def _secondary_for_cluster(
 # member set. `kw` needs S_algorithm/S_ani/cov_thresh/clusterAlg/
 # processes/mesh_shape (fill via CLUSTER_DEFAULTS).
 secondary_for_cluster = _secondary_for_cluster
+
+
+def _secondary_stage(
+    gs: GenomeSketches,
+    bdb: pd.DataFrame,
+    kw: dict[str, Any],
+    ft_cfg,
+    wd: WorkDirectory,
+    snapshot: dict[str, Any],
+    primary: np.ndarray,
+    n_primary: int,
+) -> tuple[dict[str, str], list[pd.DataFrame], dict[int, dict[str, Any]]]:
+    """The secondary (ANI) stage over every primary cluster: ({genome:
+    "P_S" secondary name}, Ndb parts in cluster order, {primary cluster:
+    its linkage and names} for Clustering_files)."""
+    from drep_tpu.cluster.secondary_ckpt import SecondaryCheckpoint
+    from drep_tpu.parallel.faulttol import pod_dead, pod_epoch, pod_live, retrying_call
+
+    secondary_names: dict[str, str] = {}
+    greedy = kw["greedy_secondary_clustering"]
+    # the batched route stays available under greedy: small clusters
+    # get their (ani, cov) from ONE device call covering many
+    # clusters, then the greedy assignment runs host-side on those
+    # matrices with identical semantics (greedy.py::
+    # greedy_assign_from_matrices) — 35k per-cluster greedy engine
+    # invocations at the 100k scale were measured pathologically
+    # slower than the batch route. Restricted to jax_ani: the greedy
+    # engine hardcodes containment-ANI numerics, so a batched variant
+    # of any OTHER algorithm must not silently substitute its numbers
+    # for small clusters only
+    batched_fn = (
+        dispatch.get_secondary_batched(kw["S_algorithm"])
+        if not greedy or kw["S_algorithm"] == "jax_ani"
+        else None
+    )
+    # one O(n) pass — a per-cluster membership scan would be
+    # O(n_clusters * n), 35M Python iterations at 10k genomes
+    members: dict[int, list[int]] = {}
+    for i, pc in enumerate(primary):
+        members.setdefault(int(pc), []).append(i)
+    multi = []
+    for pc in range(1, n_primary + 1):
+        indices = members.get(pc, [])
+        if len(indices) == 1:
+            secondary_names[gs.names[indices[0]]] = f"{pc}_1"
+        elif indices:
+            multi.append((pc, indices))
+
+    # warn_dist shapes only the Mdb retention, never secondary results;
+    # the resolved primary estimator never touches ANI numerics — keep
+    # both out of the checkpoint key so neither a warning-threshold
+    # change nor a device-count change throws away the whole ANI stage
+    sec_snapshot = {
+        k: v for k, v in snapshot.items()
+        if k not in ("warn_dist", "primary_estimator_resolved")
+    }
+    # every touch of the checkpoint store is a `secondary/checkpoint` span
+    # of its own, where the work is: a cluster is looked up just before it
+    # is computed and saved right after its post-process, so a kill loses
+    # one cluster. Two spans a cluster: thousands a job, under the 10^4 a
+    # span site may reach.
+    with counters.span("secondary/checkpoint"):
+        ckpt = SecondaryCheckpoint(
+            wd.get_dir(os.path.join("data", "secondary_checkpoints")),
+            sec_snapshot, primary, gs.names,
+        )
+    results: dict[int, tuple[pd.DataFrame, np.ndarray, np.ndarray]] = {}
+    small: list[tuple[int, list[int]]] = []
+    for pc, indices in multi:
+        m = len(indices)
+        with counters.span("secondary/checkpoint"):
+            cached = ckpt.load(pc)
+        if cached is not None:
+            results[pc] = cached  # resumed: 0 pairs counted
+        elif batched_fn is not None and m <= SMALL_CLUSTER_MAX:
+            small.append((pc, indices))  # one device call for many
+        elif greedy:
+            from drep_tpu.cluster.greedy import greedy_secondary_cluster
+
+            with counters.stage("secondary_compare"):
+                ndb, labels = greedy_secondary_cluster(gs, bdb, indices, pc, kw)
+            counters.stages["secondary_compare"].pairs += len(ndb)  # actual comparisons made
+            results[pc] = (ndb, labels, np.empty((0, 4)))
+            with counters.span("secondary/checkpoint"):
+                ckpt.save(pc, *results[pc])
+        else:
+            with counters.stage("secondary_compare", pairs=m * (m - 1) // 2):
+                # a transient device failure on one big cluster must
+                # not kill a run that already banked thousands of
+                # per-cluster checkpoint shards — bounded retries,
+                # same knobs as the streaming tile executor.
+                # local_only: the secondary engines clamp their mesh
+                # to this process's devices on pods (engines.py), so
+                # a per-process retry cannot desync the pod — a
+                # mid-batch failure retries instead of killing the run
+                results[pc] = retrying_call(
+                    lambda indices=indices, pc=pc: _secondary_for_cluster(
+                        gs, bdb, indices, pc, kw
+                    ),
+                    site="secondary_batch",
+                    config=ft_cfg,
+                    local_only=True,
+                )
+            with counters.span("secondary/checkpoint"):
+                ckpt.save(pc, *results[pc])
+
+    # flush the small clusters in row-bounded batches
+    batches: list[list[tuple[int, list[int]]]] = []
+    rows = BATCH_ROWS_MAX + 1  # force a new batch on the first item
+    for item in small:
+        if rows + len(item[1]) > BATCH_ROWS_MAX:
+            batches.append([])
+            rows = 0
+        batches[-1].append(item)
+        rows += len(item[1])
+    for batch in batches:
+        # under greedy the counter means "comparisons the greedy scan
+        # consumed" (len(ndb)) on BOTH routes, so the reported number
+        # does not depend on whether a cluster rode the batched or the
+        # per-cluster path; without greedy it is true all-pairs work
+        pairs_in_batch = (
+            0 if greedy
+            else sum(len(ix) * (len(ix) - 1) // 2 for _, ix in batch)
+        )
+        with counters.stage("secondary_compare", pairs=pairs_in_batch):
+            outs = retrying_call(
+                lambda batch=batch: batched_fn(
+                    gs, [ix for _, ix in batch], mesh_shape=kw["mesh_shape"]
+                ),
+                site="secondary_batch",
+                config=ft_cfg,
+                # process-local by the secondary-mesh contract
+                # (engines._mesh_or_none local_only): retryable on pods
+                local_only=True,
+            )
+        with counters.stage("secondary_postprocess"):
+            for (pc, indices), (ani, cov) in zip(batch, outs, strict=True):
+                if greedy:
+                    from drep_tpu.cluster.greedy import greedy_assign_from_matrices
+
+                    ndb, labels = greedy_assign_from_matrices(gs, indices, pc, kw, ani, cov)
+                    counters.stages["secondary_compare"].pairs += len(ndb)
+                    results[pc] = (ndb, labels, np.empty((0, 4)))
+                else:
+                    results[pc] = _secondary_postprocess(gs, indices, pc, kw, ani, cov)
+                with counters.span("secondary/checkpoint"):
+                    ckpt.save(pc, *results[pc])
+
+    if pod_live() is not None and ckpt.dir is not None:
+        # the pod lost member(s) somewhere before/inside the secondary
+        # loop: stamp the degradation provenance into the secondary
+        # checkpoint store's meta (same contract as the streaming and
+        # ring stores — extra keys never invalidate a resume), stamped
+        # by the lowest live process only so replicated survivors do
+        # not race the read-modify-write
+        import jax
+
+        from drep_tpu.utils.ckptmeta import stamp_checkpoint_meta
+
+        if jax.process_index() == min(pod_live()):
+            stamp_checkpoint_meta(
+                ckpt.dir,
+                {"pod_epochs": pod_epoch() + 1, "dead_processes": pod_dead()},
+            )
+    ndb_parts: list[pd.DataFrame] = []
+    files: dict[int, dict[str, Any]] = {}
+    for pc, indices in multi:  # assemble in cluster order (deterministic)
+        ndb, labels, link = results[pc]
+        ndb_parts.append(ndb)
+        files[pc] = {"linkage": link, "names": [gs.names[i] for i in indices]}
+        for idx, lab in zip(indices, labels):
+            secondary_names[gs.names[idx]] = f"{pc}_{lab}"
+    with counters.span("secondary/checkpoint"):
+        ckpt.finish(n_primary)
+    return secondary_names, ndb_parts, files
 
 
 def d_cluster_wrapper(wd: WorkDirectory, bdb: pd.DataFrame, **kwargs) -> pd.DataFrame:
@@ -489,8 +670,6 @@ def d_cluster_wrapper(wd: WorkDirectory, bdb: pd.DataFrame, **kwargs) -> pd.Data
 
         warmup_thread = threading.Thread(target=_warm, name="drep-warmup")
         warmup_thread.start()
-    from drep_tpu.utils.profiling import counters
-
     try:
         # counted so a run's stage seconds attribute the cache-load /
         # ingest wall separately from compute
@@ -517,15 +696,11 @@ def d_cluster_wrapper(wd: WorkDirectory, bdb: pd.DataFrame, **kwargs) -> pd.Data
 
     import time as _time
 
-    from drep_tpu.utils.profiling import counters
-
-    from drep_tpu.utils import telemetry
-
     t0 = _time.perf_counter()
-    # primary stage span (ISSUE 10): counters.add below keeps the totals;
-    # the span keeps WHEN the stage ran (counters.stage cannot wrap this
-    # site — pairs_done is only known after the call)
-    with telemetry.span("stage:primary_compare"):
+    # counters.add below keeps the stage's totals (counters.stage cannot
+    # wrap this site — pairs_done is only known after the call); the span
+    # is the container of the primary/* phases
+    with counters.span("stage:primary_compare"):
         primary, pdist, plink, sparse_mdb, pairs_done = _primary_clusters(
             gs, bdb, kw, wd=wd, ft_cfg=ft_cfg
         )
@@ -552,14 +727,16 @@ def d_cluster_wrapper(wd: WorkDirectory, bdb: pd.DataFrame, **kwargs) -> pd.Data
     n_primary = int(primary.max()) if n else 0
     logger.info("primary clustering: %d clusters from %d genomes", n_primary, n)
 
+    mdb = sparse_mdb
     if pdist is not None:
-        mdb = _mdb_from_dist(
-            pdist, gs.names, kw["mdb_dense_limit"], kw["P_ani"],
-            warn_dist=_warn_dist(kw),
-        )
-        wd.store_db(schemas.validate(mdb, "Mdb"), "Mdb")
-    elif sparse_mdb is not None:
-        wd.store_db(schemas.validate(sparse_mdb, "Mdb"), "Mdb")
+        with counters.span("mdb_build"):
+            mdb = _mdb_from_dist(
+                pdist, gs.names, kw["mdb_dense_limit"], kw["P_ani"],
+                warn_dist=_warn_dist(kw),
+            )
+    if mdb is not None:
+        with counters.span("tables_io"):
+            wd.store_db(schemas.validate(mdb, "Mdb"), "Mdb")
 
     clustering_files: dict[str, Any] = {
         "primary_linkage": plink,
@@ -574,162 +751,14 @@ def d_cluster_wrapper(wd: WorkDirectory, bdb: pd.DataFrame, **kwargs) -> pd.Data
         for i, g in enumerate(gs.names):
             secondary_names[g] = f"{primary[i]}_0"
     else:
-        from drep_tpu.cluster.secondary_ckpt import SecondaryCheckpoint
-
-        # controller stage open/close instants (the whole secondary loop
-        # is too branchy for one `with` block; an open with no close IS
-        # the crash evidence — a run that died inside the ANI stage)
-        telemetry.event("stage_open", stage="secondary")
-        greedy = kw["greedy_secondary_clustering"]
-        # the batched route stays available under greedy: small clusters
-        # get their (ani, cov) from ONE device call covering many
-        # clusters, then the greedy assignment runs host-side on those
-        # matrices with identical semantics (greedy.py::
-        # greedy_assign_from_matrices) — 35k per-cluster greedy engine
-        # invocations at the 100k scale were measured pathologically
-        # slower than the batch route. Restricted to jax_ani: the greedy
-        # engine hardcodes containment-ANI numerics, so a batched variant
-        # of any OTHER algorithm must not silently substitute its numbers
-        # for small clusters only
-        batched_fn = (
-            dispatch.get_secondary_batched(kw["S_algorithm"])
-            if not greedy or kw["S_algorithm"] == "jax_ani"
-            else None
-        )
-        # warn_dist shapes only the Mdb retention, never secondary results;
-        # the resolved primary estimator never touches ANI numerics — keep
-        # both out of the checkpoint key so neither a warning-threshold
-        # change nor a device-count change throws away the whole ANI stage
-        sec_snapshot = {
-            k: v for k, v in snapshot.items()
-            if k not in ("warn_dist", "primary_estimator_resolved")
-        }
-        ckpt = SecondaryCheckpoint(
-            wd.get_dir(os.path.join("data", "secondary_checkpoints")),
-            sec_snapshot, primary, gs.names,
-        )
-        # one O(n) pass — a per-cluster membership scan would be
-        # O(n_clusters * n), 35M Python iterations at 10k genomes
-        members: dict[int, list[int]] = {}
-        for i, pc in enumerate(primary):
-            members.setdefault(int(pc), []).append(i)
-        multi = []
-        for pc in range(1, n_primary + 1):
-            indices = members.get(pc, [])
-            if len(indices) == 1:
-                secondary_names[gs.names[indices[0]]] = f"{pc}_1"
-            elif indices:
-                multi.append((pc, indices))
-
-        results: dict[int, tuple[pd.DataFrame, np.ndarray, np.ndarray]] = {}
-        small: list[tuple[int, list[int]]] = []
-        for pc, indices in multi:
-            m = len(indices)
-            cached = ckpt.load(pc)
-            if cached is not None:
-                results[pc] = cached  # resumed: 0 pairs counted
-            elif batched_fn is not None and m <= SMALL_CLUSTER_MAX:
-                small.append((pc, indices))  # one device call for many
-            elif greedy:
-                from drep_tpu.cluster.greedy import greedy_secondary_cluster
-
-                with counters.stage("secondary_compare"):
-                    ndb, labels = greedy_secondary_cluster(gs, bdb, indices, pc, kw)
-                counters.stages["secondary_compare"].pairs += len(ndb)  # actual comparisons made
-                results[pc] = (ndb, labels, np.empty((0, 4)))
-                ckpt.save(pc, *results[pc])
-            else:
-                from drep_tpu.parallel.faulttol import retrying_call
-
-                with counters.stage("secondary_compare", pairs=m * (m - 1) // 2):
-                    # a transient device failure on one big cluster must
-                    # not kill a run that already banked thousands of
-                    # per-cluster checkpoint shards — bounded retries,
-                    # same knobs as the streaming tile executor.
-                    # local_only: the secondary engines clamp their mesh
-                    # to this process's devices on pods (engines.py), so
-                    # a per-process retry cannot desync the pod — a
-                    # mid-batch failure retries instead of killing the run
-                    results[pc] = retrying_call(
-                        lambda indices=indices, pc=pc: _secondary_for_cluster(
-                            gs, bdb, indices, pc, kw
-                        ),
-                        site="secondary_batch",
-                        config=ft_cfg,
-                        local_only=True,
-                    )
-                ckpt.save(pc, *results[pc])
-
-        # flush the small clusters in row-bounded batches
-        batches: list[list[tuple[int, list[int]]]] = []
-        rows = BATCH_ROWS_MAX + 1  # force a new batch on the first item
-        for item in small:
-            if rows + len(item[1]) > BATCH_ROWS_MAX:
-                batches.append([])
-                rows = 0
-            batches[-1].append(item)
-            rows += len(item[1])
-        for batch in batches:
-            # under greedy the counter means "comparisons the greedy scan
-            # consumed" (len(ndb)) on BOTH routes, so the reported number
-            # does not depend on whether a cluster rode the batched or the
-            # per-cluster path; without greedy it is true all-pairs work
-            pairs_in_batch = (
-                0 if greedy
-                else sum(len(ix) * (len(ix) - 1) // 2 for _, ix in batch)
+        # a real span where two instants used to mark the stage: an open
+        # with no close is still the crash evidence of a run that died
+        # inside the ANI stage, and the stage's self time is host time
+        # that no secondary/* phase names
+        with counters.span("stage:secondary"):
+            secondary_names, ndb_parts, clustering_files["secondary"] = _secondary_stage(
+                gs, bdb, kw, ft_cfg, wd, snapshot, primary, n_primary
             )
-            with counters.stage("secondary_compare", pairs=pairs_in_batch):
-                from drep_tpu.parallel.faulttol import retrying_call
-
-                outs = retrying_call(
-                    lambda batch=batch: batched_fn(
-                        gs, [ix for _, ix in batch], mesh_shape=kw["mesh_shape"]
-                    ),
-                    site="secondary_batch",
-                    config=ft_cfg,
-                    # process-local by the secondary-mesh contract
-                    # (engines._mesh_or_none local_only): retryable on pods
-                    local_only=True,
-                )
-            with counters.stage("secondary_postprocess"):
-                for (pc, indices), (ani, cov) in zip(batch, outs, strict=True):
-                    if greedy:
-                        from drep_tpu.cluster.greedy import greedy_assign_from_matrices
-
-                        ndb, labels = greedy_assign_from_matrices(gs, indices, pc, kw, ani, cov)
-                        counters.stages["secondary_compare"].pairs += len(ndb)
-                        results[pc] = (ndb, labels, np.empty((0, 4)))
-                    else:
-                        results[pc] = _secondary_postprocess(gs, indices, pc, kw, ani, cov)
-                    ckpt.save(pc, *results[pc])
-
-        if pod_live() is not None and ckpt.dir is not None:
-            # the pod lost member(s) somewhere before/inside the secondary
-            # loop: stamp the degradation provenance into the secondary
-            # checkpoint store's meta (same contract as the streaming and
-            # ring stores — extra keys never invalidate a resume), stamped
-            # by the lowest live process only so replicated survivors do
-            # not race the read-modify-write
-            import jax
-
-            from drep_tpu.utils.ckptmeta import stamp_checkpoint_meta
-
-            if jax.process_index() == min(pod_live()):
-                stamp_checkpoint_meta(
-                    ckpt.dir,
-                    {"pod_epochs": pod_epoch() + 1, "dead_processes": pod_dead()},
-                )
-        for pc, indices in multi:  # assemble in cluster order (deterministic)
-            ndb, labels, link = results[pc]
-            ndb_parts.append(ndb)
-            clustering_files["secondary"][pc] = {
-                "linkage": link,
-                "names": [gs.names[i] for i in indices],
-            }
-            for idx, lab in zip(indices, labels):
-                secondary_names[gs.names[idx]] = f"{pc}_{lab}"
-        ckpt.finish(n_primary)
-        telemetry.event("stage_close", stage="secondary")
 
     ndb = (
         pd.concat(ndb_parts, ignore_index=True)
@@ -780,7 +809,8 @@ def d_cluster_wrapper(wd: WorkDirectory, bdb: pd.DataFrame, **kwargs) -> pd.Data
 
         atomic_write(os.path.join(cf_dir, "clustering.pickle"), _dump)
 
-    wd.store_arguments("cluster", snapshot)
+    with counters.span("tables_io"):
+        wd.store_arguments("cluster", snapshot)
     logger.info(
         "clustering done: %d primary, %d secondary clusters",
         n_primary,

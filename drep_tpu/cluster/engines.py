@@ -31,6 +31,7 @@ from drep_tpu.cluster.dispatch import (
 from drep_tpu.ingest import GenomeSketches
 from drep_tpu.ops.containment import all_vs_all_containment, pack_scaled_sketches
 from drep_tpu.ops.minhash import all_vs_all_mash, pack_sketches
+from drep_tpu.utils.profiling import counters
 
 # below this many genomes a multi-device ring costs more in collective
 # latency + padding than it saves in compute
@@ -148,12 +149,16 @@ def mash_distance_matrix(
         # re-measured — ROADMAP D3)
         dist, _jac = all_vs_all_mash_pallas(packed, k=k)
         return dist
+    # the tile loops below ship, dispatch and read back tile by tile: the
+    # whole call is the host waiting on the device
     if estimator == "matmul" or (estimator == "auto" and packed.n >= MATMUL_MIN_GENOMES):
         from drep_tpu.ops.minhash_matmul import all_vs_all_mash_matmul
 
-        dist, _jac = all_vs_all_mash_matmul(packed, k=k)
+        with counters.span("primary/wait"):
+            dist, _jac = all_vs_all_mash_matmul(packed, k=k)
         return dist
-    dist, _jac = all_vs_all_mash(packed, k=k, tile=tile)
+    with counters.span("primary/wait"):
+        dist, _jac = all_vs_all_mash(packed, k=k, tile=tile)
     return dist
 
 
@@ -170,7 +175,8 @@ def primary_jax_mash(
     Returns (dist [N,N], similarity [N,N]) where similarity = 1 - dist
     (the Mdb convention).
     """
-    packed = pack_sketches(gs.bottom, gs.names, gs.sketch_size)
+    with counters.span("primary/pack"):
+        packed = pack_sketches(gs.bottom, gs.names, gs.sketch_size)
     dist = mash_distance_matrix(
         packed, gs.k, mesh_shape=mesh_shape, tile=tile, estimator=primary_estimator
     )
@@ -211,9 +217,23 @@ def _count_path(path: str) -> None:
     record (perf_counters.json `secondary_paths`): a measurement must be
     able to PROVE which regime (one-shot vs beyond-budget, device vs CPU)
     it exercised, not infer it from planted-vocabulary arithmetic."""
-    from drep_tpu.utils.profiling import counters
-
     counters.add_path(path)
+
+
+def _count_one_shot_call(packed, v_pad: int, cluster_sizes: list[int]) -> None:
+    """Book one one-shot call's shape beside its path (`secondary_calls`):
+    the program computes every pair of its padded rows, and only the pairs
+    inside a cluster are read."""
+    from drep_tpu.ops.containment import matmul_rows_pad
+
+    counters.add_secondary_call(
+        clusters=len(cluster_sizes),
+        rows=packed.n,
+        rows_pad=matmul_rows_pad(packed.n),
+        width=packed.ids.shape[1],
+        v_pad=v_pad,
+        useful_pairs=sum(m * (m - 1) // 2 for m in cluster_sizes),
+    )
 
 
 def containment_matrices(
@@ -264,6 +284,7 @@ def containment_matrices(
     v_pad = matmul_vocab_pad(packed)  # one scan; budget uses the REAL width
     if one_shot_fits(packed.n, v_pad):
         _count_path("one_shot")
+        _count_one_shot_call(packed, v_pad, [packed.n])
         return all_vs_all_containment_matmul(packed, k=k, v_pad=v_pad)
     mesh = _mesh_or_none(mesh_shape, packed.n, local_only=local_only)
     if mesh is not None:
@@ -295,9 +316,10 @@ def secondary_jax_ani(
     """(symmetric max-containment ani, directional cov) for a genome
     subset. `indices` index into gs.names; matrices are [m, m] in that
     order."""
-    sketches = [gs.scaled[i] for i in indices]
-    names = [gs.names[i] for i in indices]
-    packed = pack_scaled_sketches(sketches, names)
+    with counters.span("secondary/pack"):
+        sketches = [gs.scaled[i] for i in indices]
+        names = [gs.names[i] for i in indices]
+        packed = pack_scaled_sketches(sketches, names)
     return containment_matrices(packed, gs.k, mesh_shape=mesh_shape, tile=tile)
 
 
@@ -338,12 +360,14 @@ def secondary_jax_ani_batched(
     flat = [i for cl in clusters for i in cl]
     names = [gs.names[i] for i in flat]
     ani_all = cov_all = None
-    packed_l, v_extent = pack_scaled_sketches_clusterlocal(
-        [[gs.scaled[i] for i in cl] for cl in clusters], names
-    )
+    with counters.span("secondary/pack", calls=len(clusters)):
+        packed_l, v_extent = pack_scaled_sketches_clusterlocal(
+            [[gs.scaled[i] for i in cl] for cl in clusters], names
+        )
     v_pad = matmul_vocab_pad_extent(v_extent)
     if one_shot_fits(packed_l.n, v_pad):
         _count_path("one_shot_clusterlocal")
+        _count_one_shot_call(packed_l, v_pad, [len(cl) for cl in clusters])
         # full-matrix ani/cov over the cluster-local pack: diagonal
         # blocks are exact; cross blocks are id-collision garbage the
         # slicing below never reads
@@ -351,16 +375,18 @@ def secondary_jax_ani_batched(
             packed_l, k=gs.k, v_pad=v_pad
         )
     if ani_all is None:
-        packed = pack_scaled_sketches([gs.scaled[i] for i in flat], names)
+        with counters.span("secondary/pack", calls=len(clusters)):
+            packed = pack_scaled_sketches([gs.scaled[i] for i in flat], names)
         ani_all, cov_all = containment_matrices(
             packed, gs.k, mesh_shape=mesh_shape, tile=tile
         )
     out: list[tuple[np.ndarray, np.ndarray]] = []
-    o = 0
-    for cl in clusters:
-        m = len(cl)
-        out.append((ani_all[o : o + m, o : o + m], cov_all[o : o + m, o : o + m]))
-        o += m
+    with counters.span("secondary/post", calls=len(clusters)):
+        o = 0
+        for cl in clusters:
+            m = len(cl)
+            out.append((ani_all[o : o + m, o : o + m], cov_all[o : o + m, o : o + m]))
+            o += m
     return out
 
 

@@ -1166,6 +1166,7 @@ class FederatedResident:
         backoff elapsed). Returns False — the caller's PARTIAL verdict —
         when the partition is (or just became) unavailable."""
         from drep_tpu.utils import faults, telemetry
+        from drep_tpu.utils.profiling import counters
 
         slot = self._slots[pid]
         if slot.n <= 0:
@@ -1179,7 +1180,7 @@ class FederatedResident:
             return False
         probing = slot.state != PARTITION_HEALTHY
         try:
-            with telemetry.span("partition_load", pid=pid, probe=probing):
+            with counters.span("partition_load", pid=pid, probe=probing):
                 faults.fire("partition_load")
                 if slot.u_of_local is None:
                     self._load_spine(slot, self.union.names, self.union.locations)
@@ -1237,11 +1238,12 @@ class FederatedResident:
         edges are bit-identical to the union compare's slice for this
         partition. Returns (union_i, query_idx, dist) or None after
         booking a mid-compare failure (suspect/quarantine)."""
-        from drep_tpu.utils import faults, telemetry
+        from drep_tpu.utils import faults
+        from drep_tpu.utils.profiling import counters
 
         slot = self._slots[pid]
         try:
-            with telemetry.span("partition_classify", pid=pid, k=len(q_names)):
+            with counters.span("partition_classify", pid=pid, k=len(q_names)):
                 faults.fire("partition_classify")
                 return self._rect_compare(slot, q_names, q_bottoms, prune_cfg)
         except Exception as err:  # noqa: BLE001 — mid-classify containment

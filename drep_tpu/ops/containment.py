@@ -41,6 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from drep_tpu.ops.minhash import PAD_ID, PackedSketches, pad_packed_rows
+from drep_tpu.utils.profiling import counters
 
 
 def pack_scaled_sketches(
@@ -333,8 +334,6 @@ def mirror_lower_blocks(mat: np.ndarray, tb: int) -> np.ndarray:
 def _count_tri_tiles(m_pad: int, tb: int) -> None:
     """Record the triangular matmul schedule into the secondary-stage tile
     counters: B*(B+1)/2 canonical blocks of the B^2 grid."""
-    from drep_tpu.utils.profiling import counters
-
     b = m_pad // tb
     counters.add_tiles("secondary_compare", computed=b * (b + 1) // 2, total=b * b)
 
@@ -385,18 +384,24 @@ def all_vs_all_containment_matmul(
     m = packed.n
     # padding to the matmul_rows_pad target itself (>= m) gives that exact
     # row count — the same number the dispatch budget check used
-    ids, _ = pad_packed_rows(packed.ids, packed.counts, matmul_rows_pad(m))
+    with counters.span("secondary/pack"):
+        ids, _ = pad_packed_rows(packed.ids, packed.counts, matmul_rows_pad(m))
+    shape = {"rows": ids.shape[0], "width": ids.shape[1], "v_pad": v_pad}
     if triangular:
+        # transfer, dispatch and blocking readback of the one call.
         # np.array (not asarray): the host mirror mutates, and a device
         # array's __array__ view is not guaranteed writable
-        inter_pad = np.array(_intersect_matmul_tri(jnp.asarray(ids), v_pad=v_pad))
-        tb = tri_row_block(ids.shape[0])
-        mirror_lower_blocks(inter_pad, tb)
-        _count_tri_tiles(ids.shape[0], tb)
-        inter = inter_pad[:m, :m]
-    else:
+        with counters.span("secondary/wait", **shape):
+            inter_pad = np.array(_intersect_matmul_tri(jnp.asarray(ids), v_pad=v_pad))
+        with counters.span("secondary/post"):
+            tb = tri_row_block(ids.shape[0])
+            mirror_lower_blocks(inter_pad, tb)
+            _count_tri_tiles(ids.shape[0], tb)
+            return ani_cov_from_intersections(inter_pad[:m, :m], packed.counts, k)
+    with counters.span("secondary/wait", **shape):
         inter = np.asarray(_intersect_matmul(jnp.asarray(ids), v_pad=v_pad))[:m, :m]
-    return ani_cov_from_intersections(inter, packed.counts, k)
+    with counters.span("secondary/post"):
+        return ani_cov_from_intersections(inter, packed.counts, k)
 
 
 def matmul_vocab_chunk(m_pad: int) -> int:
@@ -455,9 +460,12 @@ def _indicator(ids, v_pad: int, dtype):
     scatter kernel written to replace it measured slower (0.165 s) and
     was removed (ROADMAP S2)."""
     m, s = ids.shape
-    rows = jax.lax.broadcasted_iota(jnp.int32, (m, s), 0)
-    cols = jnp.where(ids != PAD_ID, ids, v_pad)
-    return jnp.zeros((m, v_pad + 1), dtype).at[rows, cols].set(1)[:, :v_pad]
+    # the scope names the scatter (and the sort XLA lowers it through) in
+    # an operator's trace viewer; PERF.md section 3 says where it shows
+    with jax.named_scope("drep_indicator_scatter"):
+        rows = jax.lax.broadcasted_iota(jnp.int32, (m, s), 0)
+        cols = jnp.where(ids != PAD_ID, ids, v_pad)
+        return jnp.zeros((m, v_pad + 1), dtype).at[rows, cols].set(1)[:, :v_pad]
 
 
 def _int_dot(a, b_t):
@@ -465,13 +473,14 @@ def _int_dot(a, b_t):
     contracting the vocabulary axis — int32 accumulation for int8 inputs,
     f32 dot + cast for f32 inputs (exact under _indicator_dtype's width
     bound)."""
-    if a.dtype == jnp.int8:
+    with jax.named_scope("drep_indicator_dot"):
+        if a.dtype == jnp.int8:
+            return jax.lax.dot_general(
+                a, b_t, (((1,), (1,)), ((), ())), preferred_element_type=jnp.int32
+            )
         return jax.lax.dot_general(
-            a, b_t, (((1,), (1,)), ((), ())), preferred_element_type=jnp.int32
-        )
-    return jax.lax.dot_general(
-        a, b_t, (((1,), (1,)), ((), ()))
-    ).astype(jnp.int32)
+            a, b_t, (((1,), (1,)), ((), ()))
+        ).astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("v_pad", "dtype"))
@@ -790,7 +799,6 @@ def all_vs_all_containment(
     the full intersection matrix + counts on host (one shared formula,
     :func:`ani_cov_from_intersections`)."""
     from drep_tpu.ops.minhash import require_int32_ids
-    from drep_tpu.utils.profiling import counters
 
     require_int32_ids(packed.ids, "all_vs_all_containment")
     n = packed.n

@@ -235,20 +235,25 @@ def all_vs_all_mash_pallas(packed, k: int = 21) -> tuple[np.ndarray, np.ndarray]
         computed=t_blocks * (t_blocks // 2 + 1),
         total=t_blocks * t_blocks,
     )
-    a = np.full((rows, s2), PAD_ID, np.int32)
-    a[:n, :width] = ids
-    cc = np.zeros((rows, 1), np.int32)
-    cc[:n, 0] = counts
-    compact = np.asarray(
-        _mash_shared_grid_symmetric(
-            np.ascontiguousarray(a[:, ::-1]), cc, a, cc,
+    with counters.span("primary/pack"):
+        a = np.full((rows, s2), PAD_ID, np.int32)
+        a[:n, :width] = ids
+        cc = np.zeros((rows, 1), np.int32)
+        cc[:n, 0] = counts
+        a_rev = np.ascontiguousarray(a[:, ::-1])
+    # the call ships its four host operands and enqueues the one grid
+    with counters.span("primary/dispatch", rows=rows):
+        pending = _mash_shared_grid_symmetric(
+            a_rev, cc, a, cc,
             s_orig=width, r_iter=rows_per_iter(s2), interpret=_use_interpret(),
         )
-    )
-    shared = _unwrap_symmetric(compact, TILE)[:n, :n]
-    dist, j = shared_counts_to_distance(shared, counts, counts, width, k)
-    np.fill_diagonal(dist, 0.0)
-    np.fill_diagonal(j, 1.0)
+    with counters.span("primary/wait", rows=rows):
+        compact = np.asarray(pending)
+    with counters.span("primary/assemble"):
+        shared = _unwrap_symmetric(compact, TILE)[:n, :n]
+        dist, j = shared_counts_to_distance(shared, counts, counts, width, k)
+        np.fill_diagonal(dist, 0.0)
+        np.fill_diagonal(j, 1.0)
     return dist, j
 
 

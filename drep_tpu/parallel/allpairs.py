@@ -80,6 +80,11 @@ from drep_tpu.ops.minhash import PackedSketches, mash_distance_tile, pad_packed_
 from drep_tpu.parallel.mesh import AXIS, make_mesh
 from drep_tpu.utils import envknobs, telemetry
 from drep_tpu.utils.logger import get_logger
+from drep_tpu.utils.profiling import counters
+
+# the stage a ring's spans are booked to, by the kind of its tile
+# ("primary/wait", "secondary/assemble", ...)
+_STAGE_OF_KIND = {"mash": "primary", "containment": "secondary"}
 
 # monolithic-reference opt-in: explicit argument > configure_ring() >
 # env var > step-wise default
@@ -403,14 +408,16 @@ def _ring_step_shard(a_ids, a_counts, b_ids, b_counts, tile_fn, n_devices, rotat
     The tile lands as a direct program output (not a dynamic_update_slice
     into a carry), which is exactly what keeps its bits identical to a
     standalone per-block recompute — the recovery path depends on it."""
-    tiles = tile_fn(a_ids, a_counts, b_ids, b_counts)
-    if not isinstance(tiles, tuple):
-        tiles = (tiles,)
-    tiles = tuple(t.astype(jnp.float32) for t in tiles)
+    with jax.named_scope("drep_ring_tile"):
+        tiles = tile_fn(a_ids, a_counts, b_ids, b_counts)
+        if not isinstance(tiles, tuple):
+            tiles = (tiles,)
+        tiles = tuple(t.astype(jnp.float32) for t in tiles)
     if rotate:
-        perm = [(j, (j + 1) % n_devices) for j in range(n_devices)]
-        b_ids = lax.ppermute(b_ids, AXIS, perm)
-        b_counts = lax.ppermute(b_counts, AXIS, perm)
+        with jax.named_scope("drep_ring_rotate"):
+            perm = [(j, (j + 1) % n_devices) for j in range(n_devices)]
+            b_ids = lax.ppermute(b_ids, AXIS, perm)
+            b_counts = lax.ppermute(b_counts, AXIS, perm)
     return (*tiles, b_ids, b_counts)
 
 
@@ -549,8 +556,6 @@ def ring_allpairs(
     n = packed.n
     if monolithic is None:
         monolithic = ring_monolithic_default()
-    from drep_tpu.utils.profiling import counters
-
     if not monolithic:
         # honest accounting: the step-wise path reports the block tiles
         # THIS process actually computed this call — a full store resume
@@ -579,10 +584,12 @@ def _ring_allpairs_monolithic(packed, kind, k, mesh, half):
     """The original one-program ring (the bit-equality reference the
     step-wise schedule is pinned against)."""
     n_devices = mesh.devices.size
-    ids, counts = pad_packed_rows(packed.ids, packed.counts, n_devices)
-
-    ids_d = put_global(ids, NamedSharding(mesh, P(AXIS, None)))
-    counts_d = put_global(counts, NamedSharding(mesh, P(AXIS)))
+    ph = _STAGE_OF_KIND[kind]
+    with counters.span(ph + "/pack"):
+        ids, counts = pad_packed_rows(packed.ids, packed.counts, n_devices)
+    with counters.span(ph + "/put"):
+        ids_d = put_global(ids, NamedSharding(mesh, P(AXIS, None)))
+        counts_d = put_global(counts, NamedSharding(mesh, P(AXIS)))
 
     fn, _ = _ring_fn(kind, k, mesh, half)
     # bounded-retry dispatch (parallel/faulttol.py): the ring is one
@@ -595,17 +602,21 @@ def _ring_allpairs_monolithic(packed, kind, k, mesh, half):
     # unit and survives those deaths — this reference path does not.
     from drep_tpu.parallel.faulttol import build_program, retrying_call
 
-    build_program(fn, ids_d, counts_d)  # a compile error is not a device fault
-    outs = retrying_call(
-        lambda: jax.block_until_ready(fn(ids_d, counts_d)),
-        site="ring_dispatch",
-    )
-    # copy to host (np.array copies): buffers are read-only and callers
-    # fill diagonals; gather_global handles the >1-process reshard
-    gathered = [gather_global(o) for o in outs]
+    with counters.span(ph + "/dispatch"):
+        build_program(fn, ids_d, counts_d)  # a compile error is not a device fault
+    # one program: its enqueue, its run and its readback are one wait
+    with counters.span(ph + "/wait"):
+        outs = retrying_call(
+            lambda: jax.block_until_ready(fn(ids_d, counts_d)),
+            site="ring_dispatch",
+        )
+        # copy to host (np.array copies): buffers are read-only and callers
+        # fill diagonals; gather_global handles the >1-process reshard
+        gathered = [gather_global(o) for o in outs]
     if half:
-        for g in gathered:
-            mirror_half_ring(g, n_devices)
+        with counters.span(ph + "/assemble"):
+            for g in gathered:
+                mirror_half_ring(g, n_devices)
     return gathered
 
 
@@ -713,10 +724,10 @@ def _ring_allpairs_stepwise(
     )
     from drep_tpu.utils import faults
     from drep_tpu.utils.ckptmeta import atomic_savez, content_fingerprint
-    from drep_tpu.utils.profiling import counters
 
     logger = get_logger()
     cfg = ft_config if ft_config is not None else DEFAULT_CONFIG
+    ph = _STAGE_OF_KIND[kind]
     D = mesh.devices.size
     _make_tile, n_outputs = _TILE_KINDS[kind]
     pid, pc = jax.process_index(), jax.process_count()
@@ -727,7 +738,8 @@ def _ring_allpairs_stepwise(
     fp = None
     store = checkpoint_dir
     if store is not None or _RING_CONFIG["checkpoint_base"] is not None:
-        fp = content_fingerprint(packed.names, packed.counts, packed.ids)
+        with counters.span(ph + "/publish"):  # the block store's key: SHA-1 over the pack
+            fp = content_fingerprint(packed.names, packed.counts, packed.ids)
         if store is None:
             store = _ring_store_dir(kind, k, D, fp)
     if store is not None and pc > 1 and local_mesh:
@@ -792,7 +804,8 @@ def _ring_allpairs_stepwise(
         pid, pc = hb.pid, hb.pc
         resume = True
 
-    ids, counts = pad_packed_rows(packed.ids, packed.counts, D)
+    with counters.span(ph + "/pack"):
+        ids, counts = pad_packed_rows(packed.ids, packed.counts, D)
     n_pad = ids.shape[0]
     n_local = n_pad // D
     n_steps = half_ring_steps(D) if half else D
@@ -824,7 +837,8 @@ def _ring_allpairs_stepwise(
         from drep_tpu.utils.ckptmeta import open_checkpoint_dir
 
         try:
-            resume = open_checkpoint_dir(store, meta, clear_suffixes=(".npz",))
+            with counters.span(ph + "/publish"):
+                resume = open_checkpoint_dir(store, meta, clear_suffixes=(".npz",))
         except BaseException:
             if hb is not None:
                 hb.close()
@@ -872,7 +886,8 @@ def _ring_allpairs_stepwise(
         if store is None:
             return
         path = os.path.join(store, _block_name(blk[0], blk[1], epoch))
-        atomic_savez(path, **{f"o{oi}": t for oi, t in enumerate(tiles)})
+        with counters.span(ph + "/publish"):
+            atomic_savez(path, **{f"o{oi}": t for oi, t in enumerate(tiles)})
         shard_of[blk] = path
         telemetry.event(
             "blk_publish", shard=_block_name(blk[0], blk[1], epoch)
@@ -944,14 +959,14 @@ def _ring_allpairs_stepwise(
             # of ring step `tail_step` — traced as step PARTICIPATION
             # (the scaling timeline shows the joiner working the same
             # step axis as the pod), not as failure recovery
-            with telemetry.span(
+            with counters.span(
                 "ring_step", step=tail_step, steps=n_steps, joiner=True,
                 block=f"{a},{b}",
             ):
                 out = _compute_block_tiles(a, b)
             counters.add_fault("ring_join_tail_blocks")
             return out
-        with telemetry.span("ring_block_recover", a=a, b=b):
+        with counters.span("ring_block_recover", a=a, b=b):
             return _compute_block_tiles(a, b)
 
     def _compute_block_tiles(a: int, b: int) -> tuple:
@@ -972,9 +987,10 @@ def _ring_allpairs_stepwise(
             with jax.default_device(cpu):
                 return tile_jit(ids[asl], counts[asl], ids[bsl], counts[bsl])
 
-        out = ex.finalize(ex.submit(dispatch), cpu_fallback=cpu_fallback)
-        counters.add_fault("ring_blocks_recovered")
-        return tuple(np.asarray(t) for t in out)
+        with counters.span(ph + "/wait"):
+            out = ex.finalize(ex.submit(dispatch), cpu_fallback=cpu_fallback)
+            counters.add_fault("ring_blocks_recovered")
+            return tuple(np.asarray(t) for t in out)
 
     try:
         missing0 = _missing_blocks() if resume else list(schedule)
@@ -1011,8 +1027,9 @@ def _ring_allpairs_stepwise(
                 else "auto = ppermute: the fused pallas_dma step does not "
                 "compile on this toolchain (ops/pallas_ring.py)",
             )
-            ids_d = put_global(ids, NamedSharding(mesh, P(AXIS, None)))
-            counts_d = put_global(counts, NamedSharding(mesh, P(AXIS)))
+            with counters.span(ph + "/put"):
+                ids_d = put_global(ids, NamedSharding(mesh, P(AXIS, None)))
+                counts_d = put_global(counts, NamedSharding(mesh, P(AXIS)))
 
             def _step_fn(i: int):
                 """(program, fused?) of ring step `i`."""
@@ -1042,15 +1059,16 @@ def _ring_allpairs_stepwise(
                 # under EVERY comm backend
                 return _ring_step_fn(kind, k, mesh, rotate)[0], False
 
-            steps = [_step_fn(i) for i in range(n_steps)]
             # build every distinct step program BEFORE the first dispatch,
             # outside the recovery envelope (parallel/faulttol.py): a step
             # that does not trace, lower or compile ends the run with the
             # compiler's message — it must never read as a failed step
             # whose blocks get "recovered" one by one. Every step's B
             # operand has the A operand's shape and sharding.
-            for fn in {id(f): f for f, _ in steps}.values():
-                build_program(fn, ids_d, counts_d, ids_d, counts_d)
+            with counters.span(ph + "/dispatch", steps=n_steps):
+                steps = [_step_fn(i) for i in range(n_steps)]
+                for fn in {id(f): f for f, _ in steps}.values():
+                    build_program(fn, ids_d, counts_d, ids_d, counts_d)
             # only the first step's wait still absorbs anything cold
             # (executable load, first DMA): exclude exactly that one from
             # the rolling median — the TileExecutor-style warmup
@@ -1083,37 +1101,38 @@ def _ring_allpairs_stepwise(
                 return hb.maybe_check() and (len(hb.dead), len(hb.drained)) != gone
 
             pending: list[tuple[int, list]] = []
-            if elastic and _member_left():
-                aborted = "pod membership changed before the first step"
-            elif elastic:
-                # the enqueue itself can block inside the collective
-                # transport when a peer dies mid-rendezvous (observed:
-                # a survivor wedged INSIDE dispatch, never reaching the
-                # monitored finalize loop) — so the dispatch loop runs
-                # under heartbeat monitoring too; on a confirmed death
-                # everything falls to per-block recovery. Pure-JOIN
-                # admissions do NOT abandon (join_tolerant, ISSUE 15):
-                # the pod mesh is whole — the joiner works the schedule
-                # tail beside the collective instead
-                ok, res = wait_elastic(
-                    _dispatch_all,
-                    hb,
-                    collective_timeout_s(),
-                    what=f"dense ring step dispatch ({kind}, {n_steps} steps)",
-                    site="ring_dispatch",
-                    join_tolerant=True,
-                )
-                if ok:
-                    pending = res
+            with counters.span(ph + "/dispatch", steps=n_steps):
+                if elastic and _member_left():
+                    aborted = "pod membership changed before the first step"
+                elif elastic:
+                    # the enqueue itself can block inside the collective
+                    # transport when a peer dies mid-rendezvous (observed:
+                    # a survivor wedged INSIDE dispatch, never reaching the
+                    # monitored finalize loop) — so the dispatch loop runs
+                    # under heartbeat monitoring too; on a confirmed death
+                    # everything falls to per-block recovery. Pure-JOIN
+                    # admissions do NOT abandon (join_tolerant, ISSUE 15):
+                    # the pod mesh is whole — the joiner works the schedule
+                    # tail beside the collective instead
+                    ok, res = wait_elastic(
+                        _dispatch_all,
+                        hb,
+                        collective_timeout_s(),
+                        what=f"dense ring step dispatch ({kind}, {n_steps} steps)",
+                        site="ring_dispatch",
+                        join_tolerant=True,
+                    )
+                    if ok:
+                        pending = res
+                    else:
+                        aborted = "pod membership changed during step dispatch"
                 else:
-                    aborted = "pod membership changed during step dispatch"
-            else:
-                try:
-                    pending = _dispatch_all()
-                except Exception as e:  # noqa: BLE001 — recovery recomputes
-                    if not is_device_fault(e):
-                        raise
-                    aborted = e
+                    try:
+                        pending = _dispatch_all()
+                    except Exception as e:  # noqa: BLE001 — recovery recomputes
+                        if not is_device_fault(e):
+                            raise
+                        aborted = e
             for i, outs in pending:
                 if aborted is not None:
                     break
@@ -1121,28 +1140,29 @@ def _ring_allpairs_stepwise(
                 # killed at the boundary leaves its unclosed "B" as crash
                 # evidence; the elastic chaos tests SIGKILL a pod member
                 # here — with finished steps' blocks already durable
-                with telemetry.span("ring_step", step=i, steps=n_steps):
+                with counters.span("ring_step", step=i, steps=n_steps):
                     faults.fire("ring_step")
                     t0 = time.perf_counter()
                     try:
-                        if elastic:
-                            def wait(outs=outs):
-                                faults.fire("ring_dispatch")
-                                jax.block_until_ready(outs)
+                        with counters.span(ph + "/wait", step=i):
+                            if elastic:
+                                def wait(outs=outs):
+                                    faults.fire("ring_dispatch")
+                                    jax.block_until_ready(outs)
 
-                            ok, _ = wait_elastic(
-                                wait,
-                                hb,
-                                collective_timeout_s(),
-                                what=f"dense ring step {i + 1}/{n_steps} ({kind})",
-                                site="ring_dispatch",
-                                join_tolerant=True,
-                            )
-                            if not ok:
-                                aborted = "pod membership changed"
-                                break
-                        else:
-                            _wait_ready(outs, auto.effective(), "ring_dispatch", None)
+                                ok, _ = wait_elastic(
+                                    wait,
+                                    hb,
+                                    collective_timeout_s(),
+                                    what=f"dense ring step {i + 1}/{n_steps} ({kind})",
+                                    site="ring_dispatch",
+                                    join_tolerant=True,
+                                )
+                                if not ok:
+                                    aborted = "pod membership changed"
+                                    break
+                            else:
+                                _wait_ready(outs, auto.effective(), "ring_dispatch", None)
                     except WatchdogTimeout as e:
                         counters.add_fault("ring_step_failures")
                         logger.warning(
@@ -1165,7 +1185,10 @@ def _ring_allpairs_stepwise(
                         aborted = e
                         break
                     auto.note(time.perf_counter() - t0)
-                    _store_step(i, outs)
+                    # the readback of the step's tiles; their saves are
+                    # the publish spans inside it
+                    with counters.span(ph + "/wait", step=i):
+                        _store_step(i, outs)
                     if steps[i][1] and not fused_ran:
                         fused_ran = True
                         counters.set_gauge("ring_comm_pallas", 1.0)
@@ -1185,10 +1208,6 @@ def _ring_allpairs_stepwise(
                     # the remaining waits are dead weight (their tiles
                     # exist; the queued device work completes harmlessly
                     # in the background) and the dense phase ENDS here.
-                    telemetry.event(
-                        "ring_join_shortcut", after_step=i,
-                        steps=n_steps, joined=list(hb.joined),
-                    )
                     counters.add_fault("ring_join_shortcuts")
                     logger.info(
                         "dense ring: joiner(s) %s covered every block past "
@@ -1216,9 +1235,10 @@ def _ring_allpairs_stepwise(
                     f"one (configure_ring / checkpoint_dir). Original "
                     f"failure: {aborted!r}"
                 ) from (aborted if isinstance(aborted, BaseException) else None)
-            _exchange_rows_no_store(
-                mem, mesh, schedule, n_outputs, n_local, n_pad, pid, kind
-            )
+            with counters.span(ph + "/wait"):
+                _exchange_rows_no_store(
+                    mem, mesh, schedule, n_outputs, n_local, n_pad, pid, kind
+                )
 
         # per-block completion: anything still missing — resume gaps, an
         # aborted ring, a dead member's unfinished rows — is recomputed
@@ -1257,7 +1277,7 @@ def _ring_allpairs_stepwise(
                 # (reverse order, split across joiners by rank) and meets
                 # the advancing ring in the middle; the pod exits its
                 # schedule early the moment the tail is covered (the
-                # ring_join_shortcut). Any death/drain collapses everyone
+                # ring-join shortcut). Any death/drain collapses everyone
                 # back to the standard forward schedule-index deal.
                 tail_mode = joining and not hb.dead and not hb.drained
                 if tail_mode:
@@ -1318,33 +1338,34 @@ def _ring_allpairs_stepwise(
         # canonical assembly: schedule order, own blocks from memory, the
         # rest from the store; a corrupt/vanished shard is recomputed INTO
         # ITS OWN PATH (idempotent heal, streaming's contract)
-        mats = [np.zeros((n_pad, n_pad), np.float32) for _ in range(n_outputs)]
-        for blk in schedule:
-            tiles = mem.get(blk)
-            if tiles is None:
-                path = shard_of.get(blk) or (
-                    _find_block(store, *blk) if store is not None else None
-                )
-                tiles = _load_block(path, n_outputs) if path is not None else None
+        with counters.span(ph + "/assemble"):
+            mats = [np.zeros((n_pad, n_pad), np.float32) for _ in range(n_outputs)]
+            for blk in schedule:
+                tiles = mem.get(blk)
                 if tiles is None:
-                    from drep_tpu.parallel.streaming import _shard_epoch
-
-                    heal_epoch = (
-                        _shard_epoch(path)
-                        if path is not None
-                        else (hb.epoch if hb is not None else 0)
+                    path = shard_of.get(blk) or (
+                        _find_block(store, *blk) if store is not None else None
                     )
-                    tiles = _compute_block(blk)
-                    mem[blk] = tiles
-                    _save_block(blk, tiles, heal_epoch)
-            a, b = blk
-            for oi in range(n_outputs):
-                mats[oi][
-                    a * n_local : (a + 1) * n_local, b * n_local : (b + 1) * n_local
-                ] = tiles[oi]
-        if half:
-            for g in mats:
-                mirror_half_ring(g, D)
+                    tiles = _load_block(path, n_outputs) if path is not None else None
+                    if tiles is None:
+                        from drep_tpu.parallel.streaming import _shard_epoch
+
+                        heal_epoch = (
+                            _shard_epoch(path)
+                            if path is not None
+                            else (hb.epoch if hb is not None else 0)
+                        )
+                        tiles = _compute_block(blk)
+                        mem[blk] = tiles
+                        _save_block(blk, tiles, heal_epoch)
+                a, b = blk
+                for oi in range(n_outputs):
+                    mats[oi][
+                        a * n_local : (a + 1) * n_local, b * n_local : (b + 1) * n_local
+                    ] = tiles[oi]
+            if half:
+                for g in mats:
+                    mirror_half_ring(g, D)
 
         if hb is not None and hb.epoch > 0:
             if elastic:
@@ -1393,7 +1414,8 @@ def sharded_mash_allpairs(
         monolithic=monolithic, checkpoint_dir=checkpoint_dir, ft_config=ft_config,
         ring_comm=ring_comm,
     )
-    np.fill_diagonal(dist, 0.0)
+    with counters.span("primary/assemble"):
+        np.fill_diagonal(dist, 0.0)
     return dist
 
 
@@ -1417,4 +1439,5 @@ def sharded_containment_allpairs(
         monolithic=monolithic, checkpoint_dir=checkpoint_dir, ft_config=ft_config,
         ring_comm=ring_comm,
     )
-    return ani_cov_from_intersections(inter, packed.counts, k)
+    with counters.span("secondary/post"):
+        return ani_cov_from_intersections(inter, packed.counts, k)

@@ -53,6 +53,7 @@ import numpy as np
 from drep_tpu.ops.minhash import PackedSketches, mash_distance_tile, pad_packed_rows
 from drep_tpu.utils import telemetry
 from drep_tpu.utils.logger import get_logger
+from drep_tpu.utils.profiling import counters
 
 DEFAULT_BLOCK = 1024
 
@@ -87,29 +88,31 @@ def _compact_tile_jit_factory():
         jax.jit, static_argnames=("budget", "from_counts", "s_orig", "k", "diag")
     )
     def compact(out, ca, cb, cutoff, *, budget, from_counts, s_orig, k, diag):
-        if from_counts:
-            # the Pallas kernel ships raw shared counts; THE shared
-            # count->distance transform runs on device (xp=jnp) so only
-            # survivors cross the link
-            from drep_tpu.ops.pallas_mash import shared_counts_to_distance
+        with jax.named_scope("drep_tile_threshold"):
+            if from_counts:
+                # the Pallas kernel ships raw shared counts; THE shared
+                # count->distance transform runs on device (xp=jnp) so only
+                # survivors cross the link
+                from drep_tpu.ops.pallas_mash import shared_counts_to_distance
 
-            d, _j = shared_counts_to_distance(out, ca, cb, s_orig, k, xp=jnp)
-        else:
-            d = out
-        keep = d <= cutoff
-        # padding rows carry count 0 (every real genome has >= 1 k-mer);
-        # masking on counts reproduces the host path's gi/gj < n filter
-        keep &= (ca > 0)[:, None] & (cb > 0)[None, :]
-        if diag:
-            ri = jax.lax.broadcasted_iota(jnp.int32, keep.shape, 0)
-            rj = jax.lax.broadcasted_iota(jnp.int32, keep.shape, 1)
-            keep &= rj > ri  # i < j only on the diagonal tile
-        count = keep.sum(dtype=jnp.int32)
-        ki, kj = jnp.nonzero(keep, size=budget, fill_value=0)
-        # d rides along so a budget-overflow readback reuses the SAME
-        # device-computed values — the edge set must not depend on
-        # device-vs-host libm ulps at the cutoff boundary
-        return ki.astype(jnp.int32), kj.astype(jnp.int32), d[ki, kj], count, d
+                d, _j = shared_counts_to_distance(out, ca, cb, s_orig, k, xp=jnp)
+            else:
+                d = out
+            keep = d <= cutoff
+            # padding rows carry count 0 (every real genome has >= 1 k-mer);
+            # masking on counts reproduces the host path's gi/gj < n filter
+            keep &= (ca > 0)[:, None] & (cb > 0)[None, :]
+            if diag:
+                ri = jax.lax.broadcasted_iota(jnp.int32, keep.shape, 0)
+                rj = jax.lax.broadcasted_iota(jnp.int32, keep.shape, 1)
+                keep &= rj > ri  # i < j only on the diagonal tile
+        with jax.named_scope("drep_tile_compact"):
+            count = keep.sum(dtype=jnp.int32)
+            ki, kj = jnp.nonzero(keep, size=budget, fill_value=0)
+            # d rides along so a budget-overflow readback reuses the SAME
+            # device-computed values — the edge set must not depend on
+            # device-vs-host libm ulps at the cutoff boundary
+            return ki.astype(jnp.int32), kj.astype(jnp.int32), d[ki, kj], count, d
 
     return compact
 
@@ -510,7 +513,6 @@ def streaming_mash_edges(
 
     from drep_tpu.parallel.faulttol import TileExecutor, heartbeat_cadence_s
     from drep_tpu.utils import faults as _faults
-    from drep_tpu.utils.profiling import counters
 
     logger = get_logger()
     n = packed.n
@@ -524,7 +526,8 @@ def streaming_mash_edges(
     if use_pallas is None:  # override exists so CPU tests can force the
         use_pallas = pallas_mash_supported(packed.sketch_size)  # interpret path
     block = _effective_block(block, packed.sketch_size, use_pallas)
-    ids, counts = pad_packed_rows(packed.ids, packed.counts, block)
+    with counters.span("primary/pack"):
+        ids, counts = pad_packed_rows(packed.ids, packed.counts, block)
     nt = ids.shape[0]
     n_blocks = nt // block
     # rectangular schedule: first column block the walk may touch (0 =
@@ -539,7 +542,8 @@ def streaming_mash_edges(
     if use_pallas:
         from drep_tpu.ops.pallas_mash import rows_per_iter
 
-        ids_pal, ids_rev, counts_col = _pallas_tile_layout(ids, counts)
+        with counters.span("primary/pack"):
+            ids_pal, ids_rev, counts_col = _pallas_tile_layout(ids, counts)
         # env read + clamp ONCE per run: per-tile re-reads would let a
         # mid-run env change flip the jit signature and recompile between
         # tiles (thousands of dispatches per run)
@@ -636,6 +640,8 @@ def streaming_mash_edges(
     if checkpoint_dir is not None:
         from drep_tpu.utils.ckptmeta import content_fingerprint, open_checkpoint_dir
 
+        with counters.span("primary/publish"):  # the shard store's key: SHA-1 over the pack
+            fingerprint = content_fingerprint(packed.names, packed.counts, packed.ids)
         meta = {
             "n": n,
             "block": block,
@@ -645,7 +651,7 @@ def streaming_mash_edges(
             "n_blocks": n_blocks,
             # shards from a different genome set/order are meaningless even
             # at identical N (the int32 ids are a run-specific vocab remap)
-            "fingerprint": content_fingerprint(packed.names, packed.counts, packed.ids),
+            "fingerprint": fingerprint,
         }
         if first_col_block:
             # rectangular walks pin their column restriction — shards from
@@ -706,9 +712,10 @@ def streaming_mash_edges(
             # must not leak the beat writer: a zombie beat would keep this
             # process looking alive in the store forever.
             try:
-                resume = open_checkpoint_dir(
-                    checkpoint_dir, meta, clear_suffixes=(".npz",)
-                )
+                with counters.span("primary/publish"):
+                    resume = open_checkpoint_dir(
+                        checkpoint_dir, meta, clear_suffixes=(".npz",)
+                    )
             except BaseException:
                 if hb is not None:
                     hb.close()
@@ -735,16 +742,17 @@ def streaming_mash_edges(
         # into retries and CPU-recomputed tiles (parallel/faulttol.py). One
         # device suffices — a compiler verdict does not depend on which
         # chip it is for.
-        _build_tile_programs(width, block, k, cutoff, use_pallas, devices[0])
-        if use_pallas:
-            ids_on = [jax.device_put(ids_pal, dev) for dev in devices]
-            rev_on = [jax.device_put(ids_rev, dev) for dev in devices]
-            counts_on = [jax.device_put(counts_col, dev) for dev in devices]
-            counts1d_on = [jax.device_put(counts, dev) for dev in devices]
-        else:
-            ids_on = [jax.device_put(ids, dev) for dev in devices]
-            counts_on = [jax.device_put(counts, dev) for dev in devices]
-            counts1d_on = counts_on
+        with counters.span("primary/put"):
+            _build_tile_programs(width, block, k, cutoff, use_pallas, devices[0])
+            if use_pallas:
+                ids_on = [jax.device_put(ids_pal, dev) for dev in devices]
+                rev_on = [jax.device_put(ids_rev, dev) for dev in devices]
+                counts_on = [jax.device_put(counts_col, dev) for dev in devices]
+                counts1d_on = [jax.device_put(counts, dev) for dev in devices]
+            else:
+                ids_on = [jax.device_put(ids, dev) for dev in devices]
+                counts_on = [jax.device_put(counts, dev) for dev in devices]
+                counts1d_on = counts_on
 
     def _compute_stripe(bi: int, epoch: int = 0) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
         """Dispatch + finalize one row-block stripe inside a traced span
@@ -752,11 +760,23 @@ def streaming_mash_edges(
         the stripe in flight when a member died); publishes its shard
         under the epoch-stamped name when checkpointing. Returns the
         stripe's surviving edges."""
-        with telemetry.span("stripe", bi=bi, epoch=epoch):
+        with counters.span("stripe", bi=bi, epoch=epoch):
             # the elastic chaos tests SIGKILL a pod member here — at a
             # stripe boundary, with its finished shards already durable
             _faults.fire("process_death")
             return _compute_stripe_tiles(bi, epoch)
+
+    def _publish_shard(bi: int, epoch: int, s_ii, s_jj, s_dd, **note) -> None:
+        from drep_tpu.utils.ckptmeta import atomic_savez
+
+        with counters.span("primary/publish"):
+            atomic_savez(
+                os.path.join(checkpoint_dir, _shard_name(bi, epoch)),
+                ii=s_ii, jj=s_jj, dist=s_dd,
+            )
+        telemetry.event(
+            "shard_publish", shard=_shard_name(bi, epoch), edges=len(s_ii), **note
+        )
 
     def _compute_stripe_tiles(bi: int, epoch: int) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
         nonlocal pairs_computed, tiles_done, tiles_full, tiles_skipped
@@ -769,16 +789,7 @@ def streaming_mash_edges(
             tiles_full += n_blocks
             empty = (np.empty(0, np.int64), np.empty(0, np.int64), np.empty(0, np.float32))
             if checkpoint_dir is not None:
-                from drep_tpu.utils.ckptmeta import atomic_savez
-
-                atomic_savez(
-                    os.path.join(checkpoint_dir, _shard_name(bi, epoch)),
-                    ii=empty[0], jj=empty[1], dist=empty[2],
-                )
-                telemetry.event(
-                    "shard_publish", shard=_shard_name(bi, epoch), edges=0,
-                    pruned=True,
-                )
+                _publish_shard(bi, epoch, *empty, pruned=True)
             return empty
         _ensure_pack_on_devices()
         i0 = bi * block
@@ -788,106 +799,103 @@ def streaming_mash_edges(
         # points below (the dense [block, block] readback measured as the
         # composite bottleneck on slow d2h links)
         tiles = []
-        for bj in range(max(bi, first_col_block), n_blocks):
-            if occ is not None and not occ[bi, bj]:
-                tiles_skipped += 1  # no candidate pair in this tile
-                continue
-            j0 = bj * block
-            diag = j0 == i0
+        with counters.span("primary/dispatch", bi=bi):
+            for bj in range(max(bi, first_col_block), n_blocks):
+                if occ is not None and not occ[bi, bj]:
+                    tiles_skipped += 1  # no candidate pair in this tile
+                    continue
+                j0 = bj * block
+                diag = j0 == i0
 
-            def dispatch(slot, i0=i0, j0=j0, diag=diag):
-                # async dispatch on device slot `slot` (the executor's
-                # round-robin pick; retries may re-call with another slot)
-                if use_pallas:
-                    from drep_tpu.ops.pallas_mash import _mash_shared_grid
-                    from drep_tpu.ops.pallas_merge import _use_interpret
+                def dispatch(slot, i0=i0, j0=j0, diag=diag):
+                    # async dispatch on device slot `slot` (the executor's
+                    # round-robin pick; retries may re-call with another slot)
+                    if use_pallas:
+                        from drep_tpu.ops.pallas_mash import _mash_shared_grid
+                        from drep_tpu.ops.pallas_merge import _use_interpret
 
-                    out = _mash_shared_grid(
-                        rev_on[slot][i0 : i0 + block],
-                        counts_on[slot][i0 : i0 + block],
-                        ids_on[slot][j0 : j0 + block],
-                        counts_on[slot][j0 : j0 + block],
+                        out = _mash_shared_grid(
+                            rev_on[slot][i0 : i0 + block],
+                            counts_on[slot][i0 : i0 + block],
+                            ids_on[slot][j0 : j0 + block],
+                            counts_on[slot][j0 : j0 + block],
+                            s_orig=width,
+                            r_iter=r_iter,
+                            interpret=_use_interpret(),
+                        )
+                    else:
+                        out, _j = mash_distance_tile(
+                            ids_on[slot][i0 : i0 + block],
+                            counts_on[slot][i0 : i0 + block],
+                            ids_on[slot][j0 : j0 + block],
+                            counts_on[slot][j0 : j0 + block],
+                            k=k,
+                        )
+                    return compact(
+                        out,
+                        counts1d_on[slot][i0 : i0 + block],
+                        counts1d_on[slot][j0 : j0 + block],
+                        cutoff,
+                        budget=budget,
+                        from_counts=use_pallas,
                         s_orig=width,
-                        r_iter=r_iter,
-                        interpret=_use_interpret(),
-                    )
-                else:
-                    out, _j = mash_distance_tile(
-                        ids_on[slot][i0 : i0 + block],
-                        counts_on[slot][i0 : i0 + block],
-                        ids_on[slot][j0 : j0 + block],
-                        counts_on[slot][j0 : j0 + block],
                         k=k,
+                        diag=diag,
                     )
-                return compact(
-                    out,
-                    counts1d_on[slot][i0 : i0 + block],
-                    counts1d_on[slot][j0 : j0 + block],
-                    cutoff,
-                    budget=budget,
-                    from_counts=use_pallas,
-                    s_orig=width,
-                    k=k,
-                    diag=diag,
-                )
 
-            tiles.append((j0, diag, ft.submit(dispatch)))
-            pairs_computed += _real_pairs_in_tile(i0, j0, block, n)
-            tiles_done += 1
+                tiles.append((j0, diag, ft.submit(dispatch)))
+                pairs_computed += _real_pairs_in_tile(i0, j0, block, n)
+                tiles_done += 1
         tiles_full += n_blocks
 
         row_ii: list[np.ndarray] = []
         row_jj: list[np.ndarray] = []
         row_dd: list[np.ndarray] = []
-        for j0, diag, pending in tiles:
-            ki_d, kj_d, dd_d, cnt_d, d_full = ft.finalize(
-                pending,
-                cpu_fallback=lambda i0=i0, j0=j0, diag=diag: _cpu_fallback_tile(
-                    ids, counts, i0, j0, block, k, cutoff, diag
-                ),
-            )
-            cnt = int(cnt_d)  # sync point for this tile (scalar)
-            if cnt <= budget:
-                ki = np.asarray(ki_d)[:cnt]
-                kj = np.asarray(kj_d)[:cnt]
-                if cnt:
-                    # device-side masks already excluded pad rows and the
-                    # diagonal tile's lower triangle
-                    row_ii.append(ki.astype(np.int64) + i0)
-                    row_jj.append(kj.astype(np.int64) + j0)
-                    row_dd.append(np.asarray(dd_d)[:cnt].astype(np.float32))
-                continue
-            # budget overflow (denser tile than the edge model assumes):
-            # fall back to reading back the SAME device-computed dense
-            # distances — correctness never depends on the budget, only
-            # readback bytes do, and the edge set cannot shift by
-            # device-vs-host libm ulps at the cutoff boundary
-            d = np.asarray(d_full)
-            keep = d <= cutoff
-            if j0 == i0:
-                keep &= np.triu(np.ones_like(keep, dtype=bool), 1)  # i < j only
-            ki, kj = np.nonzero(keep)
-            if len(ki):
-                gi = ki + i0
-                gj = kj + j0
-                valid = (gi < n) & (gj < n)
-                row_ii.append(gi[valid])
-                row_jj.append(gj[valid])
-                row_dd.append(d[ki, kj][valid].astype(np.float32))
+        # the host blocks here: each tile's watchdog-bounded wait, its
+        # survivor count (a scalar sync) and the readback of its edges
+        with counters.span("primary/wait", bi=bi):
+            for j0, diag, pending in tiles:
+                ki_d, kj_d, dd_d, cnt_d, d_full = ft.finalize(
+                    pending,
+                    cpu_fallback=lambda i0=i0, j0=j0, diag=diag: _cpu_fallback_tile(
+                        ids, counts, i0, j0, block, k, cutoff, diag
+                    ),
+                )
+                cnt = int(cnt_d)  # sync point for this tile (scalar)
+                if cnt <= budget:
+                    ki = np.asarray(ki_d)[:cnt]
+                    kj = np.asarray(kj_d)[:cnt]
+                    if cnt:
+                        # device-side masks already excluded pad rows and the
+                        # diagonal tile's lower triangle
+                        row_ii.append(ki.astype(np.int64) + i0)
+                        row_jj.append(kj.astype(np.int64) + j0)
+                        row_dd.append(np.asarray(dd_d)[:cnt].astype(np.float32))
+                    continue
+                # budget overflow (denser tile than the edge model assumes):
+                # fall back to reading back the SAME device-computed dense
+                # distances — correctness never depends on the budget, only
+                # readback bytes do, and the edge set cannot shift by
+                # device-vs-host libm ulps at the cutoff boundary
+                d = np.asarray(d_full)
+                keep = d <= cutoff
+                if j0 == i0:
+                    keep &= np.triu(np.ones_like(keep, dtype=bool), 1)  # i < j only
+                ki, kj = np.nonzero(keep)
+                if len(ki):
+                    gi = ki + i0
+                    gj = kj + j0
+                    valid = (gi < n) & (gj < n)
+                    row_ii.append(gi[valid])
+                    row_jj.append(gj[valid])
+                    row_dd.append(d[ki, kj][valid].astype(np.float32))
 
-        s_ii = np.concatenate(row_ii) if row_ii else np.empty(0, np.int64)
-        s_jj = np.concatenate(row_jj) if row_jj else np.empty(0, np.int64)
-        s_dd = np.concatenate(row_dd) if row_dd else np.empty(0, np.float32)
+        with counters.span("primary/assemble"):
+            s_ii = np.concatenate(row_ii) if row_ii else np.empty(0, np.int64)
+            s_jj = np.concatenate(row_jj) if row_jj else np.empty(0, np.int64)
+            s_dd = np.concatenate(row_dd) if row_dd else np.empty(0, np.float32)
         if checkpoint_dir is not None:
-            from drep_tpu.utils.ckptmeta import atomic_savez
-
-            atomic_savez(
-                os.path.join(checkpoint_dir, _shard_name(bi, epoch)),
-                ii=s_ii, jj=s_jj, dist=s_dd,
-            )
-            telemetry.event(
-                "shard_publish", shard=_shard_name(bi, epoch), edges=len(s_ii)
-            )
+            _publish_shard(bi, epoch, s_ii, s_jj, s_dd)
         return s_ii, s_jj, s_dd
 
     try:
@@ -965,9 +973,10 @@ def streaming_mash_edges(
             # tile latencies (--dispatch_timeout left at 0) — reported so
             # an operator can pin an explicit value from evidence
             counters.set_gauge("derived_dispatch_timeout_s", round(derived, 3))
-        ii = np.concatenate(all_ii) if all_ii else np.empty(0, np.int64)
-        jj = np.concatenate(all_jj) if all_jj else np.empty(0, np.int64)
-        dd = np.concatenate(all_dd) if all_dd else np.empty(0, np.float32)
+        with counters.span("primary/assemble"):
+            ii = np.concatenate(all_ii) if all_ii else np.empty(0, np.int64)
+            jj = np.concatenate(all_jj) if all_jj else np.empty(0, np.int64)
+            dd = np.concatenate(all_dd) if all_dd else np.empty(0, np.float32)
         if pc > 1 and not elastic:
             ii, jj, dd, pairs_computed = _allgather_edges(ii, jj, dd, pairs_computed)
         return ii, jj, dd, pairs_computed
@@ -1410,23 +1419,26 @@ def streaming_primary_clusters(
     if primary_prune == "lsh":
         from drep_tpu.ops.lsh import build_candidates
 
-        prune = build_candidates(
-            packed, keep=keep, k=k, bands=prune_bands,
-            min_shared=prune_min_shared, join_chunk=prune_join_chunk,
-        )
+        with counters.span("primary/lsh_join"):
+            prune = build_candidates(
+                packed, keep=keep, k=k, bands=prune_bands,
+                min_shared=prune_min_shared, join_chunk=prune_join_chunk,
+            )
     ii, jj, dd, pairs_computed = streaming_mash_edges(
         packed, k, keep, block=block, checkpoint_dir=checkpoint_dir,
         ft_config=ft_config, prune=prune,
     )
     if cluster_alg == "single":
-        in_cluster = dd <= cutoff
-        labels = connected_components(packed.n, ii[in_cluster], jj[in_cluster])
+        with counters.span("primary/linkage"):
+            in_cluster = dd <= cutoff
+            labels = connected_components(packed.n, ii[in_cluster], jj[in_cluster])
     else:
         from drep_tpu.ops.linkage import sparse_average_linkage
 
-        labels, approx_merges = sparse_average_linkage(
-            packed.n, ii, jj, dd, cutoff, keep
-        )
+        with counters.span("primary/linkage"):
+            labels, approx_merges = sparse_average_linkage(
+                packed.n, ii, jj, dd, cutoff, keep
+            )
         if approx_merges:
             get_logger().warning(
                 "streaming average linkage: %d accepted merges involved pairs "

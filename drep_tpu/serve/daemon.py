@@ -154,7 +154,7 @@ class IndexServer:
         """Load the index (once), bind the listener, start the acceptor
         and generation-poller threads. Returns the bound address."""
         t0 = time.monotonic()
-        with telemetry.span("serve_load", index=self.cfg.index_loc):
+        with counters.span("serve_load", index=self.cfg.index_loc):
             self._resident = load_resident_index(
                 self.cfg.index_loc, resident_mb=self.cfg.resident_mb
             )
@@ -301,7 +301,7 @@ class IndexServer:
         self._batch_deadline = min(deadlines) if deadlines else None
         try:
             with counters.stage("serve_batch"):
-                with telemetry.span(
+                with counters.span(
                     "serve_batch", n=len(batch), unique=len(paths), generation=gen
                 ):
                     with self._compute_lock:
@@ -412,7 +412,7 @@ class IndexServer:
                 continue
             try:
                 t0 = time.monotonic()
-                with telemetry.span("generation_load", generation=gen):
+                with counters.span("generation_load", generation=gen):
                     fresh = load_resident_index(
                         self.cfg.index_loc, resident_mb=self.cfg.resident_mb
                     )

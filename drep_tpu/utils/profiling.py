@@ -7,8 +7,18 @@ tracked here as a first-class counter: every compare stage records how many
 pairwise comparisons it performed and how long it took, and the totals are
 written to ``<wd>/log/perf_counters.json`` at the end of every run.
 
-``trace(dir)`` wraps a block in ``jax.profiler.trace`` for TensorBoard-level
-kernel timelines (``--profile`` on the CLI).
+``Counters.span(name)`` is THE front door for a span (ISSUE 24): one
+context manager, three sinks. It always accumulates into the record's
+``phases`` section (seconds, self seconds = duration less what child spans
+cover, calls, per thread); when ``jax`` is already imported it enters
+``jax.profiler.TraceAnnotation("drep:<name>")``, so inside a profiler session
+the span lands on ``/host:CPU`` on the profiler's own clock, beside the device
+trace; and under ``--events on`` it writes the JSONL ``B``/``E`` lines
+(utils/telemetry.py). Names are stable strings: what varies goes in the
+keyword arguments.
+
+``trace(dir)`` wraps a job in ``jax.profiler.trace`` with the options the
+benchmark harness uses (``--profile`` on the CLI).
 """
 
 from __future__ import annotations
@@ -16,12 +26,16 @@ from __future__ import annotations
 import contextlib
 import json
 import os
+import sys
 import threading
 import time
 from dataclasses import dataclass, field
 from typing import Any, Iterator
 
 from drep_tpu.utils import telemetry
+
+# distinct one-shot program shapes the record lists (Counters.add_secondary_call)
+SECONDARY_SHAPES_MAX = 64
 
 
 @dataclass
@@ -41,6 +55,56 @@ class _Stage:
     # record reports both the honest dense totals AND how much the sparse
     # schedule saved.
     tiles_skipped: int = 0
+
+
+@dataclass
+class _Phase:
+    seconds: float = 0.0
+    self_seconds: float = 0.0
+    calls: int = 0
+
+
+def _on_main_thread() -> bool:
+    return threading.current_thread() is threading.main_thread()
+
+
+class _Span:
+    """One open span of :meth:`Counters.span`. Its frame sits on the
+    opening thread's stack; on exit its duration is booked to its phase and
+    credited to the parent frame, whose self time is what no child covers."""
+
+    __slots__ = ("_counters", "name", "_calls", "_args", "_sinks", "_t0", "_child")
+
+    def __init__(self, counters: "Counters", name: str, calls: int, args: dict) -> None:
+        self._counters = counters
+        self.name = name
+        self._calls = calls
+        self._args = args
+
+    def __enter__(self) -> "_Span":
+        self._sinks = [telemetry.Span(self.name, self._args)]
+        # the profiler's host plane, only where JAX is loaded already: a
+        # span must never be what imports it (index route / supervise)
+        prof = getattr(sys.modules.get("jax"), "profiler", None)
+        if prof is not None:
+            self._sinks.append(prof.TraceAnnotation("drep:" + self.name, **self._args))
+        self._child = 0.0
+        self._counters._stack().append(self)
+        self._t0 = time.perf_counter()
+        for sink in self._sinks:
+            sink.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        for sink in reversed(self._sinks):
+            sink.__exit__(exc_type, exc, tb)
+        dur = time.perf_counter() - self._t0
+        stack = self._counters._stack()
+        stack.pop()
+        if stack:
+            stack[-1]._child += dur
+        self._counters._book(self.name, dur, dur - self._child, self._calls)
+        return False
 
 
 class Histogram:
@@ -157,13 +221,41 @@ class Counters:
     # cpu_tiles — cluster/engines.py): a run's record must say which
     # regime it exercised, not leave it to be inferred from shapes
     paths: dict[str, int] = field(default_factory=dict)
+    # where the host's time went (ISSUE 24): every span of the front door
+    # (:meth:`span`), keyed (name, on the main thread?)
+    phases: dict[tuple[str, bool], _Phase] = field(default_factory=dict)
+    # the one-shot secondary calls by shape (cluster/engines.py): how many
+    # calls, clusters and rows went through each [rows_pad, width] x v_pad
+    # program, and how many of the rows_pad^2/2 pairs it computed were read
+    secondary_calls: dict[tuple[int, int, int], dict[str, int]] = field(default_factory=dict)
+    _open: threading.local = field(default_factory=threading.local, repr=False, compare=False)
+    _lock: threading.Lock = field(default_factory=threading.Lock, repr=False, compare=False)
+
+    def span(self, name: str, calls: int = 1, **args) -> _Span:
+        """THE front door for a span (module docstring): accumulates into
+        ``phases``, annotates the profiler's host plane, writes the JSONL
+        B/E lines. `name` is a stable string; `args` carry what varies.
+        `calls` books one span round a loop as that many units of work."""
+        return _Span(self, name, calls, args)
+
+    def _stack(self) -> list:
+        stack = getattr(self._open, "stack", None)
+        if stack is None:
+            stack = self._open.stack = []
+        return stack
+
+    def _book(self, name: str, seconds: float, self_seconds: float, calls: int) -> None:
+        with self._lock:
+            ph = self.phases.setdefault((name, _on_main_thread()), _Phase())
+            ph.seconds += seconds
+            ph.self_seconds += self_seconds
+            ph.calls += calls
 
     @contextlib.contextmanager
     def stage(self, name: str, pairs: int = 0) -> Iterator[None]:
         t0 = time.perf_counter()
-        # the one hook that traces every counted stage block (controller
-        # stage open/close, ISSUE 10) — a no-op object when events are off
-        with telemetry.span("stage:" + name):
+        # every counted stage block is a span of the front door
+        with self.span("stage:" + name):
             try:
                 yield
             finally:
@@ -204,6 +296,27 @@ class Counters:
     def add_path(self, name: str) -> None:
         """Count one secondary compare call served by kernel path `name`."""
         self.paths[name] = self.paths.get(name, 0) + 1
+
+    def add_secondary_call(
+        self, clusters: int, rows: int, rows_pad: int, width: int, v_pad: int,
+        useful_pairs: int,
+    ) -> None:
+        """Book one one-shot secondary call: `clusters` and `rows` went
+        through a [`rows_pad`, `width`] x `v_pad` program that computes
+        every pair of its padded rows, of which `useful_pairs` (the pairs
+        inside a cluster) are read. Grouped by shape, so the list is as
+        long as the run has distinct programs; past SECONDARY_SHAPES_MAX
+        of them the rest is summed per `rows_pad` with width and v_pad 0."""
+        key = (rows_pad, width, v_pad)
+        if key not in self.secondary_calls and len(self.secondary_calls) >= SECONDARY_SHAPES_MAX:
+            key = (rows_pad, 0, 0)
+        ent = self.secondary_calls.setdefault(
+            key, {"calls": 0, "clusters": 0, "rows": 0, "useful_pairs": 0}
+        )
+        ent["calls"] += 1
+        ent["clusters"] += int(clusters)
+        ent["rows"] += int(rows)
+        ent["useful_pairs"] += int(useful_pairs)
 
     def set_gauge(self, name: str, value: float) -> None:
         """Record a derived operational value (last write wins)."""
@@ -303,7 +416,42 @@ class Counters:
             }
         if self.paths:
             out["secondary_paths"] = dict(sorted(self.paths.items()))
+        if self.secondary_calls:
+            out["secondary_calls"] = [
+                {"rows_pad": k[0], "width": k[1], "v_pad": k[2], **v}
+                for k, v in sorted(self.secondary_calls.items())
+            ]
+        phases = self._phases_report()
+        if phases:
+            out["phases"] = phases
         return out
+
+    def _phases_report(self) -> dict[str, dict[str, Any]]:
+        """``{name: {"seconds", "self_seconds", "calls", "thread"}}``. A
+        span of another thread than the main one is kept apart under
+        ``<name>@other``. The calling thread's OPEN spans are counted as
+        far as they have come: the record is written inside ``job``."""
+        now = time.perf_counter()
+        with self._lock:
+            acc = {k: [p.seconds, p.self_seconds, p.calls] for k, p in self.phases.items()}
+        main = _on_main_thread()
+        inner = 0.0  # duration so far of the open span one level in
+        for sp in reversed(self._stack()):
+            dur = now - sp._t0
+            a = acc.setdefault((sp.name, main), [0.0, 0.0, 0])
+            a[0] += dur
+            a[1] += dur - sp._child - inner
+            a[2] += sp._calls
+            inner = dur
+        return {
+            name if on_main else name + "@other": {
+                "seconds": round(sec, 4),
+                "self_seconds": round(self_sec, 4),
+                "calls": calls,
+                "thread": "main" if on_main else "other",
+            }
+            for (name, on_main), (sec, self_sec, calls) in sorted(acc.items())
+        }
 
     def write(self, log_dir: str, device: bool = True) -> str:
         # atomic (utils/durableio.py): a SIGKILL mid-write must not leave
@@ -327,6 +475,9 @@ class Counters:
         self.epoch_history.clear()
         self.hists.clear()
         self.paths.clear()
+        self.secondary_calls.clear()
+        with self._lock:
+            self.phases.clear()  # a span open now stays open and books when it closes
 
 
 counters = Counters()  # the process-global instance used by the pipeline
@@ -465,12 +616,19 @@ def stop_metrics_flush(final: bool = False) -> None:
 
 @contextlib.contextmanager
 def trace(trace_dir: str | None) -> Iterator[None]:
-    """jax.profiler.trace when a directory is given; no-op otherwise."""
+    """jax.profiler.trace round a whole job when a directory is given;
+    no-op otherwise. The options are the benchmark harness's
+    (benchmark/run.py), so an operator's trace is the trace the benchmark
+    reduces: Python frames off (they swamp a whole job's trace), host
+    TraceMe events on — the ``drep:<span>`` events of :meth:`Counters.span`."""
     if not trace_dir:
         yield
         return
     import jax
 
     os.makedirs(trace_dir, exist_ok=True)
-    with jax.profiler.trace(trace_dir):
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    with jax.profiler.trace(trace_dir, profiler_options=opts):
         yield

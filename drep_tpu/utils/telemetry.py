@@ -16,10 +16,12 @@ the re-deal took — was unrecoverable. This module is the forensic record:
   fresh); ``mono``/``wall`` are ``time.monotonic()``/``time.time()``
   seconds — in-process durations come from ``mono``, cross-process
   ordering from ``wall`` (pod members share a host/fleet clock).
-- **spans** (``ph`` "B" at enter, "E" at exit with a ``dur`` arg) wrap
-  every boundary the system already treats as meaningful: controller
-  stage open/close (profiling.Counters.stage emits one per stage block),
-  streaming stripe compute, dense-ring steps, per-block recovery. A "B"
+- **spans** (``ph`` "B" at enter, "E" at exit with a ``dur`` arg): this
+  log is one of the three sinks of the program's one span front door,
+  ``profiling.Counters.span`` (the job's record and the profiler's host
+  plane are the others), so every span of the program is here: the
+  ``job`` root, the stages, streaming stripes, dense-ring steps, per-block
+  recovery, and the primary/* and secondary/* phases inside them. A "B"
   with no matching "E" IS the crash evidence — what was in flight when
   the process died.
 - **point events** (``ph`` "i") mark faults and protocol verdicts: every
@@ -34,7 +36,7 @@ at most the final line, which readers (tools/trace_report.py,
 tools/scrub_store.py) treat as expected crash evidence, never damage.
 
 **Zero overhead when off** (the default): every emit path starts with one
-falsy dict lookup, ``span()`` returns a shared no-op context manager, and
+falsy dict lookup (a :class:`Span` gates each of its two lines so), and
 no file — not even an empty one — is ever created. Pinned by
 tests/test_perf_guards.py (<= 3% on the 528-tile warm checkpointed pass
 with events ON; zero files with events off).
@@ -86,7 +88,6 @@ _STATE: dict[str, Any] = {
     "run": None,
     "epoch": 0,
     "sink": None,
-    "path": None,
 }
 _LOCK = threading.RLock()
 
@@ -115,11 +116,6 @@ def configure(
 
 def enabled() -> bool:
     return _STATE["enabled"]
-
-
-def events_path() -> str | None:
-    """The file this process is (or would be) writing, once opened."""
-    return _STATE["path"]
 
 
 def configured_log_dir() -> str | None:
@@ -151,7 +147,6 @@ def set_pid(pid: int) -> None:
     close()
     with _LOCK:
         _STATE["pid"] = int(pid)
-        _STATE["path"] = None
 
 
 def _load_run_id(log_dir: str) -> str:
@@ -206,7 +201,6 @@ def _sink():
             _STATE["enabled"] = False
             return None
         _STATE["sink"] = s
-        _STATE["path"] = path
         return s
 
 
@@ -247,10 +241,13 @@ def event(ev: str, **args) -> None:
     _emit(ev, "i", args or None)
 
 
-class _Span:
+class Span:
     """B-at-enter / E-at-exit (E carries ``dur`` from the monotonic
     clock). The B record is deliberate redundancy: it is the crash
-    evidence when the process dies inside the span."""
+    evidence when the process dies inside the span. Each line is gated
+    when it is due: with tracing off a span costs two dict lookups and
+    creates no file. The program reaches this class through the front door
+    alone (``profiling.Counters.span``), so no span is on one clock only."""
 
     __slots__ = ("ev", "args", "_t0")
 
@@ -258,39 +255,21 @@ class _Span:
         self.ev = ev
         self.args = args
 
-    def __enter__(self) -> "_Span":
+    def __enter__(self) -> "Span":
         self._t0 = time.monotonic()
-        _emit(self.ev, "B", self.args or None)
+        if _STATE["enabled"]:
+            _emit(self.ev, "B", self.args or None)
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        if not _STATE["enabled"]:
+            return False
         args = dict(self.args)
         args["dur"] = round(time.monotonic() - self._t0, 6)
         if exc_type is not None:
             args["error"] = exc_type.__name__
         _emit(self.ev, "E", args)
         return False
-
-
-class _NoopSpan:
-    __slots__ = ()
-
-    def __enter__(self) -> "_NoopSpan":
-        return self
-
-    def __exit__(self, exc_type, exc, tb) -> bool:
-        return False
-
-
-_NOOP = _NoopSpan()
-
-
-def span(ev: str, **args):
-    """Context manager tracing one span. When tracing is off this returns
-    a shared no-op object — the zero-overhead contract's span half."""
-    if not _STATE["enabled"]:
-        return _NOOP
-    return _Span(ev, args)
 
 
 def close() -> None:

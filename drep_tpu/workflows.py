@@ -19,9 +19,12 @@ from drep_tpu.workdir import WorkDirectory
 from drep_tpu.errors import UserInputError
 
 
-def _init(
-    wd_loc: str, genomes: list[str], events: str | bool | None = None
-) -> tuple[WorkDirectory, pd.DataFrame]:
+def _bring_up(wd_loc: str, events: str | bool | None = None) -> WorkDirectory:
+    """What has to be in place before the `job` span and the profiler
+    open: the distributed runtime (it must come before ANY backend use, and
+    ``jax.profiler.start_trace`` initialises the backend), the workdir, the
+    logger, the event log and fresh per-run state. `job` opens right after,
+    so its `B` line lands in THIS job's event log."""
     # multi-host bring-up must precede any backend use (no-op single-host)
     from drep_tpu.parallel.mesh import initialize_distributed
     from drep_tpu.utils.xla_cache import enable_persistent_cache
@@ -45,38 +48,48 @@ def _init(
     start_metrics_flush(wd.get_dir("log"))
     # fresh per-run state (library users may call several workflows per process)
     from drep_tpu.cluster.anim import reset_run_state
-    from drep_tpu.utils.profiling import counters, device_record
+    from drep_tpu.utils.profiling import counters
 
     counters.reset()
     reset_run_state()
+    return wd
+
+
+def _load_bdb(wd: WorkDirectory, genomes: list[str]) -> pd.DataFrame:
+    """The first work inside `job`: name the device, store or reload Bdb."""
+    from drep_tpu.utils.profiling import counters, device_record
+
     get_logger().info("device: %s", device_record())
-    if genomes:
-        bdb = make_bdb(genomes)
-        wd.store_db(bdb, "Bdb")
-    elif wd.hasDb("Bdb"):
-        bdb = wd.get_db("Bdb")  # resume from an existing workdir
-    else:
-        raise UserInputError("no genomes given and workdir has no stored Bdb")
-    return wd, bdb
+    with counters.span("tables_io"):
+        if genomes:
+            bdb = make_bdb(genomes)
+            wd.store_db(bdb, "Bdb")
+        elif wd.hasDb("Bdb"):
+            bdb = wd.get_db("Bdb")  # resume from an existing workdir
+        else:
+            raise UserInputError("no genomes given and workdir has no stored Bdb")
+    return bdb
 
 
-def _trace_dir(wd: WorkDirectory, profile) -> str | None:
+def _trace_dir(wd_loc: str, profile) -> str | None:
     if not profile:
         return None
-    return profile if isinstance(profile, str) and profile != "auto" else wd.get_dir(
-        "log/jax_trace"
-    )
+    if isinstance(profile, str) and profile != "auto":
+        return profile
+    import os
+
+    return os.path.join(wd_loc, "log", "jax_trace")
 
 
 def _finish_counters(wd: WorkDirectory) -> None:
-    from drep_tpu.utils import telemetry
+    """Write the job's record. Called inside the `job` span, which is
+    counted as far as it has come; the wrappers close the event log once
+    the span has written its end."""
     from drep_tpu.utils.profiling import counters, stop_metrics_flush
 
     stop_metrics_flush(final=True)
     rep = counters.report()
     path = counters.write(wd.get_dir("log"))
-    telemetry.event("run_finished", pairs=rep["total"]["pairs"])
-    telemetry.close()
     total = rep["total"]
     get_logger().info(
         "perf: %d pairs in %.2fs = %s pairs/sec/chip (%d chip(s)) -> %s",
@@ -88,22 +101,29 @@ def _finish_counters(wd: WorkDirectory) -> None:
 def compare_wrapper(wd_loc: str, genomes: list[str] | None = None, **kwargs) -> pd.DataFrame:
     """`compare`: cluster + evaluate + analyze. Returns Cdb."""
     from drep_tpu.utils import telemetry
-    from drep_tpu.utils.profiling import trace
+    from drep_tpu.utils.profiling import counters, trace
 
-    wd, bdb = _init(wd_loc, genomes or [], events=kwargs.pop("events", None))
-    with trace(_trace_dir(wd, kwargs.pop("profile", None))):
-        with telemetry.span("stage:cluster"):
+    # `job` is the root span, from the bring-up to the record's write: its
+    # self time is the job's unattributed host time. --profile wraps it whole.
+    wd = _bring_up(wd_loc, events=kwargs.pop("events", None))
+    with trace(_trace_dir(wd_loc, kwargs.pop("profile", None))), counters.span("job"):
+        bdb = _load_bdb(wd, genomes or [])
+        with counters.span("stage:cluster"):
             cdb = d_cluster_wrapper(wd, bdb, **kwargs)
-    # per-genome stats for downstream stages come from the ingest pass's Gdb
-    # (one FASTA read per genome, not a second parse)
-    wd.store_db(wd.get_db("Gdb")[["genome", "length", "N50", "contigs"]], "genomeInformation")
-    with telemetry.span("stage:evaluate"):
-        d_evaluate_wrapper(wd, **kwargs)
-    if not kwargs.get("skip_plots", False):
-        from drep_tpu.analyze import plot_all
+        # per-genome stats for downstream stages come from the ingest pass's Gdb
+        # (one FASTA read per genome, not a second parse)
+        with counters.span("tables_io"):
+            wd.store_db(
+                wd.get_db("Gdb")[["genome", "length", "N50", "contigs"]], "genomeInformation"
+            )
+        with counters.span("stage:evaluate"):
+            d_evaluate_wrapper(wd, **kwargs)
+        if not kwargs.get("skip_plots", False):
+            from drep_tpu.analyze import plot_all
 
-        plot_all(wd)
-    _finish_counters(wd)
+            plot_all(wd)
+        _finish_counters(wd)
+    telemetry.close()
     get_logger().info("compare finished: %d genomes, %d secondary clusters",
                       len(cdb), cdb["secondary_cluster"].nunique())
     return cdb
@@ -112,7 +132,7 @@ def compare_wrapper(wd_loc: str, genomes: list[str] | None = None, **kwargs) -> 
 def _init_index(index_loc: str, write_logs: bool = True) -> str | None:
     """Service-mode session setup: logging under the index's own log dir,
     persistent compile cache, fresh counters — the index equivalents of
-    `_init`, minus workdir/Bdb machinery (the store IS the state).
+    `_bring_up`, minus workdir/Bdb machinery (the store IS the state).
     `write_logs=False` (classify) keeps logging console-only: classify is
     read-only by contract, and even a log line under the index dir would
     violate the nothing-written assertion its tests pin. Returns the log
@@ -170,37 +190,39 @@ def index_build_wrapper(
     (index/federation.py): N range-partitioned stores under one
     meta-manifest, the whole input admitted as federation generation 0."""
     from drep_tpu.index import build_federated, build_from_paths, build_from_workdir
+    from drep_tpu.utils.profiling import counters
 
     log_dir = _init_index(index_loc)
-    if work_directory and genomes:
-        raise UserInputError(
-            "index build takes --work_directory OR -g genomes, not both"
-        )
-    partitions = int(kwargs.pop("partitions", 0) or 0)
-    if work_directory:
-        if partitions:
+    with counters.span("job"):
+        if work_directory and genomes:
             raise UserInputError(
-                "index build --partitions is a bootstrap (-g) mode: a "
-                "workdir snapshot has no per-genome routing pass — build "
-                "federated from the FASTAs instead"
+                "index build takes --work_directory OR -g genomes, not both"
             )
-        summary = build_from_workdir(index_loc, work_directory)
-    elif genomes and partitions:
-        summary = build_federated(
-            index_loc, genomes, partitions,
-            processes=kwargs.pop("processes", 1) or 1, **kwargs,
-        )
-    elif genomes:
-        summary = build_from_paths(
-            index_loc, genomes,
-            processes=kwargs.pop("processes", 1) or 1, **kwargs,
-        )
-    else:
-        raise UserInputError(
-            "index build needs a source: --work_directory <completed run> or "
-            "-g <genome FASTAs>"
-        )
-    _finish_index(log_dir)
+        partitions = int(kwargs.pop("partitions", 0) or 0)
+        if work_directory:
+            if partitions:
+                raise UserInputError(
+                    "index build --partitions is a bootstrap (-g) mode: a "
+                    "workdir snapshot has no per-genome routing pass — build "
+                    "federated from the FASTAs instead"
+                )
+            summary = build_from_workdir(index_loc, work_directory)
+        elif genomes and partitions:
+            summary = build_federated(
+                index_loc, genomes, partitions,
+                processes=kwargs.pop("processes", 1) or 1, **kwargs,
+            )
+        elif genomes:
+            summary = build_from_paths(
+                index_loc, genomes,
+                processes=kwargs.pop("processes", 1) or 1, **kwargs,
+            )
+        else:
+            raise UserInputError(
+                "index build needs a source: --work_directory <completed run> or "
+                "-g <genome FASTAs>"
+            )
+        _finish_index(log_dir)
     return summary
 
 
@@ -211,18 +233,20 @@ def index_update_wrapper(
     federated root routes by range code and updates partitions as
     independent units (``--fed_pods`` for concurrent subprocess pods)."""
     from drep_tpu.index import index_update
+    from drep_tpu.utils.profiling import counters
 
     log_dir = _init_index(index_loc)
-    summary = index_update(
-        index_loc, genomes, processes=kwargs.get("processes", 1) or 1,
-        primary_prune=kwargs.get("primary_prune", "off") or "off",
-        prune_bands=kwargs.get("prune_bands", 0) or 0,
-        prune_min_shared=kwargs.get("prune_min_shared", 0) or 0,
-        prune_join_chunk=kwargs.get("prune_join_chunk", 0) or 0,
-        fed_pods=kwargs.get("fed_pods"),
-        params_file=kwargs.get("params_file"),
-    )
-    _finish_index(log_dir)
+    with counters.span("job"):
+        summary = index_update(
+            index_loc, genomes, processes=kwargs.get("processes", 1) or 1,
+            primary_prune=kwargs.get("primary_prune", "off") or "off",
+            prune_bands=kwargs.get("prune_bands", 0) or 0,
+            prune_min_shared=kwargs.get("prune_min_shared", 0) or 0,
+            prune_join_chunk=kwargs.get("prune_join_chunk", 0) or 0,
+            fed_pods=kwargs.get("fed_pods"),
+            params_file=kwargs.get("params_file"),
+        )
+        _finish_index(log_dir)
     return summary
 
 
@@ -571,38 +595,39 @@ def index_supervise_wrapper(index_loc: str, **kwargs) -> int:
 def dereplicate_wrapper(wd_loc: str, genomes: list[str] | None = None, **kwargs) -> pd.DataFrame:
     """`dereplicate`: filter + cluster + choose + evaluate + analyze.
     Returns Wdb (the winners)."""
-    from drep_tpu.utils.profiling import trace
-
     from drep_tpu.utils import telemetry
+    from drep_tpu.utils.profiling import counters, trace
 
-    wd, bdb = _init(wd_loc, genomes or [], events=kwargs.pop("events", None))
-    if kwargs.get("run_tax"):
-        from drep_tpu.bonus import validate_bonus_args
+    wd = _bring_up(wd_loc, events=kwargs.pop("events", None))
+    with trace(_trace_dir(wd_loc, kwargs.pop("profile", None))), counters.span("job"):
+        bdb = _load_bdb(wd, genomes or [])
+        if kwargs.get("run_tax"):
+            from drep_tpu.bonus import validate_bonus_args
 
-        validate_bonus_args(kwargs)  # fail fast, before hours of clustering
-    with telemetry.span("stage:filter"):
-        filtered = d_filter_wrapper(
-            wd, bdb, genomeInfo=kwargs.pop("genomeInfo", None), **kwargs
-        )
-    with trace(_trace_dir(wd, kwargs.pop("profile", None))):
-        with telemetry.span("stage:cluster"):
+            validate_bonus_args(kwargs)  # fail fast, before hours of clustering
+        with counters.span("stage:filter"):
+            filtered = d_filter_wrapper(
+                wd, bdb, genomeInfo=kwargs.pop("genomeInfo", None), **kwargs
+            )
+        with counters.span("stage:cluster"):
             d_cluster_wrapper(wd, filtered, **kwargs)
-    with telemetry.span("stage:choose"):
-        wdb = d_choose_wrapper(wd, filtered, **kwargs)
-    if kwargs.get("run_tax"):
-        from drep_tpu.bonus import d_bonus_wrapper
+        with counters.span("stage:choose"):
+            wdb = d_choose_wrapper(wd, filtered, **kwargs)
+        if kwargs.get("run_tax"):
+            from drep_tpu.bonus import d_bonus_wrapper
 
-        d_bonus_wrapper(
-            wd, filtered,
-            cent_index=kwargs.get("cent_index"),
-            processes=kwargs.get("processes", 1),
-        )
-    with telemetry.span("stage:evaluate"):
-        d_evaluate_wrapper(wd, **kwargs)
-    if not kwargs.get("skip_plots", False):
-        from drep_tpu.analyze import plot_all
+            d_bonus_wrapper(
+                wd, filtered,
+                cent_index=kwargs.get("cent_index"),
+                processes=kwargs.get("processes", 1),
+            )
+        with counters.span("stage:evaluate"):
+            d_evaluate_wrapper(wd, **kwargs)
+        if not kwargs.get("skip_plots", False):
+            from drep_tpu.analyze import plot_all
 
-        plot_all(wd)
-    _finish_counters(wd)
+            plot_all(wd)
+        _finish_counters(wd)
+    telemetry.close()
     get_logger().info("dereplicate finished: %d winners", len(wdb))
     return wdb

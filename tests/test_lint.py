@@ -229,12 +229,16 @@ def test_telemetry_gate_fires_on_private_use_and_adhoc_sink_write(tmp_path):
         "from drep_tpu.utils.telemetry import _sink\n"
         "def bad(wd):\n"
         '    telemetry._emit("x", "i", None)\n'
+        '    with telemetry.Span("stripe", {}):\n'
+        "        pass\n"
         '    with open(os.path.join(wd, "log", "events.p9.jsonl"), "a") as f:\n'
         "        f.write('{}')\n"
     ))
     r = _run_fixture(tmp_path, ["telemetry-gate"])
     msgs = [f.message for f in r.findings]
     assert any("_emit" in m for m in msgs), msgs
+    # one front door: a span on the event log's clock alone is a finding
+    assert any("telemetry.Span" in m and "front door" in m for m in msgs), msgs
     assert any("_sink" in m and "from-imported" in m for m in msgs), msgs
     assert any("ad-hoc write" in m for m in msgs), msgs
 

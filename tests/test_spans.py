@@ -1,0 +1,427 @@
+"""The span front door (ISSUE 24, utils/profiling.py ``Counters.span``): one
+context manager, three sinks — the record's ``phases`` section, the
+profiler's host plane (``drep:<name>``), the JSONL event log.
+
+Unit half: nesting, self time, threads, a raising body, no JAX import, stable
+names. End-to-end half: one toy ``compare`` on the CPU, run once with
+``--profile`` and ``--events on`` together, whose record, trace and log the
+tests below read."""
+
+import ast
+import glob
+import importlib.util
+import json
+import os
+import subprocess
+import sys
+import threading
+import time
+
+import numpy as np
+import pytest
+
+from drep_tpu.utils import telemetry
+from drep_tpu.utils.profiling import Counters
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+@pytest.fixture(autouse=True)
+def _reset_telemetry():
+    yield
+    telemetry.configure()
+
+
+def _main_self_sum(phases: dict) -> float:
+    return sum(p["self_seconds"] for p in phases.values() if p["thread"] == "main")
+
+
+# --- the front door alone -------------------------------------------------
+
+
+def test_nesting_keeps_self_time_and_the_sum_closes_on_the_root():
+    c = Counters()
+    with c.span("job"):
+        with c.span("a"):
+            time.sleep(0.02)
+            with c.span("b"):
+                time.sleep(0.03)
+            with c.span("b"):
+                time.sleep(0.01)
+        time.sleep(0.01)
+    ph = c.report(device=False)["phases"]
+    assert set(ph) == {"job", "a", "b"}
+    assert ph["b"]["calls"] == 2 and ph["a"]["calls"] == 1
+    # a span's self time is its duration less what its child spans cover
+    assert ph["a"]["self_seconds"] == pytest.approx(ph["a"]["seconds"] - ph["b"]["seconds"], abs=2e-4)
+    assert ph["b"]["self_seconds"] == pytest.approx(ph["b"]["seconds"], abs=1e-9)
+    assert 0.02 <= ph["a"]["self_seconds"] < 0.03 + 0.02
+    assert _main_self_sum(ph) == pytest.approx(ph["job"]["seconds"], rel=0.01)
+
+
+def test_a_record_written_inside_the_root_counts_the_open_spans_so_far():
+    """The workflows write perf_counters.json inside `job`, and a library
+    user may reset the counters inside a span: neither may lose the root."""
+    c = Counters()
+    with c.span("job"):
+        with c.span("before_reset"):
+            pass
+        c.reset()
+        with c.span("work"):
+            time.sleep(0.02)
+        with c.span("writing"):
+            ph = c.report(device=False)["phases"]
+    assert set(ph) == {"job", "work", "writing"}
+    assert ph["job"]["seconds"] >= 0.02 and ph["job"]["calls"] == 1
+    assert _main_self_sum(ph) == pytest.approx(ph["job"]["seconds"], rel=0.01)
+    # once closed, the root is booked once, with its whole duration
+    done = c.report(device=False)["phases"]
+    assert done["job"]["calls"] == 1 and done["job"]["seconds"] >= ph["job"]["seconds"]
+
+
+def test_a_span_on_another_thread_is_kept_apart():
+    c = Counters()
+
+    def work():
+        with c.span("primary/wait"):
+            time.sleep(0.05)
+
+    with c.span("job"):
+        t = threading.Thread(target=work)
+        t.start()
+        with c.span("primary/wait"):
+            time.sleep(0.01)
+        t.join(timeout=10)
+        assert not t.is_alive()
+    ph = c.report(device=False)["phases"]
+    assert ph["primary/wait"]["thread"] == "main"
+    assert ph["primary/wait@other"]["thread"] == "other"
+    assert ph["primary/wait@other"]["seconds"] >= 0.05 > ph["primary/wait"]["seconds"]
+    # the other thread's time is in nobody's parent and not in the main sum
+    assert _main_self_sum(ph) == pytest.approx(ph["job"]["seconds"], rel=0.01)
+
+
+def test_a_raising_body_still_closes_and_records(tmp_path):
+    telemetry.configure(log_dir=str(tmp_path), enabled=True, pid=0)
+    c = Counters()
+    with c.span("job"):
+        with pytest.raises(ValueError):
+            with c.span("boom", bi=3):
+                raise ValueError("x")
+        with c.span("after"):
+            time.sleep(0.05)  # the record rounds to 1e-4 s
+    telemetry.close()
+    ph = c.report(device=False)["phases"]
+    assert ph["boom"]["calls"] == 1 and "after" in ph
+    # the stack unwound: `after` is a child of `job`, not of `boom`
+    assert _main_self_sum(ph) == pytest.approx(ph["job"]["seconds"], rel=0.01)
+    with open(tmp_path / "events.p0.jsonl") as f:
+        recs = [json.loads(x) for x in f if x.strip()]
+    end = next(r for r in recs if r["ev"] == "boom" and r["ph"] == "E")
+    assert end["args"]["error"] == "ValueError" and end["args"]["bi"] == 3
+
+
+def test_one_span_round_a_loop_books_its_units_as_calls():
+    c = Counters()
+    with c.span("secondary/checkpoint", calls=40):
+        pass
+    with c.span("secondary/checkpoint", calls=2):
+        pass
+    assert c.report(device=False)["phases"]["secondary/checkpoint"]["calls"] == 42
+
+
+def test_stage_keeps_its_signature_and_is_a_phase():
+    c = Counters()
+    with c.stage("secondary_compare", pairs=7):
+        with c.span("secondary/wait"):
+            pass
+    rep = c.report(device=False)
+    assert rep["stages"]["secondary_compare"]["pairs"] == 7
+    assert rep["stages"]["secondary_compare"]["calls"] == 1
+    assert set(rep["phases"]) == {"stage:secondary_compare", "secondary/wait"}
+
+
+def test_secondary_calls_group_by_shape_and_stay_bounded():
+    from drep_tpu.utils import profiling
+
+    c = Counters()
+    for _ in range(3):
+        c.add_secondary_call(clusters=10, rows=40, rows_pad=64, width=128, v_pad=256, useful_pairs=60)
+    for i in range(profiling.SECONDARY_SHAPES_MAX + 20):
+        c.add_secondary_call(clusters=1, rows=2, rows_pad=128, width=128, v_pad=1024 + i, useful_pairs=1)
+    calls = c.report(device=False)["secondary_calls"]
+    first = next(e for e in calls if e["rows_pad"] == 64)
+    assert first == {"rows_pad": 64, "width": 128, "v_pad": 256, "calls": 3, "clusters": 30,
+                     "rows": 120, "useful_pairs": 180}
+    assert len(calls) <= profiling.SECONDARY_SHAPES_MAX + 1
+    assert sum(e["calls"] for e in calls) == 3 + profiling.SECONDARY_SHAPES_MAX + 20
+
+
+_NO_JAX = """
+import sys
+sys.path.insert(0, {repo!r})
+import drep_tpu.utils.telemetry
+assert "jax" not in sys.modules, "telemetry imported jax"
+from drep_tpu.utils.profiling import counters
+with counters.span("job"):
+    with counters.stage("serve_batch"):
+        with counters.span("partition_load", pid=1):
+            pass
+rep = counters.report(device=False)
+assert set(rep["phases"]) == {{"job", "stage:serve_batch", "partition_load"}}, rep
+assert "jax" not in sys.modules, "a span imported jax"
+print("ok")
+"""
+
+
+def test_a_span_never_imports_jax():
+    """`index route`, `index supervise` and chip_smoke.py's parent never
+    load JAX: the chip belongs to their children."""
+    out = subprocess.run(
+        [sys.executable, "-c", _NO_JAX.format(repo=REPO)],
+        capture_output=True, text=True, timeout=120,
+    )
+    assert out.returncode == 0 and out.stdout.strip() == "ok", out.stderr
+
+
+def _span_calls():
+    """(path, line, name node) of every `<x>.span(...)` call in drep_tpu/."""
+    for path in glob.glob(os.path.join(REPO, "drep_tpu", "**", "*.py"), recursive=True):
+        with open(path) as f:
+            tree = ast.parse(f.read())
+        for node in ast.walk(tree):
+            if (isinstance(node, ast.Call) and isinstance(node.func, ast.Attribute)
+                    and node.func.attr in ("span", "Span") and node.args):
+                yield os.path.relpath(path, REPO), node
+
+
+def test_span_names_carry_no_varying_part_and_every_span_uses_the_front_door():
+    seen = set()
+    for path, node in _span_calls():
+        if path == "drep_tpu/utils/profiling.py":
+            continue  # the front door itself (`stage` prefixes its own name)
+        # no span on one clock only: telemetry.Span is the JSONL sink of the
+        # front door, not a second way in
+        owner = node.func.value
+        assert not (isinstance(owner, ast.Name) and owner.id == "telemetry"), (path, node.lineno)
+        name = node.args[0]
+        if isinstance(name, ast.BinOp):  # the ring's `ph + "/wait"`: stage by tile kind
+            assert isinstance(name.left, ast.Name) and isinstance(name.right, ast.Constant)
+            name = name.right
+        assert isinstance(name, ast.Constant) and isinstance(name.value, str), (
+            f"{path}:{node.lineno}: a span's name is a stable string; what varies goes "
+            f"in its keyword arguments")
+        assert not any(ch.isdigit() for ch in name.value), (path, node.lineno, name.value)
+        seen.add(name.value)
+    assert {"job", "stripe", "ring_step", "/wait", "secondary/pack", "tables_io"} <= seen
+
+
+# --- one toy job: record, profiler trace and event log --------------------
+
+
+@pytest.fixture(scope="module")
+def toy_job(tmp_path_factory, genome_paths):
+    from drep_tpu.workflows import compare_wrapper
+
+    wd = str(tmp_path_factory.mktemp("spans_wd"))
+    trace_dir = str(tmp_path_factory.mktemp("spans_trace"))
+    compare_wrapper(wd, genome_paths, skip_plots=True, profile=trace_dir, events="on")
+    telemetry.configure()
+    with open(os.path.join(wd, "log", "perf_counters.json")) as f:
+        record = json.load(f)
+    return {"wd": wd, "trace_dir": trace_dir, "record": record}
+
+
+def test_toy_compare_record_holds_every_span_its_path_reaches(toy_job):
+    rec = toy_job["record"]
+    ph = rec["phases"]
+    reached = {
+        "job", "tables_io", "mdb_build", "stage:cluster", "stage:ingest_or_cache",
+        "stage:primary_compare", "primary/pack", "primary/wait", "primary/linkage",
+        "stage:secondary", "stage:secondary_compare", "stage:secondary_postprocess",
+        "secondary/pack", "secondary/wait", "secondary/post", "secondary/checkpoint",
+        "stage:assembly_io", "stage:evaluate",
+    }
+    assert reached <= set(ph), reached - set(ph)
+    assert all(p["thread"] == "main" for p in ph.values())
+    # acceptance: the main thread's self seconds add up to the job within 1%
+    assert _main_self_sum(ph) == pytest.approx(ph["job"]["seconds"], rel=0.01)
+    # the stage totals read as before, and the stage spans agree with them
+    assert set(rec["stages"]) == {"ingest_or_cache", "primary_compare", "secondary_compare",
+                                  "secondary_postprocess", "assembly_io"}
+    assert rec["stages"]["secondary_compare"]["seconds"] == pytest.approx(
+        ph["stage:secondary_compare"]["seconds"], abs=5e-3)
+    assert rec["secondary_paths"] == {"one_shot_clusterlocal": 1}
+    # the call shapes: their useful pairs are the pairs the stage counted
+    calls = rec["secondary_calls"]
+    assert sum(c["useful_pairs"] for c in calls) == rec["stages"]["secondary_compare"]["pairs"] == 4
+    assert [(c["calls"], c["clusters"], c["rows"], c["rows_pad"]) for c in calls] == [(1, 2, 5, 64)]
+    assert all(c["rows"] <= c["rows_pad"] and c["width"] >= 128 and c["v_pad"] > 0 for c in calls)
+
+
+def test_profile_of_the_toy_job_puts_the_spans_on_the_host_plane(toy_job):
+    """`--profile` uses the harness's ProfileOptions and wraps the whole
+    job: the trace it leaves is one the benchmark's reduction reads, with
+    `drep:` events on /host:CPU that idle_gaps picks as labels."""
+    from benchmark import tracered
+
+    xplane = tracered.find_xplane(toy_job["trace_dir"])
+    assert xplane is not None
+    events = tracered.load_xplane(xplane, rehearse=True)
+    host = {name for name, _, _ in events["host"]}
+    assert {"drep:job", "drep:stage:ingest_or_cache", "drep:primary/wait", "drep:secondary/wait",
+            "drep:secondary/pack", "drep:tables_io"} <= host, sorted(n for n in host if "drep" in n)
+    # Python frames are off (python_tracer_level 0): a whole job's trace stays small
+    assert not any(n.startswith("$") for n in host)
+    job = max((d for n, _, d in events["host"] if n == "drep:job"))
+    assert job / 1e9 == pytest.approx(toy_job["record"]["phases"]["job"]["seconds"], rel=0.05)
+    labels = [label for label, _ in tracered.idle_gaps(events, n=8)]
+    assert labels and all(l != "host:unattributed" for l in labels), labels
+    assert any(l.startswith("host:drep:") for l in labels), labels
+
+
+def test_events_on_writes_the_same_schema_and_trace_report_reads_the_new_names(toy_job):
+    spec = importlib.util.spec_from_file_location(
+        "trace_report", os.path.join(REPO, "tools", "trace_report.py"))
+    trace_report = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(trace_report)
+    loaded = trace_report.load_events(os.path.join(toy_job["wd"], "log"))
+    assert not loaded["bad_lines"] and not loaded["torn_tails"]
+    for r in loaded["events"]:
+        assert set(r) >= {"run", "pid", "epoch", "ev", "ph", "mono", "wall"}
+    spans, unclosed = trace_report.pair_spans(loaded["events"])
+    assert not unclosed
+    by_name = {}
+    for sp in spans:
+        by_name.setdefault(sp["ev"], []).append(sp)
+    # every phase of the record is a span of the log, with the same calls
+    # unless one span stood for many units (calls=N round a loop)
+    ph = toy_job["record"]["phases"]
+    assert set(ph) == set(by_name), set(ph) ^ set(by_name)
+    assert len(by_name["secondary/wait"]) == ph["secondary/wait"]["calls"]
+    assert by_name["stage:secondary"][0]["dur"] == pytest.approx(
+        ph["stage:secondary"]["seconds"], abs=5e-3)
+    # the two instants this PR removed are gone; the report still renders
+    assert not {"stage_open", "stage_close"} & {r["ev"] for r in loaded["events"]}
+    text = trace_report.text_report(loaded["events"], toy_job["record"])
+    assert "stage:secondary" in text and "stage:primary_compare" in text
+
+
+
+_PROFILE_ON_A_POD = """
+import os, sys
+sys.path.insert(0, {repo!r})
+os.environ["JAX_COORDINATOR_ADDRESS"] = "localhost:1"
+import jax
+from jax._src import xla_bridge
+seen = []
+jax.distributed.initialize = lambda **kw: seen.append(
+    (xla_bridge.backends_are_initialized(), kw["coordinator_address"]))
+from drep_tpu.workflows import compare_wrapper
+compare_wrapper({wd!r}, {genomes!r}, skip_plots=True, SkipSecondary=True, profile={trace!r})
+assert seen == [(False, None)], seen
+assert xla_bridge.backends_are_initialized()
+print("ok")
+"""
+
+
+def test_profile_opens_after_the_distributed_bring_up(tmp_path, genome_paths):
+    """`jax.profiler.start_trace` initialises the backend, and
+    `jax.distributed.initialize` must come before any backend use: a pod
+    member run with `--profile` that opened its trace first would go on
+    alone, silently (mesh.initialize_distributed swallows that error)."""
+    script = _PROFILE_ON_A_POD.format(
+        repo=REPO, wd=str(tmp_path / "wd"), genomes=list(genome_paths), trace=str(tmp_path / "tr"))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("JAX_COORDINATOR_ADDRESS", None)
+    out = subprocess.run([sys.executable, "-c", script], capture_output=True, text=True,
+                         timeout=600, env=env)
+    assert out.returncode == 0 and out.stdout.strip().endswith("ok"), out.stderr[-2000:]
+
+
+def _job_lines(wd):
+    with open(os.path.join(wd, "log", "events.p0.jsonl")) as f:
+        return [r["ph"] for r in map(json.loads, f) if r["ev"] == "job"]
+
+
+def test_a_second_job_in_the_process_writes_into_its_own_log(toy_job, tmp_path, genome_paths):
+    """`job` opens after `telemetry.configure`: its B line is the crash
+    evidence of THIS job, in this job's log, and a later job in the same
+    process (library use) leaves the earlier log alone."""
+    from drep_tpu.workflows import compare_wrapper
+
+    assert _job_lines(toy_job["wd"]) == ["B", "E"]
+    size = os.path.getsize(os.path.join(toy_job["wd"], "log", "events.p0.jsonl"))
+    wd2 = str(tmp_path / "second")
+    compare_wrapper(wd2, genome_paths, skip_plots=True, SkipSecondary=True, events="on")
+    assert _job_lines(wd2) == ["B", "E"]
+    # a third job with events off writes nowhere
+    wd3 = str(tmp_path / "third")
+    compare_wrapper(wd3, genome_paths, skip_plots=True, SkipSecondary=True)
+    assert not glob.glob(os.path.join(wd3, "log", "events.*"))
+    assert _job_lines(toy_job["wd"]) == ["B", "E"] and _job_lines(wd2) == ["B", "E"]
+    assert os.path.getsize(os.path.join(toy_job["wd"], "log", "events.p0.jsonl")) == size
+
+
+def test_a_failure_mid_batch_loses_one_cluster(tmp_path, genome_paths, monkeypatch):
+    """Each cluster of a batch is saved right after its post-process, in a
+    `secondary/checkpoint` span of its own: what a kill loses is the
+    cluster in flight, not the batch."""
+    from drep_tpu.cluster import controller
+    from drep_tpu.workflows import compare_wrapper
+
+    wd = str(tmp_path / "wd")
+    real, seen = controller._secondary_postprocess, []
+
+    def second_one_dies(gs, indices, pc, *rest):
+        seen.append(pc)
+        if len(seen) == 2:
+            raise RuntimeError("killed mid-batch")
+        return real(gs, indices, pc, *rest)
+
+    monkeypatch.setattr(controller, "_secondary_postprocess", second_one_dies)
+    with pytest.raises(RuntimeError, match="killed mid-batch"):
+        compare_wrapper(wd, genome_paths, skip_plots=True)
+    telemetry.configure()
+    assert len(seen) == 2  # both clusters rode one batch
+    saved = sorted(os.listdir(os.path.join(wd, "data", "secondary_checkpoints")))
+    assert [f for f in saved if f.startswith("pc_")] == [f"pc_{seen[0]:06d}.npz"]
+    monkeypatch.setattr(controller, "_secondary_postprocess", real)
+    compare_wrapper(wd, genome_paths, skip_plots=True)
+    with open(os.path.join(wd, "log", "perf_counters.json")) as f:
+        rec = json.load(f)
+    # the resume counts the lost cluster's pairs alone (fixture: {A,B,C}=3, {D,E}=1)
+    assert rec["stages"]["secondary_compare"]["pairs"] in (1, 3)
+    assert rec["stages"]["secondary_compare"]["pairs"] < 4
+    # open, a look-up and a save a cluster, finish
+    assert rec["phases"]["secondary/checkpoint"]["calls"] == 1 + 2 + 1 + 1
+
+
+def test_streaming_stripes_hold_their_phases(tmp_path):
+    """The streaming path's spans: a `stripe` is a container of the
+    dispatch / wait / assemble / publish spans inside it."""
+    from drep_tpu.ops.minhash import PAD_ID, PackedSketches
+    from drep_tpu.parallel.streaming import streaming_mash_edges
+    from drep_tpu.utils.profiling import counters
+
+    rng = np.random.default_rng(0)
+    n, s = 32, 32
+    ids = np.full((n, s), PAD_ID, np.int32)
+    for i in range(n):
+        ids[i] = np.sort(rng.choice(4096, size=s, replace=False))
+    packed = PackedSketches(ids=ids, counts=np.full(n, s, np.int32), names=[f"g{i}" for i in range(n)])
+    counters.reset()
+    with counters.span("job"):
+        streaming_mash_edges(packed, k=21, cutoff=0.2, block=8, checkpoint_dir=str(tmp_path / "ck"))
+        ph = counters.report()["phases"]
+    stripes = ph["stripe"]["calls"]
+    assert stripes == 4
+    for name in ("primary/dispatch", "primary/wait"):
+        assert ph[name]["calls"] == stripes, (name, ph[name])
+    # the store's key and its open, then a shard a stripe
+    assert ph["primary/publish"]["calls"] == 2 + stripes
+    assert ph["primary/assemble"]["calls"] == stripes + 1  # each stripe's edges, then all
+    assert ph["primary/put"]["calls"] == 1 and ph["primary/pack"]["calls"] >= 1
+    inside = sum(ph[k]["seconds"] for k in ("primary/dispatch", "primary/wait", "primary/publish",
+                                            "primary/put"))
+    assert ph["stripe"]["self_seconds"] <= ph["stripe"]["seconds"] - inside + 0.05
+    assert _main_self_sum(ph) == pytest.approx(ph["job"]["seconds"], rel=0.01)

@@ -23,7 +23,7 @@ EXPLAIN = """\
 PR 10's observability contract has two halves this rule protects. The
 zero-overhead-off guarantee: every emission site is one falsy dict
 lookup when --events is off — code that writes into <wd>/log/ directly
-(instead of telemetry.event()/span()) bypasses the gate and costs I/O
+(instead of telemetry.event()/counters.span()) bypasses the gate and costs I/O
 on every run. And the crash-forensics format: the sink appends whole
 flushed JSONL lines so a SIGKILL tears at most the final line, which
 every reader (trace_report, scrub_store) classifies as expected crash
@@ -32,9 +32,10 @@ produces interleaved or torn MID-FILE bytes that turn forensics into
 damage reports. Telemetry's private surface (_emit/_sink/_STATE) is
 off-limits outside the module for the same reason.
 
-Fix: emit through telemetry.event()/telemetry.span(); counters through
-profiling.Counters. New durable observability artifacts belong in the
-telemetry/profiling modules, not at call sites.
+Fix: emit instants through telemetry.event() and spans through the one
+front door, profiling.counters.span() (the event log is one of its three
+sinks); counters through profiling.Counters. New durable observability
+artifacts belong in the telemetry/profiling modules, not at call sites.
 """
 
 
@@ -74,7 +75,22 @@ def run(model: RepoModel) -> list[Finding]:
                     message=f"private telemetry member telemetry.{node.attr} "
                             f"used outside the module",
                     hint="use the public gated API: telemetry.event()/"
-                         "span()/configure()",
+                         "configure(), counters.span()",
+                ))
+            # one front door: the log's Span is a sink of counters.span,
+            # never a second way in (a span on one clock only)
+            if (
+                isinstance(node, ast.Attribute)
+                and isinstance(node.value, ast.Name)
+                and node.value.id in telemetry_aliases
+                and node.attr == "Span"
+            ):
+                out.append(Finding(
+                    rule=RULE_ID, path=sf.path, line=node.lineno,
+                    message="telemetry.Span used outside the front door",
+                    hint="open the span with profiling.counters.span(): it "
+                         "feeds the record, the profiler's host plane and "
+                         "this log together",
                 ))
             # the other spelling: from drep_tpu.utils.telemetry import _emit
             if (
@@ -89,7 +105,7 @@ def run(model: RepoModel) -> list[Finding]:
                                     f"{alias.name} from-imported outside "
                                     f"the module",
                             hint="use the public gated API: telemetry."
-                                 "event()/span()/configure()",
+                                 "event()/configure(), counters.span()",
                         ))
         for call in iter_calls(sf.tree):
             kind = write_call_kind(call)
@@ -101,7 +117,7 @@ def run(model: RepoModel) -> list[Finding]:
                     rule=RULE_ID, path=sf.path, line=call.lineno,
                     message=f"ad-hoc write ({kind}) targeting the "
                             f"observability sink namespace ({hit!r})",
-                    hint="emit through telemetry.event()/span() or extend "
+                    hint="emit through telemetry.event()/counters.span() or extend "
                          "utils/telemetry.py — direct writes bypass the "
                          "--events gate and the crash-safe append format",
                 ))
