@@ -524,7 +524,10 @@ def _secondary_stage(
         with counters.stage("secondary_compare", pairs=pairs_in_batch):
             outs = retrying_call(
                 lambda batch=batch: batched_fn(
-                    gs, [ix for _, ix in batch], mesh_shape=kw["mesh_shape"]
+                    gs,
+                    [ix for _, ix in batch],
+                    processes=kw["processes"],
+                    mesh_shape=kw["mesh_shape"],
                 ),
                 site="secondary_batch",
                 config=ft_cfg,

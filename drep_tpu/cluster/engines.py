@@ -329,6 +329,7 @@ def secondary_jax_ani_batched(
     clusters: list[list[int]],
     tile: int = 128,
     mesh_shape: int | None = None,
+    processes: int = 1,
     **_,
 ) -> list[tuple[np.ndarray, np.ndarray]]:
     """One device call for MANY small primary clusters.
@@ -349,9 +350,11 @@ def secondary_jax_ani_batched(
     its keep on the per-cluster path for big single clusters, not here.
     Falls back to the shared-vocabulary pack + full path dispatch (which
     may pick the mesh ring) when even the local extent exceeds the
-    one-shot budget."""
+    one-shot budget. `processes` (dRep's `-p`) bounds the threads the
+    pack ranks clusters on; results do not depend on it."""
     from drep_tpu.ops.containment import (
         all_vs_all_containment_matmul,
+        clusterlocal_pack_workers,
         matmul_vocab_pad_extent,
         one_shot_fits,
         pack_scaled_sketches_clusterlocal,
@@ -360,9 +363,15 @@ def secondary_jax_ani_batched(
     flat = [i for cl in clusters for i in cl]
     names = [gs.names[i] for i in flat]
     ani_all = cov_all = None
-    with counters.span("secondary/pack", calls=len(clusters)):
+    # the span's args say whether the pool engaged and what a hash cost
+    with counters.span(
+        "secondary/pack",
+        calls=len(clusters),
+        workers=clusterlocal_pack_workers(processes, len(clusters)),
+        hashes=sum(len(gs.scaled[i]) for i in flat),
+    ):
         packed_l, v_extent = pack_scaled_sketches_clusterlocal(
-            [[gs.scaled[i] for i in cl] for cl in clusters], names
+            [[gs.scaled[i] for i in cl] for cl in clusters], names, processes=processes
         )
     v_pad = matmul_vocab_pad_extent(v_extent)
     if one_shot_fits(packed_l.n, v_pad):

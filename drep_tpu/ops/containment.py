@@ -35,6 +35,8 @@ transposed rest, exactly equal to the full grid at ~half the device work.
 from __future__ import annotations
 
 import functools
+import os
+from concurrent.futures import ThreadPoolExecutor
 
 import jax
 import jax.numpy as jnp
@@ -42,6 +44,22 @@ import numpy as np
 
 from drep_tpu.ops.minhash import PAD_ID, PackedSketches, pad_packed_rows
 from drep_tpu.utils.profiling import counters
+
+
+def _fill_padded_rows(ids: np.ndarray, ranks: np.ndarray, lens: np.ndarray) -> None:
+    """Write ragged rank rows into the preallocated padded matrix (or a
+    row slice of one) `ids`: row r gets the next `lens[r]` of `ranks`, cast
+    to the matrix's dtype on the way; what lies past a row's length keeps
+    its pad value. THE one way this module fills a packed matrix: a
+    contiguous slice copy per row is a memcpy (18 ms for 512 rows of 26k
+    ranks), where the `np.repeat` / `cumsum` / `arange` coordinates and
+    fancy-index scatter it replaces wrote two int64 numbers per rank
+    before the rank (~0.3 s for the same rows, a third of the
+    cluster-local pack: ISSUE 25)."""
+    o = 0
+    for row, n in zip(ids, lens):
+        row[:n] = ranks[o : o + n]
+        o += n
 
 
 def pack_scaled_sketches(
@@ -56,28 +74,63 @@ def pack_scaled_sketches(
     """
     if not sketches:
         raise ValueError("no sketches to pack")
-    vocab = np.unique(np.concatenate(sketches))
+    flat = np.concatenate(sketches)
+    vocab = np.unique(flat)
     if vocab.size >= np.iinfo(np.int32).max:
         raise ValueError("id space overflow: >2^31 distinct sketch hashes")
-    width = _pow2_bucket(max(max(len(s) for s in sketches), 1), pad_multiple)
-    n = len(sketches)
-    ids = np.full((n, width), PAD_ID, dtype=np.int32)
     lens = np.array([len(s) for s in sketches], dtype=np.int64)
-    # ONE searchsorted over the concatenation — a per-row loop was a
-    # measured hot spot at thousands of clusters/batches per run
-    flat = np.concatenate(sketches)
-    ranks = np.searchsorted(vocab, flat).astype(np.int32)
-    rows = np.repeat(np.arange(n), lens)
-    offs = np.concatenate([[0], np.cumsum(lens)[:-1]])
-    cols = np.arange(len(flat)) - np.repeat(offs, lens)
-    ids[rows, cols] = ranks
+    width = _pow2_bucket(max(int(lens.max()), 1), pad_multiple)
+    ids = np.full((len(sketches), width), PAD_ID, dtype=np.int32)
+    # ONE searchsorted over the concatenation — a per-row SEARCH was a
+    # measured hot spot at thousands of clusters/batches per run. The fill
+    # below does loop over rows: a slice copy per row costs microseconds
+    _fill_padded_rows(ids, np.searchsorted(vocab, flat), lens)
     return PackedSketches(ids=ids, counts=lens.astype(np.int32), names=list(names))
+
+
+def _usable_cores() -> int:
+    """Cores this process may run on (its affinity mask where the platform
+    has one: a container's share, not the machine's count)."""
+    if hasattr(os, "sched_getaffinity"):
+        return len(os.sched_getaffinity(0))
+    return os.cpu_count() or 1
+
+
+def clusterlocal_pack_workers(processes: int, n_groups: int) -> int:
+    """Threads :func:`pack_scaled_sketches_clusterlocal` ranks clusters on:
+    dRep's own `-p/--processes`, capped by the clusters there are and the
+    cores this process may use. 1 means inline, no pool."""
+    return max(1, min(int(processes), n_groups, _usable_cores()))
+
+
+def _rank_cluster(group: list[np.ndarray]) -> tuple[np.ndarray, int]:
+    """(dense ranks of every hash of `group` into the cluster's own sorted
+    vocabulary, in member order back to back; the vocabulary's size).
+    A pure function of the cluster's sketches: clusters rank side by side
+    on a thread pool (numpy's sort, searchsorted and copies release the
+    GIL) and the result cannot depend on the pool."""
+    flat = np.concatenate(group) if group else np.zeros(0, np.uint64)
+    # the vocabulary is np.unique(flat), spelled so that the sort is the
+    # stable one: it merges the members' already-sorted runs. Of the
+    # spellings timed on the chip machine's host (PERF.md section 6,
+    # PR 25) this is the fastest at the CLI's six workers
+    srt = np.sort(flat, kind="stable")
+    first = np.ones(len(srt), dtype=bool)
+    np.not_equal(srt[1:], srt[:-1], out=first[1:])
+    vocab = srt[first]
+    if vocab.size >= np.iinfo(np.int32).max:
+        raise ValueError("id space overflow: >2^31 distinct sketch hashes")
+    ranks = np.searchsorted(vocab, flat)
+    # narrow in the worker, so that what waits for the fill is 2 bytes a
+    # rank and the fill of a uint16 matrix is a plain copy
+    return ranks.astype(np.uint16 if vocab.size < 0xFFFF else np.int32), int(vocab.size)
 
 
 def pack_scaled_sketches_clusterlocal(
     sketch_groups: list[list[np.ndarray]],
     names: list[str],
     pad_multiple: int = 128,
+    processes: int = 1,
 ) -> tuple[PackedSketches, int]:
     """Pack MANY clusters into one id matrix with per-cluster-LOCAL dense
     id spaces: cluster c's ids are ranks into c's OWN vocabulary, so every
@@ -96,28 +149,27 @@ def pack_scaled_sketches_clusterlocal(
     blocks contain id collisions and are GARBAGE by construction — callers
     must read diagonal blocks only.
 
+    `processes` (dRep's `-p`) bounds the thread pool the clusters are
+    ranked on (:func:`clusterlocal_pack_workers`); the output does not
+    depend on it.
+
     Returns (packed, v_extent): `v_extent` = max cluster vocabulary size
     (the honest extent for budget checks; `vocab_extent(packed.ids)` would
     under-report when the widest cluster's top ids are unused).
     """
     if not sketch_groups:
         raise ValueError("no clusters to pack")
-    # one searchsorted per GROUP over its concatenation, one global
-    # scatter for the matrix fill — same vectorized-repack idiom as
-    # pack_scaled_sketches (per-row Python loops were a measured hot spot
-    # at production cluster counts)
-    rank_parts: list[np.ndarray] = []
-    lens: list[int] = []
-    v_extent = 1
-    for group in sketch_groups:
-        flat = np.concatenate(group) if group else np.array([], np.uint64)
-        vocab = np.unique(flat)
-        if vocab.size >= np.iinfo(np.int32).max:
-            raise ValueError("id space overflow: >2^31 distinct sketch hashes")
-        v_extent = max(v_extent, int(vocab.size))
-        rank_parts.append(np.searchsorted(vocab, flat).astype(np.int32))
-        lens.extend(len(s) for s in group)
-    lens_arr = np.array(lens, dtype=np.int64)
+    # rank first (the matrix's dtype depends on every cluster's vocabulary),
+    # then allocate and fill: one search per GROUP over its concatenation
+    # (a per-row search was a measured hot spot at production cluster counts)
+    workers = clusterlocal_pack_workers(processes, len(sketch_groups))
+    if workers > 1:
+        with ThreadPoolExecutor(max_workers=workers) as pool:
+            ranked = list(pool.map(_rank_cluster, sketch_groups))
+    else:
+        ranked = [_rank_cluster(group) for group in sketch_groups]
+    v_extent = max(1, max(v for _, v in ranked))
+    lens_arr = np.array([len(s) for group in sketch_groups for s in group], dtype=np.int64)
     n = len(lens_arr)
     width = _pow2_bucket(max(int(lens_arr.max()) if n else 1, 1), pad_multiple)
     # link compression: ranks < v_extent, so when every cluster vocabulary
@@ -128,11 +180,11 @@ def pack_scaled_sketches_clusterlocal(
         ids = np.full((n, width), np.uint16(0xFFFF), dtype=np.uint16)
     else:
         ids = np.full((n, width), PAD_ID, dtype=np.int32)
-    flat_ranks = np.concatenate(rank_parts) if rank_parts else np.zeros(0, np.int32)
-    rows = np.repeat(np.arange(n), lens_arr)
-    offs = np.concatenate([[0], np.cumsum(lens_arr)[:-1]])
-    cols = np.arange(len(flat_ranks)) - np.repeat(offs, lens_arr)
-    ids[rows, cols] = flat_ranks  # ranks of a sorted-unique sketch are sorted
+    r = 0
+    for group, (ranks, _) in zip(sketch_groups, ranked):
+        # ranks of a sorted-unique sketch are sorted
+        _fill_padded_rows(ids[r : r + len(group)], ranks, lens_arr[r : r + len(group)])
+        r += len(group)
     return (
         PackedSketches(ids=ids, counts=lens_arr.astype(np.int32), names=list(names)),
         v_extent,

@@ -126,3 +126,31 @@ def test_indicator_dtype_paths_bit_identical(rng, monkeypatch):
         assert rect.dtype == np.int32
     for a, b in zip(out["int8"], out["float32"]):
         np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("case", ["ragged", "with_empty_rows"])
+def test_shared_vocabulary_pack_equals_double_loop_oracle(rng, case):
+    """`pack_scaled_sketches` fills its matrix through the same row-slice
+    helper as the cluster-local pack (ISSUE 25): ids (values, dtype, pad
+    value, width) and counts against np.unique of all hashes and a
+    per-element np.searchsorted, a plain double loop."""
+    from drep_tpu.ops.minhash import PAD_ID
+
+    sketches = _sketches(rng, n=7, size=300)
+    if case == "with_empty_rows":
+        empty = np.empty(0, dtype=np.uint64)
+        sketches = [empty, sketches[0], empty, *sketches[1:], empty]
+    names = [f"g{i}" for i in range(len(sketches))]
+    packed = pack_scaled_sketches(sketches, names, pad_multiple=32)
+
+    vocab = np.unique(np.concatenate(sketches))
+    longest = max(len(s) for s in sketches)
+    want = np.full((len(sketches), max(32, 1 << (longest - 1).bit_length())), PAD_ID, np.int32)
+    for r, s in enumerate(sketches):
+        for j, h in enumerate(s):
+            want[r, j] = np.searchsorted(vocab, h)
+    assert packed.ids.dtype == np.int32 and packed.ids.shape == want.shape
+    assert packed.ids.tobytes() == want.tobytes()
+    assert packed.counts.dtype == np.int32
+    assert packed.counts.tolist() == [len(s) for s in sketches]
+    assert packed.names == names
