@@ -801,41 +801,58 @@ def all_vs_all_containment_matmul_chunked(
     require_int32_ids(packed.ids, "all_vs_all_containment_matmul_chunked")
     m = packed.n
     m_pad = matmul_rows_pad(m)
-    v_chunk = matmul_vocab_chunk(m_pad)
-    # uint16 alternative: cap chunks below 2^16 so the rebased stacked
-    # tensor ships at 2 bytes/element. More, narrower chunks cost extra
-    # per-chunk dispatches but identical total indicator/matmul work;
-    # padding skew at narrow widths can lose, so compare ACTUAL plan
-    # bytes and keep the smaller operand. The matmul jit widens u16 on
-    # device (ops/minhash.widen_ids_device).
-    extent = vocab_extent(packed.ids)
-    u16_chunk = 1 << 15
-    plan = None
-    if v_chunk > u16_chunk and extent > 0:
-        plan32 = _chunk_plan(packed.ids, v_chunk, extent)
-        plan16 = _chunk_plan(packed.ids, u16_chunk, extent)
-        if plan16[0] * plan16[3] * 2 < plan32[0] * plan32[3] * 4:
-            v_chunk, plan = u16_chunk, plan16
-        else:
-            plan = plan32
-    stacked = jnp.asarray(_stacked_vocab_chunks(packed.ids, v_chunk, m_pad, plan=plan))
+    # the host's layout work: both plans and the stacked repack
+    with counters.span("secondary/chunks"):
+        v_chunk = matmul_vocab_chunk(m_pad)
+        # uint16 alternative: cap chunks below 2^16 so the rebased stacked
+        # tensor ships at 2 bytes/element. More, narrower chunks cost extra
+        # per-chunk dispatches but identical total indicator/matmul work;
+        # padding skew at narrow widths can lose, so compare ACTUAL plan
+        # bytes and keep the smaller operand. The matmul jit widens u16 on
+        # device (ops/minhash.widen_ids_device).
+        extent = vocab_extent(packed.ids)
+        u16_chunk = 1 << 15
+        plan = None
+        if v_chunk > u16_chunk and extent > 0:
+            plan32 = _chunk_plan(packed.ids, v_chunk, extent)
+            plan16 = _chunk_plan(packed.ids, u16_chunk, extent)
+            if plan16[0] * plan16[3] * 2 < plan32[0] * plan32[3] * 4:
+                v_chunk, plan = u16_chunk, plan16
+            else:
+                plan = plan32
+        stacked_host = _stacked_vocab_chunks(packed.ids, v_chunk, m_pad, plan=plan)
+    n_chunks, _, width = stacked_host.shape
+    counters.add_chunked_call(
+        rows=m, rows_pad=m_pad, v_chunk=v_chunk, chunks=n_chunks, width=width,
+        id_dtype=stacked_host.dtype.name, extent=extent,
+        hashes=int(packed.counts.sum()), id_slots=int(stacked_host.size),
+        bytes_shipped=int(stacked_host.nbytes),
+    )
     # triangular schedule per chunk: counts are additive over disjoint hash
     # ranges AND symmetric, so each chunk contributes only its canonical
     # (bi <= bj) blocks; the partials accumulate ON DEVICE and ONE host
     # mirror after the final transfer completes the matrix — ~half the MXU
     # FLOPs of the full per-chunk matmuls, same single-result-transfer
-    # dispatch pattern
-    acc = None
-    for r in range(stacked.shape[0]):
-        part = _intersect_matmul_tri(stacked[r], v_pad=v_chunk)
-        acc = part if acc is None else acc + part
-    if acc is None:
-        inter = np.zeros((m, m), dtype=np.int32)
-    else:
-        tb = tri_row_block(m_pad)
-        inter = mirror_lower_blocks(np.array(acc), tb)[:m, :m]
-        _count_tri_tiles(m_pad, tb)
-    return ani_cov_from_intersections(inter, packed.counts, k)
+    # dispatch pattern. The span holds the transfer, the chunk loop and the
+    # one blocking readback
+    with counters.span(
+        "secondary/wait", rows=m_pad, v_chunk=v_chunk, chunks=n_chunks,
+        id_dtype=stacked_host.dtype.name,
+    ):
+        stacked = jnp.asarray(stacked_host)
+        acc = None
+        for r in range(n_chunks):
+            part = _intersect_matmul_tri(stacked[r], v_pad=v_chunk)
+            acc = part if acc is None else acc + part
+        inter_pad = None if acc is None else np.array(acc)
+    with counters.span("secondary/post"):
+        if inter_pad is None:
+            inter = np.zeros((m, m), dtype=np.int32)
+        else:
+            tb = tri_row_block(m_pad)
+            inter = mirror_lower_blocks(inter_pad, tb)[:m, :m]
+            _count_tri_tiles(m_pad, tb)
+        return ani_cov_from_intersections(inter, packed.counts, k)
 
 
 def all_vs_all_containment(

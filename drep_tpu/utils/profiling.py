@@ -34,7 +34,8 @@ from typing import Any, Iterator
 
 from drep_tpu.utils import telemetry
 
-# distinct one-shot program shapes the record lists (Counters.add_secondary_call)
+# distinct program shapes the record lists, for the one-shot calls and for
+# the chunked calls each (Counters.add_secondary_call, .add_chunked_call)
 SECONDARY_SHAPES_MAX = 64
 
 
@@ -228,6 +229,10 @@ class Counters:
     # calls, clusters and rows went through each [rows_pad, width] x v_pad
     # program, and how many of the rows_pad^2/2 pairs it computed were read
     secondary_calls: dict[tuple[int, int, int], dict[str, int]] = field(default_factory=dict)
+    # the vocabulary-chunked secondary calls by shape (ops/containment.py):
+    # what each [chunks, rows_pad, width] stacked id tensor shipped, how much
+    # of it was padding, and the chunk program it ran `chunks` times
+    chunked_calls: dict[tuple[int, int, int, int, str], dict[str, int]] = field(default_factory=dict)
     _open: threading.local = field(default_factory=threading.local, repr=False, compare=False)
     _lock: threading.Lock = field(default_factory=threading.Lock, repr=False, compare=False)
 
@@ -317,6 +322,27 @@ class Counters:
         ent["clusters"] += int(clusters)
         ent["rows"] += int(rows)
         ent["useful_pairs"] += int(useful_pairs)
+
+    def add_chunked_call(
+        self, rows: int, rows_pad: int, v_chunk: int, chunks: int, width: int,
+        id_dtype: str, extent: int, hashes: int, id_slots: int, bytes_shipped: int,
+    ) -> None:
+        """Book one vocabulary-chunked secondary call: `rows` genomes with
+        `hashes` real ids over a vocabulary of `extent` went to the device as
+        one [`chunks`, `rows_pad`, `width`] tensor of `id_dtype` (`id_slots`
+        slots, `bytes_shipped` bytes) and through `chunks` runs of the
+        [`rows_pad`, `width`] x `v_chunk` program. Grouped by that shape,
+        every other number summed over the shape's calls, and capped like
+        :meth:`add_secondary_call`: past SECONDARY_SHAPES_MAX shapes the
+        rest is summed per `rows_pad` under zeros."""
+        key = (rows_pad, v_chunk, chunks, width, id_dtype)
+        if key not in self.chunked_calls and len(self.chunked_calls) >= SECONDARY_SHAPES_MAX:
+            key = (rows_pad, 0, 0, 0, "")
+        booked = {"calls": 1, "rows": rows, "extent": extent, "hashes": hashes,
+                  "id_slots": id_slots, "bytes_shipped": bytes_shipped}
+        ent = self.chunked_calls.setdefault(key, dict.fromkeys(booked, 0))
+        for name, value in booked.items():
+            ent[name] += int(value)
 
     def set_gauge(self, name: str, value: float) -> None:
         """Record a derived operational value (last write wins)."""
@@ -421,6 +447,12 @@ class Counters:
                 {"rows_pad": k[0], "width": k[1], "v_pad": k[2], **v}
                 for k, v in sorted(self.secondary_calls.items())
             ]
+        if self.chunked_calls:
+            out["secondary_chunked_calls"] = [
+                {"rows_pad": k[0], "v_chunk": k[1], "chunks": k[2], "width": k[3],
+                 "id_dtype": k[4], **v}
+                for k, v in sorted(self.chunked_calls.items())
+            ]
         phases = self._phases_report()
         if phases:
             out["phases"] = phases
@@ -476,6 +508,7 @@ class Counters:
         self.hists.clear()
         self.paths.clear()
         self.secondary_calls.clear()
+        self.chunked_calls.clear()
         with self._lock:
             self.phases.clear()  # a span open now stays open and books when it closes
 
