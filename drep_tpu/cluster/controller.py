@@ -282,7 +282,6 @@ def _primary_clusters(
     if kw["streaming_primary"] or (
         kw["primary_algorithm"] == "jax_mash" and n >= kw["streaming_threshold"]
     ):
-        from drep_tpu.ops.minhash import pack_sketches
         from drep_tpu.parallel.streaming import streaming_primary_clusters
 
         if not kw["streaming_primary"]:
@@ -299,8 +298,7 @@ def _primary_clusters(
                 kw["primary_estimator"],
             )
         ckpt = wd.get_dir(os.path.join("data", "streaming_primary")) if wd is not None else None
-        with counters.span("primary/pack"):
-            packed = pack_sketches(gs.bottom, gs.names, gs.sketch_size)
+        packed = engines.pack_primary(gs.bottom, gs.names, gs.sketch_size)
         # --clusterAlg carries into the streaming path: average (default)
         # runs sparse UPGMA over the retained edge graph, single runs
         # connected components; anything else raises with guidance — no

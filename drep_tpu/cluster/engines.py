@@ -162,6 +162,23 @@ def mash_distance_matrix(
     return dist
 
 
+def pack_primary(bottom: list[np.ndarray], names: list[str], sketch_size: int):
+    """`pack_sketches` for a primary compare, under the `primary/pack` span
+    (arg `hashes=`: what the pack sorts) and booked in the record's
+    `primary_pack`: the phase's seconds beside what they were spent on."""
+    hashes = sum(min(len(b), sketch_size) for b in bottom)
+    with counters.span("primary/pack", hashes=hashes):
+        packed = pack_sketches(bottom, names, sketch_size)
+    # rows ascend and every rank is used, so the largest id is some row's
+    # last real entry: the vocabulary's size without a pass over the matrix
+    full = packed.counts > 0
+    last = packed.ids[full, packed.counts[full] - 1]
+    counters.add_primary_pack(
+        genomes=packed.n, hashes=hashes, distinct_ids=int(last.max()) + 1 if last.size else 0
+    )
+    return packed
+
+
 @register_primary("jax_mash")
 def primary_jax_mash(
     gs: GenomeSketches,
@@ -175,8 +192,7 @@ def primary_jax_mash(
     Returns (dist [N,N], similarity [N,N]) where similarity = 1 - dist
     (the Mdb convention).
     """
-    with counters.span("primary/pack"):
-        packed = pack_sketches(gs.bottom, gs.names, gs.sketch_size)
+    packed = pack_primary(gs.bottom, gs.names, gs.sketch_size)
     dist = mash_distance_matrix(
         packed, gs.k, mesh_shape=mesh_shape, tile=tile, estimator=primary_estimator
     )

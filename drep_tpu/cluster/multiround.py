@@ -24,7 +24,6 @@ import pandas as pd
 
 from drep_tpu.ingest import GenomeSketches
 from drep_tpu.ops.linkage import cluster_hierarchical
-from drep_tpu.ops.minhash import pack_sketches
 from drep_tpu.utils.logger import get_logger
 
 
@@ -36,9 +35,9 @@ def _cluster_chunk(
     mesh_shape: int | None,
     estimator: str = "auto",
 ) -> np.ndarray:
-    from drep_tpu.cluster.engines import mash_distance_matrix
+    from drep_tpu.cluster.engines import mash_distance_matrix, pack_primary
 
-    packed = pack_sketches([gs.bottom[i] for i in idx], [gs.names[i] for i in idx], gs.sketch_size)
+    packed = pack_primary([gs.bottom[i] for i in idx], [gs.names[i] for i in idx], gs.sketch_size)
     dist = mash_distance_matrix(packed, gs.k, mesh_shape=mesh_shape, estimator=estimator)
     labels, _ = cluster_hierarchical(dist, cutoff, method=method)
     return labels

@@ -42,24 +42,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from drep_tpu.ops.minhash import PAD_ID, PackedSketches, pad_packed_rows
+from drep_tpu.ops.minhash import PAD_ID, PackedSketches, _fill_padded_rows, pad_packed_rows
 from drep_tpu.utils.profiling import counters
-
-
-def _fill_padded_rows(ids: np.ndarray, ranks: np.ndarray, lens: np.ndarray) -> None:
-    """Write ragged rank rows into the preallocated padded matrix (or a
-    row slice of one) `ids`: row r gets the next `lens[r]` of `ranks`, cast
-    to the matrix's dtype on the way; what lies past a row's length keeps
-    its pad value. THE one way this module fills a packed matrix: a
-    contiguous slice copy per row is a memcpy (18 ms for 512 rows of 26k
-    ranks), where the `np.repeat` / `cumsum` / `arange` coordinates and
-    fancy-index scatter it replaces wrote two int64 numbers per rank
-    before the rank (~0.3 s for the same rows, a third of the
-    cluster-local pack: ISSUE 25)."""
-    o = 0
-    for row, n in zip(ids, lens):
-        row[:n] = ranks[o : o + n]
-        o += n
 
 
 def pack_scaled_sketches(

@@ -4,8 +4,8 @@ Replaces the reference's `mash sketch` + `mash paste`/`mash dist` subprocess
 pipeline (drep/d_cluster/external.py::run_MASH, SURVEY.md §3.2 hot loop #1;
 reference mount empty) with:
 
-1. host: uint64 hash sketches -> dense **int32 id space** (one global
-   ``np.unique`` vocabulary). TPUs have no native uint64; instead of paired
+1. host: uint64 hash sketches -> dense **int32 id space** (ranks in one
+   global sorted vocabulary). TPUs have no native uint64; instead of paired
    uint32 lanes we exploit that only *equality and order* of hashes matter,
    so a monotone uint64->int32 rank map is exact and loses nothing.
 2. device: for each genome pair, the proper Mash estimator — Jaccard from
@@ -81,25 +81,51 @@ class PackedSketches:
         return self.ids.shape[1]
 
 
+def _fill_padded_rows(ids: np.ndarray, ranks: np.ndarray, lens: np.ndarray) -> None:
+    """Write ragged rank rows into the preallocated padded matrix (or a
+    row slice of one) `ids`: row r gets the next `lens[r]` of `ranks`, cast
+    to the matrix's dtype on the way; what lies past a row's length keeps
+    its pad value. THE one way to fill a packed matrix (this pack and both
+    scaled packs of ops/containment.py): a contiguous slice copy per row is
+    a memcpy (18 ms for 512 rows of 26k ranks), where the `np.repeat` /
+    `cumsum` / `arange` coordinates and fancy-index scatter it replaces
+    wrote two int64 numbers per rank before the rank (~0.3 s for the same
+    rows, a third of the cluster-local pack: ISSUE 25)."""
+    o = 0
+    for row, n in zip(ids, lens):
+        row[:n] = ranks[o : o + n]
+        o += n
+
+
 def pack_sketches(sketches: list[np.ndarray], names: list[str], sketch_size: int) -> PackedSketches:
     """uint64 bottom-k sketches (sorted unique) -> padded int32 id matrix."""
     if len(sketches) != len(names):
         raise ValueError("sketches and names length mismatch")
     trimmed = [s[:sketch_size] for s in sketches]
-    vocab = np.unique(np.concatenate(trimmed)) if trimmed else np.empty(0, np.uint64)
-    if vocab.size >= np.iinfo(np.int32).max:
-        raise ValueError("id space overflow: >2^31 distinct sketch hashes")
-    n = len(trimmed)
-    ids = np.full((n, sketch_size), PAD_ID, dtype=np.int32)
     lens = np.array([len(s) for s in trimmed], dtype=np.int64)
-    # one searchsorted over the concatenation (the monotone rank map);
-    # per-row calls were a measured hot spot at 10k+ genomes
+    ids = np.full((len(trimmed), sketch_size), PAD_ID, dtype=np.int32)
     flat = np.concatenate(trimmed) if trimmed else np.empty(0, np.uint64)
-    ranks = np.searchsorted(vocab, flat).astype(np.int32)
-    rows = np.repeat(np.arange(n), lens)
-    offs = np.concatenate([[0], np.cumsum(lens)[:-1]]) if n else np.empty(0, np.int64)
-    cols = np.arange(len(flat)) - np.repeat(offs, lens)
-    ids[rows, cols] = ranks
+    if flat.size:
+        # the monotone rank map without a search: a hash's rank in the
+        # sorted vocabulary is the number of run starts at or before it in
+        # the sorted hashes, less one. `np.unique` + `np.searchsorted` gave
+        # the same ranks by one binary search a hash, every level a cache
+        # miss once the vocabulary outgrows the cache: 4.8 of 5.8 s at 10^7
+        # hashes of which 9M distinct (ISSUE 28). Equal hashes get equal
+        # ranks whatever order the sort leaves them in, so it need not be
+        # stable; the default kind is the quicker one (PERF.md section 6)
+        order = np.argsort(flat)
+        srt = flat[order]
+        first = np.ones(len(srt), dtype=bool)
+        np.not_equal(srt[1:], srt[:-1], out=first[1:])
+        # the vocabulary's size, counted before any int32 could wrap
+        if np.count_nonzero(first) >= np.iinfo(np.int32).max:
+            raise ValueError("id space overflow: >2^31 distinct sketch hashes")
+        rank_of_sorted = np.cumsum(first, dtype=np.int32)
+        rank_of_sorted -= 1
+        ranks = np.empty(len(flat), dtype=np.int32)
+        ranks[order] = rank_of_sorted
+        _fill_padded_rows(ids, ranks, lens)
     return PackedSketches(ids=ids, counts=lens.astype(np.int32), names=list(names))
 
 

@@ -233,6 +233,10 @@ class Counters:
     # what each [chunks, rows_pad, width] stacked id tensor shipped, how much
     # of it was padding, and the chunk program it ran `chunks` times
     chunked_calls: dict[tuple[int, int, int, int, str], dict[str, int]] = field(default_factory=dict)
+    # what the primary's packs ranked (cluster/engines.py::pack_primary):
+    # calls, genomes, hashes and the distinct ids they became, summed. The
+    # pack's seconds follow the hashes it sorts (ISSUE 28)
+    primary_pack: dict[str, int] = field(default_factory=dict)
     _open: threading.local = field(default_factory=threading.local, repr=False, compare=False)
     _lock: threading.Lock = field(default_factory=threading.Lock, repr=False, compare=False)
 
@@ -344,6 +348,13 @@ class Counters:
         for name, value in booked.items():
             ent[name] += int(value)
 
+    def add_primary_pack(self, genomes: int, hashes: int, distinct_ids: int) -> None:
+        """Book one `pack_sketches` of the primary compare: `hashes` bottom-k
+        hashes of `genomes` rows became `distinct_ids` int32 ranks."""
+        booked = {"calls": 1, "genomes": genomes, "hashes": hashes, "distinct_ids": distinct_ids}
+        for name, value in booked.items():
+            self.primary_pack[name] = self.primary_pack.get(name, 0) + int(value)
+
     def set_gauge(self, name: str, value: float) -> None:
         """Record a derived operational value (last write wins)."""
         self.gauges[name] = float(value)
@@ -453,6 +464,8 @@ class Counters:
                  "id_dtype": k[4], **v}
                 for k, v in sorted(self.chunked_calls.items())
             ]
+        if self.primary_pack:
+            out["primary_pack"] = dict(self.primary_pack)
         phases = self._phases_report()
         if phases:
             out["phases"] = phases
@@ -509,6 +522,7 @@ class Counters:
         self.paths.clear()
         self.secondary_calls.clear()
         self.chunked_calls.clear()
+        self.primary_pack.clear()
         with self._lock:
             self.phases.clear()  # a span open now stays open and books when it closes
 

@@ -279,11 +279,16 @@ def test_profile_of_the_toy_job_puts_the_spans_on_the_host_plane(toy_job):
     assert any(l.startswith("host:drep:") for l in labels), labels
 
 
-def test_events_on_writes_the_same_schema_and_trace_report_reads_the_new_names(toy_job):
+def _trace_report():
     spec = importlib.util.spec_from_file_location(
         "trace_report", os.path.join(REPO, "tools", "trace_report.py"))
     trace_report = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(trace_report)
+    return trace_report
+
+
+def test_events_on_writes_the_same_schema_and_trace_report_reads_the_new_names(toy_job):
+    trace_report = _trace_report()
     loaded = trace_report.load_events(os.path.join(toy_job["wd"], "log"))
     assert not loaded["bad_lines"] and not loaded["torn_tails"]
     for r in loaded["events"]:
@@ -305,6 +310,42 @@ def test_events_on_writes_the_same_schema_and_trace_report_reads_the_new_names(t
     text = trace_report.text_report(loaded["events"], toy_job["record"])
     assert "stage:secondary" in text and "stage:primary_compare" in text
 
+
+
+def test_primary_pack_says_what_it_ranked(toy_job):
+    """ISSUE 28: the `primary/pack` span round `pack_sketches` carries
+    `hashes=`, and the record's `primary_pack` counts the genomes, the
+    hashes and the distinct ids they became: NumPy's own count."""
+    from drep_tpu.ingest import _load
+    from drep_tpu.workdir import WorkDirectory
+
+    gs = _load(WorkDirectory(toy_job["wd"]), 21, 1000, 200)
+    flat = np.concatenate([b[:1000] for b in gs.bottom])
+    want = {"calls": 1, "genomes": len(gs.names), "hashes": len(flat),
+            "distinct_ids": len(np.unique(flat))}
+    assert want["genomes"] == 5 and want["distinct_ids"] < want["hashes"]  # hashes are shared
+    assert toy_job["record"]["primary_pack"] == want
+    trace_report = _trace_report()
+    spans, _ = trace_report.pair_spans(
+        trace_report.load_events(os.path.join(toy_job["wd"], "log"))["events"])
+    with_hashes = [sp["args"] for sp in spans if sp["ev"] == "primary/pack" and "hashes" in sp["args"]]
+    assert with_hashes == [{"hashes": want["hashes"]}]
+
+
+def test_primary_pack_counter_sums_its_calls_and_counts_no_padding():
+    from drep_tpu.cluster.engines import pack_primary
+    from drep_tpu.utils.profiling import counters
+
+    counters.reset()
+    assert "primary_pack" not in counters.report(device=False)
+    u = lambda *v: np.array(v, np.uint64)  # noqa: E731
+    pack_primary([], [], 4)
+    pack_primary([u(), u()], ["a", "b"], 4)  # rows of padding alone: no id
+    pack_primary([u(3, 5, 7, 9, 11), u(), u(5, 2**64 - 1)], ["a", "b", "c"], 4)  # 11 is cut
+    assert counters.report(device=False)["primary_pack"] == {
+        "calls": 3, "genomes": 5, "hashes": 6, "distinct_ids": 5}
+    counters.reset()
+    assert "primary_pack" not in counters.report(device=False)
 
 
 _PROFILE_ON_A_POD = """
