@@ -169,28 +169,6 @@ def build_parser() -> argparse.ArgumentParser:
                               "as derived_ring_step_timeout_s). Results are "
                               "bit-identical either way; env "
                               "DREP_TPU_RING_MONOLITHIC=1 also forces it")
-        tpu.add_argument("--ring_comm", default="auto",
-                         choices=["auto", "ppermute", "pallas_dma"],
-                         help="dense-ring rotation backend: 'pallas_dma' fuses "
-                              "the ICI rotation into the compare kernel "
-                              "(ops/pallas_ring.py — the neighbor transfer "
-                              "rides a Pallas async remote DMA hidden behind "
-                              "the tile compute); 'ppermute' is the shard_map "
-                              "ring. 'auto' (default) is ppermute: the fused "
-                              "kernel does not compile on the supported "
-                              "toolchain, and an explicit pallas_dma raises "
-                              "what the compiler says. Env DREP_TPU_RING_COMM "
-                              "also accepted (plus 'pallas_interpret', the "
-                              "CPU equality oracle for tests — never a "
-                              "performance mode)")
-        tpu.add_argument("--ring_vmem_mb", type=int, default=None,
-                         help="VMEM budget (MB) the gridded fused ring sizes "
-                              "its per-cell row tiles against "
-                              "(ops/pallas_ring.fused_ring_tile) — a sizing "
-                              "knob, never a refusal: any block size streams "
-                              "through VMEM in tiles that fit. Default from "
-                              "DREP_TPU_RING_VMEM_MB (12). Block tiles and "
-                              "checkpoints are bit-identical at every value")
         tpu.add_argument("--io_retries", type=int, default=None,
                          help="transient shared-filesystem I/O errors "
                               "(EIO/ESTALE/ETIMEDOUT) retried per durable "

@@ -134,8 +134,8 @@ class AutoscaleController:
         env = dict(self._spawn_env if self._spawn_env is not None else os.environ)
         # the whole actuation surface: the joiner self-registers through
         # the pod protocol (join note + heartbeat, leader admission) and
-        # stamps its churn notes as autoscale-driven so bench records of
-        # the governed run refuse as measured perf
+        # stamps its churn notes as autoscale-driven so the governed
+        # run's records book `autoscale_churn`
         env["DREP_TPU_POD_JOIN"] = "auto"
         env["DREP_TPU_AUTOSCALE_SPAWNED"] = "1"
         argv = shlex.split(self.spawn_cmd)

@@ -112,16 +112,6 @@ CLUSTER_DEFAULTS: dict[str, Any] = {
     # collective program kept as the bit-equality reference. Results are
     # bit-identical either way, so it never invalidates a workdir.
     "ring_monolithic": False,
-    # ring rotation backend (parallel/allpairs.py RING_COMM_CHOICES):
-    # "auto" selects the fused pallas DMA step (ops/pallas_ring.py —
-    # ICI rotation overlapped with the tile compute) iff the on-device
-    # self-check validates on a real TPU, else lax.ppermute. Block tiles
-    # are bit-identical across backends, so never a _RESUME_KEY.
-    "ring_comm": "auto",
-    # gridded fused-ring VMEM tile budget (MB); None defers to the
-    # DREP_TPU_RING_VMEM_MB env knob (12). Pure tile-sizing — block tiles
-    # are bit-identical at every value, so never a _RESUME_KEY.
-    "ring_vmem_mb": None,
 }
 
 _RESUME_KEYS = [
@@ -588,13 +578,9 @@ def d_cluster_wrapper(wd: WorkDirectory, bdb: pd.DataFrame, **kwargs) -> pd.Data
     # primary/secondary rings kill-resumable and pod-death elastic.
     # --ring_monolithic False maps to None so DREP_TPU_RING_MONOLITHIC
     # can still force the reference program for an A/B check.
-    # --ring_comm "auto" maps to None so DREP_TPU_RING_COMM still governs
-    # (the same deference --ring_monolithic gives its env override)
     configure_ring(
         monolithic=True if kw["ring_monolithic"] else None,
         checkpoint_base=os.path.join(wd.location, "data", "dense_ring"),
-        comm=None if kw["ring_comm"] == "auto" else kw["ring_comm"],
-        vmem_mb=kw["ring_vmem_mb"],
     )
     snapshot = {k: kw.get(k) for k in _RESUME_KEYS if k != "genomes"}
     # normalize: CLI passes 0.25 explicitly, library callers omit it — the
@@ -716,7 +702,7 @@ def d_cluster_wrapper(wd: WorkDirectory, bdb: pd.DataFrame, **kwargs) -> pd.Data
         # engines clamp their mesh to LOCAL devices (engines._mesh_or_none
         # — a global mesh would dispatch a collective that waits on the
         # corpse forever), and the honest counters (dead_processes /
-        # pod_epoch_bumps) ride into perf_counters.json + bench records
+        # pod_epoch_bumps) ride into perf_counters.json
         # so a degraded run can never read as a clean measurement.
         logger.warning(
             "degraded pod: process(es) %s died during the primary stage; "

@@ -16,7 +16,7 @@ computes only the canonical upper-triangle pair tiles (single chip: blocked
 half-ring, parallel/allpairs.py) and mirrors the transposed blocks on host
 — ~2x genome-pairs/sec/chip on the same hardware. The schedules record
 ``tiles_computed / tiles_total`` into utils/profiling counters so the
-triangular engagement is observable in perf_counters.json and bench.py.
+triangular engagement is observable in perf_counters.json.
 """
 
 from __future__ import annotations
@@ -199,35 +199,6 @@ def primary_jax_mash(
     return dist, 1.0 - dist
 
 
-# measured per-element cost ratio of the VPU bitonic merge vs the int8 MXU
-# indicator matmul. The beyond-budget dispatch weighs merge work
-# (2*s2*log2(2*s2) units/pair) against chunked-matmul work (v_pad
-# columns/pair) with this penalty on the merge side; the merge only wins
-# when the vocabulary outgrows ~47x the merge units (very diverse
-# clusters).
-# The value is from an earlier chip run, not re-measured: both kernels
-# timed at four vocabulary/merge-unit ratios (8x/20x/40x/100x), the
-# winner flipping between ratio 40 (matmul) and ratio 100 (merge). The
-# triangle-only refactor (ISSUE 1) has since cut the chunked-matmul
-# side's FLOPs ~1.8x, so a fresh fit is expected to land LOWER. ROADMAP
-# D2 re-measures both sides and keeps the choice only if a cell sits on
-# each side of it.
-MERGE_VS_MATMUL_ELEM_COST = 47.0
-
-
-def beyond_budget_secondary_path(sketch_width: int, v_pad: int) -> str:
-    """Which single-chip kernel owns a beyond-one-shot-budget cluster —
-    THE dispatch rule (containment_matrices applies it; the bench reports
-    it), so the benchmark can never drift from what the engine runs."""
-    from drep_tpu.ops.merge import next_pow2
-
-    s2 = max(128, next_pow2(sketch_width))
-    merge_units = 2 * s2 * ((2 * s2).bit_length() - 1)
-    if MERGE_VS_MATMUL_ELEM_COST * merge_units < v_pad:
-        return "pallas_range"
-    return "matmul_chunked"
-
-
 def _count_path(path: str) -> None:
     """Book which kernel path served this containment call into the run
     record (perf_counters.json `secondary_paths`): a measurement must be
@@ -273,20 +244,20 @@ def containment_matrices(
     Every path is triangle-only (intersection counts are symmetric; the
     directional cov derives from counts on host): the matmul paths run
     canonical (bi <= bj) blocks, the mesh ring the half-ring schedule,
-    the Pallas merge its wrapped symmetric grid, the CPU fallback an
-    upper-triangle tile walk — all mirror-exact vs their full grids.
+    the CPU reference an upper-triangle tile walk — all mirror-exact vs
+    their full grids.
 
-    Preference order (measured on v5e):
-    1. MXU indicator-matmul — ~340x faster than the gather path and exact;
-       used whenever the [m, vocab] int8 indicator fits the budget.
-    2. ring-sharded mesh path (multi-device, beyond-budget clusters).
-    3. beyond-budget single chip — BOTH remaining kernels extend to any
-       width/vocab by range partitioning (ops/rangepart.py), so the cheaper
-       one wins by the cost model above: vocab-chunked MXU matmul
-       (cost/pair ∝ v_pad) vs range-partitioned Pallas merge (cost/pair ∝
-       s2·log s2, vocabulary-independent — owns the diverse-cluster regime
-       where the vocabulary far outgrows the sketch width).
-    4. tiled searchsorted fallback (CPU; gathers are fine off-TPU).
+    The choice, from what the call can observe (the one-shot budget, the
+    mesh, the platform), each path booked in `secondary_paths`:
+    1. `one_shot`: the MXU indicator matmul, exact, whenever the
+       [m, vocab] int8 indicator fits the one-shot budget.
+    2. `mesh_ring`: beyond the budget with more than one device, the
+       ring-sharded half-ring (parallel/allpairs.py).
+    3. beyond the budget on one device: on a TPU `matmul_chunked`, the
+       same matmul over vocabulary chunks (ops/rangepart.py; cost/pair ∝
+       v_pad); off a TPU `cpu_tiles`, the tiled searchsorted walk — the
+       plain reference the tests compare the kernels against (gathers
+       are fine off-TPU).
     """
     import jax
 
@@ -309,12 +280,7 @@ def containment_matrices(
         _count_path("mesh_ring")
         return sharded_containment_allpairs(packed, k=k, mesh=mesh)
     if jax.devices()[0].platform == "tpu":
-        if beyond_budget_secondary_path(packed.sketch_size, v_pad) == "pallas_range":
-            from drep_tpu.ops.pallas_merge import all_vs_all_containment_pallas
-
-            # no fallback: a kernel that fails to compile or run raises
-            _count_path("pallas_range")
-            return all_vs_all_containment_pallas(packed, k=k)
+        # no fallback: a kernel that fails to compile or run raises
         _count_path("matmul_chunked")
         return all_vs_all_containment_matmul_chunked(packed, k=k)
     _count_path("cpu_tiles")

@@ -56,7 +56,7 @@ def sketch_args_snapshot(
     genomes, k: int, sketch_size: int, scale: int, hash_name: str
 ) -> dict:
     """THE sketch-cache compatibility key. Anything that pre-populates a
-    workdir sketch cache (bench.py's e2e stage, tests) must build the
+    workdir sketch cache (benchmark/'s generators, tests) must build the
     snapshot through this helper so it can never drift from the check in
     :func:`sketch_genomes`."""
     return {

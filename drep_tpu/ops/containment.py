@@ -183,14 +183,6 @@ def _pair_intersection(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     return jnp.sum(hit.astype(jnp.int32))
 
 
-def containment_inter_tile_raw(a_ids, b_ids):
-    """The UNJITTED symmetric intersection tile body — shared by
-    :func:`containment_inter_tile` and the fused Pallas ring step
-    (ops/pallas_ring.py traces it inside its own kernel)."""
-    row = jax.vmap(_pair_intersection, in_axes=(None, 0))
-    return jax.vmap(row, in_axes=(0, None))(a_ids, b_ids)
-
-
 @jax.jit
 def containment_inter_tile(a_ids, b_ids):
     """SYMMETRIC intersection-size tile between sketch blocks:
@@ -199,7 +191,8 @@ def containment_inter_tile(a_ids, b_ids):
     (set intersection is symmetric), so mirrored blocks are transposed
     copies, never recomputed. cov/ani derive from counts on host
     (:func:`ani_cov_from_intersections`)."""
-    return containment_inter_tile_raw(a_ids, b_ids)
+    row = jax.vmap(_pair_intersection, in_axes=(None, 0))
+    return jax.vmap(row, in_axes=(0, None))(a_ids, b_ids)
 
 
 def containment_to_ani(c, k: int, xp=np):
@@ -288,9 +281,9 @@ def matmul_vocab_pad(packed: PackedSketches) -> int:
 
 def one_shot_fits(n_rows: int, v_pad: int) -> bool:
     """Whether the [rows, v_pad(+trash)] indicator fits the one-shot
-    budget — THE dispatch inequality (containment_matrices, the batched
-    engine, and the bench all read this one definition so the budget rule
-    cannot drift between them)."""
+    budget — THE dispatch inequality (containment_matrices and the
+    batched engine read this one definition so the budget rule cannot
+    drift between them)."""
     return matmul_rows_pad(n_rows) * (v_pad + 1) <= MATMUL_BUDGET_ELEMS
 
 

@@ -1,7 +1,7 @@
 """Bitonic merge of pre-sorted sketch rows — the shared compare-exchange core.
 
 Both all-pairs estimators (the Mash union-bottom-s Jaccard in ops/minhash.py
-and the containment intersection in ops/pallas_merge.py) need the sorted
+and the jnp streaming tiles in parallel/streaming.py) need the sorted
 merge of two already-sorted hash-id rows. A full ``jnp.sort`` of the
 concatenation costs O(log^2 L) compare-exchange stages; but the
 concatenation of an ascending row with a reversed ascending row is
@@ -31,8 +31,7 @@ def next_pow2(n: int) -> int:
 # once — 2^28 elements is ~1 GB per temp, which measured ~3-4 GB peak on v5e
 # (16 GB HBM). Uncapped tiles at production widths hard-OOM the chip (an
 # uncapped 128-tile at sketch width 32768 wants ~4.3 GB PER temp). The ONE
-# budget rule for every jnp-merge tiling loop (parallel/streaming.py and the
-# pallas_merge over-width fallback) — kept here so the callers cannot drift.
+# budget rule for every jnp-merge tiling loop (parallel/streaming.py).
 SORT_TILE_BUDGET_ELEMS = 1 << 28
 
 

@@ -190,34 +190,21 @@ def mash_distance_from_jaccard(j, k: int, xp=jnp):
     return xp.clip(d, 0.0, 1.0)
 
 
-def mash_tile_raw(k: int):
-    """The UNJITTED (distance, jaccard) tile body — THE one definition
-    both :func:`mash_distance_tile` and the fused Pallas ring step
-    (ops/pallas_ring.py, which must trace it inside its own kernel)
-    share, so the estimators cannot drift."""
-
-    def tile(a_ids, a_counts, b_ids, b_counts):
-        def one_pair(a, na, b, nb):
-            shared, s_use = _pair_shared(a, b, na, nb)
-            j = jnp.where(s_use > 0, shared / jnp.maximum(s_use, 1), 0.0)
-            return mash_distance_from_jaccard(j, k), j
-
-        row = jax.vmap(one_pair, in_axes=(None, None, 0, 0))
-        return jax.vmap(row, in_axes=(0, 0, None, None))(
-            a_ids, a_counts, b_ids, b_counts
-        )
-
-    return tile
-
-
 @functools.partial(jax.jit, static_argnames=("k",))
 def mash_distance_tile(a_ids, a_counts, b_ids, b_counts, *, k: int = 21):
-    """Distance tile [Ta, Tb] between two blocks of packed sketches.
+    """(distance, jaccard) tiles [Ta, Tb] between two blocks of packed sketches.
 
     a_ids [Ta, s] int32 sorted+padded, a_counts [Ta]; likewise b. Pure
     fixed-shape ops -> vmap twice; XLA fuses the sort/cumsum chain per pair.
     """
-    return mash_tile_raw(k)(a_ids, a_counts, b_ids, b_counts)
+
+    def one_pair(a, na, b, nb):
+        shared, s_use = _pair_shared(a, b, na, nb)
+        j = jnp.where(s_use > 0, shared / jnp.maximum(s_use, 1), 0.0)
+        return mash_distance_from_jaccard(j, k), j
+
+    row = jax.vmap(one_pair, in_axes=(None, None, 0, 0))
+    return jax.vmap(row, in_axes=(0, 0, None, None))(a_ids, a_counts, b_ids, b_counts)
 
 
 def all_vs_all_mash(

@@ -5,9 +5,13 @@ computes Mash distance tiles with the jnp bitonic merge
 (ops/minhash.py::mash_distance_tile). That formulation materializes
 [T, T, 2*S2] s32 temporaries in HBM and re-reads them once per merge
 stage — measured HBM-bound at ~0.5 M pairs/s/chip on v5e. This kernel
-keeps each [TILE_B, 2*S2] merge batch resident in VMEM (like
-ops/pallas_merge.py, whose bitonic stages it reuses) and adds the two
-pieces the plain intersection kernel lacks:
+keeps each [TILE, 2*S2] merge batch resident in VMEM: for each A row it
+merges that row with every B row of the tile at once via Batcher's
+bitonic merge (ops/merge.py is the jnp formulation; an ascending row
+concatenated with a descending row is bitonic, so log2(2*S2)
+compare-exchange stages of full-width `pltpu.roll` + min/max yield the
+sorted merge, and adjacent duplicates are the intersection), then adds
+the two pieces the Mash estimator needs beyond the intersection:
 
 - a Hillis-Steele prefix sum over lanes (same roll+mask primitive as the
   merge stages) giving each merged position its DISTINCT rank in the
@@ -15,8 +19,9 @@ pieces the plain intersection kernel lacks:
 - the per-pair cutoff s_use = min(|A|, |B|, s), so a duplicate only
   counts when its value lies within the bottom-s_use distinct hashes of
   the union — the proper Mash estimator, bit-identical to
-  ops/minhash.py::_pair_shared (equality-tested, both interpret-mode and
-  compiled in bench.py).
+  ops/minhash.py::_pair_shared (equality-tested in interpret mode by
+  tests/test_pallas_mash.py; benchmark/ checks the compiled kernel's
+  job against its plain reference on the chip).
 
 Returns raw `shared` counts; the jaccard->distance transform runs on host
 through the SAME mash_distance_from_jaccard the jnp path uses, so the two
@@ -35,9 +40,32 @@ from jax.experimental.pallas import tpu as pltpu
 
 from drep_tpu.ops.merge import next_pow2
 from drep_tpu.ops.minhash import PAD_ID, mash_distance_from_jaccard
-from drep_tpu.ops.pallas_merge import PALLAS_MAX_WIDTH, _merge_bitonic, _use_interpret
 
 TILE = 128  # both tile dims: the pair tile's last dim must be lane-width
+# widest sketch whose [TILE, 2*S2] merge working set fits VMEM (~16 MB)
+PALLAS_MAX_WIDTH = 2048
+
+
+def _use_interpret() -> bool:
+    # device platform, not jax.default_backend(): TPU access can ride a
+    # plugin whose backend name differs while devices still report "tpu"
+    return jax.devices()[0].platform != "tpu"
+
+
+def _merge_bitonic(x: jnp.ndarray, length: int) -> jnp.ndarray:
+    """Bitonic merge of a [..., length] bitonic batch along the last
+    (lane) axis, via roll + masked min/max (Mosaic-friendly: no sub-lane
+    reshapes)."""
+    axis = x.ndim - 1
+    col = jax.lax.broadcasted_iota(jnp.int32, x.shape, axis)
+    d = length // 2
+    while d >= 1:
+        left = pltpu.roll(x, length - d, axis)  # partner for the low half: x[p + d]
+        right = pltpu.roll(x, d, axis)  # partner for the high half: x[p - d]
+        low_half = (col % (2 * d)) < d
+        x = jnp.where(low_half, jnp.minimum(x, left), jnp.maximum(x, right))
+        d //= 2
+    return x
 
 
 def rows_per_iter(s2: int) -> int:
@@ -182,10 +210,10 @@ def _mash_shared_grid(a_rev, na, b, nb, *, s_orig: int, r_iter: int, interpret: 
 def _mash_shared_grid_symmetric(a_rev, na, b, nb, *, s_orig: int, r_iter: int, interpret: bool):
     """Self-comparison: shared counts are symmetric in (A, B), so the
     (T, T//2+1) wrapped grid — cell (i, jj) computes tile (i, (i+jj)%T) —
-    covers every unordered tile pair at ~2x less kernel work (the same
-    trick as pallas_merge._intersect_grid_symmetric). Output is the
-    compact wrapped matrix; callers unwrap with
-    pallas_merge._unwrap_symmetric."""
+    covers every unordered tile pair at ~2x less kernel work (for even T
+    the last column double-covers half, the unwrap just overwrites).
+    Output is the compact wrapped matrix [n, (T//2+1)*TILE];
+    :func:`_unwrap_symmetric` scatters it on host."""
     n, s2 = a_rev.shape
     t = n // TILE
     th = t // 2 + 1
@@ -211,6 +239,23 @@ def _mash_shared_grid_symmetric(a_rev, na, b, nb, *, s_orig: int, r_iter: int, i
     )(a_rev, na, b, nb)
 
 
+def _unwrap_symmetric(compact: np.ndarray, tile: int) -> np.ndarray:
+    """[na, th*tile] wrapped-compact tiles -> full symmetric [na, na]."""
+    na = compact.shape[0]
+    t = na // tile
+    th = compact.shape[1] // tile
+    out = np.empty((na, na), dtype=compact.dtype)
+    for i in range(t):
+        rows = slice(i * tile, (i + 1) * tile)
+        for jj in range(th):
+            j = (i + jj) % t
+            cols = slice(j * tile, (j + 1) * tile)
+            blk = compact[rows, jj * tile : (jj + 1) * tile]
+            out[rows, cols] = blk
+            out[cols, rows] = blk.T
+    return out
+
+
 def all_vs_all_mash_pallas(packed, k: int = 21) -> tuple[np.ndarray, np.ndarray]:
     """Full [N, N] (distance, jaccard) for one packed sketch set — the
     single-chip TPU primary engine (faster end to end than the MXU
@@ -218,7 +263,6 @@ def all_vs_all_mash_pallas(packed, k: int = 21) -> tuple[np.ndarray, np.ndarray]
     ROADMAP D3 — AND it computes the reference-faithful union-bottom-s
     estimator, not an alternative family). Same output
     contract as ops/minhash.py::all_vs_all_mash."""
-    from drep_tpu.ops.pallas_merge import _unwrap_symmetric
     from drep_tpu.utils.profiling import counters
 
     n = packed.n

@@ -2,18 +2,15 @@
 
 Intersection counts are exactly additive over disjoint hash ranges:
 |A ∩ B| = Σ_r |A∩[b_r,b_{r+1}) ∩ B∩[b_r,b_{r+1})|. That one identity
-extends BOTH fixed-budget device kernels to production sketch widths
+extends the fixed-budget device kernel to production sketch widths
 (4 Mb genomes at the default scale=200 give ~20k-wide scaled sketches,
-far past any single-call VMEM or indicator budget — SURVEY.md §7 hard
-part (c); reference mount empty, no counterpart to cite):
-
-- the VMEM-resident Pallas bitonic merge (ops/pallas_merge.py) caps the
-  mergeable width at PALLAS_MAX_WIDTH — partition ids by range so every
-  bucket repacks to a narrow matrix, merge per bucket, sum counts;
-- the MXU indicator matmul (ops/containment.py) caps m·vocab — it chunks
-  the *vocabulary* instead: containment._stacked_vocab_chunks repacks the
-  per-chunk rows on host with this module's bucket_starts/repack_bucket,
-  ships ONE stacked tensor, and runs the same indicator matmul per chunk.
+far past any single-call indicator budget — SURVEY.md §7 hard part (c);
+reference mount empty, no counterpart to cite): the MXU indicator matmul
+(ops/containment.py) caps m·vocab, so it chunks the *vocabulary*:
+containment._stacked_vocab_chunks repacks the per-chunk rows on host with
+this module's bucket_starts/repack_bucket, ships ONE stacked tensor, and
+runs the same indicator matmul per chunk. The federated index shards its
+band-code space with the same partition (index/federation.py).
 
 Rows hold DISTINCT sorted ids (sketches are sets), so a bucket covering
 `w` consecutive id values can contribute at most `w` entries per row —
@@ -32,7 +29,7 @@ from collections.abc import Iterator
 import numpy as np
 
 from drep_tpu.ops.merge import next_pow2
-from drep_tpu.ops.minhash import PAD_ID, U16_PAD, pad_sentinel
+from drep_tpu.ops.minhash import PAD_ID, pad_sentinel
 
 MIN_BUCKET_WIDTH = 128  # lane width — never repack below one full lane row
 
@@ -117,8 +114,8 @@ def bitmap_contains_any(bitmap: np.ndarray, codes: np.ndarray) -> bool:
 
 def vocab_extent(ids: np.ndarray) -> int:
     """1 + max real id (0 when everything is padding) — THE extent rule:
-    the range partitioner, the matmul vocab bucketing, the chunk geometry,
-    and the bench's FLOP model all derive from this one definition.
+    the range partitioner, the matmul vocab bucketing and the chunk
+    geometry all derive from this one definition.
     uint16 packs (link-compressed cluster-local layout) use their own pad
     sentinel."""
     valid = ids != pad_sentinel(ids.dtype)
@@ -204,16 +201,26 @@ def partition_by_range(
         raise ValueError(f"max_count {max_count} below lane width {MIN_BUCKET_WIDTH}")
     if max_count & (max_count - 1):
         # widths are pow2-bucketed, so a non-pow2 bound would be silently
-        # exceeded (next_pow2(1400) = 2048 > 1500) — VMEM-sized callers
-        # must get exactly the bound they budgeted for
+        # exceeded (next_pow2(1400) = 2048 > 1500) — callers must get
+        # exactly the bound they budgeted for
         raise ValueError(f"max_count {max_count} must be a power of two")
     vocab = _vocab_extent(mats)
     if vocab == 0:
         return
-    chunk, starts, hists, keep, _width = _stacked_plan(mats, max_count, vocab=vocab)
-    for r in keep:
+    longest = max(int((m != PAD_ID).sum(axis=1).max()) for m in mats)
+    n_buckets = next_pow2(-(-longest // max_count))
+    while True:
+        chunk = -(-vocab // n_buckets)
+        starts = [bucket_starts(m, chunk, n_buckets) for m in mats]
+        hists = [np.diff(s, axis=1) for s in starts]
+        if max(int(h.max()) for h in hists) <= max_count or chunk <= max_count:
+            break
+        n_buckets *= 2
+    for r in range(n_buckets):
         counts_r = [h[:, r] for h in hists]
         w = max(int(c.max()) for c in counts_r)
+        if w == 0:
+            continue  # empty across all inputs
         width = max(MIN_BUCKET_WIDTH, next_pow2(w))
         yield (
             r * chunk,
@@ -222,107 +229,3 @@ def partition_by_range(
                 for m, s, c in zip(mats, starts, counts_r)
             ],
         )
-
-
-def _stacked_plan(
-    mats: list[np.ndarray],
-    max_count: int,
-    min_buckets: int = 1,
-    vocab: int | None = None,
-    longest: int | None = None,
-):
-    """Bucket plan (chunk, starts, hists, kept bucket ids, common width)
-    for a stacked layout, WITHOUT materializing — callers compare plans
-    by byte size before paying the repack. `vocab`/`longest` accept the
-    caller's already-computed scans (each is a full pass over the id
-    matrices — ~17M elements/side at production shape)."""
-    if longest is None:
-        longest = max(int((m != PAD_ID).sum(axis=1).max()) for m in mats)
-    if vocab is None:
-        vocab = _vocab_extent(mats)
-    n_buckets = max(min_buckets, next_pow2(-(-longest // max_count)), 1)
-    while True:
-        chunk = -(-vocab // n_buckets)
-        starts = [bucket_starts(m, chunk, n_buckets) for m in mats]
-        hists = [np.diff(s, axis=1) for s in starts]
-        worst = max(int(h.max()) for h in hists)
-        if worst <= max_count or chunk <= max_count:
-            break
-        n_buckets *= 2
-    keep = [r for r in range(n_buckets) if any(int(h[:, r].max()) > 0 for h in hists)]
-    width = max(MIN_BUCKET_WIDTH, next_pow2(worst))
-    return chunk, starts, hists, keep, width
-
-
-def _materialize_stacked(mats, chunk, starts, hists, keep, width, dtype):
-    out = []
-    rebase = dtype == np.uint16  # u16 needs per-bucket local values
-    pad = pad_sentinel(dtype)
-    for m, s, h in zip(mats, starts, hists):
-        stacked = np.full((len(keep), m.shape[0], width), pad, dtype)
-        for o, r in enumerate(keep):
-            b = repack_bucket(m, s[:, r], h[:, r], width, rebase=r * chunk if rebase else 0)
-            if rebase:
-                stacked[o] = np.where(b == PAD_ID, U16_PAD, b).astype(np.uint16)
-            else:
-                stacked[o] = b
-        out.append(stacked)
-    return out
-
-
-def stacked_range_buckets(
-    mats: list[np.ndarray], max_count: int, dtype: str = "auto"
-) -> list[np.ndarray]:
-    """Range partition like :func:`partition_by_range`, but materialized as
-    ONE [R, N_i, W] stacked tensor per input at a COMMON pow2 width W
-    (<= max_count) — the layout the fused Pallas merge grid consumes
-    (ops/pallas_merge.py): all buckets cross the host->device link in one
-    transfer and run in one kernel launch with an innermost
-    bucket-accumulation grid dimension, instead of R separate repacks +
-    transfers + launches (which an earlier chip run, not re-measured, found
-    overhead-bound rather than compute-bound).
-
-    Buckets empty across ALL inputs are dropped (R counts kept buckets
-    only). Two dtype plans are compared by actual byte size and the
-    smaller ships:
-
-    - int32, global ids (no rebase): each bucket's rows share one
-      disjoint global range, so cross-bucket collisions are impossible.
-    - uint16, PER-BUCKET REBASED ids (pad 0xFFFF) when a finer partition
-      brings every chunk under 2^16: HALF the host->device bytes — the
-      fused kernel is link-floored at production width on slow links —
-      at the cost of more, narrower buckets (total merge work SHRINKS
-      with bucket count: Σ 2W·log2W falls as W does; only padding skew
-      can lose). The kernel widens on device (ops/pallas_merge._widen_ids).
-    """
-    if max_count < MIN_BUCKET_WIDTH:
-        raise ValueError(f"max_count {max_count} below lane width {MIN_BUCKET_WIDTH}")
-    if max_count & (max_count - 1):
-        raise ValueError(f"max_count {max_count} must be a power of two")
-    if dtype not in ("auto", "int32"):
-        raise ValueError(f"dtype {dtype!r}: expected 'auto' or 'int32'")
-    vocab = _vocab_extent(mats)
-    if vocab == 0:
-        return [np.full((0, m.shape[0], MIN_BUCKET_WIDTH), PAD_ID, np.int32) for m in mats]
-    longest = max(int((m != PAD_ID).sum(axis=1).max()) for m in mats)
-    plan32 = _stacked_plan(mats, max_count, vocab=vocab, longest=longest)
-    best = (plan32, np.int32)
-    if dtype == "auto":
-        # the u16 plan forces chunk <= 65535 (rebased values + the 0xFFFF
-        # sentinel must fit 16 bits); when plan32's chunk already fits,
-        # the u16 plan IS plan32 — don't pay the planning pass twice
-        min_b = max(1, next_pow2(-(-vocab // 0xFFFF)))
-        plan16 = (
-            plan32
-            if plan32[0] <= 0xFFFF
-            else _stacked_plan(mats, max_count, min_buckets=min_b, vocab=vocab, longest=longest)
-        )
-        if plan16[0] <= 0xFFFF:
-            bytes32 = len(plan32[3]) * plan32[4] * 4
-            bytes16 = len(plan16[3]) * plan16[4] * 2
-            if bytes16 < bytes32:
-                best = (plan16, np.uint16)
-    plan, dtype_np = best
-    return _materialize_stacked(mats, *plan, dtype_np)
-
-

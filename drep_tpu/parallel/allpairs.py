@@ -48,18 +48,10 @@ healthy run from the shared shard store. The monolithic single-program
 ring is kept behind ``monolithic=True`` / ``--ring_monolithic`` /
 ``DREP_TPU_RING_MONOLITHIC=1`` as the bit-equality reference.
 
-Fused DMA rotation (ISSUE 8): each rotating step's shard_map program can
-be swapped for the fused Pallas kernel (ops/pallas_ring.py) that starts
-the ICI transfer of the B operand to the ring neighbor and computes the
-tile WHILE it flies. Backend selection is
-explicit (``--ring_comm`` / ``DREP_TPU_RING_COMM`` /
-:func:`resolve_ring_comm`; the default is ppermute); block tiles are
-bit-identical across backends (pinned in tests), so checkpoint shards,
-resume, per-block recovery, and the elastic death protocol are all
-backend-agnostic — a fused step that FAULTS at run time falls into the
-SAME per-block (collective-free) recovery path as a faulted ppermute
-step, while a step program that does not BUILD ends the run
-(parallel/faulttol.py).
+Every step runs the one shard_map program (:func:`_ring_step_fn`: the
+tile, then the ``lax.ppermute`` hop). A step that FAULTS at run time
+falls into the per-block (collective-free) recovery path, while a step
+program that does not BUILD ends the run (parallel/faulttol.py).
 """
 
 from __future__ import annotations
@@ -89,40 +81,29 @@ _STAGE_OF_KIND = {"mash": "primary", "containment": "secondary"}
 # monolithic-reference opt-in: explicit argument > configure_ring() >
 # env var > step-wise default
 RING_MONOLITHIC_ENV = "DREP_TPU_RING_MONOLITHIC"
-# ring comm backend request: explicit argument > configure_ring() > env >
-# "auto" (= lax.ppermute; resolve_ring_comm)
-RING_COMM_ENV = "DREP_TPU_RING_COMM"
-RING_COMM_CHOICES = ("auto", "ppermute", "pallas_dma", "pallas_interpret")
 
 # per-ring-step AutoTimeout warmup: exclude exactly the FIRST step's wait
-# from the rolling median — it absorbs the step program's compile (the
-# fused pallas step's Mosaic compile is the heaviest case), and the
-# default TileExecutor warmup (8) would discard the entire half-ring
-# schedule at production D (gauges.derived_ring_step_timeout_s never
-# derived). The warm/cold split is one step for every comm backend.
+# from the rolling median — it absorbs whatever is still cold after the
+# up-front build (executable load, first DMA), and the default
+# TileExecutor warmup (8) would discard the entire half-ring schedule at
+# production D (gauges.derived_ring_step_timeout_s never derived).
 RING_STEP_WARMUP = 1
 
 # process-wide ring execution config, set once per run by the cluster
 # controller from the CLI flags (same pattern as faulttol's
 # configure_defaults): engines call ring_allpairs deep inside replicated
 # control flow and cannot thread a workdir down to it.
-_RING_CONFIG: dict = {
-    "monolithic": None, "checkpoint_base": None, "comm": None, "vmem_mb": None,
-}
+_RING_CONFIG: dict = {"monolithic": None, "checkpoint_base": None}
 
 
 def configure_ring(
     monolithic: bool | None = None,
     checkpoint_base: str | None = None,
-    comm: str | None = None,
-    vmem_mb: int | None = None,
 ) -> None:
     """Install run-wide ring defaults: `monolithic` forces the single
     collective reference program; `checkpoint_base` roots the step-wise
     ring's per-call block shard stores (one subdirectory per distinct
-    input fingerprint, created lazily when a ring actually runs); `comm`
-    picks the rotation backend (RING_COMM_CHOICES — None defers to
-    DREP_TPU_RING_COMM, then "auto").
+    input fingerprint, created lazily when a ring actually runs).
 
     This REPLACES the whole config — an omitted argument resets that knob
     to its default (None), it does not preserve the previous value; a
@@ -130,54 +111,12 @@ def configure_ring(
     flip one knob mid-run, pass all."""
     _RING_CONFIG["monolithic"] = monolithic
     _RING_CONFIG["checkpoint_base"] = checkpoint_base
-    _RING_CONFIG["comm"] = comm
-    _RING_CONFIG["vmem_mb"] = vmem_mb
-
-
-def ring_vmem_mb_override() -> int | None:
-    """The run-wide --ring_vmem_mb override (None defers to the
-    DREP_TPU_RING_VMEM_MB env knob inside fused_ring_tile)."""
-    return _RING_CONFIG["vmem_mb"]
 
 
 def ring_monolithic_default() -> bool:
     if _RING_CONFIG["monolithic"] is not None:
         return bool(_RING_CONFIG["monolithic"])
     return envknobs.env_bool(RING_MONOLITHIC_ENV)
-
-
-def ring_comm_requested() -> str:
-    """The comm backend the run ASKS for (config > env > auto) — validated
-    here so a typo'd DREP_TPU_RING_COMM fails loudly, not as a silent
-    auto."""
-    req = _RING_CONFIG["comm"] or envknobs.env_str(RING_COMM_ENV) or "auto"
-    if req not in RING_COMM_CHOICES:
-        raise ValueError(
-            f"ring comm backend {req!r}: expected one of {RING_COMM_CHOICES}"
-        )
-    return req
-
-
-def resolve_ring_comm(mesh, requested: str | None = None) -> str:
-    """The comm backend a step-wise ring over `mesh` RUNS: 'ppermute' (the
-    shard_map ring), 'pallas_dma' (the gridded fused rotate+compare
-    kernel, ops/pallas_ring.py) or 'pallas_interpret' (the same kernel
-    discharged on the host backend — the CPU equality oracle, never a
-    perf claim).
-
-    'auto' is 'ppermute': the fused kernel does not compile on the
-    supported toolchain (ops/pallas_ring.py has the compiler's words), so
-    it is off the default dispatch. An explicit 'pallas_dma' is honored
-    as asked — the ring builds the kernel before its first step and
-    raises whatever the compiler says; nothing falls back silently."""
-    req = requested if requested is not None else ring_comm_requested()
-    if req not in RING_COMM_CHOICES:
-        raise ValueError(
-            f"ring comm backend {req!r}: expected one of {RING_COMM_CHOICES}"
-        )
-    if req == "auto" or mesh.devices.size < 2:
-        return "ppermute"
-    return req
 
 
 def half_ring_steps(n_devices: int) -> int:
@@ -422,12 +361,12 @@ def _ring_step_shard(a_ids, a_counts, b_ids, b_counts, tile_fn, n_devices, rotat
 
 
 @functools.lru_cache(maxsize=None)
-def _ring_step_fn(kind: str, k: int, mesh, rotate: bool) -> tuple[Callable, int]:
+def _ring_step_fn(kind: str, k: int, mesh, rotate: bool) -> Callable:
     """One jitted per-step program per (kind, k, mesh, rotate) — two
     compilations per schedule (the last step skips the dead rotation's
     ICI hop, same optimization as the monolithic program's lax.cond)."""
     make_tile, n_outputs = _TILE_KINDS[kind]
-    fn = jax.jit(
+    return jax.jit(
         jax.shard_map(
             functools.partial(
                 _ring_step_shard,
@@ -444,7 +383,6 @@ def _ring_step_fn(kind: str, k: int, mesh, rotate: bool) -> tuple[Callable, int]
             ),
         )
     )
-    return fn, n_outputs
 
 
 @functools.lru_cache(maxsize=None)
@@ -527,7 +465,6 @@ def ring_allpairs(
     monolithic: bool | None = None,
     checkpoint_dir: str | None = None,
     ft_config=None,
-    ring_comm: str | None = None,
 ) -> tuple[np.ndarray, ...]:
     """Run the `kind` tile kernel over every pair of rows, sharded over the
     mesh. Returns full [N, N] float32 matrices (one per kernel output),
@@ -544,10 +481,7 @@ def ring_allpairs(
     run-wide flag / env) forces the original single collective program,
     kept as the bit-equality reference. `checkpoint_dir` overrides the
     configured per-call block store location (None + no configured base =
-    in-memory only). `ring_comm` picks the step rotation backend
-    (RING_COMM_CHOICES; None defers to configure_ring/env/auto —
-    :func:`resolve_ring_comm`): the fused pallas kernel overlaps the ICI
-    rotation with the tile compute, with bit-identical block tiles.
+    in-memory only).
     """
     if mesh is None:
         mesh = make_mesh()
@@ -566,7 +500,7 @@ def ring_allpairs(
         # the pod's block geometry (from the store meta), not its own
         # local mesh's.
         outs, tiles_computed, grid_d = _ring_allpairs_stepwise(
-            packed, kind, k, mesh, half, checkpoint_dir, ft_config, ring_comm
+            packed, kind, k, mesh, half, checkpoint_dir, ft_config
         )
     else:
         outs = _ring_allpairs_monolithic(packed, kind, k, mesh, half)
@@ -687,7 +621,7 @@ def _read_ring_meta(store: str) -> dict | None:
 
 
 def _ring_allpairs_stepwise(
-    packed, kind, k, mesh, half, checkpoint_dir, ft_config, ring_comm=None
+    packed, kind, k, mesh, half, checkpoint_dir, ft_config
 ) -> tuple[list[np.ndarray], int, int]:
     """The host-stepped elastic ring (module docstring): one dispatch per
     ring step, per-step block tiles checkpointed to a shard store, missing
@@ -1005,59 +939,10 @@ def _ring_allpairs_stepwise(
             and not joining
         )
         aborted = None
-        # honest backend gauge: 1.0 only once a fused pallas step has RUN
-        # this call (set after its wait below, never from the resolution)
-        # — a resume/recovery-only call (run_ring False) executes no
-        # rotation at all and must not inherit a previous call's 1.0
-        counters.set_gauge("ring_comm_pallas", 0.0)
-        fused_ran = False
         if run_ring:
-            # rotation backend for THIS schedule (resolve_ring_comm: the
-            # shard_map ppermute unless the fused pallas kernel was asked
-            # for). Block tiles are bit-identical either way (pinned in
-            # tests), so the choice never touches checkpoint/recovery.
-            req = ring_comm if ring_comm is not None else ring_comm_requested()
-            comm = resolve_ring_comm(mesh, req) if n_steps > 1 else "ppermute"
-            # which backend ran and why, beside the gauge
-            counters.set_note("ring_comm", comm)
-            counters.set_note(
-                "ring_comm_reason",
-                "single-step schedule (nothing to rotate)" if n_steps <= 1
-                else f"requested {req!r}" if req != "auto"
-                else "auto = ppermute: the fused pallas_dma step does not "
-                "compile on this toolchain (ops/pallas_ring.py)",
-            )
             with counters.span(ph + "/put"):
                 ids_d = put_global(ids, NamedSharding(mesh, P(AXIS, None)))
                 counts_d = put_global(counts, NamedSharding(mesh, P(AXIS)))
-
-            def _step_fn(i: int):
-                """(program, fused?) of ring step `i`."""
-                rotate = i < n_steps - 1
-                if rotate and comm != "ppermute":
-                    from drep_tpu.ops.pallas_ring import (
-                        fused_ring_step_fn,
-                        fused_ring_variant,
-                        matmul_ring_vocab_pad,
-                    )
-
-                    variant = fused_ring_variant(kind)
-                    fn, _ = fused_ring_step_fn(
-                        kind, k, mesh,
-                        interpret=comm == "pallas_interpret",
-                        variant=variant,
-                        # static dense-id extent, from the host copy the
-                        # driver already holds (matmul tiles only)
-                        v_pad=matmul_ring_vocab_pad(ids)
-                        if variant == "matmul"
-                        else 0,
-                        vmem_mb=ring_vmem_mb_override(),
-                    )
-                    return fn, True
-                # the final step has no rotation to overlap — the plain
-                # program (which skips the dead hop) is the right one
-                # under EVERY comm backend
-                return _ring_step_fn(kind, k, mesh, rotate)[0], False
 
             # build every distinct step program BEFORE the first dispatch,
             # outside the recovery envelope (parallel/faulttol.py): a step
@@ -1066,8 +951,11 @@ def _ring_allpairs_stepwise(
             # whose blocks get "recovered" one by one. Every step's B
             # operand has the A operand's shape and sharding.
             with counters.span(ph + "/dispatch", steps=n_steps):
-                steps = [_step_fn(i) for i in range(n_steps)]
-                for fn in {id(f): f for f, _ in steps}.values():
+                # the final step skips the dead rotation's ICI hop
+                steps = [
+                    _ring_step_fn(kind, k, mesh, i < n_steps - 1) for i in range(n_steps)
+                ]
+                for fn in dict.fromkeys(steps):
                     build_program(fn, ids_d, counts_d, ids_d, counts_d)
             # only the first step's wait still absorbs anything cold
             # (executable load, first DMA): exclude exactly that one from
@@ -1083,7 +971,7 @@ def _ring_allpairs_stepwise(
             def _dispatch_all() -> list[tuple[int, list]]:
                 out_pending: list[tuple[int, list]] = []
                 b_ids, b_counts = ids_d, counts_d
-                for i, (fn, _fused) in enumerate(steps):
+                for i, fn in enumerate(steps):
                     *outs, b_ids, b_counts = fn(ids_d, counts_d, b_ids, b_counts)
                     out_pending.append((i, outs))
                 return out_pending
@@ -1189,9 +1077,6 @@ def _ring_allpairs_stepwise(
                     # the publish spans inside it
                     with counters.span(ph + "/wait", step=i):
                         _store_step(i, outs)
-                    if steps[i][1] and not fused_ran:
-                        fused_ran = True
-                        counters.set_gauge("ring_comm_pallas", 1.0)
                     # a drain request is honored at the step boundary: this
                     # step's blocks are durable, the departure note goes
                     # out, and the peers re-deal the rest with no
@@ -1404,15 +1289,13 @@ def sharded_mash_allpairs(
     monolithic: bool | None = None,
     checkpoint_dir: str | None = None,
     ft_config=None,
-    ring_comm: str | None = None,
 ) -> np.ndarray:
     """[N, N] Mash distance matrix, ring-sharded over the mesh (half-ring
     triangular schedule unless ``full_grid``; host-stepped elastic
-    execution unless ``monolithic``; rotation backend per ``ring_comm``)."""
+    execution unless ``monolithic``)."""
     (dist,) = ring_allpairs(
         packed, "mash", k, mesh=mesh, full_grid=full_grid,
         monolithic=monolithic, checkpoint_dir=checkpoint_dir, ft_config=ft_config,
-        ring_comm=ring_comm,
     )
     with counters.span("primary/assemble"):
         np.fill_diagonal(dist, 0.0)
@@ -1427,7 +1310,6 @@ def sharded_containment_allpairs(
     monolithic: bool | None = None,
     checkpoint_dir: str | None = None,
     ft_config=None,
-    ring_comm: str | None = None,
 ) -> tuple[np.ndarray, np.ndarray]:
     """([N,N] symmetric max-containment ani, [N,N] directional cov),
     ring-sharded over the mesh. The ring ships symmetric raw intersection
@@ -1437,7 +1319,6 @@ def sharded_containment_allpairs(
     (inter,) = ring_allpairs(
         packed, "containment", k, mesh=mesh, full_grid=full_grid,
         monolithic=monolithic, checkpoint_dir=checkpoint_dir, ft_config=ft_config,
-        ring_comm=ring_comm,
     )
     with counters.span("secondary/post"):
         return ani_cov_from_intersections(inter, packed.counts, k)

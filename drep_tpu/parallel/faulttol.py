@@ -258,10 +258,8 @@ class AutoTimeout:
     `warmup` is the number of leading waits excluded as compile warmup —
     the TileExecutor keeps the default (its schedules run hundreds of
     tiles); the step-wise dense ring passes its own
-    (allpairs.RING_STEP_WARMUP = 1: only the first step is cold — it
-    absorbs the step program's compile, the fused pallas step's Mosaic
-    compile being the heaviest case — and a half-ring schedule has too
-    few steps to discard eight)."""
+    (allpairs.RING_STEP_WARMUP = 1: only the first step is cold, and a
+    half-ring schedule has too few steps to discard eight)."""
 
     def __init__(self, config: "FaultTolConfig", warmup: int = AUTO_TIMEOUT_WARMUP) -> None:
         self.config = config
@@ -463,7 +461,7 @@ def mark_pod_joined(joined: list[int]) -> None:
     """Record admitted joiners WITHOUT degrading the downstream view: a
     pure-join stage (no deaths, no drains) leaves the original pod whole,
     so later barriers keep the healthy jax-collective path — only the
-    provenance/bench stamping needs to know capacity was grafted in."""
+    provenance stamping needs to know capacity was grafted in."""
     _POD["joined"] = list(joined)
 
 
@@ -927,8 +925,7 @@ class HeartbeatManager:
         }
         if envknobs.env_bool("DREP_TPU_AUTOSCALE_SPAWNED"):
             # controller-governed capacity departing: peers adopting this
-            # note book autoscale_churn, so bench records of the governed
-            # run refuse as measured perf (tools/missing_stages.py)
+            # note book autoscale_churn in their run records
             note["autoscale"] = True
         atomic_write_json(self.drain_path(), note)
         counters.add_fault("drain_announced")
@@ -970,7 +967,7 @@ class HeartbeatManager:
             return False
         if autoscaled:
             # the departure was DECIDED by the autoscaling controller, not
-            # an operator/preemption: provenance for bench honesty
+            # an operator/preemption: provenance in the run record
             counters.add_fault("autoscale_churn", autoscaled)
         telemetry.event(
             "drain_adopted", peers=departed, latency_s=round(latency, 3)
@@ -1178,7 +1175,7 @@ class HeartbeatManager:
 
     def _publish_pod_state(self) -> None:
         """Module pod state for DOWNSTREAM consumers (later barriers,
-        bench provenance). Joiners are stage-scoped: the downstream live
+        the run record's provenance). Joiners are stage-scoped: the downstream live
         view holds original members only, and a PURE-join stage (no
         deaths, no drains) leaves the pod state healthy — later stages
         keep the normal collective path over the whole original pod."""
@@ -1320,8 +1317,8 @@ def join_elastic_pod(
         if envknobs.env_bool("DREP_TPU_AUTOSCALE_SPAWNED"):
             # spawned by the autoscaling controller: the stamp rides the
             # join note into the leader's admit note, so every member
-            # books autoscale_churn and the run's bench records refuse
-            # as measured perf (the PR 9 membership-churn rule)
+            # books autoscale_churn in its run record (the PR 9
+            # membership-churn rule)
             join_note["autoscale"] = True
         atomic_write_json(
             os.path.join(note_dir, f".pod-join.p{jid}"), join_note

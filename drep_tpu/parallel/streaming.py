@@ -34,7 +34,7 @@ The estimator compares int32 ids whose order must agree across every pair
 alternative, per-tile local remaps, would preserve order within each tile
 but re-transfer packed ids per tile: ~8 MB x ~4800 tiles ≈ 38 GB across
 the link at 100k genomes vs ~400 MB once for the global pack. With the
-native ingest at ~92 MB/s/core (measured, bench `ingest` stage — ~78
+native ingest at ~92 MB/s/core (an earlier round's measurement — ~78
 core-minutes per 100k genomes, so minutes of wall on a real multi-core
 TPU-VM host with `-p`), ingest is small next to the tile compute, and the
 one overlap that is exact AND free is taken instead:
@@ -68,8 +68,7 @@ DEFAULT_BLOCK = 1024
 EDGE_BUDGET = 16384
 
 # the sort-merge HBM-temp budget rule lives beside the merge itself
-# (ops/merge.py::cap_merge_tile), shared with the pallas_merge over-width
-# fallback
+# (ops/merge.py::cap_merge_tile)
 from drep_tpu.ops.merge import cap_merge_tile  # noqa: E402
 
 
@@ -344,8 +343,11 @@ def _build_tile_programs(
     counts1d = spec((block,))
     if use_pallas:
         from drep_tpu.ops.merge import next_pow2
-        from drep_tpu.ops.pallas_mash import _mash_shared_grid, rows_per_iter
-        from drep_tpu.ops.pallas_merge import _use_interpret
+        from drep_tpu.ops.pallas_mash import (
+            _mash_shared_grid,
+            _use_interpret,
+            rows_per_iter,
+        )
 
         s2 = max(128, next_pow2(width))
         build_program(
@@ -811,8 +813,10 @@ def streaming_mash_edges(
                     # async dispatch on device slot `slot` (the executor's
                     # round-robin pick; retries may re-call with another slot)
                     if use_pallas:
-                        from drep_tpu.ops.pallas_mash import _mash_shared_grid
-                        from drep_tpu.ops.pallas_merge import _use_interpret
+                        from drep_tpu.ops.pallas_mash import (
+                            _mash_shared_grid,
+                            _use_interpret,
+                        )
 
                         out = _mash_shared_grid(
                             rev_on[slot][i0 : i0 + block],

@@ -90,26 +90,9 @@ _declare(
 )
 # -- dense ring --------------------------------------------------------------
 _declare(
-    "DREP_TPU_RING_COMM", "str", "",
-    "Ring comm backend: auto|ppermute|pallas_dma|pallas_interpret "
-    "(parallel/allpairs.resolve_ring_comm). Empty = auto = ppermute.",
-)
-_declare(
     "DREP_TPU_RING_MONOLITHIC", "bool", False,
     "Run the dense ring as the single fori_loop program (the pre-PR-4 "
     "reference) instead of host-stepped redoable units.",
-)
-_declare(
-    "DREP_TPU_RING_VARIANT", "str", "",
-    "Fused-ring tile variant: merge|matmul "
-    "(ops/pallas_ring.fused_ring_variant). Empty = merge; matmul only "
-    "ever applies to count-free |A∩B| kinds.",
-)
-_declare(
-    "DREP_TPU_RING_VMEM_MB", "int", 12,
-    "VMEM budget (MB) the gridded fused ring sizes its row tiles against "
-    "(ops/pallas_ring.fused_ring_tile). Sizing knob, never a refusal: any "
-    "block streams through VMEM in tiles that fit. --ring_vmem_mb mirrors it.",
 )
 # -- single-chip kernels -----------------------------------------------------
 _declare(
@@ -120,7 +103,7 @@ _declare(
 _declare(
     "DREP_TPU_MASH_ROWS_PER_ITER", "int", 1,
     "Rows per grid iteration for the Pallas mash kernel "
-    "(ops/pallas_mash.py); bench sweeps it.",
+    "(ops/pallas_mash.py): 1, 2 or 4.",
 )
 _declare(
     "DREP_TPU_GREEDY_MATMUL", "bool", False,
@@ -310,8 +293,8 @@ _declare(
     "DREP_TPU_AUTOSCALE_SPAWNED", "bool", False,
     "Set by the autoscaling controller on processes IT spawns/drains: the "
     "join/drain notes such a process publishes carry an `autoscale` stamp, "
-    "so every pod member books `autoscale_churn` and bench records refuse "
-    "the run as measured perf (tools/missing_stages.py). Never set by hand.",
+    "so every pod member books `autoscale_churn` in its run record. "
+    "Never set by hand.",
 )
 # -- fleet supervisor --------------------------------------------------------
 _declare(

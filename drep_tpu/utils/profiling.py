@@ -201,10 +201,8 @@ class Counters:
     # --dispatch_timeout was left at 0 (parallel/faulttol.py) — reported so
     # an operator can pin an explicit value from evidence.
     gauges: dict[str, float] = field(default_factory=dict)
-    # short WHY strings riding beside the gauges (ISSUE 16): a 0.0
-    # `ring_comm_pallas` gauge says the fused ring did not run, the
-    # `ring_comm_fallback_reason` note says WHY (env pin / failed
-    # self-check / cpu backend) — last write wins, same as gauges.
+    # short strings riding beside the gauges (`ingest_path`: which
+    # ingest implementation ran) — last write wins, same as gauges.
     notes: dict[str, str] = field(default_factory=dict)
     # elastic-pod membership history (ISSUE 9): one entry per ownership-
     # epoch bump, with WHY it bumped (death / drain / join). The faults
@@ -218,8 +216,8 @@ class Counters:
     # named series (serve_request_ms, serve_batch_ms, ...).
     hists: dict[str, Histogram] = field(default_factory=dict)
     # which kernel path served each secondary compare call (one_shot,
-    # one_shot_clusterlocal, mesh_ring, matmul_chunked, pallas_range,
-    # cpu_tiles — cluster/engines.py): a run's record must say which
+    # one_shot_clusterlocal, mesh_ring, matmul_chunked, cpu_tiles —
+    # cluster/engines.py): a run's record must say which
     # regime it exercised, not leave it to be inferred from shapes
     paths: dict[str, int] = field(default_factory=dict)
     # where the host's time went (ISSUE 24): every span of the front door

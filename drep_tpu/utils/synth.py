@@ -1,4 +1,4 @@
-"""Shared synthetic sketch planting for benches, chaos cells, and tests.
+"""Shared synthetic sketch planting for chip_smoke, chaos cells, and tests.
 
 One recipe for the "group-pool" packed sketches that the LSH pruning
 work measures itself against: members of a group draw their sketch ids
@@ -6,8 +6,8 @@ from a common pool (small Mash distance inside the group, ~none across),
 and `contiguous=True` lays group members out adjacently in index order —
 the realistic post-sort layout where candidate pruning actually skips
 tiles (interleaved members occupy every tile, the worst case). Kept in
-ONE place so the bench proxy stage (bench.py), the chaos matrix
-(tools/chaos_matrix.py --prune), and the test suites cannot drift onto
+ONE place so chip_smoke.py, the chaos matrix (tools/chaos_matrix.py
+--prune), and the test suites cannot drift onto
 subtly different data while claiming to measure the same property.
 
 (The pre-existing per-suite planters — tests/_chaos_worker.py's

@@ -120,9 +120,8 @@ def enabled() -> bool:
 
 def configured_log_dir() -> str | None:
     """The log dir the sink was configured with (set whether or not
-    tracing is on). bench.py's wedge diagnosis reads this to find the
-    wedged stage's own event logs without plumbing the workdir out of
-    the stage thunk."""
+    tracing is on): where a wedged run's own event logs are, for
+    tools/trace_report.stall_diagnosis."""
     return _STATE["log_dir"]
 
 

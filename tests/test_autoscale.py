@@ -8,8 +8,7 @@ math, the cost-miss drain pick. Then the controller's read-only contract
 (byte-for-byte digest over a planted checkpoint dir — the pod_status
 idiom), the decision log, the ``autoscale_decide`` fault site, the
 ``pod_status --follow --json`` NDJSON stream, and the provenance story
-(autoscale-stamped join/drain notes -> ``autoscale_churn`` ->
-bench/missing_stages refusal).
+(autoscale-stamped join/drain notes -> ``autoscale_churn``).
 
 Multi-process cells (a controller governing a REAL pod under --deadline
 pressure; the ring-phase JOIN speedup) live in
@@ -473,11 +472,3 @@ def test_unstamped_churn_books_no_autoscale_provenance(tmp_path):
     finally:
         hb0.close()
         hb1.close()
-
-
-def test_missing_stages_refuses_autoscale_churned_records():
-    from tools.missing_stages import _degraded
-
-    assert _degraded({"autoscale_decisions": 1})
-    assert _degraded({"fault_tolerance": {"autoscale_churn": 2}})
-    assert not _degraded({"pairs_per_sec_per_chip": 1.0})

@@ -238,8 +238,7 @@ def test_ring_phase_join_tail_participation_d3_bit_identical(tmp_path):
 
     configure_ring()  # the monolithic fixed-membership reference, D=3
     oracle = sharded_mash_allpairs(
-        w._elastic_packed(), k=21, mesh=make_mesh(3), monolithic=True,
-        ring_comm="ppermute",
+        w._elastic_packed(), k=21, mesh=make_mesh(3), monolithic=True
     )
 
     outdir, ckpt = str(tmp_path / "out"), str(tmp_path / "ring")

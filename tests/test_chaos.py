@@ -1309,129 +1309,3 @@ def test_io_fault_spec_fields_and_path_targeting():
         faults.configure("io:not_a_mode")
     with pytest.raises(faults.FaultSpecError):
         faults.configure("io:corrupt:1.0:bogus=1")
-
-
-def test_missing_stages_refuses_healed_corruption():
-    """bench stamps io_retries/corrupt_shards_healed into every stage
-    record; a record with healed corruption is NOT measured perf (healing
-    implies recompute — same contract as degradation), while transient
-    io_retries alone stay measured."""
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "missing_stages", os.path.join(REPO, "tools", "missing_stages.py")
-    )
-    ms = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(ms)
-
-    link = {"h2d_gbps": 1.0, "d2h_gbps": 1.0}
-
-    def merged(rec):
-        return {
-            "stages": {"e2e_50k": rec},
-            "stage_provenance": {"e2e_50k": {"link": link}},
-        }
-
-    clean = {"pairs_per_sec_per_chip": 1.0}
-    assert "scale" not in ms.missing(merged(clean))
-    assert "scale" in ms.missing(merged({**clean, "corrupt_shards_healed": 1}))
-    assert "scale" in ms.missing(
-        merged({**clean, "fault_tolerance": {"corrupt_shards_healed": 2}})
-    )
-    # retried-but-clean I/O is still a measurement (retries cost ms, not
-    # recompute); a zero-valued heal stamp must not refuse either
-    assert "scale" not in ms.missing(merged({**clean, "io_retries": 3}))
-    assert "scale" not in ms.missing(
-        merged({**clean, "io_retries": 3, "corrupt_shards_healed": 0})
-    )
-
-
-def test_missing_stages_refuses_degraded_records():
-    """bench stamps pod_epochs/dead_processes into a degraded e2e record;
-    the recovery tooling must keep such stages on the re-measure list —
-    correct results on fewer chips are not measured perf."""
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "missing_stages", os.path.join(REPO, "tools", "missing_stages.py")
-    )
-    ms = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(ms)
-
-    link = {"h2d_gbps": 1.0, "d2h_gbps": 1.0}
-
-    def merged(rec, key="e2e_50k"):
-        return {
-            "stages": {key: rec},
-            "stage_provenance": {key: {"link": link}},
-        }
-
-    clean = {"pairs_per_sec_per_chip": 1.0}
-    assert "scale" not in ms.missing(merged(clean))
-    assert "scale" in ms.missing(merged({**clean, "dead_processes": 1}))
-    assert "scale" in ms.missing(merged({**clean, "pod_epochs": 2}))
-    assert "scale" in ms.missing(
-        merged({**clean, "fault_tolerance": {"pod_epoch_bumps": 1}})
-    )
-    assert "scale" in ms.missing(
-        merged({**clean, "fault_tolerance": {"dead_processes": 1}})
-    )
-    # DENSE and SECONDARY records get the same refusal (ISSUE 4): a dense
-    # ring that survived a pod death via per-block recovery, or a
-    # secondary stage that lost a member, finished on fewer chips than
-    # the record claims — never measured perf
-    for plan, key in (("primary", "primary"), ("secondary", "secondary_matmul")):
-        assert plan not in ms.missing(merged(clean, key))
-        assert plan in ms.missing(merged({**clean, "pod_epochs": 2}, key))
-        assert plan in ms.missing(merged({**clean, "dead_processes": 1}, key))
-        assert plan in ms.missing(
-            merged({**clean, "fault_tolerance": {"dead_processes": 1}}, key)
-        )
-        # a ring that finished via per-block recovery after step failures
-        # also wants a clean re-measure: recovery serializes block compute
-        assert plan in ms.missing(
-            merged({**clean, "fault_tolerance": {"ring_step_failures": 1}}, key)
-        )
-
-
-def test_missing_stages_refuses_interpret_pallas_records():
-    """ISSUE 8 satellite: a ring_scaling record whose rows ran the fused
-    pallas ring in INTERPRET mode (the CPU equality oracle) is
-    correctness evidence, never a hardware speedup claim — refused
-    exactly like proxy metrics, wherever the marker nests."""
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "missing_stages", os.path.join(REPO, "tools", "missing_stages.py")
-    )
-    ms = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(ms)
-
-    link = {"h2d_gbps": 1.0, "d2h_gbps": 1.0}
-
-    def merged(rec):
-        return {
-            "stages": {"ring_scaling": rec},
-            "stage_provenance": {"ring_scaling": {"link": link}},
-        }
-
-    hw = {
-        "backend": "tpu",
-        "rows": [
-            {"D": 8, "ring_comm": "ppermute", "efficiency": 0.81},
-            {"D": 8, "ring_comm": "pallas_dma", "efficiency": 0.96},
-        ],
-    }
-    assert "ring" not in ms.missing(merged(hw))
-    # one interpret row poisons the record (its wall says nothing about
-    # ICI overlap); nested-dict markers are caught too
-    tainted = {**hw, "rows": hw["rows"] + [{"D": 8, "ring_comm": "pallas_interpret"}]}
-    assert "ring" in ms.missing(merged(tainted))
-    assert "ring" in ms.missing(
-        merged({"backend": "cpu", "proxy_metrics": {
-            "rows": [{"D": 8, "ring_comm": "pallas_interpret"}]}})
-    )
-    # and the CPU proxy record refuses even without interpret rows
-    assert "ring" in ms.missing(
-        merged({"backend": "cpu", "proxy_metrics": {"dispatch_gap_ms_per_step": 1.0}})
-    )

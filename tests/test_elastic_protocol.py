@@ -475,23 +475,6 @@ def test_stripe_weights_counts_occupied_tiles():
 # --- provenance + tooling honesty (satellite 5) ---------------------------
 
 
-def test_missing_stages_refuses_membership_churned_records():
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "missing_stages",
-        os.path.join(os.path.dirname(os.path.dirname(__file__)), "tools", "missing_stages.py"),
-    )
-    ms = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(ms)
-    assert ms._degraded({"pod_joins": 1})
-    assert ms._degraded({"planned_departures": 2})
-    assert ms._degraded({"fault_tolerance": {"pod_joins": 1}})
-    assert ms._degraded({"fault_tolerance": {"planned_departures": 1}})
-    assert ms._degraded({"fault_tolerance": {"drain_announced": 1}})
-    assert not ms._degraded({"fault_tolerance": {"io_retries": 2}})
-
-
 def test_scrub_recognizes_membership_notes_as_checked_json(tmp_path):
     import importlib.util
 

@@ -266,10 +266,8 @@ def test_join_mid_ring_bit_identical(tmp_path):
     sys.path.insert(0, os.path.dirname(WORKER))
     import _multihost_worker as w
 
-    configure_ring()  # oracle: store-less, ppermute, in THIS process
-    oracle = sharded_mash_allpairs(
-        w._elastic_packed(), k=21, mesh=make_mesh(4), ring_comm="ppermute"
-    )
+    configure_ring()  # oracle: store-less, in THIS process
+    oracle = sharded_mash_allpairs(w._elastic_packed(), k=21, mesh=make_mesh(4))
 
     outdir, ckpt = str(tmp_path / "out"), str(tmp_path / "ring")
     pod = _launch_pod(

@@ -359,102 +359,6 @@ def test_elastic_ring_survives_sigkilled_member(tmp_path):
 
 
 @pytest.mark.chaos
-def test_elastic_pallas_ring_survives_sigkilled_member(tmp_path):
-    """Death mid-PALLAS-ring (ISSUE 8): the fused DMA ring (interpret
-    mode on CPU — the same kernel, remote copies discharged onto the
-    mesh) must inherit the ppermute ring's whole elastic story. Process 1
-    SIGKILLs itself at a ring-step boundary while the pod is running
-    `DREP_TPU_RING_COMM=pallas_interpret`; the survivors must abandon the
-    fused collective, fall back to the standalone-block recompute path (a
-    DMA against a dead peer must never wedge them), and assemble a matrix
-    BIT-IDENTICAL to a single-process ppermute oracle over the same
-    6-device mesh — checkpoint shards and degradation stamps exactly as
-    the ppermute pod leaves them."""
-    killed_dir = str(tmp_path / "killed")
-    ckpt = str(tmp_path / "ring_pallas")
-
-    sys.path.insert(0, os.path.dirname(WORKER))
-    import _multihost_worker as w
-
-    from drep_tpu.parallel.allpairs import configure_ring, sharded_mash_allpairs
-    from drep_tpu.parallel.mesh import make_mesh
-
-    configure_ring()  # oracle runs store-less, ppermute, in THIS process
-    oracle = sharded_mash_allpairs(
-        w._elastic_packed(), k=21, mesh=make_mesh(6), ring_comm="ppermute"
-    )
-
-    _run_elastic_pod(
-        killed_dir, ckpt,
-        faults="ring_step:kill:1.0:proc=1:skip=1", expect_dead=1, mode="ring",
-        extra_env={"DREP_TPU_RING_COMM": "pallas_interpret"},
-    )
-    for pid in (0, 2):
-        got = _ring_matrix(killed_dir, pid)
-        assert got.tobytes() == oracle.tobytes(), (
-            f"survivor {pid}'s pallas-ring matrix differs from the "
-            f"single-process ppermute oracle"
-        )
-    ctrs = [_elastic_counters(killed_dir, pid) for pid in (0, 2)]
-    assert any(c.get("dead_processes") == 1 for c in ctrs), ctrs
-    assert any(c.get("pod_epoch_bumps") == 1 for c in ctrs), ctrs
-    # the dead member's unfinished blocks were recomputed STANDALONE by
-    # the survivors (the fallback path — no fused collective involved)
-    assert sum(c.get("ring_blocks_recovered", 0) for c in ctrs) >= 1, ctrs
-    blocks = sorted(f for f in os.listdir(ckpt) if f.startswith("blk_"))
-    assert len(blocks) == 6 * 7 // 2, blocks
-    assert any(".e01." in f for f in blocks), blocks
-
-
-@pytest.mark.chaos
-def test_elastic_gridded_ring_survives_sigkilled_member(tmp_path):
-    """Death mid-GRIDDED-ring (ISSUE 16): with ``DREP_TPU_RING_VMEM_MB=0``
-    the fused step runs its maximal grid — single-row tiles, the remote
-    copy's start pinned to the first cell and the semaphore wait to the
-    last — so the SIGKILL lands while survivors are mid-grid-sweep, not
-    between monolithic programs. The elastic story must be unchanged:
-    survivors abandon the fused collective, recompute the dead member's
-    blocks standalone, and assemble a matrix BIT-IDENTICAL to a
-    single-process ppermute oracle — block checkpoints and degradation
-    stamps exactly as the ungridded pod leaves them."""
-    killed_dir = str(tmp_path / "killed")
-    ckpt = str(tmp_path / "ring_gridded")
-
-    sys.path.insert(0, os.path.dirname(WORKER))
-    import _multihost_worker as w
-
-    from drep_tpu.parallel.allpairs import configure_ring, sharded_mash_allpairs
-    from drep_tpu.parallel.mesh import make_mesh
-
-    configure_ring()  # oracle runs store-less, ppermute, in THIS process
-    oracle = sharded_mash_allpairs(
-        w._elastic_packed(), k=21, mesh=make_mesh(6), ring_comm="ppermute"
-    )
-
-    _run_elastic_pod(
-        killed_dir, ckpt,
-        faults="ring_step:kill:1.0:proc=1:skip=1", expect_dead=1, mode="ring",
-        extra_env={
-            "DREP_TPU_RING_COMM": "pallas_interpret",
-            "DREP_TPU_RING_VMEM_MB": "0",
-        },
-    )
-    for pid in (0, 2):
-        got = _ring_matrix(killed_dir, pid)
-        assert got.tobytes() == oracle.tobytes(), (
-            f"survivor {pid}'s gridded-ring matrix differs from the "
-            f"single-process ppermute oracle"
-        )
-    ctrs = [_elastic_counters(killed_dir, pid) for pid in (0, 2)]
-    assert any(c.get("dead_processes") == 1 for c in ctrs), ctrs
-    assert any(c.get("pod_epoch_bumps") == 1 for c in ctrs), ctrs
-    assert sum(c.get("ring_blocks_recovered", 0) for c in ctrs) >= 1, ctrs
-    blocks = sorted(f for f in os.listdir(ckpt) if f.startswith("blk_"))
-    assert len(blocks) == 6 * 7 // 2, blocks
-    assert any(".e01." in f for f in blocks), blocks
-
-
-@pytest.mark.chaos
 def test_streaming_prebarrier_death_continues_degraded(tmp_path):
     """Death BEFORE the stage-open barrier (the ROADMAP hard case): a pod
     member that exits before ever heartbeating or reaching

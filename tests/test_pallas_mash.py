@@ -4,7 +4,9 @@ Exact equality is the contract: the kernel implements the SAME estimator
 (shared-within-bottom-s_use-of-union), so `shared` counts — and hence
 distances — must be bit-identical to ops/minhash.py::mash_distance_tile.
 CPU runs use interpret mode (SURVEY.md §4 rebuild note); the compiled
-kernel is pinned on hardware by bench.py.
+kernel's jobs are checked against the plain reference on the chip by
+benchmark/. The jnp bitonic merge both formulations share (ops/merge.py)
+is pinned against a full sort here too.
 """
 
 import numpy as np
@@ -112,3 +114,24 @@ def test_rows_per_iter_batching_equals_default(rng, monkeypatch, r_iter):
     monkeypatch.setenv("DREP_TPU_MASH_ROWS_PER_ITER", str(r_iter))
     got_rd, _ = mash_distance_tile_pallas(a_ids, a_cnt, b_ids, b_cnt, k=21)
     np.testing.assert_array_equal(got_rd, want_rd)
+
+
+def test_merge_sorted_rows_equals_sort(rng):
+    import jax.numpy as jnp
+
+    from drep_tpu.ops.merge import merge_sorted_rows
+
+    a = np.sort(rng.integers(0, 1 << 20, size=(7, 256)).astype(np.int32), axis=1)
+    b = np.sort(rng.integers(0, 1 << 20, size=(7, 256)).astype(np.int32), axis=1)
+    got = np.asarray(merge_sorted_rows(jnp.asarray(a), jnp.asarray(b)))
+    want = np.sort(np.concatenate([a, b], axis=1), axis=1)
+    np.testing.assert_array_equal(got, want)
+
+
+def test_merge_rejects_non_pow2():
+    import jax.numpy as jnp
+
+    from drep_tpu.ops.merge import merge_sorted_rows
+
+    with pytest.raises(ValueError):
+        merge_sorted_rows(jnp.zeros((2, 100), jnp.int32), jnp.zeros((2, 100), jnp.int32))

@@ -2,11 +2,12 @@
 
 The production regime (SURVEY.md §7 hard part (c)): 4 Mb genomes at the
 default scale=200 give ~20k-wide scaled sketches — past the single-call
-VMEM (PALLAS_MAX_WIDTH) and indicator (MATMUL_BUDGET_ELEMS) budgets. Both
-device kernels extend by range partitioning (ops/rangepart.py); these
-tests pin (a) the partition machinery itself, (b) exact oracle equality
-of the range-partitioned Pallas merge and the vocab-chunked MXU matmul,
-and (c) that the jnp over-width fallback obeys the shared HBM-temp cap.
+indicator budget (MATMUL_BUDGET_ELEMS). The MXU matmul extends by range
+partitioning (ops/rangepart.py); these tests pin (a) the partition
+machinery itself, (b) exact oracle equality of the vocab-chunked MXU
+matmul at every vocabulary and that it is the one kernel a TPU takes
+beyond the budget, and (c) that the jnp merge tiles obey the shared
+HBM-temp cap.
 """
 
 import numpy as np
@@ -101,101 +102,97 @@ def test_stacked_vocab_chunks_rebase_and_reconstruct(rng):
         np.testing.assert_array_equal(seen[i], ids[i][ids[i] != PAD_ID].astype(np.int64))
 
 
-def test_range_partitioned_pallas_matches_oracle(rng):
-    """Over-width rectangular intersection through the forced range path
-    (interpret-mode Pallas on CPU) — exact oracle equality."""
-    from drep_tpu.ops.pallas_merge import PALLAS_MAX_WIDTH, intersect_counts_pallas
-
-    a = _sorted_rows(rng, 7, PALLAS_MAX_WIDTH + 600, 3 * PALLAS_MAX_WIDTH)
-    b = _sorted_rows(rng, 5, PALLAS_MAX_WIDTH + 600, 3 * PALLAS_MAX_WIDTH)
-    assert max(a.shape[1], b.shape[1]) > PALLAS_MAX_WIDTH  # over-width for real
-    got = intersect_counts_pallas(a, b, force="range")
-    np.testing.assert_array_equal(got, _oracle_inter(a, b))
-
-
-def test_range_partitioned_self_matches_rectangular(rng):
-    from drep_tpu.ops.pallas_merge import (
-        PALLAS_MAX_WIDTH,
-        intersect_counts_pallas,
-        intersect_counts_pallas_self,
-    )
-
-    ids = _sorted_rows(rng, 9, PALLAS_MAX_WIDTH + 500, 3 * PALLAS_MAX_WIDTH)
-    got = intersect_counts_pallas_self(ids, force="range")
-    want = intersect_counts_pallas(ids, ids, force="range")
-    np.testing.assert_array_equal(got, want)
-    np.testing.assert_array_equal(got, got.T)
-
-
-def test_stacked_range_buckets_reconstruct_and_share_layout(rng):
-    """The fused-kernel layout: every input's real elements survive the
-    stacked repack exactly once, buckets share boundaries and ONE common
-    width <= max_count, and all-empty buckets are dropped."""
-    from drep_tpu.ops.rangepart import stacked_range_buckets
-
-    a = _sorted_rows(rng, 6, 700, 4096)
-    b = _sorted_rows(rng, 4, 500, 4096)
-    a_st, b_st = stacked_range_buckets([a, b], MIN_BUCKET_WIDTH, dtype="int32")
-    assert a_st.shape[0] == b_st.shape[0]  # shared bucket set
-    assert a_st.shape[2] == b_st.shape[2] <= MIN_BUCKET_WIDTH
-    for mat, st in ((a, a_st), (b, b_st)):
-        for i in range(mat.shape[0]):
-            got = np.sort(st[:, i][st[:, i] != PAD_ID])
-            np.testing.assert_array_equal(got, mat[i][mat[i] != PAD_ID])
-    # no bucket is empty across BOTH inputs
-    for r in range(a_st.shape[0]):
-        assert (a_st[r] != PAD_ID).any() or (b_st[r] != PAD_ID).any()
-
-
-def test_stacked_buckets_hold_disjoint_ranges(rng):
-    """Each kept bucket's values must lie in one disjoint global range —
-    the additivity precondition the fused kernel's accumulation rests on."""
-    from drep_tpu.ops.rangepart import stacked_range_buckets
-
-    (st,) = stacked_range_buckets(
-        [_sorted_rows(rng, 5, 900, 5000)], MIN_BUCKET_WIDTH, dtype="int32"
-    )
-    prev_max = -1
-    for r in range(st.shape[0]):
-        vals = st[r][st[r] != PAD_ID]
-        if vals.size:
-            assert int(vals.min()) > prev_max
-            prev_max = int(vals.max())
-
-
-def test_stacked_auto_picks_u16_and_stays_exact(rng):
-    """When every chunk fits 16 bits the auto plan must ship uint16
-    (HALF the link bytes — the production fused-merge path is
-    link-floored), and the end-to-end range path must stay exact."""
-    from drep_tpu.ops.pallas_merge import PALLAS_MAX_WIDTH, intersect_counts_pallas
-    from drep_tpu.ops.rangepart import U16_PAD, stacked_range_buckets
-
-    a = _sorted_rows(rng, 7, PALLAS_MAX_WIDTH + 600, 3 * PALLAS_MAX_WIDTH)
-    b = _sorted_rows(rng, 5, PALLAS_MAX_WIDTH + 600, 3 * PALLAS_MAX_WIDTH)
-    a_st, b_st = stacked_range_buckets([a, b], PALLAS_MAX_WIDTH)
-    assert a_st.dtype == np.uint16 == b_st.dtype  # vocab 6144 << 2^16
-    # rebased per-bucket values never reach the sentinel
-    assert all((a_st[r][a_st[r] != U16_PAD] < 0xFFFF).all() for r in range(a_st.shape[0]))
-    got = intersect_counts_pallas(a, b, force="range")  # u16 plan end-to-end
-    np.testing.assert_array_equal(got, _oracle_inter(a, b))
-
-
-def test_jnp_fallback_is_capped_and_exact(rng):
-    """The over-width jnp fallback must obey the shared HBM-temp budget
-    (VERDICT r2 weak #1: a fixed 128-tile at width 32768 materializes
-    ~4.3 GB per merge temporary) and stay exact."""
+def test_merge_tile_cap_obeys_the_hbm_temp_budget():
+    """The jnp sort-merge tiles (parallel/streaming.py) obey the shared
+    HBM-temp budget: a fixed 128-tile at width 32768 would materialize
+    ~4.3 GB per merge temporary."""
     from drep_tpu.ops.merge import SORT_TILE_BUDGET_ELEMS
-    from drep_tpu.ops.pallas_merge import PALLAS_MAX_WIDTH, intersect_counts_pallas
 
-    # the production shape: width 32768 -> tile must drop to 64
     tile = cap_merge_tile(128, 32768)
     assert tile * tile * 2 * next_pow2(32768) <= SORT_TILE_BUDGET_ELEMS
     assert tile == 64
     assert 128 * 128 * 2 * next_pow2(32768) > SORT_TILE_BUDGET_ELEMS
 
-    ids = _sorted_rows(rng, 5, PALLAS_MAX_WIDTH + 300, 3 * PALLAS_MAX_WIDTH)
-    got = intersect_counts_pallas(ids, ids, force="jnp")
-    np.testing.assert_array_equal(got, _oracle_inter(ids, ids))
+
+# ---- beyond the one-shot budget: the chunked matmul, at every shape ----------
+
+
+def _sparse_cluster(rng, n, hashes):
+    """`n` sketches of about `hashes` hashes that share almost nothing, an
+    empty row and a one-hash row among them: ids are dense ranks, so the
+    vocabulary is about n * hashes — far wider than any row."""
+    sketches = [
+        np.unique(rng.integers(0, 1 << 60, size=hashes).astype(np.uint64)) for _ in range(n)
+    ]
+    sketches[1] = np.union1d(sketches[0][::2], sketches[1])[:hashes]  # one pair that shares
+    sketches[2] = sketches[2][:0]
+    sketches[3] = sketches[0][:1]  # its one hash is row 0's
+    return sketches
+
+
+def _merge_units(width: int) -> int:
+    s2 = max(128, next_pow2(width))
+    return 2 * s2 * ((2 * s2).bit_length() - 1)
+
+
+# (width, rows, hashes a row, vocabulary over 47 x the width's merge units?):
+# the wide-vocabulary shapes are the region a deleted cost constant gave to
+# a VPU merge kernel; the last is a shape well under it
+BEYOND_BUDGET_SHAPES = [(128, 1000, 120, True), (256, 1000, 250, True), (256, 200, 250, False)]
+
+
+def _beyond_budget_pack(rng, width, n, hashes, wide, monkeypatch):
+    import drep_tpu.ops.containment as cont
+
+    packed = cont.pack_scaled_sketches(
+        _sparse_cluster(rng, n, hashes), [f"g{i}" for i in range(n)]
+    )
+    assert packed.ids.shape[1] == width
+    v_pad = cont.matmul_vocab_pad(packed)
+    assert (v_pad > 47 * _merge_units(width)) == wide
+    # several chunks, and no one-shot call
+    monkeypatch.setattr(cont, "MATMUL_BUDGET_ELEMS", cont.matmul_rows_pad(n) * 8193)
+    assert not cont.one_shot_fits(packed.n, v_pad)
+    return packed
+
+
+@pytest.mark.parametrize("width,n,hashes,wide", BEYOND_BUDGET_SHAPES)
+def test_chunked_matmul_equals_the_tiles_at_any_vocabulary(rng, width, n, hashes, wide, monkeypatch):
+    from drep_tpu.ops.containment import (
+        all_vs_all_containment,
+        all_vs_all_containment_matmul_chunked,
+    )
+
+    packed = _beyond_budget_pack(rng, width, n, hashes, wide, monkeypatch)
+    ani, cov = all_vs_all_containment_matmul_chunked(packed, k=21)
+    want_ani, want_cov = all_vs_all_containment(packed, k=21)
+    np.testing.assert_array_equal(cov, want_cov)
+    np.testing.assert_array_equal(ani, want_ani)
+    assert cov[2].sum() == cov[:, 2].sum() == 1.0  # the empty row: only its pinned diagonal
+    assert cov[3, 0] == 1.0 and 0 < cov[0, 3] < 0.01  # the one-hash row
+
+
+@pytest.mark.parametrize("width,n,hashes,wide", BEYOND_BUDGET_SHAPES)
+def test_beyond_the_budget_a_tpu_books_matmul_chunked(rng, width, n, hashes, wide, monkeypatch):
+    """`containment_matrices` with the platform patched to a TPU: one kernel
+    beyond the budget, whatever the vocabulary; off a TPU the tiles."""
+    import types
+
+    import jax
+
+    from drep_tpu.cluster.engines import containment_matrices
+    from drep_tpu.utils.profiling import counters
+
+    packed = _beyond_budget_pack(rng, width, n, hashes, wide, monkeypatch)
+    want = containment_matrices(packed, 21, mesh_shape=1)
+    counters.reset()
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: [types.SimpleNamespace(platform="tpu")])
+    got = containment_matrices(packed, 21, mesh_shape=1)
+    assert counters.paths == {"matmul_chunked": 1}
+    assert counters.report(device=False)["secondary_chunked_calls"][0]["chunks"] >= 2
+    counters.reset()
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
 
 
 def test_chunked_matmul_matches_one_shot(rng):

@@ -849,7 +849,7 @@ def test_pod_status_follow_renders_in_place(tmp_path):
 
 
 def test_stall_diagnosis_names_open_span(tmp_path):
-    """trace_report.stall_diagnosis (wired into bench.py's wedge bail):
+    """trace_report.stall_diagnosis:
     an event log whose stream stops inside a span names that span as the
     stall site, with idle gaps and the last event."""
     tr = _tool("trace_report")
@@ -888,7 +888,7 @@ def test_serve_bench_loadgen_guard(tmp_path):
     """The perf guard (proxy metrics, never hardware claims): the
     loadgen pins batched >= unbatched throughput at concurrency and a
     startup-amortization ratio; the record is stamped proxy_metrics so
-    tools/missing_stages.py refuses it as a hardware number."""
+    nothing reads it as a hardware number."""
     out = str(tmp_path / "SERVE_BENCH.json")
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                PYTHONPATH=REPO + os.pathsep + os.environ.get("PYTHONPATH", ""))

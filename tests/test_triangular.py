@@ -180,6 +180,64 @@ def test_stepwise_ring_equals_monolithic_bit_exact(rng, n_dev):
         )
 
 
+# the edge shapes of the ring's blocks: one row a device, an empty shard
+# (a device whose whole block is padding), a single genome
+@pytest.mark.parametrize("n_genomes", ["n_dev", "n_dev-1", "1"])
+@pytest.mark.parametrize("n_dev", [3, 8])
+@pytest.mark.parametrize("kind", ["mash", "containment"])
+def test_ring_edge_shapes_equal_single_device_tiles(rng, kind, n_dev, n_genomes):
+    from drep_tpu.parallel.allpairs import configure_ring
+
+    configure_ring()  # hermetic: no store base leaked from earlier tests
+    mesh = make_mesh(n_dev)
+    n = {"n_dev": n_dev, "n_dev-1": n_dev - 1, "1": 1}[n_genomes]
+    names = [f"g{i}" for i in range(n)]
+    f0 = counters.faults.get("ring_step_failures", 0)
+    r0 = counters.faults.get("ring_blocks_recovered", 0)
+    if kind == "mash":
+        packed = pack_sketches(_sketch_set(rng, n, 32), names, 32)
+        got = (sharded_mash_allpairs(packed, k=21, mesh=mesh),)
+        want = all_vs_all_mash(packed, k=21, tile=8)[:1]
+    else:
+        packed = pack_scaled_sketches(_sketch_set(rng, n, 96), names, pad_multiple=32)
+        got = sharded_containment_allpairs(packed, k=21, mesh=mesh)
+        want = all_vs_all_containment(packed, k=21, tile=8)
+    for g, w in zip(got, want):
+        assert g.shape == (n, n)
+        np.testing.assert_array_equal(g, w)
+    # the ring's own steps made the blocks: the per-block recovery path
+    # gives the same bits, so a ring that never ran would pass without this
+    assert counters.faults.get("ring_step_failures", 0) == f0
+    assert counters.faults.get("ring_blocks_recovered", 0) == r0
+
+
+def test_ring_step_autotimeout_excludes_first_step_only():
+    """The ring's per-step AutoTimeout excludes exactly the FIRST (cold)
+    step from the rolling median — the TileExecutor-style warmup
+    exclusion resized for half-ring schedules (a warmup of 8 would
+    discard every sample at production D and the gauge never derive)."""
+    from drep_tpu.parallel.allpairs import RING_STEP_WARMUP
+    from drep_tpu.parallel.faulttol import (
+        AUTO_TIMEOUT_FLOOR_S,
+        AutoTimeout,
+        FaultTolConfig,
+    )
+
+    assert RING_STEP_WARMUP == 1
+    auto = AutoTimeout(FaultTolConfig(auto_timeout=True), warmup=RING_STEP_WARMUP)
+    auto.note(500.0)  # the cold step must not poison the median
+    for _ in range(4):
+        auto.note(0.01)  # the D=8 half-ring's warm steps
+    derived = auto.derived()
+    assert derived is not None, "gauge must derive from a half-ring schedule"
+    assert derived == AUTO_TIMEOUT_FLOOR_S  # 20x median(0.01) floors at 30s
+    # default warmup (the TileExecutor) still excludes its 8
+    auto_default = AutoTimeout(FaultTolConfig(auto_timeout=True))
+    for _ in range(5):
+        auto_default.note(0.01)
+    assert auto_default.derived() is None
+
+
 @pytest.mark.parametrize("n", [20, 300])  # spans the _TRI_BLOCK boundary
 def test_mash_matmul_triangular_equals_full(rng, n):
     s = 48

@@ -208,7 +208,7 @@ def _streaming(spec, ft_config=None, checkpoint_dir=None):
     ), "edges differ under injection"
 
 
-def _ring(spec, ft_config=None, ring_comm=None, vmem_mb=None):
+def _ring(spec, ft_config=None):
     from drep_tpu.parallel.allpairs import sharded_mash_allpairs
     from drep_tpu.parallel.mesh import make_mesh
     from drep_tpu.utils import faults
@@ -216,17 +216,11 @@ def _ring(spec, ft_config=None, ring_comm=None, vmem_mb=None):
     packed = _packed(n=21)
     mesh = make_mesh(3)
     want = sharded_mash_allpairs(packed, k=21, mesh=mesh)
-    if vmem_mb is not None:  # starve the grid: fused cells go single-row
-        os.environ["DREP_TPU_RING_VMEM_MB"] = str(vmem_mb)
     faults.configure(spec)
     try:
-        got = sharded_mash_allpairs(
-            packed, k=21, mesh=mesh, ft_config=ft_config, ring_comm=ring_comm
-        )
+        got = sharded_mash_allpairs(packed, k=21, mesh=mesh, ft_config=ft_config)
     finally:
         faults.configure(None)
-        if vmem_mb is not None:
-            os.environ.pop("DREP_TPU_RING_VMEM_MB", None)
     assert got.tobytes() == want.tobytes(), "ring matrix differs under injection"
 
 
@@ -294,19 +288,6 @@ def _cells():
         ("ring_dispatch", "hang", "wedged ring step -> watchdog + recovery",
          "survive", lambda: _ring(
              "ring_dispatch:hang:1.0:max=1:secs=30", _ft(dispatch_timeout_s=0.5))),
-        # the fused pallas ring (ISSUE 8, interpret mode on CPU) shares
-        # the per-block recovery path: a failed fused step must fall back
-        # to standalone-block recompute with a bit-identical matrix
-        ("ring_dispatch", "raise", "failed FUSED pallas step -> per-block recovery",
-         "survive", lambda: _ring(
-             "ring_dispatch:raise:1.0:max=1", ring_comm="pallas_interpret")),
-        # the GRIDDED fused step (ISSUE 16): VMEM budget starved to zero
-        # forces single-row tiles — the maximal grid — and the per-block
-        # recovery story must hold mid-grid exactly as it does monolithic
-        ("ring_dispatch", "raise", "failed GRIDDED fused step -> per-block recovery",
-         "survive", lambda: _ring(
-             "ring_dispatch:raise:1.0:max=1", ring_comm="pallas_interpret",
-             vmem_mb=0)),
         ("secondary_batch", "raise", "one failed batch -> local retry",
          "survive", lambda: _secondary_retry("secondary_batch:raise:1.0:max=1")),
         ("secondary_batch", "raise", "beyond retry budget -> abort",
@@ -797,10 +778,6 @@ POD_CELLS = [
      "survive", "tests/test_multihost.py::test_elastic_pod_survives_sigkilled_member"),
     ("ring_step", "kill", "SIGKILL between ring steps -> block re-deal",
      "survive", "tests/test_multihost.py::test_elastic_ring_survives_sigkilled_member"),
-    ("ring_step", "kill", "SIGKILL mid-PALLAS-ring -> survivors fall back, bit-identical",
-     "survive", "tests/test_multihost.py::test_elastic_pallas_ring_survives_sigkilled_member"),
-    ("ring_step", "kill", "SIGKILL mid-GRIDDED-ring (starved VMEM) -> bit-identical recovery",
-     "survive", "tests/test_multihost.py::test_elastic_gridded_ring_survives_sigkilled_member"),
     ("barrier", "death", "death BEFORE the stage-open barrier -> admission",
      "survive", "tests/test_multihost.py::test_streaming_prebarrier_death_continues_degraded"),
     ("secondary_batch", "raise", "mid-batch failure on a pod -> local retry",

@@ -205,7 +205,7 @@ class RepoModel:
                 for fn in sorted(filenames):
                     if fn.endswith(".py"):
                         rels.append(f"{rel_dir}/{fn}")
-        for top_file in ("bench.py", "__graft_entry__.py", "chip_smoke.py"):
+        for top_file in ("__graft_entry__.py", "chip_smoke.py"):
             if os.path.exists(os.path.join(self.root, top_file)):
                 rels.append(top_file)
         return rels
@@ -213,7 +213,7 @@ class RepoModel:
     # -- scopes -------------------------------------------------------------
 
     def prod_files(self):
-        """The production scope: pipeline + tools + bench, never tests."""
+        """The production scope: pipeline + tools, never tests."""
         for sf in self.files.values():
             if not sf.path.startswith("tests/"):
                 yield sf

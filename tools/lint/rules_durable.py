@@ -24,13 +24,13 @@ ALLOWED = frozenset({
 EXPLAIN = """\
 Every recovery path in this repo ASSUMES shared-filesystem payloads are
 whole-file-or-nothing and checksummed: resume globs trust that a file
-that exists is complete, scrub_store classifies torn bytes as damage,
-missing_stages refuses healed records. A bare open(path, "w") (or
+that exists is complete, scrub_store classifies torn bytes as damage.
+A bare open(path, "w") (or
 np.savez / json.dump / os.replace / Path.write_*) outside the funnel
 publishes exactly the torn, CRC-less artifacts those paths misclassify.
-Pinned by PR 5 (durable storage); the four drifted writers it found
-(cluster/external.py, tools/serve_client.py, tools/trace_report.py,
-tools/merge_bench_partials.py) were fixed by PR 12.
+Pinned by PR 5 (durable storage); the drifted writers it found
+(cluster/external.py, tools/serve_client.py, tools/trace_report.py)
+were fixed by PR 12.
 
 Fix: route through drep_tpu.utils.durableio — atomic_write_bytes /
 atomic_write_json / atomic_savez, or atomic_write(path, write_fn) when

@@ -239,10 +239,9 @@ def chrome_trace(events: list[dict]) -> dict:
 
 
 def stall_diagnosis(log_dir: str) -> dict | None:
-    """Name a wedged run's stall site from its own event logs (ISSUE 11
-    satellite — bench.py's wedge bail calls this so a traced stage that
-    overruns its watchdog records WHERE it stalled, not just that it
-    did). Returns None when there are no events to read.
+    """Name a wedged run's stall site from its own event logs: WHERE a
+    traced run that overran its watchdog stalled, not just that it did.
+    Returns None when there are no events to read.
 
     The diagnosis is the crash-forensics triple:
 
