@@ -722,8 +722,8 @@ def d_cluster_wrapper(wd: WorkDirectory, bdb: pd.DataFrame, **kwargs) -> pd.Data
                 warn_dist=_warn_dist(kw),
             )
     if mdb is not None:
-        with counters.span("tables_io"):
-            wd.store_db(schemas.validate(mdb, "Mdb"), "Mdb")
+        with counters.span("tables_io", rows=len(mdb)) as io:
+            io.note(bytes=wd.store_db(schemas.validate(mdb, "Mdb"), "Mdb"))
 
     clustering_files: dict[str, Any] = {
         "primary_linkage": plink,

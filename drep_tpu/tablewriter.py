@@ -1,0 +1,179 @@
+"""The columnar CSV serialiser behind ``WorkDirectory.store_db`` (ISSUE 30).
+
+``DataFrame.to_csv`` renders every value of every row: it turns each float
+into a Python string, boxes each column as objects and hands the rows one by
+one to ``csv.writer``. The tables this pipeline writes are long and hold few
+distinct values: the dense Mdb of a 1,024-genome cluster is 1M rows of about
+a thousand distances and a thousand names, an Ndb's coverage columns are one
+matrix and its transpose. So :func:`write_csv` works by column:
+
+1. a chunk of rows at a time (``CHUNK_ROWS``), each column becomes ``(texts,
+   codes)``: its distinct values rendered once, as the bytes pandas writes for
+   that dtype, and the row's index into them. Float columns of one width are
+   factorised together, so a value that several columns hold is rendered once;
+2. the chunk's rows are assembled in blocks (``BLOCK_BYTES``) as a ``[rows,
+   row width]`` byte matrix: each column's fixed-width texts gathered by code,
+   ``,`` and ``\\n`` in place, the NUL padding dropped by one compress; and
+   each block is written as it is made.
+
+The file is byte for byte what ``df.to_csv(path, index=False)`` writes
+(tests/test_table_writer.py holds it to the installed pandas). What the
+writer does not render identically goes through ``to_csv`` itself, decided
+by what the frame holds and nothing else: a missing value, a string that
+``csv.QUOTE_MINIMAL`` would quote, a dtype other than float32/float64, an
+integer or plain strings, a column label that is not a plain string.
+"""
+
+from __future__ import annotations
+
+import os
+import re
+
+import numpy as np
+import pandas as pd
+
+# rows factorised and rendered together. A table up to this long renders each
+# distinct value once, a longer one once a chunk: the writer's memory is one
+# chunk's codes and texts, never a second copy of a 50k-genome Ndb
+CHUNK_ROWS = 1 << 21
+# bytes of one assembled block of rows, padding included
+BLOCK_BYTES = 1 << 21
+# a string column is assembled at the width of its longest text: one that
+# would come to blocks of padding, over RAGGED_FACTOR times its texts, is not
+RAGGED_FACTOR = 8
+
+# what csv.QUOTE_MINIMAL quotes (delimiter, quote, line ends), and NUL, which
+# the padding is made of
+_NOT_PLAIN = re.compile(r'[,"\r\n\x00]')
+
+
+class _Unrenderable(Exception):
+    """The frame holds something whose pandas text this writer does not
+    reproduce; the message is the reason the record books."""
+
+
+def write_csv(df: pd.DataFrame, path: str) -> dict:
+    """Write `df` to `path` as ``df.to_csv(path, index=False)`` would, byte
+    for byte. Returns what was done: ``rows``, ``bytes``, ``values`` (fields
+    of the table), ``distinct`` (texts rendered for them; 0 on the fallback)
+    and ``fallback`` (None, or why the frame went through ``to_csv``)."""
+    done = {"rows": len(df), "values": int(df.size), "distinct": 0, "fallback": None}
+    try:
+        done["distinct"] = _write_columnar(df, path)
+    except _Unrenderable as why:
+        df.to_csv(path, index=False)
+        done["fallback"] = str(why)
+    done["bytes"] = os.path.getsize(path)
+    return done
+
+
+def _plain(text) -> bool:
+    return type(text) is str and text != "" and _NOT_PLAIN.search(text) is None
+
+
+def _narrow(texts: np.ndarray) -> np.ndarray:
+    """An ``S`` array at the width of its longest text."""
+    wide = texts.view(np.uint8).reshape(len(texts), texts.dtype.itemsize)
+    width = max(1, int(wide.any(axis=0).nonzero()[0].max(initial=0)) + 1)
+    return np.ascontiguousarray(wide[:, :width]).view(f"S{width}").ravel()
+
+
+def _float_texts(bits: np.ndarray, dtype: np.dtype) -> tuple[np.ndarray, np.ndarray]:
+    """(texts, codes) of float values given as their bit patterns: 0.0 and
+    -0.0 are one value to a hash table and two texts to pandas. pandas
+    renders a float block by ``astype(str)``, numpy's shortest round-trip
+    text at the block's own width."""
+    codes, uniq = pd.factorize(bits)
+    values = uniq.view(dtype)
+    if np.isnan(values).any():
+        raise _Unrenderable("missing value")
+    return _narrow(values.astype("S32")), codes
+
+
+def _string_texts(col: pd.Series) -> tuple[np.ndarray, np.ndarray]:
+    try:
+        codes, uniq = pd.factorize(col)
+    except TypeError:  # unhashable objects
+        raise _Unrenderable("mixed object column") from None
+    if (codes < 0).any():
+        raise _Unrenderable("missing value")
+    uniq = uniq.tolist()
+    if not all(type(u) is str for u in uniq):
+        raise _Unrenderable("mixed object column")
+    if not all(map(_plain, uniq)):
+        raise _Unrenderable("string that needs quoting")
+    texts = np.array([u.encode() for u in uniq], dtype="S")
+    padded = texts.dtype.itemsize * len(codes)
+    if padded > RAGGED_FACTOR * max(BLOCK_BYTES, int(np.char.str_len(texts)[codes].sum())):
+        raise _Unrenderable("ragged strings")
+    return texts, codes
+
+
+def _chunk_columns(df: pd.DataFrame, kinds: list[str], lo: int, hi: int) -> tuple[list, int]:
+    """``(texts, codes)`` for each column of rows [lo, hi), and how many
+    texts were rendered for them."""
+    cols: list = [None] * len(kinds)
+    rendered = 0
+    floats: dict[np.dtype, list[tuple[int, np.ndarray]]] = {}  # by width: (column, bit patterns)
+    for i, kind in enumerate(kinds):
+        part = df.iloc[lo:hi, i]
+        if kind == "float":
+            bits = np.ascontiguousarray(part.to_numpy()).view(f"i{part.dtype.itemsize}")
+            floats.setdefault(part.dtype, []).append((i, bits))
+            continue
+        if kind == "int":
+            codes, uniq = pd.factorize(part.to_numpy())
+            cols[i] = (_narrow(uniq.astype("S21")), codes)
+        else:
+            cols[i] = _string_texts(part)
+        rendered += len(cols[i][0])
+    for dtype, members in floats.items():
+        texts, codes = _float_texts(np.concatenate([bits for _, bits in members]), dtype)
+        rendered += len(texts)
+        for (i, _), own in zip(members, np.split(codes, len(members))):
+            cols[i] = (texts, own)
+    return cols, rendered
+
+
+def _kind(dtype) -> str:
+    if isinstance(dtype, np.dtype):
+        if dtype in (np.float32, np.float64):
+            return "float"
+        if dtype.kind in "iu":
+            return "int"
+        if dtype == object:
+            return "str"
+    elif isinstance(dtype, pd.StringDtype):
+        return "str"
+    raise _Unrenderable(f"dtype {dtype}")
+
+
+def _write_columnar(df: pd.DataFrame, path: str) -> int:
+    """The columnar write; returns the texts rendered."""
+    labels = df.columns
+    if not len(labels) or isinstance(labels, pd.MultiIndex) or not all(map(_plain, labels)):
+        raise _Unrenderable("column labels that are not plain strings")
+    kinds = [_kind(dtype) for dtype in df.dtypes]
+    rendered = 0
+    # drep-lint: allow[durable-funnel] — write_fn body: `path` is the tmp path workdir._atomic_write hands store_db
+    with open(path, "wb") as f:
+        f.write((",".join(labels) + "\n").encode())
+        for lo in range(0, len(df), CHUNK_ROWS):
+            n = min(CHUNK_ROWS, len(df) - lo)
+            cols, texts_made = _chunk_columns(df, kinds, lo, lo + n)
+            rendered += texts_made
+            widths = [texts.dtype.itemsize for texts, _ in cols]
+            row_bytes = sum(widths) + len(cols)
+            block_rows = max(1, BLOCK_BYTES // row_bytes)
+            buf = np.empty((min(block_rows, n), row_bytes), np.uint8)
+            for start in range(0, n, block_rows):
+                block = buf[: min(block_rows, n - start)]
+                at = 0
+                for (texts, codes), width in zip(cols, widths):
+                    field = texts[codes[start : start + len(block)]]
+                    block[:, at : at + width] = field.view(np.uint8).reshape(len(block), width)
+                    block[:, at + width] = ord(",")
+                    at += width + 1
+                block[:, -1] = ord("\n")
+                f.write(block[block != 0])
+    return rendered

@@ -96,6 +96,12 @@ class _Span:
             sink.__enter__()
         return self
 
+    def note(self, **args) -> None:
+        """Args known only when the work is done (the bytes a write came
+        to): they ride on the span's E line in the event log. The profiler's
+        annotation took its args at entry."""
+        self._args.update(args)
+
     def __exit__(self, exc_type, exc, tb) -> bool:
         for sink in reversed(self._sinks):
             sink.__exit__(exc_type, exc, tb)
@@ -235,6 +241,11 @@ class Counters:
     # calls, genomes, hashes and the distinct ids they became, summed. The
     # pack's seconds follow the hashes it sorts (ISSUE 28)
     primary_pack: dict[str, int] = field(default_factory=dict)
+    # what `WorkDirectory.store_db` wrote, a table name (ISSUE 30): calls,
+    # rows, bytes, the table's values and the distinct texts the columnar
+    # writer rendered for them, and the calls that went through pandas'
+    # `to_csv` instead, with why (drep_tpu/tablewriter.py)
+    tables_write: dict[str, dict[str, Any]] = field(default_factory=dict)
     _open: threading.local = field(default_factory=threading.local, repr=False, compare=False)
     _lock: threading.Lock = field(default_factory=threading.Lock, repr=False, compare=False)
 
@@ -353,6 +364,23 @@ class Counters:
         for name, value in booked.items():
             self.primary_pack[name] = self.primary_pack.get(name, 0) + int(value)
 
+    def add_table_write(
+        self, table: str, rows: int, bytes: int, values: int, distinct: int, fallback: str | None
+    ) -> None:
+        """Book one `store_db` of `table`: `rows` rows of `values` fields left
+        as `bytes` bytes, for which the columnar writer rendered `distinct`
+        texts; or `fallback` says why pandas wrote the frame."""
+        ent = self.tables_write.setdefault(
+            table, {"calls": 0, "rows": 0, "bytes": 0, "values": 0, "distinct": 0, "fallback": 0}
+        )
+        booked = {"calls": 1, "rows": rows, "bytes": bytes, "values": values,
+                  "distinct": distinct, "fallback": int(fallback is not None)}
+        for name, value in booked.items():
+            ent[name] += int(value)
+        if fallback is not None:
+            reasons = ent.setdefault("fallback_reasons", {})
+            reasons[fallback] = reasons.get(fallback, 0) + 1
+
     def set_gauge(self, name: str, value: float) -> None:
         """Record a derived operational value (last write wins)."""
         self.gauges[name] = float(value)
@@ -464,6 +492,8 @@ class Counters:
             ]
         if self.primary_pack:
             out["primary_pack"] = dict(self.primary_pack)
+        if self.tables_write:
+            out["tables_write"] = {name: dict(ent) for name, ent in sorted(self.tables_write.items())}
         phases = self._phases_report()
         if phases:
             out["phases"] = phases
@@ -521,6 +551,7 @@ class Counters:
         self.secondary_calls.clear()
         self.chunked_calls.clear()
         self.primary_pack.clear()
+        self.tables_write.clear()
         with self._lock:
             self.phases.clear()  # a span open now stays open and books when it closes
 

@@ -86,10 +86,20 @@ class WorkDirectory:
     def _table_loc(self, name: str) -> str:
         return os.path.join(self.location, "data_tables", f"{name}.csv")
 
-    def store_db(self, df: pd.DataFrame, name: str) -> None:
+    def store_db(self, df: pd.DataFrame, name: str) -> int:
+        """Publish `df` as ``data_tables/<name>.csv``: the bytes of
+        ``df.to_csv(index=False)``, written column by column
+        (drep_tpu/tablewriter.py). What the write took is booked in the
+        record's ``tables_write``; returns the file's bytes."""
+        from drep_tpu.tablewriter import write_csv
+        from drep_tpu.utils.profiling import counters
+
         loc = self._table_loc(name)
-        _atomic_write(loc, lambda tmp: df.to_csv(tmp, index=False))
+        done: dict = {}  # of the attempt that was published: a transient I/O error re-runs the write
+        _atomic_write(loc, lambda tmp: done.update(write_csv(df, tmp)))
+        counters.add_table_write(name, **done)
         get_logger().debug("stored table %s (%d rows) -> %s", name, len(df), loc)
+        return done["bytes"]
 
     def get_db(self, name: str) -> pd.DataFrame:
         loc = self._table_loc(name)

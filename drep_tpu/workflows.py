@@ -60,10 +60,10 @@ def _load_bdb(wd: WorkDirectory, genomes: list[str]) -> pd.DataFrame:
     from drep_tpu.utils.profiling import counters, device_record
 
     get_logger().info("device: %s", device_record())
-    with counters.span("tables_io"):
+    with counters.span("tables_io") as io:
         if genomes:
             bdb = make_bdb(genomes)
-            wd.store_db(bdb, "Bdb")
+            io.note(rows=len(bdb), bytes=wd.store_db(bdb, "Bdb"))
         elif wd.hasDb("Bdb"):
             bdb = wd.get_db("Bdb")  # resume from an existing workdir
         else:
@@ -112,10 +112,9 @@ def compare_wrapper(wd_loc: str, genomes: list[str] | None = None, **kwargs) -> 
             cdb = d_cluster_wrapper(wd, bdb, **kwargs)
         # per-genome stats for downstream stages come from the ingest pass's Gdb
         # (one FASTA read per genome, not a second parse)
-        with counters.span("tables_io"):
-            wd.store_db(
-                wd.get_db("Gdb")[["genome", "length", "N50", "contigs"]], "genomeInformation"
-            )
+        with counters.span("tables_io") as io:
+            stats = wd.get_db("Gdb")[["genome", "length", "N50", "contigs"]]
+            io.note(rows=len(stats), bytes=wd.store_db(stats, "genomeInformation"))
         with counters.span("stage:evaluate"):
             d_evaluate_wrapper(wd, **kwargs)
         if not kwargs.get("skip_plots", False):
