@@ -23,6 +23,7 @@ import pandas as pd
 
 from drep_tpu import schemas
 from drep_tpu.utils.logger import get_logger
+from drep_tpu.utils.profiling import counters
 from drep_tpu.workdir import WorkDirectory
 
 SCORE_DEFAULTS: dict[str, Any] = {
@@ -85,7 +86,8 @@ def score_genomes(
             df[col] = 0.0
         df[col] = df[col].fillna(0.0)
 
-    centrality = compute_centrality(ndb, cdb)
+    with counters.span("choose/centrality", pairs=len(ndb)):
+        centrality = compute_centrality(ndb, cdb)
     df["centrality"] = df["genome"].map(centrality).fillna(0.0)
 
     score = (
@@ -146,30 +148,34 @@ def score_and_pick(
 def d_choose_wrapper(wd: WorkDirectory, bdb: pd.DataFrame, **kwargs) -> pd.DataFrame:
     """Score + pick winners; stores Sdb/Wdb; copies winners; returns Wdb."""
     logger = get_logger()
-    cdb = wd.get_db("Cdb")
-    ndb = wd.get_db("Ndb") if wd.hasDb("Ndb") else schemas.empty("Ndb")
-    stats = wd.get_db("genomeInformation")
-    quality = wd.get_db("genomeInfo") if wd.hasDb("genomeInfo") else None
+    # `choose/tables` is every table read and written here; the score (with
+    # the centrality inside it) and the winners' copy have spans of their own
+    with counters.span("choose/tables"):
+        cdb = wd.get_db("Cdb")
+        ndb = wd.get_db("Ndb") if wd.hasDb("Ndb") else schemas.empty("Ndb")
+        stats = wd.get_db("genomeInformation")
+        quality = wd.get_db("genomeInfo") if wd.hasDb("genomeInfo") else None
+        extra = None
+        if kwargs.get("extra_weight_table"):
+            extra = pd.read_csv(kwargs["extra_weight_table"], sep=None, engine="python")
 
-    extra = None
-    if kwargs.get("extra_weight_table"):
-        extra = pd.read_csv(kwargs["extra_weight_table"], sep=None, engine="python")
-
-    sdb_full, wdb = score_and_pick(cdb, stats, ndb, quality, extra_weights=extra, **kwargs)
+    with counters.span("choose/score", genomes=len(cdb)):
+        sdb_full, wdb = score_and_pick(cdb, stats, ndb, quality, extra_weights=extra, **kwargs)
     sdb = sdb_full[["genome", "score"]].copy()
     # the reference ABORTS dereplicate without quality info; we proceed with
     # the quality terms scoring 0 (documented delta) — but the Sdb must say
     # so, or a downstream reader would take the scores as quality-informed
     sdb["quality_informed"] = quality is not None
-    wd.store_db(schemas.validate(sdb, "Sdb"), "Sdb")
-
-    wd.store_db(schemas.validate(wdb, "Wdb"), "Wdb")
+    with counters.span("choose/tables"):
+        wd.store_db(schemas.validate(sdb, "Sdb"), "Sdb")
+        wd.store_db(schemas.validate(wdb, "Wdb"), "Wdb")
 
     out_dir = wd.get_loc("dereplicated_genomes")
     loc = bdb.set_index("genome")["location"]
-    for row in wdb.itertuples():
-        src = loc.get(row.genome)
-        if src is not None:
-            shutil.copy(src, out_dir)
+    with counters.span("choose/copy", winners=len(wdb)):
+        for row in wdb.itertuples():
+            src = loc.get(row.genome)
+            if src is not None:
+                shutil.copy(src, out_dir)
     logger.info("choose: %d winners from %d genomes", len(wdb), len(cdb))
     return wdb

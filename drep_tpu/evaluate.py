@@ -14,7 +14,9 @@ from typing import Any
 
 import pandas as pd
 
+from drep_tpu.utils.ckptmeta import atomic_write_bytes
 from drep_tpu.utils.logger import get_logger
+from drep_tpu.utils.profiling import counters
 from drep_tpu.workdir import WorkDirectory
 
 EVALUATE_DEFAULTS: dict[str, Any] = {
@@ -94,23 +96,22 @@ def make_widb(wdb: pd.DataFrame, cdb: pd.DataFrame, stats: pd.DataFrame | None, 
 
 def d_evaluate_wrapper(wd: WorkDirectory, **kwargs) -> list[str]:
     logger = get_logger()
-    mdb = wd.get_db("Mdb") if wd.hasDb("Mdb") else None
-    ndb = wd.get_db("Ndb") if wd.hasDb("Ndb") else None
-    cdb = wd.get_db("Cdb")
-    has_wdb = wd.hasDb("Wdb")
-    wdb = wd.get_db("Wdb") if has_wdb else pd.DataFrame({"genome": cdb["genome"]})
+    with counters.span("evaluate/tables"):
+        mdb = wd.get_db("Mdb") if wd.hasDb("Mdb") else None
+        ndb = wd.get_db("Ndb") if wd.hasDb("Ndb") else None
+        cdb = wd.get_db("Cdb")
+        has_wdb = wd.hasDb("Wdb")
+        wdb = wd.get_db("Wdb") if has_wdb else pd.DataFrame({"genome": cdb["genome"]})
+        if has_wdb:
+            stats = wd.get_db("genomeInformation") if wd.hasDb("genomeInformation") else None
+            quality = wd.get_db("genomeInfo") if wd.hasDb("genomeInfo") else None
+            wd.store_db(make_widb(wdb, cdb, stats, quality), "Widb")
 
-    if has_wdb:
-        stats = wd.get_db("genomeInformation") if wd.hasDb("genomeInformation") else None
-        quality = wd.get_db("genomeInfo") if wd.hasDb("genomeInfo") else None
-        wd.store_db(make_widb(wdb, cdb, stats, quality), "Widb")
-
-    warnings = evaluate_warnings(mdb, ndb, cdb, wdb, **kwargs)
-    path = wd.get_loc("warnings")
-    # atomic (utils/durableio.py): a SIGKILL mid-write must not leave a
-    # torn warnings.txt a resumed run trusts as the stage's full output
-    from drep_tpu.utils.ckptmeta import atomic_write_bytes
-
-    atomic_write_bytes(path, "".join(w + "\n" for w in warnings).encode())
+    with counters.span("evaluate/warnings", winners=len(wdb)):
+        warnings = evaluate_warnings(mdb, ndb, cdb, wdb, **kwargs)
+        path = wd.get_loc("warnings")
+        # atomic (utils/durableio.py): a SIGKILL mid-write must not leave a
+        # torn warnings.txt a resumed run trusts as the stage's full output
+        atomic_write_bytes(path, "".join(w + "\n" for w in warnings).encode())
     logger.info("evaluate: %d warnings -> %s", len(warnings), path)
     return warnings

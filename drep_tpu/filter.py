@@ -8,6 +8,13 @@ contamination) or, when available on $PATH, from CheckM via subprocess
 `!!!` warning is emitted (the reference aborts dereplicate without quality —
 we soften this to keep the TPU pipeline runnable in binary-free
 environments, with the same loud warning).
+
+`stage:filter` reads every FASTA once, serially, in this process
+(`fasta_stats`, the `filter/fasta_stats` span) for length, N50 and contigs.
+The ingest pool of `stage:cluster` then reads every file that passed again
+and returns the same three numbers with the sketches
+(`GenomeSketches.gdb`, stored as Gdb): a second read of each genome, kept
+because the length filter has to come before the sketching it spares.
 """
 
 from __future__ import annotations
@@ -21,6 +28,7 @@ import pandas as pd
 
 from drep_tpu.utils.fasta import fasta_stats
 from drep_tpu.utils.logger import get_logger, user_warning
+from drep_tpu.utils.profiling import counters
 from drep_tpu.workdir import WorkDirectory
 from drep_tpu.errors import UserInputError
 
@@ -119,16 +127,37 @@ def d_filter_wrapper(
     kw = dict(FILTER_DEFAULTS)
     kw.update({k: v for k, v in kwargs.items() if v is not None})
 
-    stats = pd.DataFrame(
-        [fasta_stats(row.location, row.genome).__dict__ for row in bdb.itertuples()]
-    )
-    wd.store_db(stats, "genomeInformation")
+    with counters.span("filter/fasta_stats", genomes=len(bdb)):
+        stats = pd.DataFrame(
+            [fasta_stats(row.location, row.genome).__dict__ for row in bdb.itertuples()]
+        )
+    with counters.span("tables_io") as io:
+        io.note(rows=len(stats), bytes=wd.store_db(stats, "genomeInformation"))
 
     keep = stats["length"] >= kw["length"]
     dropped_len = list(stats.loc[~keep, "genome"])
     if dropped_len:
         logger.info("filtered %d genomes below length %d: %s", len(dropped_len), kw["length"], dropped_len)
 
+    with counters.span("filter/quality"):
+        keep, dropped = _quality_filter(wd, bdb, stats, keep, genomeInfo, kw, kwargs.get("processes", 1))
+
+    filtered = bdb[bdb["genome"].isin(stats.loc[keep, "genome"])].reset_index(drop=True)
+    counters.add_filter(len(bdb), len(dropped_len), *dropped)
+    if len(filtered) == 0:
+        raise RuntimeError("all genomes were filtered out — relax --length/--completeness/--contamination")
+    with counters.span("tables_io") as io:
+        io.note(rows=len(filtered), bytes=wd.store_db(filtered, "Bdb"))
+        wd.store_arguments("filter", {k: kw[k] for k in FILTER_DEFAULTS})
+    logger.info("filter: %d/%d genomes pass", len(filtered), len(bdb))
+    return filtered
+
+
+def _quality_filter(wd, bdb, stats, keep, genomeInfo, kw, processes):
+    """`keep` narrowed by completeness and contamination, from --genomeInfo
+    or CheckM, and (dropped by completeness, dropped by contamination)
+    among the genomes the length filter kept. Stores the genomeInfo table."""
+    logger = get_logger()
     quality: pd.DataFrame | None = None
     if genomeInfo is not None:
         quality = load_genome_info(genomeInfo)
@@ -140,7 +169,7 @@ def d_filter_wrapper(
             quality = run_checkm_wrapper(
                 bdb,
                 wd.get_dir(os.path.join("data", "checkM")),
-                kwargs.get("processes", 1),
+                processes,
                 checkm_method=kw["checkM_method"],
             )
         else:
@@ -149,24 +178,18 @@ def d_filter_wrapper(
                 "filtering and quality-based scoring are DISABLED for this run"
             )
 
-    if quality is not None:
-        q = quality.set_index("genome")
-        in_q = stats["genome"].isin(q.index)
-        if (~in_q).any():
-            raise UserInputError(f"genomes missing from genomeInfo: {list(stats.loc[~in_q, 'genome'])}")
-        comp = stats["genome"].map(q["completeness"])
-        cont = stats["genome"].map(q["contamination"])
-        qkeep = (comp >= kw["completeness"]) & (cont <= kw["contamination"])
-        dropped_q = list(stats.loc[keep & ~qkeep, "genome"])
-        if dropped_q:
-            logger.info("filtered %d genomes by quality: %s", len(dropped_q), dropped_q)
-        keep &= qkeep
-        wd.store_db(quality, "genomeInfo")
-
-    filtered = bdb[bdb["genome"].isin(stats.loc[keep, "genome"])].reset_index(drop=True)
-    if len(filtered) == 0:
-        raise RuntimeError("all genomes were filtered out — relax --length/--completeness/--contamination")
-    wd.store_db(filtered, "Bdb")
-    wd.store_arguments("filter", {k: kw[k] for k in FILTER_DEFAULTS})
-    logger.info("filter: %d/%d genomes pass", len(filtered), len(bdb))
-    return filtered
+    if quality is None:
+        return keep, (0, 0)
+    q = quality.set_index("genome")
+    in_q = stats["genome"].isin(q.index)
+    if (~in_q).any():
+        raise UserInputError(f"genomes missing from genomeInfo: {list(stats.loc[~in_q, 'genome'])}")
+    # a missing value passes neither rule
+    low_comp = ~(stats["genome"].map(q["completeness"]) >= kw["completeness"])
+    high_cont = ~(stats["genome"].map(q["contamination"]) <= kw["contamination"])
+    qkeep = ~(low_comp | high_cont)
+    dropped_q = list(stats.loc[keep & ~qkeep, "genome"])
+    if dropped_q:
+        logger.info("filtered %d genomes by quality: %s", len(dropped_q), dropped_q)
+    wd.store_db(quality, "genomeInfo")
+    return keep & qkeep, (int((keep & low_comp).sum()), int((keep & high_cont).sum()))

@@ -177,18 +177,18 @@ pack_ragged = _pack_ragged
 unpack_ragged = _unpack_ragged
 
 
-def _note_ingest_path() -> None:
+def _note_ingest_path() -> str:
     """Record which per-genome kernel sketched this run's genomes — the
     native C++ library or the numpy path (native/__init__.py) — in the
-    run record (perf_counters.json note ``ingest_path``). The pool
-    workers load the very library this process loads, so asking here
-    answers for them."""
+    run record (perf_counters.json note ``ingest_path``), and return it.
+    The pool workers load the very library this process loads, so asking
+    here answers for them."""
     from drep_tpu import native
     from drep_tpu.utils.profiling import counters
 
-    counters.set_note(
-        "ingest_path", "native" if native.get_library() is not None else "numpy"
-    )
+    path = "native" if native.get_library() is not None else "numpy"
+    counters.set_note("ingest_path", path)
+    return path
 
 
 def sketch_paths(
@@ -398,16 +398,20 @@ def sketch_genomes(
         todo = [j for j in jobs if j[0] not in results]
     my_shard_files: set[str] = set()  # shards THIS process wrote (skip re-reading)
     pending: dict[str, dict] = {}
+    sketched: list[dict] = []  # what THIS run sketched: the record's `ingest` counter
+    from drep_tpu.utils.profiling import counters
 
     def flush(force: bool = False) -> None:
         if shard_dir is not None and pending and (force or len(pending) >= INGEST_SHARD):
             path = os.path.join(shard_dir, f"shard_{uuid.uuid4().hex}.npz")
-            _save_sketch_shard(path, pending)
+            with counters.span("ingest/shard_flush", genomes=len(pending)):
+                _save_sketch_shard(path, pending)
             my_shard_files.add(path)  # already in `results`: barrier skips it
             pending.clear()
 
     def collect(name: str, res: dict) -> None:
         results[name] = res
+        sketched.append(res)
         # never checkpoint an unparseable result: a persisted zero-kmer
         # shard would be resumed by name on the next run and keep raising
         # the validation error even after the user fixes the file
@@ -415,23 +419,32 @@ def sketch_genomes(
             pending[name] = res
             flush()
 
-    if processes > 1 and len(todo) > 1:
-        # spawn, not fork: by the time ingest runs inside a pipeline the
-        # JAX backend is usually initialized and multithreaded, and a
-        # forked child can deadlock on locks held at fork time (CPython
-        # itself warns on fork-after-threads). The worker module chain is
-        # deliberately jax-free and lean (sketch_worker.py), so spawn
-        # startup stays ~0.7 s/worker.
-        ctx = multiprocessing.get_context("spawn")
-        with ProcessPoolExecutor(max_workers=processes, mp_context=ctx) as pool:
-            for name, res in pool.map(_sketch_one, todo):
-                collect(name, res)
-    else:
-        for job in todo:
-            collect(*_sketch_one(job))
-    flush(force=True)
+    # `ingest/sketch` is the main thread's wall from the pool's spawn to its
+    # last result and the last shard flush; the workers open no spans, each
+    # result carries its own seconds (the record's `ingest` counter)
+    workers = min(processes, len(todo)) if processes > 1 and len(todo) > 1 else 1
+    with counters.span("ingest/sketch", genomes=len(todo), workers=workers):
+        if workers > 1:
+            # spawn, not fork: by the time ingest runs inside a pipeline the
+            # JAX backend is usually initialized and multithreaded, and a
+            # forked child can deadlock on locks held at fork time (CPython
+            # itself warns on fork-after-threads). The worker module chain is
+            # deliberately jax-free and lean (sketch_worker.py), so spawn
+            # startup stays ~0.7 s/worker.
+            ctx = multiprocessing.get_context("spawn")
+            with ProcessPoolExecutor(max_workers=workers, mp_context=ctx) as pool:
+                with counters.span("ingest/pool_start"):  # spawn to the first result
+                    arriving = pool.map(_sketch_one, todo)
+                    first = next(arriving)
+                collect(*first)
+                for name, res in arriving:
+                    collect(name, res)
+        else:
+            for job in todo:
+                collect(*_sketch_one(job))
+        flush(force=True)
     if todo:
-        _note_ingest_path()
+        counters.add_ingest(sketched, workers, _note_ingest_path())
 
     if nproc > 1:
         from drep_tpu.utils.ckptmeta import atomic_write_bytes
@@ -581,12 +594,13 @@ def sketch_genomes(
             while not peers_done and time.monotonic() < deadline:
                 time.sleep(_INGEST_BARRIER_POLL_S)
                 peers_done = all(os.path.exists(f) for f in peers)
-        _save(wd, out)
-        wd.store_arguments("sketch", args_snapshot)
-        # the assembled cache supersedes the shards — drop them rather
-        # than double the on-disk footprint (~16 GB at 100k genomes)
-        if shard_dir is not None and (nproc == 1 or peers_done):
-            shutil.rmtree(shard_dir, ignore_errors=True)
+        with counters.span("ingest/cache_save", genomes=len(names)):
+            _save(wd, out)
+            wd.store_arguments("sketch", args_snapshot)
+            # the assembled cache supersedes the shards — drop them rather
+            # than double the on-disk footprint (~16 GB at 100k genomes)
+            if shard_dir is not None and (nproc == 1 or peers_done):
+                shutil.rmtree(shard_dir, ignore_errors=True)
     return out
 
 

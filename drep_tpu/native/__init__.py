@@ -43,6 +43,7 @@ class _DrepSketch(ctypes.Structure):
         ("scaled_len", ctypes.c_int64),
         ("bottom", ctypes.POINTER(ctypes.c_uint64)),
         ("scaled", ctypes.POINTER(ctypes.c_uint64)),
+        ("n_valid", ctypes.c_int64),
     ]
 
 
@@ -143,7 +144,7 @@ def sketch_fasta_native(
 ) -> dict | None:
     """Full per-genome ingest in one native call.
 
-    Returns {length, N50, contigs, n_kmers, bottom, scaled} with uint64
+    Returns {length, N50, contigs, n_kmers, valid_kmers, bottom, scaled} with uint64
     sketch arrays (copies — safe after the native buffers are freed), or
     None when the native library is unavailable. Raises on file errors,
     matching the numpy path.
@@ -176,6 +177,7 @@ def sketch_fasta_native(
         "N50": int(out.n50),
         "contigs": int(out.n_contigs),
         "n_kmers": n_kmers,
+        "valid_kmers": int(out.n_valid),  # windows hashed, duplicates counted
         "bottom": bottom.astype(np.uint64),
         "scaled": scaled.astype(np.uint64),
     }

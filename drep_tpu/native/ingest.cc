@@ -39,6 +39,7 @@ typedef struct {
   int64_t scaled_len;  // entries in `scaled`
   uint64_t* bottom;    // sorted ascending, malloc'd (free via drep_sketch_free)
   uint64_t* scaled;    // sorted ascending, malloc'd
+  int64_t n_valid;     // valid k-mer windows hashed (duplicates counted)
 } DrepSketch;
 
 static inline uint64_t splitmix64(uint64_t z) {
@@ -265,6 +266,7 @@ int drep_sketch_fasta(const char* path, int k, int64_t sketch_size,
   // its first s entries — the full multi-million-hash sort is skipped and
   // n_kmers is reported as -1 ("estimate as scaled_len * scale", done by
   // the Python wrapper). Small genomes fall back to the exact full dedup.
+  const int64_t n_valid = (int64_t)hashes.size();
   std::vector<uint64_t> small;
   small.reserve(hashes.size() / 64 + 16);
   for (uint64_t h : hashes) {
@@ -286,6 +288,7 @@ int drep_sketch_fasta(const char* path, int k, int64_t sketch_size,
   out->length = total;
   out->n_contigs = (int32_t)contig_lengths.size();
   out->n_kmers = fast ? -1 : (int64_t)hashes.size();
+  out->n_valid = n_valid;
 
   // N50: descending lengths, first cumulative sum >= total/2 (fasta.py::n50)
   if (!contig_lengths.empty()) {

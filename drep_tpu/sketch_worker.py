@@ -10,6 +10,9 @@ sketching itself at <100 genomes otherwise.
 
 from __future__ import annotations
 
+import os
+import time
+
 import numpy as np
 
 from drep_tpu.ops import kmers
@@ -18,14 +21,23 @@ from drep_tpu.utils.fasta import n50, read_fasta_contigs
 
 def sketch_one(args) -> tuple[str, dict]:
     """(name, path, k, sketch_size, scale, hash_name) -> (name, result
-    dict with length/N50/contigs/n_kmers/bottom/scaled)."""
-    name, path, k, sketch_size, scale, hash_name = args
+    dict with length/N50/contigs/n_kmers/bottom/scaled, and what the
+    record's `ingest` counter sums: the file's bytes, the valid k-mers
+    hashed and the seconds this call took, on either path)."""
+    name, path = args[:2]
+    t0 = time.perf_counter()
+    res = _sketch(*args[1:])
+    res["file_bytes"] = os.path.getsize(path)
+    res["seconds"] = time.perf_counter() - t0
+    return name, res
 
+
+def _sketch(path, k, sketch_size, scale, hash_name) -> dict:
     from drep_tpu.native import sketch_fasta_native
 
     native = sketch_fasta_native(path, k, sketch_size, scale, hash_name)
     if native is not None:
-        return name, native
+        return native
 
     contigs = read_fasta_contigs(path)
     lengths = np.array([len(c) for c in contigs], dtype=np.int64)
@@ -34,11 +46,12 @@ def sketch_one(args) -> tuple[str, dict]:
         or [np.empty(0, np.uint64)]
     )
     bottom, scaled, n_kmers = kmers.sketches_from_raw(raw, sketch_size, scale)
-    return name, {
+    return {
         "length": int(lengths.sum()) if len(lengths) else 0,
         "N50": n50(lengths),
         "contigs": len(contigs),
         "n_kmers": n_kmers,
+        "valid_kmers": int(raw.size),
         "bottom": bottom,
         "scaled": scaled,
     }

@@ -246,6 +246,13 @@ class Counters:
     # writer rendered for them, and the calls that went through pandas'
     # `to_csv` instead, with why (drep_tpu/tablewriter.py)
     tables_write: dict[str, dict[str, Any]] = field(default_factory=dict)
+    # what FASTA ingest sketched (ingest.py, ISSUE 31): genomes, the files'
+    # bytes, bases, valid k-mers, the hashes of both sketches, and the
+    # seconds the workers themselves spent in `sketch_one`, summed; the
+    # widest pool and the kernel that served ride beside the sums
+    ingest: dict[str, Any] = field(default_factory=dict)
+    # what `stage:filter` saw and dropped, by reason (filter.py)
+    filter: dict[str, int] = field(default_factory=dict)
     _open: threading.local = field(default_factory=threading.local, repr=False, compare=False)
     _lock: threading.Lock = field(default_factory=threading.Lock, repr=False, compare=False)
 
@@ -381,6 +388,33 @@ class Counters:
             reasons = ent.setdefault("fallback_reasons", {})
             reasons[fallback] = reasons.get(fallback, 0) + 1
 
+    def add_ingest(self, results, workers: int, path: str) -> None:
+        """Book the genomes one ingest sketched: `results` are the dicts
+        `sketch_worker.sketch_one` returned, each with its own `seconds`,
+        `workers` the processes that shared them, `path` the kernel."""
+        booked = {
+            "genomes": len(results),
+            "file_bytes": sum(r["file_bytes"] for r in results),
+            "bases": sum(r["length"] for r in results),
+            "valid_kmers": sum(r["valid_kmers"] for r in results),
+            "bottom_hashes": sum(len(r["bottom"]) for r in results),
+            "scaled_hashes": sum(len(r["scaled"]) for r in results),
+        }
+        for name, value in booked.items():
+            self.ingest[name] = self.ingest.get(name, 0) + int(value)
+        self.ingest["busy_seconds"] = self.ingest.get("busy_seconds", 0.0) + float(
+            sum(r["seconds"] for r in results))
+        self.ingest["workers"] = max(int(workers), self.ingest.get("workers", 0))
+        self.ingest["path"] = path
+
+    def add_filter(self, genomes: int, length: int, completeness: int, contamination: int) -> None:
+        """Book one `d_filter_wrapper`: `genomes` came in; the rest are the
+        genomes each rule dropped (a genome both rules drop counts in both)."""
+        booked = {"genomes": genomes, "dropped_length": length,
+                  "dropped_completeness": completeness, "dropped_contamination": contamination}
+        for name, value in booked.items():
+            self.filter[name] = self.filter.get(name, 0) + int(value)
+
     def set_gauge(self, name: str, value: float) -> None:
         """Record a derived operational value (last write wins)."""
         self.gauges[name] = float(value)
@@ -494,6 +528,10 @@ class Counters:
             out["primary_pack"] = dict(self.primary_pack)
         if self.tables_write:
             out["tables_write"] = {name: dict(ent) for name, ent in sorted(self.tables_write.items())}
+        if self.ingest:
+            out["ingest"] = {**self.ingest, "busy_seconds": round(self.ingest["busy_seconds"], 4)}
+        if self.filter:
+            out["filter"] = dict(self.filter)
         phases = self._phases_report()
         if phases:
             out["phases"] = phases
@@ -552,6 +590,8 @@ class Counters:
         self.chunked_calls.clear()
         self.primary_pack.clear()
         self.tables_write.clear()
+        self.ingest.clear()
+        self.filter.clear()
         with self._lock:
             self.phases.clear()  # a span open now stays open and books when it closes
 
