@@ -19,6 +19,7 @@ round-trips — sketches are packed once and all-pairs tiles run on device
 
 from __future__ import annotations
 
+import contextlib
 import os
 import pickle
 from typing import Any
@@ -33,6 +34,8 @@ from drep_tpu.ingest import (
     DEFAULT_SCALE,
     DEFAULT_SKETCH_SIZE,
     GenomeSketches,
+    IngestPass,
+    read_genomes,
     sketch_cache_will_hit,
     sketch_genomes,
 )
@@ -565,8 +568,95 @@ def _secondary_stage(
     return secondary_names, ndb_parts, files
 
 
-def d_cluster_wrapper(wd: WorkDirectory, bdb: pd.DataFrame, **kwargs) -> pd.DataFrame:
-    """Run (or resume) the full clustering stage; returns Cdb."""
+def _sketch_args(kw: dict[str, Any]) -> dict[str, Any]:
+    return {"k": kw["kmer_size"], "sketch_size": kw["MASH_sketch"], "scale": kw["scale"],
+            "processes": kw["processes"], "hash_name": kw["hash"]}
+
+
+@contextlib.contextmanager
+def _ingest_stage(wd: WorkDirectory, genomes, kw: dict[str, Any], resolved: str):
+    """`stage:ingest_or_cache` (counted, so a run's stage seconds attribute
+    the cache-load / ingest wall separately from compute), with the
+    streaming tile programs' cold compile hidden behind it where that buys
+    anything. `resolved` is the primary estimator the run resolves to."""
+    warmup_thread = None
+    warmup_error: list[BaseException] = []
+    if (
+        kw["overlap_ingest"]
+        # ingest pool workers are SPAWNED (ingest.py::read_genomes), so
+        # running them while this thread sits inside XLA's multithreaded
+        # compiler is safe — spawn children inherit no locks
+        and resolved == "streaming_sort"
+        # nothing to hide the compile behind when ingest will return
+        # without sketching (whole-run cache hit on resumed runs /
+        # pre-planted workdirs, or a shard store that already covers
+        # every genome after a kill between the last flush and cache
+        # assembly): the main thread then just waits on the same compile.
+        # Read-only pre-check; the revalidation inside sketch_genomes
+        # still governs whether the cache is actually used
+        and not sketch_cache_will_hit(
+            wd, genomes, kw["kmer_size"], kw["MASH_sketch"],
+            kw["scale"], kw["hash"],
+        )
+    ):
+        # overlap the streaming tile programs' cold compile with host
+        # ingest — the one ingest/compute overlap that is exact and free
+        # (parallel/streaming.py module docstring has the analysis);
+        # compile only, nothing executes
+        import threading
+
+        from drep_tpu.parallel.streaming import (
+            retention_bound,
+            warmup_streaming_compile,
+        )
+
+        def _warm() -> None:
+            # this thread is the first code to touch the backend: whatever
+            # it raises (no device, a compiler rejection) must reach the
+            # run, so it is kept and re-raised after the join
+            try:
+                warmup_streaming_compile(
+                    kw["MASH_sketch"], block=kw["streaming_block"],
+                    k=kw["kmer_size"],
+                    cutoff=retention_bound(
+                        1.0 - kw["P_ani"], _warn_dist(kw), kw["clusterAlg"]
+                    ),
+                )
+            except BaseException as e:  # noqa: BLE001 — re-raised below
+                warmup_error.append(e)
+
+        warmup_thread = threading.Thread(target=_warm, name="drep-warmup")
+        warmup_thread.start()
+    try:
+        with counters.stage("ingest_or_cache"):
+            yield
+    finally:
+        if warmup_thread is not None:
+            # joined even when ingest raises — a dangling thread inside
+            # XLA's C++ compile aborts interpreter teardown and masks the
+            # real error; by now ingest has absorbed the compile anyway
+            warmup_thread.join()
+    if warmup_error:
+        raise warmup_error[0]
+
+
+def read_for_filter(wd: WorkDirectory, bdb: pd.DataFrame, stats_only, **kwargs) -> IngestPass:
+    """`dereplicate`'s one read of every FASTA, in a `stage:ingest_or_cache`
+    of its own between the filter's two halves (filter.py): `stats_only`
+    are the genomes the quality table already drops. The pass goes to
+    :func:`d_cluster_wrapper` as `sketches`."""
+    kw = _fill_defaults(kwargs)
+    resolved = _resolve_estimator_for_run(len(bdb) - len(stats_only), kw)
+    with _ingest_stage(wd, bdb["genome"], kw, resolved):
+        return read_genomes(bdb, wd=wd, stats_only=stats_only, **_sketch_args(kw))
+
+
+def d_cluster_wrapper(
+    wd: WorkDirectory, bdb: pd.DataFrame, sketches: IngestPass | None = None, **kwargs
+) -> pd.DataFrame:
+    """Run (or resume) the full clustering stage; returns Cdb. `sketches`
+    is the filter's pass over the FASTAs where there was one: `bdb`'s
+    genomes are kept from it and none is read here."""
     logger = get_logger()
     kw = _fill_defaults(kwargs)
     ft_cfg = _ft_config(kw)  # install the run's fault-tolerance defaults
@@ -609,75 +699,14 @@ def d_cluster_wrapper(wd: WorkDirectory, bdb: pd.DataFrame, **kwargs) -> pd.Data
         logger.info("resuming: Cdb present with matching cluster arguments — skipping recompute")
         return wd.get_db("Cdb")
 
-    warmup_thread = None
-    warmup_error: list[BaseException] = []
-    if (
-        kw["overlap_ingest"]
-        # ingest pool workers are SPAWNED (ingest.py::sketch_genomes), so
-        # running them while this thread sits inside XLA's multithreaded
-        # compiler is safe — spawn children inherit no locks
-        and snapshot["primary_estimator_resolved"] == "streaming_sort"
-        # nothing to hide the compile behind when ingest will return
-        # without sketching (whole-run cache hit on resumed runs /
-        # pre-planted workdirs, or a shard store that already covers
-        # every genome after a kill between the last flush and cache
-        # assembly): the main thread then just waits on the same compile.
-        # Read-only pre-check; the revalidation inside sketch_genomes
-        # still governs whether the cache is actually used
-        and not sketch_cache_will_hit(
-            wd, bdb["genome"], kw["kmer_size"], kw["MASH_sketch"],
-            kw["scale"], kw["hash"],
-        )
-    ):
-        # overlap the streaming tile programs' cold compile with host
-        # ingest — the one ingest/compute overlap that is exact and free
-        # (parallel/streaming.py module docstring has the analysis);
-        # compile only, nothing executes
-        import threading
-
-        from drep_tpu.parallel.streaming import (
-            retention_bound,
-            warmup_streaming_compile,
-        )
-
-        def _warm() -> None:
-            # this thread is the first code to touch the backend: whatever
-            # it raises (no device, a compiler rejection) must reach the
-            # run, so it is kept and re-raised after the join
-            try:
-                warmup_streaming_compile(
-                    kw["MASH_sketch"], block=kw["streaming_block"],
-                    k=kw["kmer_size"],
-                    cutoff=retention_bound(
-                        1.0 - kw["P_ani"], _warn_dist(kw), kw["clusterAlg"]
-                    ),
-                )
-            except BaseException as e:  # noqa: BLE001 — re-raised below
-                warmup_error.append(e)
-
-        warmup_thread = threading.Thread(target=_warm, name="drep-warmup")
-        warmup_thread.start()
-    try:
-        # counted so a run's stage seconds attribute the cache-load /
-        # ingest wall separately from compute
+    if sketches is not None:
+        # the filter's pass read every FASTA already: its sketches of the
+        # kept genomes become this run's cache, nothing is sketched here
         with counters.stage("ingest_or_cache"):
-            gs = sketch_genomes(
-                bdb,
-                k=kw["kmer_size"],
-                sketch_size=kw["MASH_sketch"],
-                scale=kw["scale"],
-                processes=kw["processes"],
-                wd=wd,
-                hash_name=kw["hash"],
-            )
-    finally:
-        if warmup_thread is not None:
-            # joined even when ingest raises — a dangling thread inside
-            # XLA's C++ compile aborts interpreter teardown and masks the
-            # real error; by now ingest has absorbed the compile anyway
-            warmup_thread.join()
-    if warmup_error:
-        raise warmup_error[0]
+            gs = sketches.keep(bdb["genome"])
+    else:
+        with _ingest_stage(wd, bdb["genome"], kw, snapshot["primary_estimator_resolved"]):
+            gs = sketch_genomes(bdb, wd=wd, **_sketch_args(kw))
     n = len(gs.names)
     logger.info("clustering %d genomes (primary=%s, secondary=%s)", n, kw["primary_algorithm"], kw["S_algorithm"])
 

@@ -12,6 +12,14 @@ sketch files under ``<wd>/data/``.
 Parallelism: a process pool over genomes (numpy releases little GIL during
 the pack matmul, so processes, not threads). The optional C++ ingest
 (drep_tpu.native) replaces the per-genome numpy kernel transparently.
+
+A job reads each FASTA once. `compare` sketches its whole Bdb
+(:func:`sketch_genomes`). `dereplicate`'s filter needs every genome's
+length, N50 and contigs before it knows what to keep, so it takes the pass
+in two steps: :func:`read_genomes` over the whole input Bdb (a genome the
+quality table already drops is read for its stats alone), then
+:meth:`IngestPass.keep` of the genomes that passed — the cache, Gdb and the
+`sketch` arguments hold those and no other (filter.py).
 """
 
 from __future__ import annotations
@@ -292,10 +300,6 @@ def sketch_genomes(
     """Sketch every genome in Bdb; cache/restore via the work directory
     (whole-run cache, plus mid-run shard checkpoints every INGEST_SHARD
     genomes so a killed ingest resumes where it stopped)."""
-    import glob
-    import shutil
-    import uuid
-
     logger = get_logger()
     args_snapshot = sketch_args_snapshot(bdb["genome"], k, sketch_size, scale, hash_name)
 
@@ -311,8 +315,61 @@ def sketch_genomes(
             "ingest: cached sketches contain zero-kmer genomes (stale cache "
             "from an unvalidated run?) — recomputing"
         )
+    return read_genomes(bdb, k, sketch_size, scale, processes, wd, hash_name).keep(bdb["genome"])
 
-    jobs = [(row.genome, row.location, k, sketch_size, scale, hash_name) for row in bdb.itertuples()]
+
+def _results_of(gs: GenomeSketches) -> dict[str, dict]:
+    """A whole-run cache as the per-genome results it was assembled from."""
+    scalars = {key: gs.gdb[key].to_numpy() for key in _SHARD_SCALARS}
+    return {
+        g: {**{key: int(scalars[key][i]) for key in _SHARD_SCALARS},
+            "bottom": gs.bottom[i], "scaled": gs.scaled[i]}
+        for i, g in enumerate(gs.names)
+    }
+
+
+def _scalars_frame(names: list[str], results: dict[str, dict], keys) -> pd.DataFrame:
+    """genome and the per-genome integers `keys`, a row a name."""
+    return pd.DataFrame(
+        {"genome": names, **{key: [results[g][key] for g in names] for key in keys}}
+    )
+
+
+def _unparseable(names: list[str], k: int) -> UserInputError:
+    shown = ", ".join(names[:10]) + (" ..." if len(names) > 10 else "")
+    return UserInputError(
+        f"no FASTA records with valid nucleotide {k}-mers in {len(names)} "
+        f"input file(s) (not FASTA, empty, or shorter than k): {shown}"
+    )
+
+
+def read_genomes(
+    bdb: pd.DataFrame,
+    k: int,
+    sketch_size: int,
+    scale: int,
+    processes: int = 1,
+    wd: WorkDirectory | None = None,
+    hash_name: str = "splitmix64",
+    stats_only=None,
+) -> IngestPass:
+    """One pass of the pool over every genome of `bdb`: each file opened and
+    parsed once, by `sketch_one`. The caller then says which genomes it keeps
+    (:meth:`IngestPass.keep`), having seen every genome's length, N50 and
+    contigs (:attr:`IngestPass.stats`).
+
+    `stats_only` is the filter's (filter.py): the names it already knows it
+    will drop, read for their stats alone — no k-mer hashed, no sketch, never
+    a shard entry. None from every other caller, which keeps all it reads.
+    The shard store is keyed on the whole list given here; a rerun resumes
+    the sketched genomes from it and reads the stats-only ones again. In a
+    multi-process pod every genome is sketched: the shard store is the only
+    channel between the processes and holds sketched genomes alone."""
+    import glob
+    import uuid
+
+    logger = get_logger()
+    args_snapshot = sketch_args_snapshot(bdb["genome"], k, sketch_size, scale, hash_name)
     results: dict[str, dict] = {}
     shard_dir = None
     resume_loaded: set[str] = set()  # shard paths the resume glob consumed
@@ -354,7 +411,7 @@ def sketch_genomes(
             if results:
                 logger.info(
                     "ingest: resumed %d/%d sketched genomes from shards",
-                    len(results), len(jobs),
+                    len(results), len(bdb),
                 )
 
     # per-process sharded ingest (SURVEY.md §7 hard part (f)): under an
@@ -371,6 +428,14 @@ def sketch_genomes(
         import jax
 
         nproc, pid = jax.process_count(), jax.process_index()
+    # the shard dir is the only channel between a pod's processes and holds
+    # sketched genomes alone: there every genome is sketched
+    read_for_stats = frozenset(stats_only or ()) if nproc == 1 else frozenset()
+    jobs = [
+        (row.genome, row.location) if row.genome in read_for_stats
+        else (row.genome, row.location, k, sketch_size, scale, hash_name)
+        for row in bdb.itertuples()
+    ]
     if nproc > 1:
         # stripe ownership keys on the GLOBAL job index, never on the
         # locally-observed resume state: two processes whose resume globs
@@ -388,17 +453,15 @@ def sketch_genomes(
         # marker writes below keep any residual race benign, this just
         # removes the common case
         if pid == 0:
-            import glob as _glob
-
             for pat in ("assembled_*.done", "ingest_error_*.json"):
-                for f in _glob.glob(os.path.join(shard_dir, pat)):
+                for f in glob.glob(os.path.join(shard_dir, pat)):
                     with contextlib.suppress(OSError):
                         os.remove(f)
     else:
         todo = [j for j in jobs if j[0] not in results]
     my_shard_files: set[str] = set()  # shards THIS process wrote (skip re-reading)
     pending: dict[str, dict] = {}
-    sketched: list[dict] = []  # what THIS run sketched: the record's `ingest` counter
+    read: dict[str, dict] = {}  # what THIS run read: the record's `ingest` counter
     from drep_tpu.utils.profiling import counters
 
     def flush(force: bool = False) -> None:
@@ -410,11 +473,11 @@ def sketch_genomes(
             pending.clear()
 
     def collect(name: str, res: dict) -> None:
-        results[name] = res
-        sketched.append(res)
+        results[name] = read[name] = res
         # never checkpoint an unparseable result: a persisted zero-kmer
         # shard would be resumed by name on the next run and keep raising
-        # the validation error even after the user fixes the file
+        # the validation error even after the user fixes the file. A genome
+        # read for its stats alone hashed nothing and is such a result
         if res["n_kmers"] > 0:
             pending[name] = res
             flush()
@@ -443,12 +506,9 @@ def sketch_genomes(
             for job in todo:
                 collect(*_sketch_one(job))
         flush(force=True)
-    if todo:
-        counters.add_ingest(sketched, workers, _note_ingest_path())
+    path = _note_ingest_path() if todo else None
 
     if nproc > 1:
-        from drep_tpu.utils.ckptmeta import atomic_write_bytes
-
         # unparseable inputs in THIS stripe fail the whole pod fast: a
         # poison marker carries the real error to every peer's barrier
         # (zero-kmer results are never checkpointed, so without it peers
@@ -462,11 +522,7 @@ def sketch_genomes(
                     os.path.join(shard_dir, f"ingest_error_{pid}.json"),
                     {"pid": pid, "genomes": bad[:10], "n": len(bad)},
                 )
-            shown = ", ".join(bad[:10]) + (" ..." if len(bad) > 10 else "")
-            raise UserInputError(
-                f"no FASTA records with valid nucleotide {k}-mers in {len(bad)} "
-                f"input file(s) (not FASTA, empty, or shorter than k): {shown}"
-            )
+            raise _unparseable(bad, k)
 
         # assemble peers' stripes: re-glob until all genomes are covered,
         # or until the whole-run cache appears (a peer that finished
@@ -515,16 +571,12 @@ def sketch_genomes(
                     logger.info(
                         "ingest: peer assembled the whole-run cache first — using it"
                     )
-                    if pid != 0:
-                        # still signal process 0: its marker wait may be
-                        # pending, and an unsignaled exit here would leak
-                        # the superseded shard store forever (no later
-                        # run reopens it past the whole-run cache hit)
-                        with contextlib.suppress(OSError):
-                            atomic_write_bytes(
-                                os.path.join(shard_dir, f"assembled_{pid}.done"), b""
-                            )
-                    return cached
+                    # :meth:`IngestPass.keep` still signals process 0: its
+                    # marker wait may be pending, and an unsignaled exit
+                    # would leak the superseded shard store forever (no
+                    # later run reopens it past the whole-run cache hit)
+                    results.update(_results_of(cached))
+                    break
             if time.monotonic() > deadline:
                 missing = sorted(need - set(results))[:10]
                 raise RuntimeError(
@@ -537,34 +589,69 @@ def sketch_genomes(
                 )
             time.sleep(_INGEST_BARRIER_POLL_S)
 
-    names = list(bdb["genome"])
-    unparsed = [g for g in names if results[g]["n_kmers"] == 0]
-    if unparsed:
-        shown = ", ".join(unparsed[:10]) + (" ..." if len(unparsed) > 10 else "")
-        raise UserInputError(
-            f"no FASTA records with valid nucleotide {k}-mers in {len(unparsed)} "
-            f"input file(s) (not FASTA, empty, or shorter than k): {shown}"
+    return IngestPass(
+        names=list(bdb["genome"]), results=results, read=read, workers=workers, path=path,
+        for_filter=stats_only is not None, k=k, sketch_size=sketch_size, scale=scale,
+        hash_name=hash_name, wd=wd, shard_dir=shard_dir, nproc=nproc, pid=pid,
+    )
+
+
+@dataclass
+class IngestPass:
+    """What :func:`read_genomes` read, until the caller says what it keeps."""
+
+    names: list[str]  # every genome of the Bdb the pool was given, in its order
+    results: dict[str, dict]  # per genome: `sketch_one`'s result, or a shard's entry
+    read: dict[str, dict]  # the results THIS run read (the rest came from shards)
+    workers: int
+    path: str | None  # the kernel that served, None where nothing was read
+    for_filter: bool  # the filter's pass: the `ingest` counter says what it did beside the kept
+    k: int
+    sketch_size: int
+    scale: int
+    hash_name: str
+    wd: WorkDirectory | None
+    shard_dir: str | None
+    nproc: int
+    pid: int
+
+    @property
+    def stats(self) -> pd.DataFrame:
+        """genome, length, N50, contigs of every genome read: the filter's
+        `genomeInformation`."""
+        return _scalars_frame(self.names, self.results, ("length", "N50", "contigs"))
+
+    def keep(self, genomes) -> GenomeSketches:
+        """The sketches of `genomes` (all of them sketched, in this order),
+        written as the workdir's sketch cache, which holds these genomes and
+        no other; what was read beside them is thrown away. Raises
+        UserInputError for a kept genome with no valid k-mer, and books the
+        record's `ingest` counter."""
+        import shutil
+
+        from drep_tpu.utils.profiling import counters
+
+        names = list(genomes)
+        results, wd, shard_dir = self.results, self.wd, self.shard_dir
+        unparsed = [g for g in names if results[g]["n_kmers"] == 0]
+        if unparsed:
+            raise _unparseable(unparsed, self.k)
+        if self.read:
+            counters.add_ingest(self.read, set(names), self.workers, self.path, self.for_filter)
+        out = GenomeSketches(
+            names=names,
+            gdb=_scalars_frame(names, results, _SHARD_SCALARS),
+            bottom=[results[g]["bottom"] for g in names],
+            scaled=[results[g]["scaled"] for g in names],
+            k=self.k,
+            sketch_size=self.sketch_size,
+            scale=self.scale,
         )
-    gdb = pd.DataFrame(
-        {
-            "genome": names,
-            "length": [results[g]["length"] for g in names],
-            "N50": [results[g]["N50"] for g in names],
-            "contigs": [results[g]["contigs"] for g in names],
-            "n_kmers": [results[g]["n_kmers"] for g in names],
-        }
-    )
-    out = GenomeSketches(
-        names=names,
-        gdb=gdb,
-        bottom=[results[g]["bottom"] for g in names],
-        scaled=[results[g]["scaled"] for g in names],
-        k=k,
-        sketch_size=sketch_size,
-        scale=scale,
-    )
-    if wd is not None:
-        if nproc > 1 and pid != 0:
+        # the caller may hold this pass to the job's end: what was not kept goes now
+        self.results = self.read = {}
+        if wd is None:
+            return out
+        if self.nproc > 1 and self.pid != 0:
             # signal assembly-complete and leave the cache write + shard
             # reclamation to process 0: concurrent identical cache writes
             # are not atomic, and reclaiming shards a peer still reads
@@ -578,17 +665,18 @@ def sketch_genomes(
 
             with contextlib.suppress(OSError):
                 atomic_write_bytes(
-                    os.path.join(shard_dir, f"assembled_{pid}.done"), b""
+                    os.path.join(shard_dir, f"assembled_{self.pid}.done"), b""
                 )
             return out
-        if nproc > 1:
+        peers_done = True
+        if self.nproc > 1:
             # wait (bounded) for peers to finish assembling; cache-first
             # ordering below makes a timeout or stale marker harmless —
             # a peer still polling finds the cache on its next pass
             deadline = _barrier_deadline()
             peers = [
                 os.path.join(shard_dir, f"assembled_{p}.done")
-                for p in range(1, nproc)
+                for p in range(1, self.nproc)
             ]
             peers_done = all(os.path.exists(f) for f in peers)
             while not peers_done and time.monotonic() < deadline:
@@ -596,12 +684,15 @@ def sketch_genomes(
                 peers_done = all(os.path.exists(f) for f in peers)
         with counters.span("ingest/cache_save", genomes=len(names)):
             _save(wd, out)
-            wd.store_arguments("sketch", args_snapshot)
+            wd.store_arguments(
+                "sketch",
+                sketch_args_snapshot(names, self.k, self.sketch_size, self.scale, self.hash_name),
+            )
             # the assembled cache supersedes the shards — drop them rather
             # than double the on-disk footprint (~16 GB at 100k genomes)
-            if shard_dir is not None and (nproc == 1 or peers_done):
+            if peers_done:
                 shutil.rmtree(shard_dir, ignore_errors=True)
-    return out
+        return out
 
 
 def _save(wd: WorkDirectory, gs: GenomeSketches) -> None:

@@ -139,24 +139,16 @@ def scaled_max_hash(scale: int) -> int:
 _HASH_IDS = {"splitmix64": 0, "murmur3": 1}
 
 
-def sketch_fasta_native(
-    path: str, k: int, sketch_size: int, scale: int, hash_name: str = "splitmix64"
-) -> dict | None:
-    """Full per-genome ingest in one native call.
-
-    Returns {length, N50, contigs, n_kmers, valid_kmers, bottom, scaled} with uint64
-    sketch arrays (copies — safe after the native buffers are freed), or
-    None when the native library is unavailable. Raises on file errors,
-    matching the numpy path.
-    """
+def _read_fasta(path: str, k: int, sketch_size: int, scaled_max: int, hash_id: int) -> dict | None:
+    """One call of the native kernel (k == 0: stats only, nothing hashed).
+    None when the library is unavailable; raises on file errors, matching
+    the numpy path. The sketch arrays are copies — safe after the native
+    buffers are freed."""
     lib = get_library()
     if lib is None:
         return None
     out = _DrepSketch()
-    rc = lib.drep_sketch_fasta(
-        path.encode(), k, sketch_size, scaled_max_hash(scale),
-        _HASH_IDS[hash_name], ctypes.byref(out),
-    )
+    rc = lib.drep_sketch_fasta(path.encode(), k, sketch_size, scaled_max, hash_id, ctypes.byref(out))
     if rc == -1:
         if not os.path.exists(path):
             raise FileNotFoundError(f"cannot read FASTA {path!r}")
@@ -168,19 +160,39 @@ def sketch_fasta_native(
         scaled = np.ctypeslib.as_array(out.scaled, shape=(out.scaled_len,)).copy()
     finally:
         lib.drep_sketch_free(ctypes.byref(out))
-    # n_kmers == -1 marks the FracMinHash fast path: the native side never
-    # built the full distinct set, so report the standard cardinality
-    # estimate |scaled| * scale (ops/kmers.py::sketches_from_raw rule)
-    n_kmers = int(out.n_kmers) if out.n_kmers >= 0 else int(out.scaled_len) * scale
     return {
         "length": int(out.length),
         "N50": int(out.n50),
         "contigs": int(out.n_contigs),
-        "n_kmers": n_kmers,
+        "n_kmers": int(out.n_kmers),
         "valid_kmers": int(out.n_valid),  # windows hashed, duplicates counted
         "bottom": bottom.astype(np.uint64),
         "scaled": scaled.astype(np.uint64),
     }
+
+
+def sketch_fasta_native(
+    path: str, k: int, sketch_size: int, scale: int, hash_name: str = "splitmix64"
+) -> dict | None:
+    """Full per-genome ingest in one native call.
+
+    Returns {length, N50, contigs, n_kmers, valid_kmers, bottom, scaled} with uint64
+    sketch arrays, or None when the native library is unavailable.
+    """
+    res = _read_fasta(path, k, sketch_size, scaled_max_hash(scale), _HASH_IDS[hash_name])
+    # n_kmers == -1 marks the FracMinHash fast path: the native side never
+    # built the full distinct set, so report the standard cardinality
+    # estimate |scaled| * scale (ops/kmers.py::sketches_from_raw rule)
+    if res is not None and res["n_kmers"] < 0:
+        res["n_kmers"] = len(res["scaled"]) * scale
+    return res
+
+
+def fasta_stats_native(path: str) -> dict | None:
+    """The kernel's stats-only mode: {length, N50, contigs} from the same
+    parse, no k-mer hashed. None when the native library is unavailable."""
+    res = _read_fasta(path, 0, 0, 0, 0)
+    return None if res is None else {key: res[key] for key in ("length", "N50", "contigs")}
 
 
 def sparse_upgma_native(

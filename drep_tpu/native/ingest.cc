@@ -13,6 +13,11 @@
 //   - scaled sketch = all unique hashes <= scaled_max (FracMinHash)
 //   - N50 matches utils/fasta.py::n50 (descending cumsum, first >= total/2)
 //
+// k == 0 is the stats-only mode: the same parse, the same length, N50 and
+// contig count, no k-mer hashed and both sketches empty. `dereplicate` reads
+// a genome that way when its quality table already drops it
+// (drep_tpu/filter.py).
+//
 // Reads plain and gzip FASTA through zlib's gzopen (transparent for both).
 // Build: g++ -O3 -std=c++17 -shared -fPIC ingest.cc -o libdrep_native.so -lz
 // (driven by drep_tpu/native/__init__.py; ctypes bindings, no pybind11).
@@ -168,19 +173,26 @@ struct BaseCode {
 };
 static const BaseCode kBase;
 
+// Python's bytes.strip() set, so a line is cut where fasta.py cuts it
+static inline bool is_space(unsigned char c) {
+  return c == ' ' || (c >= '\t' && c <= '\r');
+}
+
 // returns 0 on success, -1 file error, -2 bad args
 // hash_id: 0 = splitmix64 over the packed value, 1 = murmur3 (Mash-compatible)
+// k == 0: stats only (length, N50, contigs), nothing hashed
 int drep_sketch_fasta(const char* path, int k, int64_t sketch_size,
                       uint64_t scaled_max, int hash_id, DrepSketch* out) {
-  if (k < 1 || k > 31 || out == nullptr || hash_id < 0 || hash_id > 1) return -2;
+  if (k < 0 || k > 31 || out == nullptr || hash_id < 0 || hash_id > 1) return -2;
   std::memset(out, 0, sizeof(*out));
+  const bool stats_only = (k == 0);
 
   gzFile f = gzopen(path, "rb");
   if (f == nullptr) return -1;
 
   const uint8_t* code = kBase.code;
-  const uint64_t mask = (k == 32) ? ~0ULL : ((1ULL << (2 * k)) - 1);
-  const int shift = 2 * (k - 1);
+  const uint64_t mask = (1ULL << (2 * k)) - 1;
+  const int shift = stats_only ? 0 : 2 * (k - 1);
 
   std::vector<uint64_t> hashes;
   std::vector<int64_t> contig_lengths;
@@ -210,8 +222,12 @@ int drep_sketch_fasta(const char* path, int k, int64_t sketch_size,
       return;
     }
     size_t lo = 0, hi = line.size();
-    while (lo < hi && (unsigned char)line[lo] <= ' ') ++lo;
-    while (hi > lo && (unsigned char)line[hi - 1] <= ' ') --hi;
+    while (lo < hi && is_space((unsigned char)line[lo])) ++lo;
+    while (hi > lo && is_space((unsigned char)line[hi - 1])) --hi;
+    if (stats_only) {
+      contig_len += (int64_t)(hi - lo);
+      return;
+    }
     for (size_t i = lo; i < hi; ++i) {
       ++contig_len;
       uint8_t b = code[(unsigned char)line[i]];

@@ -16,20 +16,35 @@ import time
 import numpy as np
 
 from drep_tpu.ops import kmers
-from drep_tpu.utils.fasta import n50, read_fasta_contigs
+from drep_tpu.utils.fasta import fasta_stats, n50, read_fasta_contigs
 
 
 def sketch_one(args) -> tuple[str, dict]:
     """(name, path, k, sketch_size, scale, hash_name) -> (name, result
     dict with length/N50/contigs/n_kmers/bottom/scaled, and what the
     record's `ingest` counter sums: the file's bytes, the valid k-mers
-    hashed and the seconds this call took, on either path)."""
+    hashed and the seconds this call took, on either path).
+
+    A job of (name, path) alone is read for its stats: the same parse, the
+    same length/N50/contigs, no k-mer hashed (`valid_kmers` and `n_kmers`
+    0) and no `bottom` or `scaled` in the result. `dereplicate` sends such
+    a job for a genome its quality table already drops (filter.py)."""
     name, path = args[:2]
     t0 = time.perf_counter()
-    res = _sketch(*args[1:])
+    res = _sketch(*args[1:]) if len(args) > 2 else _stats(path)
     res["file_bytes"] = os.path.getsize(path)
     res["seconds"] = time.perf_counter() - t0
     return name, res
+
+
+def _stats(path) -> dict:
+    from drep_tpu.native import fasta_stats_native
+
+    stats = fasta_stats_native(path)
+    if stats is None:
+        st = fasta_stats(path)
+        stats = {"length": st.length, "N50": st.N50, "contigs": st.contigs}
+    return {**stats, "n_kmers": 0, "valid_kmers": 0}
 
 
 def _sketch(path, k, sketch_size, scale, hash_name) -> dict:

@@ -246,10 +246,14 @@ class Counters:
     # writer rendered for them, and the calls that went through pandas'
     # `to_csv` instead, with why (drep_tpu/tablewriter.py)
     tables_write: dict[str, dict[str, Any]] = field(default_factory=dict)
-    # what FASTA ingest sketched (ingest.py, ISSUE 31): genomes, the files'
-    # bytes, bases, valid k-mers, the hashes of both sketches, and the
+    # what FASTA ingest sketched and kept (ingest.py, ISSUE 31): genomes, the
+    # files' bytes, bases, valid k-mers, the hashes of both sketches, and the
     # seconds the workers themselves spent in `sketch_one`, summed; the
-    # widest pool and the kernel that served ride beside the sums
+    # widest pool and the kernel that served ride beside the sums. In a
+    # `dereplicate` job also what the one pass read beside them (ISSUE 32):
+    # `stats_only_*`, the genomes the quality table dropped beforehand, read
+    # for length, N50 and contigs alone; `sketched_then_dropped*`, those a
+    # rule dropped once their length (or CheckM) was known
     ingest: dict[str, Any] = field(default_factory=dict)
     # what `stage:filter` saw and dropped, by reason (filter.py)
     filter: dict[str, int] = field(default_factory=dict)
@@ -388,22 +392,39 @@ class Counters:
             reasons = ent.setdefault("fallback_reasons", {})
             reasons[fallback] = reasons.get(fallback, 0) + 1
 
-    def add_ingest(self, results, workers: int, path: str) -> None:
-        """Book the genomes one ingest sketched: `results` are the dicts
-        `sketch_worker.sketch_one` returned, each with its own `seconds`,
-        `workers` the processes that shared them, `path` the kernel."""
-        booked = {
-            "genomes": len(results),
-            "file_bytes": sum(r["file_bytes"] for r in results),
-            "bases": sum(r["length"] for r in results),
-            "valid_kmers": sum(r["valid_kmers"] for r in results),
-            "bottom_hashes": sum(len(r["bottom"]) for r in results),
-            "scaled_hashes": sum(len(r["scaled"]) for r in results),
+    def add_ingest(self, read: dict, kept: set, workers: int, path: str, for_filter: bool) -> None:
+        """Book what one ingest pass read: `read` are the dicts
+        `sketch_worker.sketch_one` returned, by genome, each with its own
+        `seconds`; `kept` the genomes whose sketches the job keeps; `workers`
+        the processes that shared the work, `path` the kernel. `genomes` to
+        `busy_seconds` are the kept genomes' alone. The filter's pass
+        (`for_filter`) books beside them the genomes it read for their stats
+        alone and those it sketched and the rules then dropped."""
+        mine: list[dict] = []
+        alone: list[dict] = []
+        dropped: list[dict] = []
+        for name, r in read.items():
+            (alone if "bottom" not in r else mine if name in kept else dropped).append(r)
+        booked: dict[str, float] = {
+            "genomes": len(mine),
+            "file_bytes": sum(r["file_bytes"] for r in mine),
+            "bases": sum(r["length"] for r in mine),
+            "valid_kmers": sum(r["valid_kmers"] for r in mine),
+            "bottom_hashes": sum(len(r["bottom"]) for r in mine),
+            "scaled_hashes": sum(len(r["scaled"]) for r in mine),
+            "busy_seconds": float(sum(r["seconds"] for r in mine)),
         }
+        if for_filter:
+            booked.update({
+                "stats_only_genomes": len(alone),
+                "stats_only_bases": sum(r["length"] for r in alone),
+                "stats_only_seconds": float(sum(r["seconds"] for r in alone)),
+                "sketched_then_dropped": len(dropped),
+                "sketched_then_dropped_bases": sum(r["length"] for r in dropped),
+                "sketched_then_dropped_seconds": float(sum(r["seconds"] for r in dropped)),
+            })
         for name, value in booked.items():
-            self.ingest[name] = self.ingest.get(name, 0) + int(value)
-        self.ingest["busy_seconds"] = self.ingest.get("busy_seconds", 0.0) + float(
-            sum(r["seconds"] for r in results))
+            self.ingest[name] = self.ingest.get(name, 0) + value
         self.ingest["workers"] = max(int(workers), self.ingest.get("workers", 0))
         self.ingest["path"] = path
 
@@ -529,7 +550,8 @@ class Counters:
         if self.tables_write:
             out["tables_write"] = {name: dict(ent) for name, ent in sorted(self.tables_write.items())}
         if self.ingest:
-            out["ingest"] = {**self.ingest, "busy_seconds": round(self.ingest["busy_seconds"], 4)}
+            out["ingest"] = {name: round(value, 4) if isinstance(value, float) else value
+                             for name, value in self.ingest.items()}
         if self.filter:
             out["filter"] = dict(self.filter)
         phases = self._phases_report()

@@ -604,12 +604,13 @@ def dereplicate_wrapper(wd_loc: str, genomes: list[str] | None = None, **kwargs)
             from drep_tpu.bonus import validate_bonus_args
 
             validate_bonus_args(kwargs)  # fail fast, before hours of clustering
-        with counters.span("stage:filter"):
-            filtered = d_filter_wrapper(
-                wd, bdb, genomeInfo=kwargs.pop("genomeInfo", None), **kwargs
-            )
+        # `stage:filter` in two halves, round the one read of every FASTA
+        # (a `stage:ingest_or_cache` of its own): filter.py
+        filtered, sketches = d_filter_wrapper(
+            wd, bdb, genomeInfo=kwargs.pop("genomeInfo", None), **kwargs
+        )
         with counters.span("stage:cluster"):
-            d_cluster_wrapper(wd, filtered, **kwargs)
+            d_cluster_wrapper(wd, filtered, sketches=sketches, **kwargs)
         with counters.span("stage:choose"):
             wdb = d_choose_wrapper(wd, filtered, **kwargs)
         if kwargs.get("run_tax"):

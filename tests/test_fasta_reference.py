@@ -5,7 +5,9 @@ contaminated members) the program's sketches equal
 ``benchmark.reference_fasta``'s hash for hash, its tables agree with the
 reference's answers, the native and the NumPy ingest path give the same
 sketches, the new spans partition their stages and the `ingest` and `filter`
-counters say what the sketches and the tables hold."""
+counters say what the sketches and the tables hold. Every FASTA is opened once,
+by the pool's `sketch_one` (ISSUE 32): the filter reads none, a genome the
+quality table drops is read for its stats alone, a rerun reads nothing."""
 
 import json
 import os
@@ -154,7 +156,7 @@ def test_the_cells_own_comparison_passes_and_prints_every_number(job, reference,
 
 
 @pytest.mark.parametrize("stage,inside,holds_only", [
-    ("stage:filter", {"filter/fasta_stats", "filter/quality", "tables_io"}, True),
+    ("stage:filter", {"filter/quality", "tables_io"}, True),  # both halves: no FASTA is read in it
     ("stage:ingest_or_cache", {"ingest/sketch", "ingest/cache_save"}, True),
     ("ingest/sketch", {"ingest/pool_start", "ingest/shard_flush"}, False),  # its self time is the wait
     ("stage:choose", {"choose/tables", "choose/score", "choose/copy"}, True),
@@ -170,6 +172,143 @@ def test_the_new_spans_partition_their_stage(job, stage, inside, holds_only):
     assert all(ph[n]["seconds"] <= ph[stage]["seconds"] + 1e-3 for n in inside - {"tables_io"})
     mains = sum(p["self_seconds"] for p in ph.values() if p["thread"] == "main")
     assert mains == pytest.approx(ph["job"]["seconds"], rel=0.01)
+
+
+@pytest.fixture(scope="module")
+def serial_job(planted, tmp_path_factory):
+    """The same job with `-p 1`, so that every `sketch_one` call is in this
+    process and can be counted; then the same command again on its work
+    directory. Every open of a planted file from Python is recorded too, by
+    the module that opened it."""
+    import builtins
+    import sys
+
+    from drep_tpu import ingest
+    from drep_tpu.utils import telemetry
+
+    mix = cells.read_json(os.path.join(BENCH, "traffic", "dereplicate.json"))
+    wd = str(tmp_path_factory.mktemp("fasta_serial") / "wd")
+    argv = fasta_jobs.job_argv(mix["argv"] + ["-p", "1"], wd, planted)
+    calls: list[tuple] = []
+    opened: list[str] = []
+    real_sketch, real_open = ingest._sketch_one, builtins.open
+
+    def counted(job):
+        calls.append(job)
+        return real_sketch(job)
+
+    def recording(file, *a, **k):
+        if file in planted.paths:
+            opened.append(sys._getframe(1).f_globals["__name__"])
+        return real_open(file, *a, **k)
+
+    from drep_tpu import controller
+
+    runs = []
+    mp = pytest.MonkeyPatch()
+    mp.setattr(ingest, "_sketch_one", counted)
+    mp.setattr(builtins, "open", recording)
+    try:
+        for _ in range(2):
+            calls.clear()
+            controller.main(argv)
+            with real_open(os.path.join(wd, "log", "perf_counters.json")) as f:
+                runs.append({"calls": list(calls), "record": json.load(f)})
+    finally:
+        mp.undo()
+        telemetry.configure()
+    return {"workdir": wd, "first": runs[0], "again": runs[1], "opened": opened}
+
+
+def test_every_fasta_is_opened_once_by_the_pool_and_by_nothing_else(serial_job, job, planted, reference, cfg):
+    p = cfg["params"]
+    first = serial_job["first"]
+    # one sketch_one call a path of the input Bdb; beside the kernel's, no open of a FASTA from Python
+    # but the copies of the winners
+    assert sorted(c[1] for c in first["calls"]) == sorted(planted.paths)
+    assert set(serial_job["opened"]) == {"shutil"}
+    by_table = {g for g, c, x in zip(planted.names, planted.completeness, planted.contamination)
+                if c < p["completeness"] or x > p["contamination"]}
+    assert {c[0] for c in first["calls"] if len(c) == 2} == by_table and by_table
+    ph, ingest, booked = first["record"]["phases"], first["record"]["ingest"], first["record"]["filter"]
+    assert "filter/fasta_stats" not in ph and ph["stage:filter"]["calls"] == 2
+    assert ph["ingest/sketch"]["calls"] == 1 and ph["stage:ingest_or_cache"]["calls"] == 2
+    kept = reference["want"]["kept"]
+    short = [g for g in planted.names if reference["sketches"][g]["length"] < p["length"] and g not in by_table]
+    assert ingest["stats_only_genomes"] == len(by_table) and ingest["sketched_then_dropped"] == len(short) >= 1
+    assert ingest["stats_only_genomes"] + ingest["sketched_then_dropped"] + ingest["genomes"] == booked["genomes"] == 16
+    assert ingest["stats_only_bases"] == sum(reference["sketches"][g]["length"] for g in by_table)
+    assert ingest["sketched_then_dropped_bases"] == sum(reference["sketches"][g]["length"] for g in short)
+    assert ingest["stats_only_seconds"] > 0 and ingest["sketched_then_dropped_seconds"] > 0
+    assert ingest["genomes"] == len(kept) and ingest["bases"] == sum(reference["sketches"][g]["length"] for g in kept)
+    # the cache holds the kept genomes and no other, in either job; the pooled job books the same counts
+    bdb = pd.read_csv(os.path.join(serial_job["workdir"], "data_tables", "Bdb.csv"))
+    assert sorted(fasta_jobs.read_sketches(serial_job["workdir"])) == sorted(bdb["genome"]) == sorted(kept)
+    pooled = job["record"]["ingest"]
+    assert {k: v for k, v in pooled.items() if not k.endswith("seconds") and k != "workers"} \
+        == {k: v for k, v in ingest.items() if not k.endswith("seconds") and k != "workers"}
+    for table in ("genomeInformation", "Bdb", "Gdb", "genomeInfo", "Cdb", "Sdb", "Wdb"):
+        with open(os.path.join(serial_job["workdir"], "data_tables", table + ".csv"), "rb") as a, \
+                open(os.path.join(job["workdir"], "data_tables", table + ".csv"), "rb") as b:
+            assert a.read() == b.read(), table
+
+
+def test_a_second_dereplicate_on_the_work_directory_reads_no_fasta(serial_job):
+    again = serial_job["again"]
+    assert again["calls"] == [] and set(serial_job["opened"]) == {"shutil"}
+    assert not {"ingest/sketch", "ingest/cache_save", "filter/fasta_stats"} & set(again["record"]["phases"])
+    assert "ingest" not in again["record"] and again["record"]["filter"] == serial_job["first"]["record"]["filter"]
+
+
+def test_a_candidate_with_no_sequence_is_dropped_by_length_and_raises_nothing(planted, serial_job, tmp_path):
+    """As before this PR: length 0, dropped by -l, never the "no valid k-mers"
+    error, which is for genomes the filter keeps."""
+    from drep_tpu.errors import UserInputError
+    from drep_tpu.utils import telemetry
+    from drep_tpu.workflows import dereplicate_wrapper
+
+    hollow = tmp_path / "hollow.fa"
+    hollow.write_text(">only a header\n")
+    quality = pd.read_csv(planted.genome_info)
+    quality.loc[len(quality)] = {"genome": "hollow.fa", "completeness": 99.0, "contamination": 0.0}
+    wd = str(tmp_path / "wd")
+    dereplicate_wrapper(wd, planted.paths + [str(hollow)], genomeInfo=quality, skip_plots=True, processes=1)
+    info = pd.read_csv(os.path.join(wd, "data_tables", "genomeInformation.csv")).set_index("genome")
+    assert tuple(info.loc["hollow.fa"]) == (0, 0, 0) and len(info) == 17
+    assert "hollow.fa" not in fasta_jobs.read_sketches(wd)
+    with open(os.path.join(wd, "log", "perf_counters.json")) as f:
+        ingest = json.load(f)["ingest"]
+    without = serial_job["first"]["record"]["ingest"]
+    assert ingest["sketched_then_dropped"] == without["sketched_then_dropped"] + 1
+    assert ingest["stats_only_genomes"] == without["stats_only_genomes"] and ingest["genomes"] == without["genomes"]
+    # kept, it is the error it always was
+    with pytest.raises(UserInputError, match="no FASTA records with valid nucleotide"):
+        dereplicate_wrapper(str(tmp_path / "wd2"), planted.paths + [str(hollow)], genomeInfo=quality,
+                            skip_plots=True, processes=1, length=0)
+    telemetry.configure()
+
+
+def test_without_a_quality_table_every_genome_is_sketched(planted, reference, cfg, tmp_path):
+    from drep_tpu.utils import telemetry
+    from drep_tpu.workflows import dereplicate_wrapper
+
+    wd = str(tmp_path / "wd")
+    dereplicate_wrapper(wd, planted.paths, ignoreGenomeQuality=True, skip_plots=True, processes=2)
+    telemetry.configure()
+    with open(os.path.join(wd, "log", "perf_counters.json")) as f:
+        rec = json.load(f)
+    long_enough = [g for g in planted.names if reference["sketches"][g]["length"] >= cfg["params"]["length"]]
+    assert rec["ingest"]["stats_only_genomes"] == 0 and rec["ingest"]["genomes"] == len(long_enough)
+    assert rec["ingest"]["sketched_then_dropped"] == 16 - len(long_enough) == rec["filter"]["dropped_length"]
+    tables = os.path.join(wd, "data_tables")
+    info = pd.read_csv(os.path.join(tables, "genomeInformation.csv"))
+    assert {g: (a, b, c) for g, a, b, c in zip(info["genome"], info["length"], info["N50"], info["contigs"])} \
+        == {g: (s["length"], s["N50"], s["contigs"]) for g, s in reference["sketches"].items()}
+    assert list(pd.read_csv(os.path.join(tables, "Bdb.csv"))["genome"]) == long_enough
+    assert sorted(fasta_jobs.read_sketches(wd)) == sorted(long_enough)
+    assert not os.path.exists(os.path.join(tables, "genomeInfo.csv"))
+    cache = fasta_jobs.read_sketches(wd)
+    assert all(np.array_equal(cache[g][1], reference["sketches"][g]["scaled"]) for g in long_enough)
 
 
 def test_the_ingest_counter_says_what_the_sketches_hold(job, reference, planted):
@@ -223,6 +362,8 @@ def test_a_compare_job_books_neither_counter_and_none_of_the_new_stage_spans(tmp
     with open(os.path.join(wd, "log", "perf_counters.json")) as f:
         first = json.load(f)
     assert first["ingest"]["genomes"] == 5 and "filter" not in first
+    # the single read's engagement keys are `dereplicate`'s alone
+    assert not [k for k in first["ingest"] if k.startswith(("stats_only", "sketched_then_dropped"))]
     os.remove(os.path.join(wd, "data_tables", "Cdb.csv"))  # recompute from the sketch cache
     compare_wrapper(wd, skip_plots=True)
     telemetry.configure()

@@ -125,6 +125,59 @@ def test_native_stats_match_fasta_stats(genome_paths):
         )
 
 
+_SEQ = "ACGTTGCAAGCTTAGCCGATATCGGCTAAGCTTGCAACGTACGGATCCGTA"  # 50 bases
+
+# what the filter's numbers have to survive, now that they are the kernel's:
+# name -> the file's text (gzip and the empty file are made from it below)
+_STATS_CASES = {
+    "n_runs": f">a\n{_SEQ}{'N' * 40}{_SEQ}\n>b\n{'N' * 30}\n{_SEQ}\n",
+    "iupac": f">a\n{_SEQ}RYKMSWBDHVN{_SEQ}\n>b\n{_SEQ}\n",
+    "soft_masked": f">a\n{_SEQ.lower()}\n{_SEQ}\n>b\n{_SEQ.lower()}{_SEQ}\n",
+    "crlf": f">a\r\n{_SEQ}\r\n{_SEQ}\r\n>b\r\n{_SEQ}\r\n",
+    "blank_lines": f">a\n{_SEQ}\n\n   \n\t\n{_SEQ}\n\n>b\n \n{_SEQ}\n \n",
+    "header_without_sequence": f">a\n>b\n{_SEQ}\n>c\n\n>d\n{_SEQ}{_SEQ}\n>e\n",
+    "no_trailing_newline": f">a\n{_SEQ}\n>b\n{_SEQ}{_SEQ}",
+    "empty_file": "",
+    "gzip": f">a\n{_SEQ}\n{_SEQ}\n>b\n{_SEQ}\n",
+    "shorter_than_k": ">a\nACGTACGT\n>b\nACG\n",
+}
+
+
+@needs_native
+@pytest.mark.parametrize("mode", ["sketch", "stats_only"])
+@pytest.mark.parametrize("path_kind", ["native", "numpy"])
+@pytest.mark.parametrize("case", sorted(_STATS_CASES))
+def test_stats_of_either_kernel_in_either_mode_match_fasta_stats(
+    tmp_path, monkeypatch, case, path_kind, mode
+):
+    """`sketch_one`'s length, N50 and contigs — the native kernel's and the
+    NumPy fall-back's, sketching and read for the stats alone — are
+    `fasta_stats`'s; the stats-only result hashed nothing and carries no sketch."""
+    from drep_tpu.sketch_worker import sketch_one
+
+    path = tmp_path / (case + ".fa")
+    data = _STATS_CASES[case].encode()
+    path.write_bytes(gzip.compress(data) if case == "gzip" else data)
+    want = fasta_stats(str(path))
+    if case != "empty_file":
+        assert want.contigs >= 2 and want.length > 0
+    if path_kind == "numpy":
+        monkeypatch.setenv("DREP_TPU_NO_NATIVE", "1")
+    job = ("g", str(path)) + ((K, SKETCH, SCALE, "splitmix64") if mode == "sketch" else ())
+    name, got = sketch_one(job)
+    assert name == "g"
+    assert (got["length"], got["N50"], got["contigs"]) == (want.length, want.N50, want.contigs)
+    assert got["file_bytes"] == os.path.getsize(path) and got["seconds"] > 0
+    if mode == "stats_only":
+        assert "bottom" not in got and "scaled" not in got
+        assert got["valid_kmers"] == 0 and got["n_kmers"] == 0
+    else:
+        oracle = _oracle(str(path))
+        np.testing.assert_array_equal(got["bottom"], oracle["bottom"])
+        np.testing.assert_array_equal(got["scaled"], oracle["scaled"])
+        assert (got["valid_kmers"] == 0) == (case in ("empty_file", "shorter_than_k"))
+
+
 @needs_native
 def test_env_kill_switch(monkeypatch, genome_paths):
     monkeypatch.setenv("DREP_TPU_NO_NATIVE", "1")

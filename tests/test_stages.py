@@ -40,7 +40,10 @@ def test_filter_quality_drops_low_completeness(tmp_path, bdb):
     wd = WorkDirectory(str(tmp_path / "wd"))
     quality = _quality_df(list(bdb["genome"]))
     quality.loc[quality["genome"] == "genome_C.fasta", "completeness"] = 10.0
-    filtered = d_filter_wrapper(wd, bdb, genomeInfo=quality)
+    filtered, sketches = d_filter_wrapper(wd, bdb, genomeInfo=quality)
+    # the genome the table drops was read for its stats alone, every other sketched
+    assert "bottom" not in sketches.results["genome_C.fasta"]
+    assert all("bottom" in r for g, r in sketches.results.items() if g != "genome_C.fasta")
     assert "genome_C.fasta" not in set(filtered["genome"])
     assert len(filtered) == len(bdb) - 1
 
