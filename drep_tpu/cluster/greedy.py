@@ -36,27 +36,8 @@ from drep_tpu.ops.containment import (
     self_from_chunks,
 )
 from drep_tpu.ops.minhash import PAD_ID
-
-# per-process wall-clock attribution for the greedy engine (seconds per
-# phase + device call count) — bench_greedy diffs it around a run so a
-# weak genomes/s number is diagnosable from the record
-GREEDY_TIMINGS: dict[str, float] = {}
-
-
-def _timed(key: str):
-    import time as _t
-
-    class _Ctx:
-        def __enter__(self):
-            self.t0 = _t.perf_counter()
-
-        def __exit__(self, *exc):
-            GREEDY_TIMINGS[key] = GREEDY_TIMINGS.get(key, 0.0) + (
-                _t.perf_counter() - self.t0
-            )
-
-    return _Ctx()
-
+from drep_tpu.ops.rangepart import vocab_extent
+from drep_tpu.utils.profiling import counters
 
 def _cov_from_inter(inter: np.ndarray, denom: np.ndarray) -> np.ndarray:
     """cov = inter / denom with zero-count rows/cols pinned to 0 (matches
@@ -105,7 +86,17 @@ def greedy_assign_from_matrices(
     block padding to 128 rows for a 3-genome cluster) was measured
     pathologically slower than the batch route — the exact fan-out cost
     the batched path exists to avoid (cluster/controller.py
-    SMALL_CLUSTER_MAX rationale)."""
+    SMALL_CLUSTER_MAX rationale).
+
+    Books the span `secondary/greedy_assign` (the engine's name for the
+    same work) and the cluster into the record's `secondary_greedy_batched`."""
+    with counters.span("secondary/greedy_assign"):
+        ndb, labels = _assign_from_matrices(gs, indices, pc, kw, ani, cov)
+    counters.add_greedy_batched(rows=len(indices), compared_pairs=len(ndb))
+    return ndb, labels
+
+
+def _assign_from_matrices(gs, indices, pc, kw, ani, cov) -> tuple[pd.DataFrame, np.ndarray]:
     s_ani, cov_thresh = kw["S_ani"], kw["cov_thresh"]
     m = len(indices)
     n_kmers = [int(gs.gdb["n_kmers"].iloc[i]) for i in indices]
@@ -151,12 +142,24 @@ def greedy_secondary_cluster(
 
     Genomes are visited largest-first (most k-mers), the reference's
     heuristic that big complete genomes make good representatives.
+
+    Books the route that served in `secondary_paths` (`greedy_matmul` on a
+    TPU or under DREP_TPU_GREEDY_MATMUL, `greedy_gather` off it), one
+    entry of the record's `secondary_greedy_calls`, and the spans
+    `secondary/pack`, `secondary/greedy_layout` (the chunk geometry, every
+    block's and every new representative's repack and pad, the
+    representatives' shipment), `secondary/greedy_wait` (a block's
+    transfer, its tiles against the representatives, its self comparison,
+    the readbacks) and `secondary/greedy_assign`.
     """
     s_ani, cov_thresh = kw["S_ani"], kw["cov_thresh"]
     m = len(indices)
     order = sorted(range(m), key=lambda t: -int(gs.gdb["n_kmers"].iloc[indices[t]]))
 
-    packed = pack_scaled_sketches([gs.scaled[indices[t]] for t in order], [gs.names[indices[t]] for t in order])
+    with counters.span("secondary/pack"):
+        packed = pack_scaled_sketches(
+            [gs.scaled[indices[t]] for t in order], [gs.names[indices[t]] for t in order]
+        )
     ids, counts = packed.ids, packed.counts
     import jax
 
@@ -169,6 +172,7 @@ def greedy_secondary_cluster(
         jax.devices()[0].platform == "tpu"
         or envknobs.env_bool("DREP_TPU_GREEDY_MATMUL")
     )
+    counters.add_path("greedy_matmul" if use_matmul else "greedy_gather")
     mesh = None
     base_block = block
     if use_matmul:
@@ -192,6 +196,11 @@ def greedy_secondary_cluster(
     reps: list[int] = []  # positions (in `order` space) of representatives
     ndb_rows: list[dict] = []
     name_arr = np.array(packed.names)  # invariant across blocks
+    # what the cluster's counter entry sums over its blocks
+    # (Counters.add_greedy_call)
+    booked = dict.fromkeys(
+        ("blocks", "rep_rows_shipped", "rep_rows_real", "id_slots", "device_calls"), 0
+    )
 
     if use_matmul:
         import jax.numpy as jnp
@@ -206,26 +215,36 @@ def greedy_secondary_cluster(
         # The tile rides the UNSCALED block: under a mesh the candidate
         # block grows by D but the replicated rep side should not.
         rep_tile = 4 * base_block
-        geom = VocabChunkGeometry(ids, max_rows_per_call=max(rep_tile, block))
-        if mesh is None:
-            rep_chunks_dev = [
-                jnp.asarray(np.full((0, w), PAD_ID, np.int32)) for w in geom.widths
-            ]
-        else:
-            # mesh mode: reps stay HOST-side (appending to a replicated
-            # device array is not incremental); FILLED rep tiles are
-            # replicated once and cached — only the trailing partial tile
-            # re-crosses the link per block
-            from drep_tpu.ops.containment import replicate_on_mesh
+        with counters.span("secondary/greedy_layout"):
+            geom = VocabChunkGeometry(ids, max_rows_per_call=max(rep_tile, block))
+            if mesh is None:
+                rep_chunks_dev = [
+                    jnp.asarray(np.full((0, w), PAD_ID, np.int32)) for w in geom.widths
+                ]
+            else:
+                # mesh mode: reps stay HOST-side (appending to a replicated
+                # device array is not incremental); FILLED rep tiles are
+                # replicated once and cached — only the trailing partial tile
+                # re-crosses the link per block
+                from drep_tpu.ops.containment import replicate_on_mesh
 
-            rep_chunks_host = [np.full((0, w), PAD_ID, np.int32) for w in geom.widths]
-            rep_tiles_cached: list[list] = []  # per filled tile: replicated chunks
+                rep_chunks_host = [np.full((0, w), PAD_ID, np.int32) for w in geom.widths]
+                rep_tiles_cached: list[list] = []  # per filled tile: replicated chunks
         n_shipped = 0  # reps already resident on device / in the host store
+        shape = {"rep_tile": rep_tile, "v_chunk": geom.v_chunk, "chunks": geom.n_chunks,
+                 "widths": int(sum(geom.widths))}
+    else:
+        rep_tile = block
+        shape = {"rep_tile": rep_tile, "v_chunk": 0, "chunks": 0, "widths": int(ids.shape[1])}
 
     for b0 in range(0, m, block):
         rows = list(range(b0, min(b0 + block, m)))
         nb = len(rows)
         b_ids, b_counts = _pad_pack(ids, counts, rows, block)
+        rep_pad = max(-(-len(reps) // rep_tile) * rep_tile, rep_tile)
+        booked["blocks"] += 1
+        booked["rep_rows_shipped"] += rep_pad
+        booked["rep_rows_real"] += len(reps)
 
         # block vs existing reps (padded to a block multiple for shape reuse);
         # both coverage directions — the gate, like the default all-pairs
@@ -236,9 +255,8 @@ def greedy_secondary_cluster(
         # from the rectangular chunked MXU matmul (gather tiles serialize
         # on the scalar unit there); off-TPU the gather tiles are fine.
         if use_matmul:
-            rep_pad = max(-(-len(reps) // rep_tile) * rep_tile, rep_tile)
-            if n_shipped < len(reps):
-                with _timed("ship_reps_s"):
+            with counters.span("secondary/greedy_layout"):
+                if n_shipped < len(reps):
                     new_chunks = geom.rows_chunks(np.array(reps[n_shipped:]))
                     if mesh is None:
                         rep_chunks_dev = [
@@ -260,18 +278,23 @@ def greedy_secondary_cluster(
                                 )
                                 for rc in rep_chunks_host
                             ])
+                    booked["id_slots"] += (len(reps) - n_shipped) * shape["widths"]
                     n_shipped = len(reps)
-            r_counts = np.zeros(rep_pad, np.int32)
-            r_counts[: len(reps)] = counts[reps]
-            # the block's chunk tensors go to device ONCE and serve both
-            # the vs-reps tiles and the self comparison
-            with _timed("host_repack_s"):
+                r_counts = np.zeros(rep_pad, np.int32)
+                r_counts[: len(reps)] = counts[reps]
+                # the block's chunk tensors go to device ONCE and serve both
+                # the vs-reps tiles and the self comparison
                 blk_chunks = [
                     np.pad(bc, ((0, block - nb), (0, 0)), constant_values=PAD_ID)
                     for bc in geom.rows_chunks(np.array(rows))
                 ]
-            with _timed("device_compare_s"):
-                GREEDY_TIMINGS["device_calls"] = GREEDY_TIMINGS.get("device_calls", 0) + 1
+                booked["id_slots"] += block * shape["widths"]
+            with counters.span(
+                "secondary/greedy_wait", rows=nb, reps=len(reps), rep_pad=rep_pad,
+                chunks=geom.n_chunks,
+            ):
+                # one program call a chunk: each rep tile, then the self comparison
+                booked["device_calls"] += (rep_pad // rep_tile + 1) * geom.n_chunks
                 if mesh is None:
                     blk_dev = [jnp.asarray(bc) for bc in blk_chunks]
                 inter = np.empty((block, rep_pad), np.float32)
@@ -317,58 +340,68 @@ def greedy_secondary_cluster(
                     inter_self = self_from_chunks(blk_dev, geom.v_chunk).astype(np.float32)
                 c_blk = _cov_from_inter(inter_self, b_counts[:, None])
         else:
-            rep_pad = max(-(-len(reps) // block) * block, block)
-            r_ids, r_counts = _pad_pack(ids, counts, reps, rep_pad)
-            cov_vs_reps = np.zeros((block, rep_pad), np.float32)
-            cov_rev_reps = np.zeros((block, rep_pad), np.float32)
-            for r0 in range(0, rep_pad, block):
-                c = containment_cov_tile(
-                    b_ids, b_counts, r_ids[r0 : r0 + block], k=gs.k
-                )
-                c_rev = containment_cov_tile(
-                    r_ids[r0 : r0 + block], r_counts[r0 : r0 + block], b_ids, k=gs.k
-                )
-                cov_vs_reps[:, r0 : r0 + block] = np.asarray(c)
-                cov_rev_reps[:, r0 : r0 + block] = np.asarray(c_rev).T
+            with counters.span("secondary/greedy_layout"):
+                r_ids, r_counts = _pad_pack(ids, counts, reps, rep_pad)
+                booked["id_slots"] += (block + rep_pad) * shape["widths"]
+            with counters.span(
+                "secondary/greedy_wait", rows=nb, reps=len(reps), rep_pad=rep_pad, chunks=0,
+            ):
+                # two gather tiles a representative tile, one for the block itself
+                booked["device_calls"] += 2 * (rep_pad // rep_tile) + 1
+                cov_vs_reps = np.zeros((block, rep_pad), np.float32)
+                cov_rev_reps = np.zeros((block, rep_pad), np.float32)
+                for r0 in range(0, rep_pad, block):
+                    c = containment_cov_tile(
+                        b_ids, b_counts, r_ids[r0 : r0 + block], k=gs.k
+                    )
+                    c_rev = containment_cov_tile(
+                        r_ids[r0 : r0 + block], r_counts[r0 : r0 + block], b_ids, k=gs.k
+                    )
+                    cov_vs_reps[:, r0 : r0 + block] = np.asarray(c)
+                    cov_rev_reps[:, r0 : r0 + block] = np.asarray(c_rev).T
 
-            # block vs itself (for genomes that become reps mid-block)
-            c_blk = np.asarray(containment_cov_tile(b_ids, b_counts, b_ids, k=gs.k))
+                # block vs itself (for genomes that become reps mid-block)
+                c_blk = np.asarray(containment_cov_tile(b_ids, b_counts, b_ids, k=gs.k))
 
         # assignment: sequential over genomes (a genome can become a rep
         # mid-block) but VECTORIZED over reps — the O(reps) inner work is
         # numpy row math, never a Python pair loop (100k-scale requirement)
-        assign_ctx = _timed("assign_s")
-        assign_ctx.__enter__()
-        n_pre = len(reps)  # reps existing before this block (all < b0)
-        in_block: list[int] = []  # block-local positions of mid-block reps
-        for t, pos in enumerate(rows):
-            cov_row = np.concatenate([cov_vs_reps[t, :n_pre], c_blk[t, in_block]])
-            cov_rev = np.concatenate([cov_rev_reps[t, :n_pre], c_blk[in_block, t]])
-            ani_row = containment_to_ani(np.maximum(cov_row, cov_rev), gs.k)
-            if len(ani_row):
-                rep_pos_arr = np.array(reps, dtype=np.int64)
-                ndb_rows.append(
-                    {
-                        "reference": name_arr[rep_pos_arr],
-                        "querry": np.repeat(name_arr[pos], len(ani_row)),
-                        "ani": ani_row.astype(np.float64),
-                        "alignment_coverage": cov_row.astype(np.float64),
-                        "ref_coverage": cov_rev.astype(np.float64),
-                        "querry_coverage": cov_row.astype(np.float64),
-                    }
-                )
-                ok = (ani_row >= s_ani) & (cov_row >= cov_thresh) & (cov_rev >= cov_thresh)
-                if ok.any():
-                    masked = np.where(ok, ani_row, -1.0)
-                    labels_ordered[pos] = int(np.argmax(masked)) + 1
-                    continue
-            reps.append(pos)
-            in_block.append(pos - b0)
-            labels_ordered[pos] = len(reps)
-        assign_ctx.__exit__()
+        with counters.span("secondary/greedy_assign"):
+            n_pre = len(reps)  # reps existing before this block (all < b0)
+            in_block: list[int] = []  # block-local positions of mid-block reps
+            for t, pos in enumerate(rows):
+                cov_row = np.concatenate([cov_vs_reps[t, :n_pre], c_blk[t, in_block]])
+                cov_rev = np.concatenate([cov_rev_reps[t, :n_pre], c_blk[in_block, t]])
+                ani_row = containment_to_ani(np.maximum(cov_row, cov_rev), gs.k)
+                if len(ani_row):
+                    rep_pos_arr = np.array(reps, dtype=np.int64)
+                    ndb_rows.append(
+                        {
+                            "reference": name_arr[rep_pos_arr],
+                            "querry": np.repeat(name_arr[pos], len(ani_row)),
+                            "ani": ani_row.astype(np.float64),
+                            "alignment_coverage": cov_row.astype(np.float64),
+                            "ref_coverage": cov_rev.astype(np.float64),
+                            "querry_coverage": cov_row.astype(np.float64),
+                        }
+                    )
+                    ok = (ani_row >= s_ani) & (cov_row >= cov_thresh) & (cov_rev >= cov_thresh)
+                    if ok.any():
+                        masked = np.where(ok, ani_row, -1.0)
+                        labels_ordered[pos] = int(np.argmax(masked)) + 1
+                        continue
+                reps.append(pos)
+                in_block.append(pos - b0)
+                labels_ordered[pos] = len(reps)
 
-    # back to the original `indices` order
-    labels = np.zeros(m, dtype=np.int64)
-    for t in range(m):
-        labels[order[t]] = labels_ordered[t]
-    return _ndb_from_rows(ndb_rows, pc), labels
+    with counters.span("secondary/greedy_assign"):
+        # back to the original `indices` order
+        labels = np.zeros(m, dtype=np.int64)
+        for t in range(m):
+            labels[order[t]] = labels_ordered[t]
+        ndb = _ndb_from_rows(ndb_rows, pc)
+    counters.add_greedy_call(
+        rows=m, block_rows=block, reps=len(reps), extent=vocab_extent(ids),
+        hashes=int(counts.sum()), compared_pairs=len(ndb), **shape, **booked,
+    )
+    return ndb, labels

@@ -35,7 +35,8 @@ from typing import Any, Iterator
 from drep_tpu.utils import telemetry
 
 # distinct program shapes the record lists, for the one-shot calls and for
-# the chunked calls each (Counters.add_secondary_call, .add_chunked_call)
+# the chunked calls each (Counters.add_secondary_call, .add_chunked_call),
+# and the clusters it lists of the greedy engine's (.add_greedy_call)
 SECONDARY_SHAPES_MAX = 64
 
 
@@ -223,8 +224,9 @@ class Counters:
     hists: dict[str, Histogram] = field(default_factory=dict)
     # which kernel path served each secondary compare call (one_shot,
     # one_shot_clusterlocal, mesh_ring, matmul_chunked, cpu_tiles —
-    # cluster/engines.py): a run's record must say which
-    # regime it exercised, not leave it to be inferred from shapes
+    # cluster/engines.py; greedy_matmul, greedy_gather — one a cluster
+    # the greedy engine served, cluster/greedy.py): a run's record must
+    # say which regime it exercised, not leave it to be inferred from shapes
     paths: dict[str, int] = field(default_factory=dict)
     # where the host's time went (ISSUE 24): every span of the front door
     # (:meth:`span`), keyed (name, on the main thread?)
@@ -237,6 +239,15 @@ class Counters:
     # what each [chunks, rows_pad, width] stacked id tensor shipped, how much
     # of it was padding, and the chunk program it ran `chunks` times
     chunked_calls: dict[tuple[int, int, int, int, str], dict[str, int]] = field(default_factory=dict)
+    # the clusters the greedy engine served (cluster/greedy.py), one entry
+    # each in the order they were met: rows, blocks, the representatives it
+    # ended with and the representative rows its blocks were computed
+    # against, the chunk plan, what was shipped, and the pairs the greedy
+    # scan consumed beside the cluster's all-pairs
+    greedy_calls: list[dict[str, int]] = field(default_factory=list)
+    # the clusters `greedy_assign_from_matrices` served from the batched
+    # one-shot call's matrices: clusters, rows, pairs consumed, all-pairs
+    greedy_batched: dict[str, int] = field(default_factory=dict)
     # what the primary's packs ranked (cluster/engines.py::pack_primary):
     # calls, genomes, hashes and the distinct ids they became, summed. The
     # pack's seconds follow the hashes it sorts (ISSUE 28)
@@ -367,6 +378,50 @@ class Counters:
         ent = self.chunked_calls.setdefault(key, dict.fromkeys(booked, 0))
         for name, value in booked.items():
             ent[name] += int(value)
+
+    def add_greedy_call(
+        self, rows: int, blocks: int, block_rows: int, reps: int, rep_tile: int,
+        rep_rows_shipped: int, rep_rows_real: int, v_chunk: int, chunks: int, extent: int,
+        widths: int, hashes: int, id_slots: int, device_calls: int, compared_pairs: int,
+    ) -> None:
+        """Book one primary cluster the greedy engine served: `rows` genomes
+        holding `hashes` real ids over a vocabulary of `extent` went through
+        `blocks` blocks of `block_rows` rows, each against the
+        representatives that existed then (`rep_rows_real`, summed over the
+        blocks) padded to whole tiles of `rep_tile` rows (`rep_rows_shipped`:
+        the padded rows, summed likewise) and against itself, over `chunks`
+        vocabulary chunks of `v_chunk` ids whose id widths add up to
+        `widths`; `id_slots` int32 id slots crossed to the device
+        (`bytes_shipped`) for `device_calls` program calls, and the cluster
+        ended with `reps` representatives after `compared_pairs`
+        genome-against-representative comparisons (its Ndb rows) of the
+        `all_pairs` an all-pairs secondary makes. Off the matmul route
+        `v_chunk` and `chunks` are 0. One entry a cluster (`clusters` 1), in
+        the order met; past SECONDARY_SHAPES_MAX entries the rest is summed
+        into the last one, whose `clusters` says how many it holds."""
+        booked = {name: int(value) for name, value in {
+            "clusters": 1, "rows": rows, "blocks": blocks, "block_rows": block_rows,
+            "reps": reps, "rep_tile": rep_tile, "rep_rows_shipped": rep_rows_shipped,
+            "rep_rows_real": rep_rows_real, "v_chunk": v_chunk, "chunks": chunks,
+            "extent": extent, "widths": widths, "hashes": hashes, "id_slots": id_slots,
+            "device_calls": device_calls, "compared_pairs": compared_pairs,
+            "all_pairs": rows * (rows - 1) // 2, "bytes_shipped": 4 * id_slots}.items()}
+        if len(self.greedy_calls) < SECONDARY_SHAPES_MAX:
+            self.greedy_calls.append(booked)
+            return
+        rest = self.greedy_calls[-1]
+        for name, value in booked.items():
+            rest[name] += value
+
+    def add_greedy_batched(self, rows: int, compared_pairs: int) -> None:
+        """Book one primary cluster of `rows` genomes that the greedy rule
+        served from the batched one-shot call's matrices
+        (`greedy_assign_from_matrices`): it consumed `compared_pairs` of
+        the cluster's `all_pairs`."""
+        booked = {"clusters": 1, "rows": rows, "compared_pairs": compared_pairs,
+                  "all_pairs": rows * (rows - 1) // 2}
+        for name, value in booked.items():
+            self.greedy_batched[name] = self.greedy_batched.get(name, 0) + int(value)
 
     def add_primary_pack(self, genomes: int, hashes: int, distinct_ids: int) -> None:
         """Book one `pack_sketches` of the primary compare: `hashes` bottom-k
@@ -545,6 +600,10 @@ class Counters:
                  "id_dtype": k[4], **v}
                 for k, v in sorted(self.chunked_calls.items())
             ]
+        if self.greedy_calls:
+            out["secondary_greedy_calls"] = [dict(ent) for ent in self.greedy_calls]
+        if self.greedy_batched:
+            out["secondary_greedy_batched"] = dict(self.greedy_batched)
         if self.primary_pack:
             out["primary_pack"] = dict(self.primary_pack)
         if self.tables_write:
@@ -610,6 +669,8 @@ class Counters:
         self.paths.clear()
         self.secondary_calls.clear()
         self.chunked_calls.clear()
+        self.greedy_calls.clear()
+        self.greedy_batched.clear()
         self.primary_pack.clear()
         self.tables_write.clear()
         self.ingest.clear()

@@ -86,9 +86,14 @@ def test_greedy_mesh_sharded_equals_single_device(synthetic, monkeypatch):
     for col in ("ani", "alignment_coverage", "ref_coverage", "querry_coverage"):
         np.testing.assert_allclose(got_ndb[col], want_ndb[col], atol=1e-6, err_msg=col)
 
-    from drep_tpu.cluster.greedy import GREEDY_TIMINGS
+    # attribution recorded: the span of the device work, the route, the cluster's entry
+    from drep_tpu.utils.profiling import counters
 
-    assert GREEDY_TIMINGS.get("device_compare_s", 0) > 0  # attribution recorded
+    rec = counters.report(device=False)
+    assert rec["phases"]["secondary/greedy_wait"]["seconds"] > 0
+    assert rec["secondary_paths"]["greedy_matmul"] >= 1
+    assert rec["secondary_greedy_calls"][-1]["block_rows"] == 128 * 8  # the mesh's block
+    assert rec["secondary_greedy_calls"][-1]["device_calls"] > 0
 
 
 def test_greedy_matmul_single_device_equals_gather(synthetic, monkeypatch):

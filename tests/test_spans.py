@@ -466,3 +466,97 @@ def test_streaming_stripes_hold_their_phases(tmp_path):
                                             "primary/put"))
     assert ph["stripe"]["self_seconds"] <= ph["stripe"]["seconds"] - inside + 0.05
     assert _main_self_sum(ph) == pytest.approx(ph["job"]["seconds"], rel=0.01)
+
+
+# --- a toy job under greedy secondary clustering (ISSUE 34) ----------------
+
+
+@pytest.fixture(scope="module")
+def greedy_job(tmp_path_factory):
+    """One `compare --greedy_secondary_clustering --streaming_primary` on a
+    planted workdir of 70 genomes: a cluster of 40 for the engine, one of 6
+    for the batched route, singletons; with the event log on."""
+    from benchmark import cells
+    from drep_tpu import controller
+
+    gen = cells.load_module(os.path.join(REPO, "benchmark", "generators", "planted_release.py"))
+    cfg = cells.read_json(os.path.join(REPO, "benchmark", "configs", "gtdb_release_6k.json"))
+    cfg["data"].update({"n": 70, "s_scaled": 1900, "clusters": [
+        {"size": 40, "count": 1, "groups": [28, 12]}, {"size": 6, "count": 1, "groups": [6]},
+        {"size": 1, "count": 24, "groups": [1]}]})
+    wd = gen.prepare(cfg, 34, str(tmp_path_factory.mktemp("spans_greedy")))["workdir"]
+    controller.main(["compare", wd, "--greedy_secondary_clustering", "--streaming_primary",
+                     "--skip_plots", "--events", "on"])
+    telemetry.configure()
+    with open(os.path.join(wd, "log", "perf_counters.json")) as f:
+        record = json.load(f)
+    trace_report = _trace_report()
+    spans, unclosed = trace_report.pair_spans(
+        trace_report.load_events(os.path.join(wd, "log"))["events"])
+    assert not unclosed
+    return {"record": record, "spans": spans}
+
+
+def _inside(spans, name: str, stage: str) -> list[bool]:
+    """For each span `name` of the log: does a span `stage` cover it?"""
+    outer = [(sp["begin"], sp["end"]) for sp in spans if sp["ev"] == stage]
+    return [any(lo <= sp["begin"] and sp["end"] <= hi for lo, hi in outer)
+            for sp in spans if sp["ev"] == name]
+
+
+@pytest.mark.parametrize("name,stages,calls", [
+    # the engine's pack; the batched route's cluster-local pack and its row pad
+    ("secondary/pack", ("stage:secondary_compare",), 3),
+    ("secondary/greedy_layout", ("stage:secondary_compare",), 1),  # a block (off a TPU: the pad)
+    ("secondary/greedy_wait", ("stage:secondary_compare",), 1),
+    # a block and the cluster's rows in the engine, then the batched route's one cluster
+    ("secondary/greedy_assign", ("stage:secondary_compare", "stage:secondary_postprocess"), 3),
+])
+def test_the_greedy_spans_nest_in_their_stages(greedy_job, name, stages, calls):
+    ph, spans = greedy_job["record"]["phases"], greedy_job["spans"]
+    assert ph[name]["calls"] == calls == sum(sp["ev"] == name for sp in spans)
+    assert ph[name]["thread"] == "main" and ph[name]["seconds"] > 0
+    covered = [_inside(spans, name, stage) for stage in stages]
+    assert all(any(by_stage) for by_stage in zip(*covered)), (name, covered)
+    assert all(any(c) for c in covered)  # each stage named holds at least one
+    # all inside the secondary stage, and the sum still closes on the root
+    assert all(_inside(spans, name, "stage:secondary"))
+    assert _main_self_sum(ph) == pytest.approx(ph["job"]["seconds"], rel=0.01)
+
+
+def test_the_greedy_job_s_record_names_its_routes_and_its_wait_span_carries_the_shape(greedy_job):
+    rec, spans = greedy_job["record"], greedy_job["spans"]
+    assert rec["secondary_paths"] == {"greedy_gather": 1, "one_shot_clusterlocal": 1}
+    (call,) = rec["secondary_greedy_calls"]
+    assert (call["rows"], call["blocks"], call["reps"], call["all_pairs"]) == (40, 1, 2, 780)
+    assert rec["secondary_greedy_batched"] == {"clusters": 1, "rows": 6, "compared_pairs": 5,
+                                               "all_pairs": 15}
+    assert rec["stages"]["secondary_compare"]["pairs"] == call["compared_pairs"] + 5
+    (wait,) = [sp for sp in spans if sp["ev"] == "secondary/greedy_wait"]
+    assert wait["args"] == {"rows": 40, "reps": 0, "rep_pad": call["rep_tile"], "chunks": 0}
+    assert "secondary/wait" in rec["phases"]  # the batched route's one-shot call keeps its own name
+
+
+def test_greedy_calls_list_a_cluster_each_and_stay_bounded():
+    from drep_tpu.utils import profiling
+
+    c = Counters()
+    booked = dict(rows=300, blocks=3, block_rows=128, reps=5, rep_tile=512, rep_rows_shipped=1536,
+                  rep_rows_real=9, v_chunk=262144, chunks=2, extent=400_000, widths=49152,
+                  hashes=6 * 10**6, id_slots=10**7, device_calls=12, compared_pairs=1200)
+    for i in range(profiling.SECONDARY_SHAPES_MAX + 5):
+        c.add_greedy_call(**{**booked, "rows": 300 + i})
+    c.add_greedy_batched(rows=4, compared_pairs=3)
+    c.add_greedy_batched(rows=2, compared_pairs=1)
+    rep = c.report(device=False)
+    calls = rep["secondary_greedy_calls"]
+    assert len(calls) == profiling.SECONDARY_SHAPES_MAX
+    assert calls[0] == {**booked, "clusters": 1, "all_pairs": 300 * 299 // 2, "bytes_shipped": 4 * 10**7}
+    assert [e["rows"] for e in calls[:3]] == [300, 301, 302]  # in the order met
+    assert calls[-1]["clusters"] == 6 and calls[-1]["compared_pairs"] == 6 * 1200  # the rest, summed
+    assert sum(e["clusters"] for e in calls) == profiling.SECONDARY_SHAPES_MAX + 5
+    assert rep["secondary_greedy_batched"] == {"clusters": 2, "rows": 6, "compared_pairs": 4,
+                                               "all_pairs": 7}
+    c.reset()
+    rep = c.report(device=False)
+    assert "secondary_greedy_calls" not in rep and "secondary_greedy_batched" not in rep
