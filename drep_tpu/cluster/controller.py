@@ -651,6 +651,17 @@ def read_for_filter(wd: WorkDirectory, bdb: pd.DataFrame, stats_only, **kwargs) 
         return read_genomes(bdb, wd=wd, stats_only=stats_only, **_sketch_args(kw))
 
 
+def _hold_for_evaluate(wd: WorkDirectory, df: pd.DataFrame, table: str) -> None:
+    """Leave `stage:evaluate` what it reads of the pair table `df`, just
+    stored as `table`: name codes and value columns (evaluate.PairColumns),
+    not the frame's strings. The job that computed a table does not read it
+    back; a resumed work directory holds nothing and reads the file."""
+    from drep_tpu.evaluate import PairColumns
+
+    with counters.span("evaluate/columns", rows=len(df)):
+        wd.hold(table, PairColumns.of(df, table, held=True))
+
+
 def d_cluster_wrapper(
     wd: WorkDirectory, bdb: pd.DataFrame, sketches: IngestPass | None = None, **kwargs
 ) -> pd.DataFrame:
@@ -753,6 +764,7 @@ def d_cluster_wrapper(
     if mdb is not None:
         with counters.span("tables_io", rows=len(mdb)) as io:
             io.note(bytes=wd.store_db(schemas.validate(mdb, "Mdb"), "Mdb"))
+        _hold_for_evaluate(wd, mdb, "Mdb")
 
     clustering_files: dict[str, Any] = {
         "primary_linkage": plink,
@@ -824,6 +836,7 @@ def d_cluster_wrapper(
                 pickle.dump(clustering_files, f)
 
         atomic_write(os.path.join(cf_dir, "clustering.pickle"), _dump)
+    _hold_for_evaluate(wd, ndb, "Ndb")
 
     with counters.span("tables_io"):
         wd.store_arguments("cluster", snapshot)

@@ -67,7 +67,8 @@ def write_csv(df: pd.DataFrame, path: str) -> dict:
     return done
 
 
-def _plain(text) -> bool:
+def plain(text) -> bool:
+    """Whether `text` is a non-empty string that ``csv.QUOTE_MINIMAL`` leaves as it is."""
     return type(text) is str and text != "" and _NOT_PLAIN.search(text) is None
 
 
@@ -78,16 +79,28 @@ def _narrow(texts: np.ndarray) -> np.ndarray:
     return np.ascontiguousarray(wide[:, :width]).view(f"S{width}").ravel()
 
 
-def _float_texts(bits: np.ndarray, dtype: np.dtype) -> tuple[np.ndarray, np.ndarray]:
-    """(texts, codes) of float values given as their bit patterns: 0.0 and
-    -0.0 are one value to a hash table and two texts to pandas. pandas
-    renders a float block by ``astype(str)``, numpy's shortest round-trip
-    text at the block's own width."""
+def distinct_floats(values: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """(distinct values, codes) of a float32 / float64 array, told apart by
+    their bit patterns: 0.0 and -0.0 are one value to a hash table and two
+    texts to pandas."""
+    bits = np.ascontiguousarray(values).view(f"i{values.dtype.itemsize}")
     codes, uniq = pd.factorize(bits)
-    values = uniq.view(dtype)
+    return uniq.view(values.dtype), codes
+
+
+def float_texts(values: np.ndarray) -> np.ndarray:
+    """The text pandas writes for each float: it renders a float block by
+    ``astype(str)``, numpy's shortest round-trip text at the block's own
+    width."""
+    return values.astype("S32")
+
+
+def _float_texts(values: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """(texts, codes) of float values, each distinct one rendered once."""
+    values, codes = distinct_floats(values)
     if np.isnan(values).any():
         raise _Unrenderable("missing value")
-    return _narrow(values.astype("S32")), codes
+    return _narrow(float_texts(values)), codes
 
 
 def _string_texts(col: pd.Series) -> tuple[np.ndarray, np.ndarray]:
@@ -100,7 +113,7 @@ def _string_texts(col: pd.Series) -> tuple[np.ndarray, np.ndarray]:
     uniq = uniq.tolist()
     if not all(type(u) is str for u in uniq):
         raise _Unrenderable("mixed object column")
-    if not all(map(_plain, uniq)):
+    if not all(map(plain, uniq)):
         raise _Unrenderable("string that needs quoting")
     texts = np.array([u.encode() for u in uniq], dtype="S")
     padded = texts.dtype.itemsize * len(codes)
@@ -114,12 +127,11 @@ def _chunk_columns(df: pd.DataFrame, kinds: list[str], lo: int, hi: int) -> tupl
     texts were rendered for them."""
     cols: list = [None] * len(kinds)
     rendered = 0
-    floats: dict[np.dtype, list[tuple[int, np.ndarray]]] = {}  # by width: (column, bit patterns)
+    floats: dict[np.dtype, list[tuple[int, np.ndarray]]] = {}  # by width: (column, values)
     for i, kind in enumerate(kinds):
         part = df.iloc[lo:hi, i]
         if kind == "float":
-            bits = np.ascontiguousarray(part.to_numpy()).view(f"i{part.dtype.itemsize}")
-            floats.setdefault(part.dtype, []).append((i, bits))
+            floats.setdefault(part.dtype, []).append((i, part.to_numpy()))
             continue
         if kind == "int":
             codes, uniq = pd.factorize(part.to_numpy())
@@ -128,7 +140,7 @@ def _chunk_columns(df: pd.DataFrame, kinds: list[str], lo: int, hi: int) -> tupl
             cols[i] = _string_texts(part)
         rendered += len(cols[i][0])
     for dtype, members in floats.items():
-        texts, codes = _float_texts(np.concatenate([bits for _, bits in members]), dtype)
+        texts, codes = _float_texts(np.concatenate([values for _, values in members]))
         rendered += len(texts)
         for (i, _), own in zip(members, np.split(codes, len(members))):
             cols[i] = (texts, own)
@@ -148,10 +160,35 @@ def _kind(dtype) -> str:
     raise _Unrenderable(f"dtype {dtype}")
 
 
+def assemble_rows(parts: list, n: int, write) -> None:
+    """Rows [0, n) as bytes, a block (``BLOCK_BYTES``) at a time, each handed
+    to `write`. A part is constant text (``bytes``: the same on every row) or
+    a column ``(texts, codes)``: its fixed-width texts gathered by code. The
+    NUL padding is dropped by one compress a block, so no text holds a NUL."""
+    widths = [len(part) if isinstance(part, bytes) else part[0].dtype.itemsize for part in parts]
+    row_bytes = sum(widths)
+    block_rows = max(1, BLOCK_BYTES // row_bytes)
+    buf = np.empty((min(block_rows, n), row_bytes), np.uint8)
+    cols = []
+    at = 0
+    for part, width in zip(parts, widths):
+        if isinstance(part, bytes):
+            buf[:, at : at + width] = np.frombuffer(part, np.uint8)  # once: the blocks share the buffer
+        else:
+            cols.append((at, width, *part))
+        at += width
+    for start in range(0, n, block_rows):
+        block = buf[: min(block_rows, n - start)]
+        for at, width, texts, codes in cols:
+            field = texts[codes[start : start + len(block)]]
+            block[:, at : at + width] = field.view(np.uint8).reshape(len(block), width)
+        write(block[block != 0])
+
+
 def _write_columnar(df: pd.DataFrame, path: str) -> int:
     """The columnar write; returns the texts rendered."""
     labels = df.columns
-    if not len(labels) or isinstance(labels, pd.MultiIndex) or not all(map(_plain, labels)):
+    if not len(labels) or isinstance(labels, pd.MultiIndex) or not all(map(plain, labels)):
         raise _Unrenderable("column labels that are not plain strings")
     kinds = [_kind(dtype) for dtype in df.dtypes]
     rendered = 0
@@ -162,18 +199,7 @@ def _write_columnar(df: pd.DataFrame, path: str) -> int:
             n = min(CHUNK_ROWS, len(df) - lo)
             cols, texts_made = _chunk_columns(df, kinds, lo, lo + n)
             rendered += texts_made
-            widths = [texts.dtype.itemsize for texts, _ in cols]
-            row_bytes = sum(widths) + len(cols)
-            block_rows = max(1, BLOCK_BYTES // row_bytes)
-            buf = np.empty((min(block_rows, n), row_bytes), np.uint8)
-            for start in range(0, n, block_rows):
-                block = buf[: min(block_rows, n - start)]
-                at = 0
-                for (texts, codes), width in zip(cols, widths):
-                    field = texts[codes[start : start + len(block)]]
-                    block[:, at : at + width] = field.view(np.uint8).reshape(len(block), width)
-                    block[:, at + width] = ord(",")
-                    at += width + 1
-                block[:, -1] = ord("\n")
-                f.write(block[block != 0])
+            parts = [part for col in cols for part in (col, b",")]
+            parts[-1] = b"\n"
+            assemble_rows(parts, n, f.write)
     return rendered

@@ -268,6 +268,12 @@ class Counters:
     ingest: dict[str, Any] = field(default_factory=dict)
     # what `stage:filter` saw and dropped, by reason (filter.py)
     filter: dict[str, int] = field(default_factory=dict)
+    # what `stage:evaluate` read and wrote (evaluate.py, ISSUE 35): under
+    # `mdb` and `ndb` the `source` of the pair table (`job`: the columns this
+    # process held from the stage that wrote it; `disk`: the file read back)
+    # and its `rows`; `warnings`, the lines by kind; their `bytes`; and the
+    # `distinct` texts rendered for them (names encoded, values formatted)
+    evaluate: dict[str, Any] = field(default_factory=dict)
     _open: threading.local = field(default_factory=threading.local, repr=False, compare=False)
     _lock: threading.Lock = field(default_factory=threading.Lock, repr=False, compare=False)
 
@@ -491,6 +497,20 @@ class Counters:
         for name, value in booked.items():
             self.filter[name] = self.filter.get(name, 0) + int(value)
 
+    def add_evaluate_table(self, table: str, source: str, rows: int) -> None:
+        """Book where `stage:evaluate` took the pair table `table` (`mdb`,
+        `ndb`) from, and its rows."""
+        self.evaluate[table] = {"source": source, "rows": int(rows)}
+
+    def add_evaluate_warnings(self, lines: dict[str, int], bytes: int, distinct: int) -> None:
+        """Book one rendering of the warnings: `lines` by kind, their
+        `bytes`, and the `distinct` texts rendered for them."""
+        by_kind = self.evaluate.setdefault("warnings", {})
+        for kind, n in lines.items():
+            by_kind[kind] = by_kind.get(kind, 0) + int(n)
+        self.evaluate["bytes"] = self.evaluate.get("bytes", 0) + int(bytes)
+        self.evaluate["distinct"] = self.evaluate.get("distinct", 0) + int(distinct)
+
     def set_gauge(self, name: str, value: float) -> None:
         """Record a derived operational value (last write wins)."""
         self.gauges[name] = float(value)
@@ -613,6 +633,9 @@ class Counters:
                              for name, value in self.ingest.items()}
         if self.filter:
             out["filter"] = dict(self.filter)
+        if self.evaluate:
+            out["evaluate"] = {name: dict(ent) if isinstance(ent, dict) else ent
+                               for name, ent in self.evaluate.items()}
         phases = self._phases_report()
         if phases:
             out["phases"] = phases
@@ -675,6 +698,7 @@ class Counters:
         self.tables_write.clear()
         self.ingest.clear()
         self.filter.clear()
+        self.evaluate.clear()
         with self._lock:
             self.phases.clear()  # a span open now stays open and books when it closes
 

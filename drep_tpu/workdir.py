@@ -75,6 +75,9 @@ class WorkDirectory:
         self.location = os.path.abspath(location)
         for sub in _SUBDIRS:
             os.makedirs(os.path.join(self.location, sub), exist_ok=True)
+        # what :meth:`hold` keeps of tables this process stored, by table:
+        # (columns, the file they were stored as)
+        self._held: dict[str, tuple[Any, tuple]] = {}
 
     # ---- directories -----------------------------------------------------
     def get_dir(self, name: str) -> str:
@@ -109,6 +112,26 @@ class WorkDirectory:
 
     def hasDb(self, name: str) -> bool:  # noqa: N802 — reference-compatible name
         return os.path.exists(self._table_loc(name))
+
+    # ---- a stored table's columns, for a later stage of the same job -----
+    def _table_file(self, name: str) -> tuple:
+        st = os.stat(self._table_loc(name))
+        return st.st_ino, st.st_size, st.st_mtime_ns
+
+    def hold(self, name: str, columns: Any) -> None:
+        """Keep `columns` of the table `name` this process has just stored,
+        so that a later stage of the same job need not read the file back
+        (ISSUE 35: `stage:evaluate` and the pair tables of `stage:cluster`)."""
+        self._held[name] = (columns, self._table_file(name))
+
+    def take_held(self, name: str) -> Any:
+        """What :meth:`hold` kept of `name`, handed over once: None where
+        this process holds nothing of it (a resumed work directory), or the
+        file is no longer the one the columns were stored as."""
+        columns, stored_as = self._held.pop(name, (None, None))
+        if columns is not None and self.hasDb(name) and stored_as == self._table_file(name):
+            return columns
+        return None
 
     # ---- packed arrays (TPU-native extension) ----------------------------
     def _array_loc(self, name: str) -> str:

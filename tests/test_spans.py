@@ -560,3 +560,133 @@ def test_greedy_calls_list_a_cluster_each_and_stay_bounded():
     c.reset()
     rep = c.report(device=False)
     assert "secondary_greedy_calls" not in rep and "secondary_greedy_batched" not in rep
+
+
+# --- stage:evaluate: the job's own columns, or the tables read back (ISSUE 35) ---
+
+
+@pytest.fixture(scope="module")
+def evaluate_jobs(tmp_path_factory, genome_paths):
+    """A toy `compare`, the same command again on its work directory (the
+    cluster stage skipped on resume), and a `dereplicate`. Of each: its
+    record, its `warnings.txt`, every pair table opened while `stage:evaluate`
+    was open, and a weak reference to whatever `WorkDirectory.hold` kept."""
+    import builtins
+    import weakref
+
+    import pandas as pd
+
+    from drep_tpu.utils.profiling import counters
+    from drep_tpu.workdir import WorkDirectory
+    from drep_tpu.workflows import compare_wrapper, dereplicate_wrapper
+
+    opened: list[str] = []
+    kept: list = []
+    real_read, real_open, real_hold = pd.read_csv, builtins.open, WorkDirectory.hold
+
+    def note(file) -> None:
+        inside = any(sp.name == "stage:evaluate" for sp in counters._stack())
+        if inside and isinstance(file, str) and os.path.basename(file) in ("Mdb.csv", "Ndb.csv"):
+            opened.append(os.path.basename(file))
+
+    def read_csv(file, *a, **k):
+        note(file)
+        return real_read(file, *a, **k)
+
+    def open_(file, *a, **k):
+        note(file)
+        return real_open(file, *a, **k)
+
+    def hold(self, name, columns):
+        kept.append(weakref.ref(columns))
+        real_hold(self, name, columns)
+
+    def run(job, wd, **kwargs) -> dict:
+        opened.clear()
+        kept.clear()
+        job(wd, genome_paths, skip_plots=True, **kwargs)
+        with real_open(os.path.join(wd, "log", "perf_counters.json")) as f:
+            record = json.load(f)
+        with real_open(os.path.join(wd, "log", "warnings.txt"), "rb") as f:
+            return {"wd": wd, "record": record, "warnings": f.read(), "opened": list(opened),
+                    "kept": list(kept)}
+
+    quality = str(tmp_path_factory.mktemp("evaluate_quality") / "q.csv")
+    names = [os.path.basename(p) for p in genome_paths]
+    pd.DataFrame({"genome": names, "completeness": [99.0, 90.0, 85.0, 95.0, 94.0],
+                  "contamination": [0.5, 1.0, 2.0, 0.1, 0.2]}).to_csv(quality, index=False)
+    wd = str(tmp_path_factory.mktemp("evaluate_wd"))
+    mp = pytest.MonkeyPatch()
+    mp.setattr(pd, "read_csv", read_csv)
+    mp.setattr(builtins, "open", open_)
+    mp.setattr(WorkDirectory, "hold", hold)
+    try:
+        return {
+            "first": run(compare_wrapper, wd),
+            "again": run(compare_wrapper, wd),
+            "dereplicate": run(dereplicate_wrapper, str(tmp_path_factory.mktemp("evaluate_dwd")),
+                               genomeInfo=quality),
+        }
+    finally:
+        mp.undo()
+        telemetry.configure()
+
+
+def test_the_job_that_wrote_the_pair_tables_does_not_read_them_back(evaluate_jobs):
+    import gc
+
+    first = evaluate_jobs["first"]
+    booked = first["record"]["evaluate"]
+    assert booked["mdb"] == {"source": "job", "rows": 25} and booked["ndb"] == {"source": "job", "rows": 8}
+    assert first["opened"] == []
+    assert booked["warnings"] == {"primary": 4, "secondary": 0, "coverage": 2}
+    assert booked["bytes"] == len(first["warnings"]) and first["warnings"].count(b"\n") == 6
+    assert booked["distinct"] == 5 + 4 + 3 + 2  # names and values of the Primary lines, then of the Coverage lines
+    ph = first["record"]["phases"]
+    assert ph["evaluate/columns"]["calls"] == 2 and ph["evaluate/tables"]["calls"] == 1
+    assert _main_self_sum(ph) == pytest.approx(ph["job"]["seconds"], rel=0.01)
+    # what was handed over went with the stage: two tables held, neither alive
+    gc.collect()
+    assert len(first["kept"]) == 2 and all(ref() is None for ref in first["kept"])
+
+
+def test_a_resumed_work_directory_reads_them_from_disk_and_writes_the_same_bytes(evaluate_jobs):
+    first, again = evaluate_jobs["first"], evaluate_jobs["again"]
+    booked = again["record"]["evaluate"]
+    assert booked["mdb"] == {"source": "disk", "rows": 25} and booked["ndb"] == {"source": "disk", "rows": 8}
+    assert set(again["opened"]) == {"Mdb.csv", "Ndb.csv"} and again["kept"] == []
+    assert "evaluate/columns" not in again["record"]["phases"]
+    assert again["warnings"] == first["warnings"] != b""
+    assert {k: v for k, v in booked.items() if k not in ("mdb", "ndb")} \
+        == {k: v for k, v in first["record"]["evaluate"].items() if k not in ("mdb", "ndb")}
+
+
+def test_a_dereplicate_job_writes_the_widb_and_the_warnings_of_the_parents_spelling(evaluate_jobs):
+    from test_evaluate_bytes import parent_stage
+
+    from drep_tpu.evaluate import make_widb
+    from drep_tpu.workdir import WorkDirectory
+
+    job = evaluate_jobs["dereplicate"]
+    assert job["record"]["evaluate"]["mdb"]["source"] == job["record"]["evaluate"]["ndb"]["source"] == "job"
+    assert job["opened"] == [] and all(ref() is None for ref in job["kept"])
+    wd = WorkDirectory(job["wd"])
+    assert job["warnings"] == parent_stage(wd) != b""
+    widb = make_widb(wd.get_db("Wdb"), wd.get_db("Cdb"), wd.get_db("genomeInformation"), wd.get_db("genomeInfo"))
+    with open(os.path.join(job["wd"], "data_tables", "Widb.csv"), "rb") as f:
+        assert f.read() == widb.to_csv(index=False).encode()
+
+
+def test_the_evaluate_spans_carry_their_source_and_what_they_wrote(tmp_path, genome_paths):
+    from drep_tpu.workflows import compare_wrapper
+
+    trace_report = _trace_report()
+    wd = str(tmp_path / "wd")
+    for source in ("job", "disk"):
+        compare_wrapper(wd, genome_paths, skip_plots=True, events="on")
+        telemetry.configure()
+        spans, _ = trace_report.pair_spans(trace_report.load_events(os.path.join(wd, "log"))["events"])
+        last = {sp["ev"]: sp for sp in spans}  # the second job's spans follow the first's in the log
+        assert last["evaluate/tables"]["args"] == {"source": source}
+        size = os.path.getsize(os.path.join(wd, "log", "warnings.txt"))
+        assert last["evaluate/warnings"]["args"] == {"winners": 5, "lines": 6, "bytes": size}
