@@ -17,7 +17,13 @@ SURVEY.md §0):
   (Bdb/Mdb/Ndb/Cdb/Sdb/Wdb) persisted through :class:`WorkDirectory`.
 """
 
+import time as _time
+
 __version__ = "0.5.0"
+
+# the package's first import on the process's clock: the record's
+# `process.imported_at_s` (utils/profiling.py::ProcessLedger)
+_IMPORTED_AT = _time.perf_counter()
 
 
 def __getattr__(name):  # PEP 562 — keep the package import lean: ingest

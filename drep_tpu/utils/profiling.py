@@ -19,10 +19,20 @@ keyword arguments.
 
 ``trace(dir)`` wraps a job in ``jax.profiler.trace`` with the options the
 benchmark harness uses (``--profile`` on the CLI).
+
+What a process pays before its first warm job (ISSUE 36) is in two sections
+of the same record. ``compile``: the programs this job traced, lowered,
+compiled or loaded from the persistent cache, booked by the ``jax.monitoring``
+listeners of :func:`listen_for_compiles` under the innermost span open on the
+compiling thread. ``process`` (:class:`ProcessLedger`): when the process
+began this job, on the process's own clock, and the jobs it ran before, the
+first of them kept with its programs. ``Counters.reset`` clears the first and
+leaves the second.
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import json
 import os
@@ -32,12 +42,132 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Iterator
 
+import drep_tpu
 from drep_tpu.utils import telemetry
 
 # distinct program shapes the record lists, for the one-shot calls and for
 # the chunked calls each (Counters.add_secondary_call, .add_chunked_call),
 # and the clusters it lists of the greedy engine's (.add_greedy_call)
 SECONDARY_SHAPES_MAX = 64
+# jobs the process ledger lists, the newest (ProcessLedger.jobs)
+LEDGER_JOBS_MAX = 16
+
+# what one program cost to build, by phase, and how the persistent cache
+# answered: the fields of an entry of Counters.built
+_BUILT_FIELDS = ("calls", "trace_s", "lower_s", "backend_compile_s", "cache_load_s",
+                 "hits", "misses")
+
+# jax.monitoring's names (jax 0.9.0: _src/dispatch.py, _src/compiler.py). The
+# trace event carries the function's name, the lowering and backend-compile
+# events the module's, `jit(<function>)`: _bare_name folds them.
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_REQUEST_EVENT = "/jax/compilation_cache/compile_requests_use_cache"
+_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+
+def _bare_name(fun_name: str) -> str:
+    """The function's name out of a module's: `jit(f)` -> `f`."""
+    if fun_name.endswith(")") and "(" in fun_name:
+        return fun_name[fun_name.index("(") + 1:-1]
+    return fun_name
+
+
+def _built_seconds(ent: dict[str, Any]) -> float:
+    return ent["trace_s"] + ent["lower_s"] + ent["backend_compile_s"] + ent["cache_load_s"]
+
+
+def _built_rounded(ent: dict[str, Any], count_as: str) -> dict[str, Any]:
+    """An entry of _BUILT_FIELDS as the record writes it, its `calls` under
+    the name `count_as`."""
+    return {count_as if name == "calls" else name:
+            round(ent[name], 6) if name.endswith("_s") else int(ent[name])
+            for name in _BUILT_FIELDS}
+
+
+def _process_age_s() -> tuple[float, str]:
+    """Seconds since this process started, and the clock that says so: on
+    Linux the kernel's start time of the process against CLOCK_BOOTTIME
+    (`proc_stat`; the interpreter's own start is inside it), elsewhere the
+    first import of the package (`package_import`)."""
+    try:
+        with open("/proc/self/stat", "rb") as f:
+            # the command's name may hold spaces: the fields after it count from ')'
+            after_comm = f.read().rsplit(b")", 1)[1].split()
+        started = int(after_comm[19]) / os.sysconf("SC_CLK_TCK")  # field 22, starttime
+        age = time.clock_gettime(time.CLOCK_BOOTTIME) - started
+        if age >= 0.0:
+            return age, "proc_stat"
+    except (OSError, ValueError, IndexError, AttributeError):
+        pass
+    return time.perf_counter() - drep_tpu._IMPORTED_AT, "package_import"
+
+
+class ProcessLedger:
+    """The record's ``process`` section: what this process had paid when a
+    job began, and the jobs it ran before. Marks are seconds since the
+    process started (:func:`_process_age_s`). A job is opened by its
+    bring-up (``workflows._bring_up`` / ``_init_index``), which no span
+    covers, and entered as the last thing inside `job`
+    (:meth:`Counters.finish_job`): ``{verb, began_at_s, bring_up_s, job_s,
+    compile}``. The first entry stays for good with its programs: in the
+    benchmark harness it is the warm-up job, whose own record is deleted.
+    The newest LEDGER_JOBS_MAX entries keep their totals, so a long-lived
+    process writes a bounded record."""
+
+    def __init__(self) -> None:
+        age, self.clock = _process_age_s()
+        self._origin = time.perf_counter() - age
+        self.imported_at_s = drep_tpu._IMPORTED_AT - self._origin
+        self.n_jobs = 0
+        self.first_job: dict[str, Any] | None = None
+        self.jobs: collections.deque = collections.deque(maxlen=LEDGER_JOBS_MAX)
+        # the job under way: verb, then perf_counter at the bring-up's entry and end
+        self.verb: str | None = None
+        self._began = self._brought_up = 0.0
+
+    def begin(self, verb: str) -> None:
+        self.verb = verb
+        self._began = self._brought_up = time.perf_counter()
+
+    def brought_up(self) -> None:
+        self._brought_up = time.perf_counter()
+
+    def entry(self, job_t0: float | None, compile_: dict[str, Any]) -> dict[str, Any]:
+        """The job under way as far as it has come; `job_t0` is where its
+        `job` span opened (a verb without one counts from its bring-up)."""
+        t0 = self._brought_up if job_t0 is None else job_t0
+        return {
+            "verb": self.verb,
+            "began_at_s": round(self._began - self._origin, 4),
+            "bring_up_s": round(self._brought_up - self._began, 4),
+            "job_s": round(time.perf_counter() - t0, 4),
+            "compile": compile_,
+        }
+
+    def append(self, entry: dict[str, Any]) -> None:
+        if self.first_job is None:
+            self.first_job = entry
+        totals = {k: v for k, v in entry["compile"].items() if k not in ("by_program", "by_span")}
+        self.jobs.append({**entry, "compile": totals})
+        self.n_jobs += 1
+        self.verb = None
+
+    def report(self, under_way: dict[str, Any] | None) -> dict[str, Any]:
+        """`under_way`: the entry of the job that writes this record, or
+        None outside any. A process's first job is its own `first_job`."""
+        job = under_way or {}
+        return {
+            "clock": self.clock,
+            "imported_at_s": round(self.imported_at_s, 4),
+            "began_at_s": job.get("began_at_s"),
+            "bring_up_s": job.get("bring_up_s"),
+            "n_jobs": self.n_jobs,
+            "first_job": self.first_job or under_way,
+            "jobs": list(self.jobs),
+        }
 
 
 @dataclass
@@ -274,6 +404,12 @@ class Counters:
     # and its `rows`; `warnings`, the lines by kind; their `bytes`; and the
     # `distinct` texts rendered for them (names encoded, values formatted)
     evaluate: dict[str, Any] = field(default_factory=dict)
+    # the programs this job built (ISSUE 36), keyed (function, the innermost
+    # span open on the thread that built it): _BUILT_FIELDS. Booked by the
+    # jax.monitoring listeners (:func:`listen_for_compiles`)
+    built: dict[tuple[str, str], dict[str, float]] = field(default_factory=dict)
+    # the process's own ledger: not a job's, so :meth:`reset` leaves it
+    process: ProcessLedger = field(default_factory=ProcessLedger, repr=False, compare=False)
     _open: threading.local = field(default_factory=threading.local, repr=False, compare=False)
     _lock: threading.Lock = field(default_factory=threading.Lock, repr=False, compare=False)
 
@@ -511,6 +647,124 @@ class Counters:
         self.evaluate["bytes"] = self.evaluate.get("bytes", 0) + int(bytes)
         self.evaluate["distinct"] = self.evaluate.get("distinct", 0) + int(distinct)
 
+    # -- the programs a job builds (ISSUE 36) ------------------------------
+    #
+    # jax.monitoring calls its listeners on the thread that builds the
+    # program, in this order: trace begins (a scalar), trace ends, lowering
+    # ends, then inside the backend-compile event the persistent cache's
+    # request and, on a hit, the hit and its retrieval seconds, then the
+    # backend-compile event itself. What is between two events of one
+    # program is kept per thread.
+
+    def _building(self) -> dict[str, Any]:
+        b = getattr(self._open, "building", None)
+        if b is None:
+            b = self._open.building = {"depth": 0, "inside": 0.0, "requested": False,
+                                       "hit": False, "load_s": 0.0}
+        return b
+
+    def _add_built(self, fun_name: str, **seconds_and_counts: float) -> None:
+        stack = self._stack()
+        span = stack[-1].name if stack else "thread:" + threading.current_thread().name
+        with self._lock:
+            ent = self.built.setdefault((_bare_name(fun_name), span), dict.fromkeys(_BUILT_FIELDS, 0))
+            for name, value in seconds_and_counts.items():
+                ent[name] += value
+
+    def on_compile_scalar(self, event: str) -> None:
+        if event == _TRACE_EVENT:
+            b = self._building()
+            b["depth"] += 1
+            if b["depth"] == 1:
+                b["inside"] = 0.0
+
+    def on_compile_event(self, event: str) -> None:
+        if event == _REQUEST_EVENT:
+            self._building()["requested"] = True
+        elif event == _HIT_EVENT:
+            self._building()["hit"] = True
+
+    def on_compile_duration(self, event: str, seconds: float, fun_name: str) -> None:
+        """Book one finished phase of one program. A function traced inside
+        another's trace (`jnp.sin` inside a jitted function) is the outer
+        one's time: only the outermost trace is booked, less what a program
+        built whole inside it (an eager operation at trace time) booked
+        itself. The backend-compile event wraps `compile_or_get_cached`, so a
+        hit's retrieval lies inside it and is booked apart."""
+        if event == _RETRIEVAL_EVENT:
+            self._building()["load_s"] += seconds
+            return
+        if event not in (_TRACE_EVENT, _LOWER_EVENT, _BACKEND_EVENT):
+            return
+        b = self._building()
+        if event == _TRACE_EVENT:
+            b["depth"] = max(0, b["depth"] - 1)
+            if b["depth"] == 0:
+                self._add_built(fun_name, trace_s=max(0.0, seconds - b["inside"]))
+            return
+        if b["depth"]:
+            b["inside"] += seconds
+        if event == _LOWER_EVENT:
+            self._add_built(fun_name, lower_s=seconds)
+            return
+        cache = "hit" if b["hit"] else "miss" if b["requested"] else "off"
+        load_s = min(b["load_s"], seconds) if b["hit"] else 0.0
+        b.update(requested=False, hit=False, load_s=0.0)
+        self._add_built(fun_name, calls=1, backend_compile_s=seconds - load_s, cache_load_s=load_s,
+                        hits=int(cache == "hit"), misses=int(cache == "miss"))
+        # the timeline has the order: one instant a program built, none a call
+        telemetry.event("compile", fun_name=_bare_name(fun_name), dur=round(seconds, 6), cache=cache)
+
+    def _compile_report(self) -> dict[str, Any]:
+        """The record's ``compile``: totals (`programs`: backend-compile
+        events, executables built or loaded; `cache_misses`: requests that
+        asked the persistent cache and compiled, among them the programs too
+        quick for jax to store), `by_program` (one entry a function, `calls`
+        the programs built of it, `span` where most of its seconds went;
+        the longest SECONDARY_SHAPES_MAX, the rest summed under an empty
+        name) and `by_span`."""
+        with self._lock:
+            rows = [(key, dict(ent)) for key, ent in self.built.items()]
+        total = dict.fromkeys(_BUILT_FIELDS, 0)
+        by_program: dict[str, dict[str, Any]] = {}
+        by_span: dict[str, dict[str, float]] = {}
+        most: dict[str, float] = {}  # a function's seconds in the span it is listed under
+        for (fun_name, span), ent in rows:
+            prog = by_program.setdefault(fun_name, dict.fromkeys(_BUILT_FIELDS, 0))
+            if _built_seconds(ent) > most.get(fun_name, -1.0):
+                prog["span"], most[fun_name] = span, _built_seconds(ent)
+            for acc in (total, prog, by_span.setdefault(span, dict.fromkeys(_BUILT_FIELDS, 0))):
+                for name in _BUILT_FIELDS:
+                    acc[name] += ent[name]
+        longest = sorted(by_program.items(), key=lambda kv: -_built_seconds(kv[1]))
+        listed = [{"fun_name": name, "span": ent["span"], **_built_rounded(ent, "calls")}
+                  for name, ent in longest[:SECONDARY_SHAPES_MAX]]
+        if len(longest) > SECONDARY_SHAPES_MAX:
+            rest = {name: sum(ent[name] for _fn, ent in longest[SECONDARY_SHAPES_MAX:])
+                    for name in _BUILT_FIELDS}
+            listed.append({"fun_name": "", "span": "", **_built_rounded(rest, "calls")})
+        totals = _built_rounded(total, "programs")
+        totals["cache_hits"], totals["cache_misses"] = totals.pop("hits"), totals.pop("misses")
+        return {**totals, "by_program": listed,
+                "by_span": {span: _built_rounded(ent, "programs")
+                            for span, ent in sorted(by_span.items())}}
+
+    def _job_entry(self, compile_: dict[str, Any]) -> dict[str, Any] | None:
+        """The process ledger's entry of the job under way, or None outside
+        any: read on the job's own thread, whose outermost open span is `job`."""
+        if self.process.verb is None:
+            return None
+        stack = self._stack()
+        return self.process.entry(stack[0]._t0 if stack else None, compile_)
+
+    def finish_job(self) -> None:
+        """Enter the job under way in the process ledger: the last thing
+        inside `job`, after the record's write, so a record lists the jobs
+        before its own."""
+        entry = self._job_entry(self._compile_report())
+        if entry is not None:
+            self.process.append(entry)
+
     def set_gauge(self, name: str, value: float) -> None:
         """Record a derived operational value (last write wins)."""
         self.gauges[name] = float(value)
@@ -639,6 +893,8 @@ class Counters:
         phases = self._phases_report()
         if phases:
             out["phases"] = phases
+        out["compile"] = self._compile_report()
+        out["process"] = self.process.report(self._job_entry(out["compile"]))
         return out
 
     def _phases_report(self) -> dict[str, dict[str, Any]]:
@@ -701,9 +957,33 @@ class Counters:
         self.evaluate.clear()
         with self._lock:
             self.phases.clear()  # a span open now stays open and books when it closes
+            self.built.clear()
 
 
 counters = Counters()  # the process-global instance used by the pipeline
+
+_listening = False
+
+
+def listen_for_compiles() -> None:
+    """Register the jax.monitoring listeners that book the record's
+    ``compile`` section into the process-global counters: once a process,
+    from the bring-up of a verb that computes (``workflows._bring_up``,
+    ``_init_index``). Never at import: the ingest pool's workers and the
+    control-plane verbs import this module without JAX."""
+    global _listening
+    if _listening:
+        return
+    _listening = True
+    import jax
+
+    jax.monitoring.register_scalar_listener(
+        lambda event, _value, **_kw: counters.on_compile_scalar(event))
+    jax.monitoring.register_event_listener(
+        lambda event, **_kw: counters.on_compile_event(event))
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, seconds, **kw: counters.on_compile_duration(
+            event, seconds, str(kw.get("fun_name", ""))))
 
 
 # -- periodic Prometheus-textfile flush (ISSUE 10 satellite) ----------------

@@ -29,7 +29,9 @@ the re-deal took — was unrecoverable. This module is the forensic record:
   fallbacks, io retries/heals, injected faults), every epoch bump with
   its reason (death/drain/join), heartbeat death verdicts, drain
   announce/adopt, join admit/adopt, done-notes, shard publishes, index
-  generation commits.
+  generation commits, and every program built or loaded (``compile``:
+  ``fun_name``, ``dur``, ``cache`` — one a program, none a call;
+  profiling.Counters.on_compile_duration).
 
 **Crash safety**: each line is written+flushed whole; a SIGKILL can tear
 at most the final line, which readers (tools/trace_report.py,
@@ -38,8 +40,9 @@ tools/scrub_store.py) treat as expected crash evidence, never damage.
 **Zero overhead when off** (the default): every emit path starts with one
 falsy dict lookup (a :class:`Span` gates each of its two lines so), and
 no file — not even an empty one — is ever created. Pinned by
-tests/test_perf_guards.py (<= 3% on the 528-tile warm checkpointed pass
-with events ON; zero files with events off).
+tests/test_perf_guards.py by count (zero files and no sink call with events
+off; a fixed number of lines a stripe on the 528-tile warm checkpointed
+pass with events ON, never one a tile).
 
 Gating: ``--events {off,on}`` on the CLI, or ``DREP_TPU_EVENTS=on`` for
 library/worker embeddings. ``configure()`` resolves the sink; without a

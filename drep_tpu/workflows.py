@@ -19,17 +19,22 @@ from drep_tpu.workdir import WorkDirectory
 from drep_tpu.errors import UserInputError
 
 
-def _bring_up(wd_loc: str, events: str | bool | None = None) -> WorkDirectory:
+def _bring_up(wd_loc: str, verb: str, events: str | bool | None = None) -> WorkDirectory:
     """What has to be in place before the `job` span and the profiler
     open: the distributed runtime (it must come before ANY backend use, and
     ``jax.profiler.start_trace`` initialises the backend), the workdir, the
     logger, the event log and fresh per-run state. `job` opens right after,
-    so its `B` line lands in THIS job's event log."""
+    so its `B` line lands in THIS job's event log. No span covers this: the
+    process ledger holds its wall as the job's `bring_up_s`."""
+    from drep_tpu.utils.profiling import counters, listen_for_compiles
+
+    counters.process.begin(verb)
     # multi-host bring-up must precede any backend use (no-op single-host)
     from drep_tpu.parallel.mesh import initialize_distributed
     from drep_tpu.utils.xla_cache import enable_persistent_cache
 
     enable_persistent_cache()
+    listen_for_compiles()
     initialize_distributed()
     wd = WorkDirectory(wd_loc)
     setup_logger(wd.get_dir("log"))
@@ -48,10 +53,10 @@ def _bring_up(wd_loc: str, events: str | bool | None = None) -> WorkDirectory:
     start_metrics_flush(wd.get_dir("log"))
     # fresh per-run state (library users may call several workflows per process)
     from drep_tpu.cluster.anim import reset_run_state
-    from drep_tpu.utils.profiling import counters
 
     counters.reset()
     reset_run_state()
+    counters.process.brought_up()
     return wd
 
 
@@ -82,9 +87,9 @@ def _trace_dir(wd_loc: str, profile) -> str | None:
 
 
 def _finish_counters(wd: WorkDirectory) -> None:
-    """Write the job's record. Called inside the `job` span, which is
-    counted as far as it has come; the wrappers close the event log once
-    the span has written its end."""
+    """Write the job's record, then enter the job in the process ledger.
+    Called inside the `job` span, which is counted as far as it has come;
+    the wrappers close the event log once the span has written its end."""
     from drep_tpu.utils.profiling import counters, stop_metrics_flush
 
     stop_metrics_flush(final=True)
@@ -96,6 +101,7 @@ def _finish_counters(wd: WorkDirectory) -> None:
         total["pairs"], total["seconds"], total["pairs_per_sec_per_chip"],
         rep["n_chips"], path,
     )
+    counters.finish_job()
 
 
 def compare_wrapper(wd_loc: str, genomes: list[str] | None = None, **kwargs) -> pd.DataFrame:
@@ -105,7 +111,7 @@ def compare_wrapper(wd_loc: str, genomes: list[str] | None = None, **kwargs) -> 
 
     # `job` is the root span, from the bring-up to the record's write: its
     # self time is the job's unattributed host time. --profile wraps it whole.
-    wd = _bring_up(wd_loc, events=kwargs.pop("events", None))
+    wd = _bring_up(wd_loc, "compare", events=kwargs.pop("events", None))
     with trace(_trace_dir(wd_loc, kwargs.pop("profile", None))), counters.span("job"):
         bdb = _load_bdb(wd, genomes or [])
         with counters.span("stage:cluster"):
@@ -128,7 +134,7 @@ def compare_wrapper(wd_loc: str, genomes: list[str] | None = None, **kwargs) -> 
     return cdb
 
 
-def _init_index(index_loc: str, write_logs: bool = True) -> str | None:
+def _init_index(index_loc: str, verb: str, write_logs: bool = True) -> str | None:
     """Service-mode session setup: logging under the index's own log dir,
     persistent compile cache, fresh counters — the index equivalents of
     `_bring_up`, minus workdir/Bdb machinery (the store IS the state).
@@ -139,9 +145,11 @@ def _init_index(index_loc: str, write_logs: bool = True) -> str | None:
     import os
 
     from drep_tpu.utils.xla_cache import enable_persistent_cache
-    from drep_tpu.utils.profiling import counters
+    from drep_tpu.utils.profiling import counters, listen_for_compiles
 
+    counters.process.begin(verb)
     enable_persistent_cache()
+    listen_for_compiles()
     log_dir = None
     if write_logs:
         log_dir = os.path.join(os.path.abspath(index_loc), "log")
@@ -159,6 +167,7 @@ def _init_index(index_loc: str, write_logs: bool = True) -> str | None:
     else:
         stop_metrics_flush()
     counters.reset()
+    counters.process.brought_up()
     return log_dir
 
 
@@ -166,7 +175,8 @@ def _finish_index(log_dir: str | None) -> None:
     """The index verbs' run record (the `_finish_counters` twin): which
     device the verb ran on, its fault-tolerance counters and kernel
     paths — ``<index>/log/perf_counters.json``, or ONE console log line
-    for the read-only classify, which may write nothing."""
+    for the read-only classify, which may write nothing. Then the verb is
+    entered in the process ledger."""
     import json
 
     from drep_tpu.utils.profiling import counters
@@ -177,6 +187,7 @@ def _finish_index(log_dir: str | None) -> None:
         get_logger().info(
             "perf_counters: %s", json.dumps(counters.report(), sort_keys=True)
         )
+    counters.finish_job()
 
 
 def index_build_wrapper(
@@ -191,7 +202,7 @@ def index_build_wrapper(
     from drep_tpu.index import build_federated, build_from_paths, build_from_workdir
     from drep_tpu.utils.profiling import counters
 
-    log_dir = _init_index(index_loc)
+    log_dir = _init_index(index_loc, "index build")
     with counters.span("job"):
         if work_directory and genomes:
             raise UserInputError(
@@ -234,7 +245,7 @@ def index_update_wrapper(
     from drep_tpu.index import index_update
     from drep_tpu.utils.profiling import counters
 
-    log_dir = _init_index(index_loc)
+    log_dir = _init_index(index_loc, "index update")
     with counters.span("job"):
         summary = index_update(
             index_loc, genomes, processes=kwargs.get("processes", 1) or 1,
@@ -257,7 +268,7 @@ def index_maintenance_wrapper(index_loc: str, *, op: str, **kwargs) -> dict:
     from drep_tpu.index import fed_compact, fed_merge, fed_split
     from drep_tpu.utils import envknobs
 
-    log_dir = _init_index(index_loc)
+    log_dir = _init_index(index_loc, "index " + op)
     processes = kwargs.get("processes", 1) or 1
     if op == "split":
         summary = fed_split(index_loc, int(kwargs["pid"]), processes=processes)
@@ -288,7 +299,7 @@ def index_classify_wrapper(
 
     if not genomes:
         raise UserInputError("index classify needs -g <genome FASTAs>")
-    log_dir = _init_index(index_loc, write_logs=False)
+    log_dir = _init_index(index_loc, "index classify", write_logs=False)
     verdicts = index_classify(
         index_loc, genomes, processes=kwargs.get("processes", 1) or 1,
         primary_prune=kwargs.get("primary_prune", "off") or "off",
@@ -597,7 +608,7 @@ def dereplicate_wrapper(wd_loc: str, genomes: list[str] | None = None, **kwargs)
     from drep_tpu.utils import telemetry
     from drep_tpu.utils.profiling import counters, trace
 
-    wd = _bring_up(wd_loc, events=kwargs.pop("events", None))
+    wd = _bring_up(wd_loc, "dereplicate", events=kwargs.pop("events", None))
     with trace(_trace_dir(wd_loc, kwargs.pop("profile", None))), counters.span("job"):
         bdb = _load_bdb(wd, genomes or [])
         if kwargs.get("run_tax"):
