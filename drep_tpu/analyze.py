@@ -83,9 +83,36 @@ DENDROGRAM_LABEL_MAX = 1_000
 SECONDARY_PAGES_MAX = 300
 
 
+def _primary_tree(wd: WorkDirectory, cf: dict) -> np.ndarray | None:
+    """The primary linkage matrix of the clustering files `cf`, or None where
+    there is none to draw. A dense job under --skip_plots stores no tree
+    (cluster/controller.py): it is built here, as that job would have, from
+    the distances the work directory keeps for small collections."""
+    link = cf.get("primary_linkage")
+    if link is not None and len(link):
+        return link
+    args = wd.get_arguments("cluster") or {}
+    dist = cf.get("primary_dist")
+    if args.get("SkipMash") or len(cf["primary_names"]) < 2:
+        return None  # one primary cluster by decree: no tree ever
+    if dist is None:
+        get_logger().info(
+            "no primary dendrogram: the work directory holds no primary tree (a dense "
+            "primary stores one only when the job that clusters runs without "
+            "--skip_plots; the streaming and multiround primaries build none) and no "
+            "primary distances to build it from (%d genomes)", len(cf["primary_names"]),
+        )
+        return None
+    from drep_tpu.ops.linkage import cluster_hierarchical
+
+    # the tree is the same at any cut: only the labels, unused here, follow it
+    return cluster_hierarchical(dist, 0.0, method=args.get("clusterAlg", "average"))[1]
+
+
 def plot_primary_dendrogram(wd: WorkDirectory) -> str | None:
     cf = _load_clustering(wd)
-    if cf is None or cf.get("primary_linkage") is None or len(cf["primary_linkage"]) == 0:
+    link = _primary_tree(wd, cf) if cf is not None else None
+    if link is None:
         return None
     out = os.path.join(wd.get_loc("figures"), "Primary_clustering_dendrogram.pdf")
     threshold, _ = _cluster_thresholds(wd)
@@ -93,14 +120,14 @@ def plot_primary_dendrogram(wd: WorkDirectory) -> str | None:
     if len(names) > DENDROGRAM_LABEL_MAX:
         fig, ax = plt.subplots(figsize=(10, 8))
         _fancy_dendrogram(
-            ax, cf["primary_linkage"], None, threshold,
+            ax, link, None, threshold,
             "Mash distance",
             f"Primary clustering (MinHash, {len(names)} genomes — labels omitted)",
         )
     else:
         fig, ax = plt.subplots(figsize=(10, max(4, len(names) * 0.25)))
         _fancy_dendrogram(
-            ax, cf["primary_linkage"], names, threshold,
+            ax, link, names, threshold,
             "Mash distance", "Primary clustering (MinHash)",
         )
     fig.tight_layout()

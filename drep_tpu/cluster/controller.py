@@ -40,7 +40,11 @@ from drep_tpu.ingest import (
     sketch_genomes,
 )
 from drep_tpu.ops.kmers import DEFAULT_K
-from drep_tpu.ops.linkage import cluster_hierarchical, single_linkage_device
+from drep_tpu.ops.linkage import (
+    cluster_by_components,
+    cluster_hierarchical,
+    single_linkage_device,
+)
 from drep_tpu.utils.logger import get_logger
 from drep_tpu.utils.profiling import counters
 from drep_tpu.workdir import WorkDirectory
@@ -336,12 +340,22 @@ def _primary_clusters(
         primary_estimator=kw["primary_estimator"],
     )
     cutoff = 1.0 - kw["P_ani"]
-    with counters.span("primary/linkage"):
-        if kw["clusterAlg"] == "single" and n > 64:
+    on_device = kw["clusterAlg"] == "single" and n > 64
+    # the tree's one reader is the dendrogram (analyze.py): a job that plots
+    # nothing stores the empty tree the streaming path stores (PARITY.md)
+    tree = "skipped" if on_device or kw.get("skip_plots", False) else "built"
+    link = np.empty((0, 4))
+    with counters.span("primary/linkage", genomes=n, tree=tree) as sp:
+        if on_device:
             labels = single_linkage_device(dist, cutoff)
-            link = np.empty((0, 4))
         else:
-            labels, link = cluster_hierarchical(dist, cutoff, method=kw["clusterAlg"])
+            # the labels are the component route's in either case, so one
+            # code path decides Cdb
+            labels, did = cluster_by_components(dist, cutoff, method=kw["clusterAlg"])
+            counters.add_primary_linkage(tree=tree, **did)
+            sp.note(**{k: did[k] for k in ("components", "linkage_calls", "largest")})
+            if tree == "built":
+                _, link = cluster_hierarchical(dist, cutoff, method=kw["clusterAlg"])
     return labels, dist, link, None, n * (n - 1) // 2
 
 

@@ -6,7 +6,10 @@ t=1-threshold, criterion='distance') (SURVEY.md §2; reference mount empty).
 
 Two engines:
 - ``scipy`` (host): exact reference semantics for every linkage method
-  (average is the reference default). Fine through ~10k genomes.
+  (average is the reference default). Fine through ~10k genomes. The dense
+  primary takes only its flat clusters, from the components of the graph of
+  pairs under the cutoff (:func:`cluster_by_components`): scipy runs inside
+  the components that are not all under it, and on nothing else.
 - ``device`` (jit): single-linkage flat clusters at a cutoff == connected
   components of the thresholded distance graph, computed as min-label
   propagation (a few O(N^2) matrix ops per sweep — XLA/VPU friendly, no
@@ -59,6 +62,117 @@ def cluster_hierarchical(
     link = sch.linkage(condensed, method=method)
     labels = sch.fcluster(link, t=cutoff, criterion="distance")
     return _renumber_first_appearance(labels), link
+
+
+# linkage methods whose distance between two clusters is a convex combination
+# (or the minimum, or the maximum) of the pair distances across them: no two
+# clusters merge at or under a cutoff unless some pair across them is, and a
+# set whose every pair is under the cutoff merges wholly under it
+COMPONENT_METHODS = frozenset({"average", "single", "complete", "weighted"})
+
+
+def _cutoff_in(dtype: np.dtype, cutoff: float):
+    """The largest value of `dtype` that is <= `cutoff`: `d <= that` in the
+    matrix's own dtype decides what `float64(d) <= cutoff` decides (NumPy
+    would round a Python float to float32 first, and 1 - 0.9 rounds UP)."""
+    c = dtype.type(cutoff)
+    return np.nextafter(c, dtype.type(-np.inf)) if float(c) > cutoff else c
+
+
+def _components(adj: np.ndarray) -> tuple[np.ndarray, int]:
+    """(component id of every node, number of components) of a symmetric
+    boolean adjacency, breadth first: every node's row is read once and
+    nothing but the ids is allocated, however dense the graph."""
+    m = adj.shape[0]
+    comp = np.full(m, -1, dtype=np.int64)
+    n_comp = 0
+    for start in range(m):
+        if comp[start] >= 0:
+            continue
+        comp[start] = n_comp
+        frontier = np.array([start])
+        while frontier.size:
+            reach = adj[frontier].any(axis=0)
+            reach &= comp < 0
+            frontier = np.flatnonzero(reach)
+            comp[frontier] = n_comp
+        n_comp += 1
+    return comp, n_comp
+
+
+def cluster_by_components(
+    dist: np.ndarray,
+    cutoff: float,
+    method: str = "average",
+) -> tuple[np.ndarray, dict[str, int]]:
+    """The flat clusters :func:`cluster_hierarchical` cuts at `cutoff`, without
+    the tree above the cutoff: (labels 1..C by first appearance, what was done).
+
+    For the :data:`COMPONENT_METHODS` no merge at or under the cutoff joins two
+    clusters with every pair across them over it, so every flat cluster lies
+    inside one connected component of the graph `max(d[i,j], d[j,i]) <=
+    cutoff` (`cluster_hierarchical` symmetrises by the maximum). Singletons of
+    that graph are clusters; a component whose every pair is under the cutoff
+    is one cluster whatever the merge order; any other component goes through
+    `cluster_hierarchical` on its own submatrix, members in ascending order. A
+    matrix that is one loose component costs the whole linkage plus the pass.
+    `ward` (and anything else) takes `cluster_hierarchical` on the whole matrix.
+
+    The partition is `cluster_hierarchical`'s up to the order of exactly tied
+    merges inside a loose component (scipy's nearest-neighbour chain starts
+    elsewhere on a submatrix), and up to the last bits of scipy's running means
+    where a distance lies within rounding of the cutoff (PARITY.md).
+
+    The second value counts `genomes`, `components`, `singletons`, `cliques`
+    (components of two or more settled with no linkage call), `linkage_calls`,
+    `rows_linked` (genomes that went through scipy) and `largest` (component).
+    """
+    dist = np.asarray(dist)
+    if not np.issubdtype(dist.dtype, np.floating):
+        dist = dist.astype(np.float64)
+    n = dist.shape[0]
+    if method not in COMPONENT_METHODS:  # the whole matrix as one loose component
+        labels, _ = cluster_hierarchical(dist, cutoff, method=method)
+        return labels, {"genomes": n, "components": 1, "singletons": 0, "cliques": 0,
+                        "linkage_calls": 1, "rows_linked": n, "largest": n}
+    under = dist <= _cutoff_in(dist.dtype, cutoff)
+    np.fill_diagonal(under, False)
+    # a row with nothing under the cutoff has no edge: the graph is built on
+    # the others alone (a tenth of a catalogue of species representatives)
+    active = np.flatnonzero(under.any(axis=1))
+    adj = under if len(active) == n else under[np.ix_(active, active)]
+    adj = adj & adj.T  # an edge needs both directions under the cutoff
+    comp, n_comp = _components(adj)
+    size = np.bincount(comp, minlength=n_comp)
+    # a clique's members each have size - 1 neighbours
+    loose = np.zeros(n_comp, dtype=bool)
+    loose[comp[np.count_nonzero(adj, axis=1) != size[comp] - 1]] = True
+
+    raw = np.arange(n, dtype=np.int64)  # a label of its own for every genome
+    order = np.argsort(comp, kind="stable")  # members of a component ascend
+    starts = np.concatenate([[0], np.cumsum(size)])
+    raw[active] = active[order[starts[comp]]]  # a component: its first member
+    next_label = n
+    rows_linked = 0
+    for c in np.flatnonzero(loose):
+        members = active[order[starts[c]:starts[c + 1]]]
+        of = dist if len(members) == n else dist[np.ix_(members, members)]
+        sub, _ = cluster_hierarchical(of, cutoff, method=method)
+        raw[members] = next_label + sub
+        next_label += int(sub.max()) + 1
+        rows_linked += len(members)
+    components = n - len(active) + n_comp
+    singletons = n - len(active) + int((size == 1).sum())
+    linked = int(loose.sum())
+    return _renumber_first_appearance(raw), {
+        "genomes": n,
+        "components": components,
+        "singletons": singletons,
+        "cliques": components - singletons - linked,
+        "linkage_calls": linked,
+        "rows_linked": rows_linked,
+        "largest": int(size.max()) if n_comp else min(n, 1),
+    }
 
 
 @functools.partial(jax.jit, static_argnames=())

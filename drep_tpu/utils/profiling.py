@@ -382,6 +382,13 @@ class Counters:
     # calls, genomes, hashes and the distinct ids they became, summed. The
     # pack's seconds follow the hashes it sorts (ISSUE 28)
     primary_pack: dict[str, int] = field(default_factory=dict)
+    # what the dense primary's linkage did (ops/linkage.py::
+    # cluster_by_components, ISSUE 37): genomes, the components of the graph
+    # of pairs under the cutoff, how many were singletons, how many were
+    # settled with no linkage call (`cliques`), the scipy calls and the
+    # genomes they held, the largest component, and whether the whole tree
+    # was built beside them (`tree`: built | skipped)
+    primary_linkage: dict[str, Any] = field(default_factory=dict)
     # what `WorkDirectory.store_db` wrote, a table name (ISSUE 30): calls,
     # rows, bytes, the table's values and the distinct texts the columnar
     # writer rendered for them, and the calls that went through pandas'
@@ -571,6 +578,12 @@ class Counters:
         booked = {"calls": 1, "genomes": genomes, "hashes": hashes, "distinct_ids": distinct_ids}
         for name, value in booked.items():
             self.primary_pack[name] = self.primary_pack.get(name, 0) + int(value)
+
+    def add_primary_linkage(self, tree: str, **did: int) -> None:
+        """Book one `cluster_by_components` of the dense primary: `did` is
+        what it returned beside the labels, `tree` whether the job also built
+        the whole tree for the dendrogram."""
+        self.primary_linkage = {**{name: int(value) for name, value in did.items()}, "tree": tree}
 
     def add_table_write(
         self, table: str, rows: int, bytes: int, values: int, distinct: int, fallback: str | None
@@ -880,6 +893,8 @@ class Counters:
             out["secondary_greedy_batched"] = dict(self.greedy_batched)
         if self.primary_pack:
             out["primary_pack"] = dict(self.primary_pack)
+        if self.primary_linkage:
+            out["primary_linkage"] = dict(self.primary_linkage)
         if self.tables_write:
             out["tables_write"] = {name: dict(ent) for name, ent in sorted(self.tables_write.items())}
         if self.ingest:
@@ -951,6 +966,7 @@ class Counters:
         self.greedy_calls.clear()
         self.greedy_batched.clear()
         self.primary_pack.clear()
+        self.primary_linkage.clear()
         self.tables_write.clear()
         self.ingest.clear()
         self.filter.clear()
