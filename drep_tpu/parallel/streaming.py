@@ -47,6 +47,7 @@ change).
 from __future__ import annotations
 
 import os
+import time
 
 import numpy as np
 
@@ -731,6 +732,15 @@ def streaming_mash_edges(
     tiles_done = 0  # upper-triangle tiles actually dispatched this call
     tiles_full = 0  # full-grid tiles of the same stripes (resumed: 0/0)
     tiles_skipped = 0  # schedule tiles pruned by the candidate bitmap
+    # per local device slot (the record's `primary_stream_slots`): pairs of
+    # the tiles first dispatched there, bytes of the pack put there, host
+    # seconds inside `ft.finalize` for those tiles; and over the computed
+    # stripes the turns they took: ceil(tiles / active slots), summed
+    slot_pairs = [0] * len(devices)
+    slot_put_bytes = [0] * len(devices)
+    slot_wait_s = [0.0] * len(devices)
+    stripes_computed = 0
+    turns = 0
     # per-tile device->host budget for the compact threshold path
     budget = min(EDGE_BUDGET, block * block)
     compact = _compact_tile()
@@ -739,13 +749,18 @@ def streaming_mash_edges(
         nonlocal ids_on, rev_on, counts_on, counts1d_on
         if ids_on is not None:
             return
+        operands = (ids_pal, ids_rev, counts_col, counts) if use_pallas else (ids, counts)
+        slot_put_bytes[:] = [sum(a.nbytes for a in operands)] * len(devices)
         # build BEFORE the first dispatch, outside the retry envelope: a
         # tile program that does not compile must end the run, not turn it
-        # into retries and CPU-recomputed tiles (parallel/faulttol.py). One
-        # device suffices — a compiler verdict does not depend on which
-        # chip it is for.
-        with counters.span("primary/put"):
-            _build_tile_programs(width, block, k, cutoff, use_pallas, devices[0])
+        # into retries and CPU-recomputed tiles (parallel/faulttol.py). For
+        # every device the walk can reach: an executable is bound to its
+        # device, so a slot's first tile would otherwise build its own
+        # inside the envelope; the round-robin gives slot s the walk's
+        # (s+1)-th tile, so a walk of fewer tiles than devices builds fewer.
+        with counters.span("primary/put", devices=len(devices), bytes=sum(slot_put_bytes)):
+            for dev in devices[: n_blocks * (n_blocks + 1) // 2]:
+                _build_tile_programs(width, block, k, cutoff, use_pallas, dev)
             if use_pallas:
                 ids_on = [jax.device_put(ids_pal, dev) for dev in devices]
                 rev_on = [jax.device_put(ids_rev, dev) for dev in devices]
@@ -781,7 +796,7 @@ def streaming_mash_edges(
         )
 
     def _compute_stripe_tiles(bi: int, epoch: int) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-        nonlocal pairs_computed, tiles_done, tiles_full, tiles_skipped
+        nonlocal pairs_computed, tiles_done, tiles_full, tiles_skipped, stripes_computed, turns
         if occ is not None and not occ[bi, max(bi, first_col_block):n_blocks].any():
             # fully-pruned stripe: no tile holds a candidate, so the dense
             # walk would retain nothing here — publish the (empty) shard
@@ -801,11 +816,15 @@ def streaming_mash_edges(
         # points below (the dense [block, block] readback measured as the
         # composite bottleneck on slow d2h links)
         tiles = []
-        with counters.span("primary/dispatch", bi=bi):
-            for bj in range(max(bi, first_col_block), n_blocks):
-                if occ is not None and not occ[bi, bj]:
-                    tiles_skipped += 1  # no candidate pair in this tile
-                    continue
+        cols = [
+            bj for bj in range(max(bi, first_col_block), n_blocks)
+            if occ is None or occ[bi, bj]  # else: no candidate pair in this tile
+        ]
+        tiles_skipped += n_blocks - max(bi, first_col_block) - len(cols)
+        stripes_computed += 1
+        turns += -(-len(cols) // len(ft.active))
+        with counters.span("primary/dispatch", bi=bi, tiles=len(cols)):
+            for bj in cols:
                 j0 = bj * block
                 diag = j0 == i0
 
@@ -847,8 +866,11 @@ def streaming_mash_edges(
                         diag=diag,
                     )
 
-                tiles.append((j0, diag, ft.submit(dispatch)))
-                pairs_computed += _real_pairs_in_tile(i0, j0, block, n)
+                pending = ft.submit(dispatch)
+                tiles.append((j0, diag, pending))
+                real = _real_pairs_in_tile(i0, j0, block, n)
+                slot_pairs[pending[1]] += real
+                pairs_computed += real
                 tiles_done += 1
         tiles_full += n_blocks
 
@@ -857,14 +879,16 @@ def streaming_mash_edges(
         row_dd: list[np.ndarray] = []
         # the host blocks here: each tile's watchdog-bounded wait, its
         # survivor count (a scalar sync) and the readback of its edges
-        with counters.span("primary/wait", bi=bi):
+        with counters.span("primary/wait", bi=bi, tiles=len(tiles)):
             for j0, diag, pending in tiles:
+                t_fin = time.perf_counter()
                 ki_d, kj_d, dd_d, cnt_d, d_full = ft.finalize(
                     pending,
                     cpu_fallback=lambda i0=i0, j0=j0, diag=diag: _cpu_fallback_tile(
                         ids, counts, i0, j0, block, k, cutoff, diag
                     ),
                 )
+                slot_wait_s[pending[1]] += time.perf_counter() - t_fin
                 cnt = int(cnt_d)  # sync point for this tile (scalar)
                 if cnt <= budget:
                     ki = np.asarray(ki_d)[:cnt]
@@ -970,6 +994,9 @@ def streaming_mash_edges(
             # read like one that used the host's four
             counters.set_gauge(
                 "streaming_devices_used", float(sum(1 for d in ft.dispatched if d))
+            )
+            counters.add_stream_slots(
+                stripes_computed, turns, ft.dispatched, slot_pairs, slot_put_bytes, slot_wait_s
             )
         derived = ft.derived_timeout_s()
         if derived is not None:

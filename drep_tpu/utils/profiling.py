@@ -389,6 +389,14 @@ class Counters:
     # genomes they held, the largest component, and whether the whole tree
     # was built beside them (`tree`: built | skipped)
     primary_linkage: dict[str, Any] = field(default_factory=dict)
+    # how the streaming primary dealt its tiles over the local devices
+    # (parallel/streaming.py, ISSUE 39): the computed `stripes`, their
+    # `tiles`, the `turns` they took (ceil(tiles of a stripe / active
+    # slots), summed), the `slots`, and `by_slot`, one entry a device slot
+    # in slot order: `tiles` first dispatched there, their `pairs`, the
+    # `put_bytes` of the pack put there, `finalize_wait_s` the host spent
+    # inside `TileExecutor.finalize` for them
+    stream_slots: dict[str, Any] = field(default_factory=dict)
     # what `WorkDirectory.store_db` wrote, a table name (ISSUE 30): calls,
     # rows, bytes, the table's values and the distinct texts the columnar
     # writer rendered for them, and the calls that went through pandas'
@@ -584,6 +592,24 @@ class Counters:
         what it returned beside the labels, `tree` whether the job also built
         the whole tree for the dendrogram."""
         self.primary_linkage = {**{name: int(value) for name, value in did.items()}, "tree": tree}
+
+    def add_stream_slots(
+        self, stripes: int, turns: int, tiles: list[int], pairs: list[int],
+        put_bytes: list[int], finalize_wait_s: list[float],
+    ) -> None:
+        """Book one streaming edge walk: per-slot lists in slot order, summed
+        slot by slot over the walks of a job."""
+        booked = self.stream_slots
+        by_slot = booked.setdefault("by_slot", [])
+        names = ("tiles", "pairs", "put_bytes", "finalize_wait_s")
+        for slot, did in enumerate(zip(tiles, pairs, put_bytes, finalize_wait_s)):
+            if slot == len(by_slot):
+                by_slot.append(dict.fromkeys(names, 0))
+            for name, value in zip(names, did):
+                by_slot[slot][name] += value
+        for name, value in (("stripes", stripes), ("tiles", sum(tiles)), ("turns", turns)):
+            booked[name] = booked.get(name, 0) + int(value)
+        booked["slots"] = len(by_slot)
 
     def add_table_write(
         self, table: str, rows: int, bytes: int, values: int, distinct: int, fallback: str | None
@@ -895,6 +921,12 @@ class Counters:
             out["primary_pack"] = dict(self.primary_pack)
         if self.primary_linkage:
             out["primary_linkage"] = dict(self.primary_linkage)
+        if self.stream_slots:
+            out["primary_stream_slots"] = {
+                **self.stream_slots,
+                "by_slot": [{**ent, "finalize_wait_s": round(ent["finalize_wait_s"], 4)}
+                            for ent in self.stream_slots["by_slot"]],
+            }
         if self.tables_write:
             out["tables_write"] = {name: dict(ent) for name, ent in sorted(self.tables_write.items())}
         if self.ingest:
@@ -967,6 +999,7 @@ class Counters:
         self.greedy_batched.clear()
         self.primary_pack.clear()
         self.primary_linkage.clear()
+        self.stream_slots.clear()
         self.tables_write.clear()
         self.ingest.clear()
         self.filter.clear()
