@@ -295,7 +295,7 @@ def _primary_clusters(
                 kw["primary_estimator"],
             )
         ckpt = wd.get_dir(os.path.join("data", "streaming_primary")) if wd is not None else None
-        packed = engines.pack_primary(gs.bottom, gs.names, gs.sketch_size)
+        packed = engines.pack_primary(gs.bottom, gs.names, gs.sketch_size, kw["processes"])
         # --clusterAlg carries into the streaming path: average (default)
         # runs sparse UPGMA over the retained edge graph, single runs
         # connected components; anything else raises with guidance — no

@@ -30,7 +30,7 @@ from drep_tpu.cluster.dispatch import (
 )
 from drep_tpu.ingest import GenomeSketches
 from drep_tpu.ops.containment import all_vs_all_containment, pack_scaled_sketches
-from drep_tpu.ops.minhash import all_vs_all_mash, pack_sketches
+from drep_tpu.ops.minhash import all_vs_all_mash, pack_sketches, rank_route
 from drep_tpu.utils.profiling import counters
 
 # below this many genomes a multi-device ring costs more in collective
@@ -162,19 +162,23 @@ def mash_distance_matrix(
     return dist
 
 
-def pack_primary(bottom: list[np.ndarray], names: list[str], sketch_size: int):
+def pack_primary(bottom: list[np.ndarray], names: list[str], sketch_size: int, processes: int = 1):
     """`pack_sketches` for a primary compare, under the `primary/pack` span
-    (arg `hashes=`: what the pack sorts) and booked in the record's
-    `primary_pack`: the phase's seconds beside what they were spent on."""
+    and booked in the record's `primary_pack`: the phase's seconds beside
+    what they were spent on. The span's args say what the pack sorts
+    (`hashes=`) and how: `path=` native | numpy, on `workers=` threads of
+    the job's `-p` (`processes`)."""
     hashes = sum(min(len(b), sketch_size) for b in bottom)
-    with counters.span("primary/pack", hashes=hashes):
-        packed = pack_sketches(bottom, names, sketch_size)
+    path, threads = rank_route(hashes, processes)
+    with counters.span("primary/pack", hashes=hashes, path=path, workers=threads):
+        packed = pack_sketches(bottom, names, sketch_size, workers=processes)
     # rows ascend and every rank is used, so the largest id is some row's
     # last real entry: the vocabulary's size without a pass over the matrix
     full = packed.counts > 0
     last = packed.ids[full, packed.counts[full] - 1]
     counters.add_primary_pack(
-        genomes=packed.n, hashes=hashes, distinct_ids=int(last.max()) + 1 if last.size else 0
+        genomes=packed.n, hashes=hashes, distinct_ids=int(last.max()) + 1 if last.size else 0,
+        native=path == "native", threads=threads,
     )
     return packed
 
@@ -185,6 +189,7 @@ def primary_jax_mash(
     tile: int = 256,
     mesh_shape: int | None = None,
     primary_estimator: str = "auto",
+    processes: int = 1,
     **_,
 ) -> tuple[np.ndarray, np.ndarray]:
     """All-vs-all Mash distance from bottom-k sketches on device.
@@ -192,7 +197,7 @@ def primary_jax_mash(
     Returns (dist [N,N], similarity [N,N]) where similarity = 1 - dist
     (the Mdb convention).
     """
-    packed = pack_primary(gs.bottom, gs.names, gs.sketch_size)
+    packed = pack_primary(gs.bottom, gs.names, gs.sketch_size, processes)
     dist = mash_distance_matrix(
         packed, gs.k, mesh_shape=mesh_shape, tile=tile, estimator=primary_estimator
     )

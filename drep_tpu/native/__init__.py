@@ -1,13 +1,16 @@
-"""Native (C++) host-ingest bindings — ctypes, no pybind11.
+"""Native (C++) host-side bindings — ctypes, no pybind11.
 
-The hot host-side loop (FASTA -> canonical k-mers -> sketches; SURVEY.md §7
-step 2 / hard part (f)) has a C++ implementation in ingest.cc, built lazily
-with g++ into a content-addressed shared library cached next to the source.
-Without a compiler everything degrades to the numpy path (ops/kmers.py),
-so the framework never *requires* the native path — but never silently:
-a failed build is logged with the compiler's output, and every run
-records which path served (perf_counters.json note ``ingest_path``; the
-numpy path is more than an order of magnitude slower).
+Three sources, built lazily with g++ into ONE content-addressed shared
+library cached next to them: ingest.cc (FASTA -> canonical k-mers ->
+sketches; SURVEY.md §7 step 2 / hard part (f)), linkage.cc (sparse UPGMA
+over the streaming primary's retained edges) and rank.cc (the dense ranks
+of the primary pack's hashes, bucketed and threaded). Without a compiler
+everything degrades to the numpy / python path (ops/kmers.py,
+ops/linkage.py, ops/minhash.py), so the framework never *requires* the
+native path — but never silently: a failed build is logged with the
+compiler's output, and every run records which path served
+(perf_counters.json note ``ingest_path``, ``primary_pack.native_calls``;
+the numpy ingest is more than an order of magnitude slower).
 
 DREP_TPU_NO_NATIVE=1 disables the native path entirely (used by the
 equivalence tests to pin the numpy oracle).
@@ -26,7 +29,7 @@ import numpy as np
 from drep_tpu.utils.logger import get_logger
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
-_SOURCES = [os.path.join(_HERE, "ingest.cc"), os.path.join(_HERE, "linkage.cc")]
+_SOURCES = [os.path.join(_HERE, name) for name in ("ingest.cc", "linkage.cc", "rank.cc")]
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
@@ -48,7 +51,7 @@ class _DrepSketch(ctypes.Structure):
 
 
 def _build_library() -> str | None:
-    """Compile ingest.cc -> cached .so keyed on source hash; None on failure.
+    """Compile the sources -> cached .so keyed on their hash; None on failure.
 
     EVERYTHING here may fail — including makedirs when the package sits in
     a read-only site-packages — and then degrades to the numpy path, never
@@ -66,7 +69,7 @@ def _build_library() -> str | None:
             return so_path
         os.makedirs(build_dir, exist_ok=True)
         tmp = so_path + f".tmp{os.getpid()}"
-        cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", *_SOURCES, "-o", tmp, "-lz"]
+        cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread", *_SOURCES, "-o", tmp, "-lz"]
         res = subprocess.run(cmd, capture_output=True, text=True, timeout=300)
         if res.returncode != 0:
             get_logger().warning("native build failed: %s", res.stderr[-1000:])
@@ -123,6 +126,18 @@ def get_library() -> ctypes.CDLL | None:
             ctypes.c_double,
             ctypes.c_double,
             ctypes.POINTER(ctypes.c_int64),
+            ctypes.POINTER(ctypes.c_int64),
+        ]
+        lib.drep_rank_rows.restype = ctypes.c_int
+        lib.drep_rank_rows.argtypes = [
+            ctypes.POINTER(ctypes.c_void_p),
+            ctypes.POINTER(ctypes.c_int64),
+            ctypes.c_int64,
+            ctypes.c_int64,
+            ctypes.POINTER(ctypes.c_int32),
+            ctypes.c_int32,
+            ctypes.c_int,
+            ctypes.c_int64,
             ctypes.POINTER(ctypes.c_int64),
         ]
         _lib = lib
@@ -232,3 +247,31 @@ def sparse_upgma_native(
         get_logger().warning("native sparse UPGMA failed (rc=%d) — python fallback", rc)
         return None
     return labels, int(approx.value)
+
+
+def rank_rows_native(
+    rows: list[np.ndarray], out: np.ndarray, pad: int, threads: int, limit: int
+) -> int | None:
+    """Dense ranks of the hashes of `rows` (rank.cc: the number of distinct
+    hashes smaller, over all rows), row r's written to `out[r, :len(rows[r])]`
+    and `pad` past them, on `threads` threads. Returns the number of
+    distinct hashes — where it reaches `limit` the CALLER refuses, as the
+    NumPy path does, and `out` holds no rank — or None when the native
+    library is unavailable. `rows` are 1-D uint64, none longer than `out` is
+    wide; `out` is C-contiguous int32 and need not be initialised."""
+    lib = get_library()
+    if lib is None:
+        return None
+    rows = [np.ascontiguousarray(r) for r in rows]  # (itself where it is: a sketch's slice)
+    offsets = np.zeros(len(rows) + 1, dtype=np.int64)
+    np.cumsum([len(r) for r in rows], out=offsets[1:])
+    at = np.fromiter((r.ctypes.data for r in rows), dtype=np.uintp, count=len(rows))
+    distinct = ctypes.c_int64(0)
+    p = lambda a, t: a.ctypes.data_as(ctypes.POINTER(t))  # noqa: E731
+    rc = lib.drep_rank_rows(
+        p(at, ctypes.c_void_p), p(offsets, ctypes.c_int64), len(rows), out.shape[1],
+        p(out, ctypes.c_int32), int(pad), int(threads), int(limit), ctypes.byref(distinct),
+    )
+    if rc == 2:
+        raise MemoryError(f"native rank: no memory for {offsets[-1]} (hash, place) pairs")
+    return int(distinct.value)

@@ -35,14 +35,19 @@ transposed rest, exactly equal to the full grid at ~half the device work.
 from __future__ import annotations
 
 import functools
-import os
 from concurrent.futures import ThreadPoolExecutor
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from drep_tpu.ops.minhash import PAD_ID, PackedSketches, _fill_padded_rows, pad_packed_rows
+from drep_tpu.ops.minhash import (
+    PAD_ID,
+    PackedSketches,
+    _fill_padded_rows,
+    _usable_cores,
+    pad_packed_rows,
+)
 from drep_tpu.utils.profiling import counters
 
 
@@ -70,14 +75,6 @@ def pack_scaled_sketches(
     # below does loop over rows: a slice copy per row costs microseconds
     _fill_padded_rows(ids, np.searchsorted(vocab, flat), lens)
     return PackedSketches(ids=ids, counts=lens.astype(np.int32), names=list(names))
-
-
-def _usable_cores() -> int:
-    """Cores this process may run on (its affinity mask where the platform
-    has one: a container's share, not the machine's count)."""
-    if hasattr(os, "sched_getaffinity"):
-        return len(os.sched_getaffinity(0))
-    return os.cpu_count() or 1
 
 
 def clusterlocal_pack_workers(processes: int, n_groups: int) -> int:

@@ -19,6 +19,7 @@ Distance: ``d = -ln(2j / (1+j)) / k`` (the Mash distance), clipped to [0, 1].
 from __future__ import annotations
 
 import functools
+import os
 from dataclasses import dataclass
 
 import jax
@@ -97,12 +98,58 @@ def _fill_padded_rows(ids: np.ndarray, ranks: np.ndarray, lens: np.ndarray) -> N
         o += n
 
 
-def pack_sketches(sketches: list[np.ndarray], names: list[str], sketch_size: int) -> PackedSketches:
-    """uint64 bottom-k sketches (sorted unique) -> padded int32 id matrix."""
+def _usable_cores() -> int:
+    """Cores this process may run on (its affinity mask where the platform
+    has one: a container's share, not the machine's count)."""
+    if hasattr(os, "sched_getaffinity"):
+        return len(os.sched_getaffinity(0))
+    return os.cpu_count() or 1
+
+
+# hashes a thread of the rank kernel should have to itself: starting one
+# costs more than ranking fewer. On the chip host (13 cores; PERF.md section
+# 6, PR 40) 5,000 hashes took 0.2 ms on one thread and 3.6 ms on six, 1e5
+# 4.4 against 4.8 ms, 3.84e5 18.7 against 8.1 ms on four
+RANK_HASHES_PER_THREAD = 1 << 16
+
+
+def rank_route(hashes: int, workers: int = 1) -> tuple[str, int]:
+    """(path, threads) by which :func:`pack_sketches` ranks `hashes` uint64
+    hashes when its caller grants `workers`: `native` (native/rank.cc) where
+    the library is to be had, on `workers` threads capped by the usable
+    cores and by `RANK_HASHES_PER_THREAD`, else `numpy` on one. A function
+    of the hash count and the host, nothing else, so that a caller can say
+    beforehand what the pack will do."""
+    from drep_tpu import native
+
+    if hashes == 0 or native.get_library() is None:
+        return "numpy", 1
+    return "native", max(1, min(int(workers), _usable_cores(), hashes // RANK_HASHES_PER_THREAD))
+
+
+def pack_sketches(
+    sketches: list[np.ndarray], names: list[str], sketch_size: int, workers: int = 1
+) -> PackedSketches:
+    """uint64 bottom-k sketches (sorted unique) -> padded int32 id matrix.
+    `workers` is the thread width the native rank kernel may take (the
+    job's `-p`); the matrix is the same bytes at any width and by the NumPy
+    lines below, which serve without the library and are the tests' oracle."""
     if len(sketches) != len(names):
         raise ValueError("sketches and names length mismatch")
     trimmed = [s[:sketch_size] for s in sketches]
     lens = np.array([len(s) for s in trimmed], dtype=np.int64)
+    path, threads = rank_route(int(lens.sum()), workers)
+    # (rows of another dtype are NumPy's to promote and order, as they were)
+    if path == "native" and all(s.dtype == np.uint64 for s in trimmed):
+        from drep_tpu.native import rank_rows_native
+
+        # the kernel reads the rows where they lie and writes the whole
+        # matrix, padding included; it counts the vocabulary first
+        ids = np.empty((len(trimmed), sketch_size), dtype=np.int32)
+        limit = np.iinfo(np.int32).max
+        if rank_rows_native(trimmed, ids, PAD_ID, threads, limit) >= limit:
+            raise ValueError("id space overflow: >2^31 distinct sketch hashes")
+        return PackedSketches(ids=ids, counts=lens.astype(np.int32), names=list(names))
     ids = np.full((len(trimmed), sketch_size), PAD_ID, dtype=np.int32)
     flat = np.concatenate(trimmed) if trimmed else np.empty(0, np.uint64)
     if flat.size:

@@ -380,7 +380,9 @@ class Counters:
     greedy_batched: dict[str, int] = field(default_factory=dict)
     # what the primary's packs ranked (cluster/engines.py::pack_primary):
     # calls, genomes, hashes and the distinct ids they became, summed. The
-    # pack's seconds follow the hashes it sorts (ISSUE 28)
+    # pack's seconds follow the hashes it sorts (ISSUE 28) and the path that
+    # ranked them: `native_calls` of the calls went through native/rank.cc,
+    # the widest on `threads` threads (ISSUE 40)
     primary_pack: dict[str, int] = field(default_factory=dict)
     # what the dense primary's linkage did (ops/linkage.py::
     # cluster_by_components, ISSUE 37): genomes, the components of the graph
@@ -580,12 +582,18 @@ class Counters:
         for name, value in booked.items():
             self.greedy_batched[name] = self.greedy_batched.get(name, 0) + int(value)
 
-    def add_primary_pack(self, genomes: int, hashes: int, distinct_ids: int) -> None:
+    def add_primary_pack(
+        self, genomes: int, hashes: int, distinct_ids: int, native: bool = False, threads: int = 1
+    ) -> None:
         """Book one `pack_sketches` of the primary compare: `hashes` bottom-k
-        hashes of `genomes` rows became `distinct_ids` int32 ranks."""
-        booked = {"calls": 1, "genomes": genomes, "hashes": hashes, "distinct_ids": distinct_ids}
+        hashes of `genomes` rows became `distinct_ids` int32 ranks, by the
+        native kernel (`native_calls`) or NumPy, on `threads` threads (the
+        record keeps the widest of the job's calls)."""
+        booked = {"calls": 1, "native_calls": int(native), "genomes": genomes, "hashes": hashes,
+                  "distinct_ids": distinct_ids}
         for name, value in booked.items():
             self.primary_pack[name] = self.primary_pack.get(name, 0) + int(value)
+        self.primary_pack["threads"] = max(self.primary_pack.get("threads", 0), int(threads))
 
     def add_primary_linkage(self, tree: str, **did: int) -> None:
         """Book one `cluster_by_components` of the dense primary: `did` is

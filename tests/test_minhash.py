@@ -121,6 +121,10 @@ def _shared(rng, rows, take, extra=0, pool=600):
             for _ in range(rows)]
 
 
+def _under(rng, n, top):
+    return np.unique(rng.integers(0, top, size=n, dtype=np.uint64))
+
+
 # name -> (rng -> sketches, sketch_size); built inside its own case only
 _PACK_CASES = {
     "all_rows_full": (lambda rng: [_hashes(rng, 80)[:64] for _ in range(9)], 64),
@@ -133,18 +137,47 @@ _PACK_CASES = {
     "one_genome": (lambda rng: [_hashes(rng, 40)], 64),
     "no_genome": (lambda rng: [], 64),
     "rows_2000_of_1000": (lambda rng: [s[:1000] for s in _shared(rng, 2000, 300, extra=800)], 1000),
+    # ISSUE 40, the kernel's buckets: 30,000 hashes are cut into 8 by their
+    # top three bits under the largest
+    "every_hash_in_one_bucket": (lambda rng: [np.arange(7, 607, dtype=np.uint64) + 2**40] * 50, 600),
+    "hashes_crowd_the_low_buckets": (
+        lambda rng: [_under(rng, 700, 2**57 if i % 3 else 2**57 // 100)[:600] for i in range(60)], 600),
+    "fewer_hashes_than_threads": (lambda rng: [_hashes(rng, 2), _hashes(rng, 0), _hashes(rng, 1)], 4),
 }
+
+# how the ranks are computed: NumPy's lines, or native/rank.cc on so many threads
+_PACK_PATHS = {"numpy": None, "native_x1": 1, "native_x2": 2, "native_x6": 6}
+
+
+@pytest.fixture(params=list(_PACK_PATHS))
+def pack_workers(request, monkeypatch):
+    """`workers` for `pack_sketches`, with the path it names made the one
+    that serves: the kill switch for NumPy, six usable cores for native."""
+    from drep_tpu import native
+
+    workers = _PACK_PATHS[request.param]
+    if workers is None:
+        monkeypatch.setenv("DREP_TPU_NO_NATIVE", "1")
+        assert minhash.rank_route(10, 6) == ("numpy", 1)
+        return 6
+    if native.get_library() is None:
+        pytest.skip("native library unavailable (no g++?)")
+    monkeypatch.setattr(minhash, "_usable_cores", lambda: 6)
+    monkeypatch.setattr(minhash, "RANK_HASHES_PER_THREAD", 1)  # the toy cases on every thread asked for
+    assert minhash.rank_route(10, workers) == ("native", workers)
+    return workers
 
 
 @pytest.mark.parametrize("case", list(_PACK_CASES))
-def test_pack_sketches_is_byte_equal_to_the_searching_spelling(case):
+def test_pack_sketches_is_byte_equal_to_the_searching_spelling(case, pack_workers):
     """ISSUE 28: ranks from one sort and a running count of run starts are
-    the ranks a binary search into the sorted vocabulary finds."""
+    the ranks a binary search into the sorted vocabulary finds. ISSUE 40:
+    so are the native kernel's, at every thread width."""
     build, sketch_size = _PACK_CASES[case]
     sketches = build(np.random.default_rng(28))
     names = [f"g{i}" for i in range(len(sketches))]
     want_ids, want_counts = _pack_by_search(sketches, names, sketch_size)
-    packed = minhash.pack_sketches(sketches, names, sketch_size)
+    packed = minhash.pack_sketches(sketches, names, sketch_size, workers=pack_workers)
     assert packed.ids.dtype == np.int32 and packed.counts.dtype == np.int32
     assert packed.ids.shape == want_ids.shape == (len(sketches), sketch_size)
     assert packed.ids.tobytes() == want_ids.tobytes()
@@ -152,10 +185,41 @@ def test_pack_sketches_is_byte_equal_to_the_searching_spelling(case):
     assert packed.names == names and packed.names is not names
 
 
-def test_pack_sketches_refuses_a_vocabulary_beyond_int32(monkeypatch):
-    """The check reads the vocabulary's size before a rank is an int32."""
+def test_pack_sketches_refuses_a_vocabulary_beyond_int32(monkeypatch, pack_workers):
+    """The check reads the vocabulary's size before a rank is an int32: in
+    NumPy's lines, and as the limit the native kernel is handed."""
     monkeypatch.setattr(minhash.np, "iinfo", lambda dtype: type("I", (), {"max": 5})())
     sk = [np.arange(3, dtype=np.uint64), np.arange(2, 5, dtype=np.uint64)]
     with pytest.raises(ValueError, match="id space overflow"):
-        minhash.pack_sketches(sk, ["a", "b"], 8)
-    assert minhash.pack_sketches([sk[0], sk[0] + 1], ["a", "b"], 8).ids.max() == minhash.PAD_ID
+        minhash.pack_sketches(sk, ["a", "b"], 8, workers=pack_workers)
+    assert minhash.pack_sketches([sk[0], sk[0] + 1], ["a", "b"], 8, workers=pack_workers).ids.max() == minhash.PAD_ID
+
+
+def test_native_rank_kernel_says_overflow_by_its_return_and_writes_no_rank():
+    """The kernel counts the vocabulary before it writes: at the limit it
+    returns the count with the matrix holding no rank, under it the ranks."""
+    from drep_tpu import native
+
+    if native.get_library() is None:
+        pytest.skip("native library unavailable (no g++?)")
+    rows = [np.array([9, 4, 4], np.uint64), np.array([2**64 - 1, 9], np.uint64)]  # unsorted, repeated
+    out = np.full((2, 4), -7, np.int32)
+    assert native.rank_rows_native(rows, out, pad=-1, threads=2, limit=3) == 3
+    assert not np.isin(out, [0, 1, 2]).any()
+    assert native.rank_rows_native(rows, out, pad=-1, threads=2, limit=4) == 3
+    assert out.tolist() == [[1, 0, 0, -1], [2, 1, -1, -1]]
+
+
+def test_rank_route_is_the_workers_capped_by_the_cores_and_the_hashes(monkeypatch):
+    from drep_tpu import native
+
+    if native.get_library() is None:
+        pytest.skip("native library unavailable (no g++?)")
+    monkeypatch.setattr(minhash, "_usable_cores", lambda: 4)
+    assert [minhash.rank_route(10**6, w) for w in (0, 1, 3, 6)] == [
+        ("native", 1), ("native", 1), ("native", 3), ("native", 4)]
+    # a thread needs RANK_HASHES_PER_THREAD hashes to itself: few hashes, few threads
+    per = minhash.RANK_HASHES_PER_THREAD
+    assert [minhash.rank_route(h, 6)[1] for h in (1, per, 2 * per - 1, 2 * per, 3 * per, 10 * per)] == [
+        1, 1, 1, 2, 3, 4]
+    assert minhash.rank_route(0, 6) == ("numpy", 1)  # nothing to rank

@@ -239,3 +239,36 @@ def test_native_fast_path_matches_oracle(tmp_path):
     assert len(oracle["scaled"]) >= SKETCH, "fixture too small for the fast path"
     assert oracle["n_kmers"] == len(oracle["scaled"]) * SCALE  # estimated
     _assert_equal(native, oracle)
+
+
+@needs_native
+def test_without_the_library_the_primary_pack_is_numpys_and_the_tables_are_the_same(
+    tmp_path, genome_paths, monkeypatch
+):
+    """ISSUE 40: `DREP_TPU_NO_NATIVE=1` sends the primary's pack through
+    NumPy's lines, the record says so (`native_calls` 0, one thread), and
+    the job's tables are the bytes the native kernel's job leaves."""
+    import json
+
+    from drep_tpu.workflows import compare_wrapper
+
+    def job(name):
+        wd = str(tmp_path / name)
+        compare_wrapper(wd, genome_paths, skip_plots=True, processes=2)
+        with open(os.path.join(wd, "log", "perf_counters.json")) as f:
+            pack = json.load(f)["primary_pack"]
+        tables = {}
+        for table in ("Cdb", "Mdb", "Ndb"):
+            with open(os.path.join(wd, "data_tables", table + ".csv"), "rb") as f:
+                tables[table] = f.read()
+        return pack, tables
+
+    pack, tables = job("native")
+    assert (pack["calls"], pack["native_calls"]) == (1, 1) and 1 <= pack["threads"] <= 2
+    monkeypatch.setenv("DREP_TPU_NO_NATIVE", "1")
+    pack_np, tables_np = job("numpy")
+    assert (pack_np["calls"], pack_np["native_calls"], pack_np["threads"]) == (1, 0, 1)
+    for how in ("native_calls", "threads"):  # what was ranked is the same
+        del pack[how], pack_np[how]
+    assert pack_np == pack
+    assert tables_np == tables and all(tables.values())

@@ -315,37 +315,95 @@ def test_events_on_writes_the_same_schema_and_trace_report_reads_the_new_names(t
 def test_primary_pack_says_what_it_ranked(toy_job):
     """ISSUE 28: the `primary/pack` span round `pack_sketches` carries
     `hashes=`, and the record's `primary_pack` counts the genomes, the
-    hashes and the distinct ids they became: NumPy's own count."""
+    hashes and the distinct ids they became: NumPy's own count. ISSUE 40:
+    both say which path ranked them and on how many threads (the toy job
+    runs at the wrappers' `processes` of 1)."""
+    from drep_tpu import native
     from drep_tpu.ingest import _load
     from drep_tpu.workdir import WorkDirectory
 
     gs = _load(WorkDirectory(toy_job["wd"]), 21, 1000, 200)
     flat = np.concatenate([b[:1000] for b in gs.bottom])
-    want = {"calls": 1, "genomes": len(gs.names), "hashes": len(flat),
-            "distinct_ids": len(np.unique(flat))}
+    is_native = native.get_library() is not None
+    want = {"calls": 1, "native_calls": int(is_native), "threads": 1, "genomes": len(gs.names),
+            "hashes": len(flat), "distinct_ids": len(np.unique(flat))}
     assert want["genomes"] == 5 and want["distinct_ids"] < want["hashes"]  # hashes are shared
     assert toy_job["record"]["primary_pack"] == want
     trace_report = _trace_report()
     spans, _ = trace_report.pair_spans(
         trace_report.load_events(os.path.join(toy_job["wd"], "log"))["events"])
     with_hashes = [sp["args"] for sp in spans if sp["ev"] == "primary/pack" and "hashes" in sp["args"]]
-    assert with_hashes == [{"hashes": want["hashes"]}]
+    assert with_hashes == [
+        {"hashes": want["hashes"], "path": "native" if is_native else "numpy", "workers": 1}]
 
 
-def test_primary_pack_counter_sums_its_calls_and_counts_no_padding():
+def test_primary_pack_counter_sums_its_calls_and_counts_no_padding(monkeypatch):
+    from drep_tpu import native
     from drep_tpu.cluster.engines import pack_primary
+    from drep_tpu.ops import minhash
     from drep_tpu.utils.profiling import counters
 
+    monkeypatch.setattr(minhash, "_usable_cores", lambda: 4)
+    monkeypatch.setattr(minhash, "RANK_HASHES_PER_THREAD", 1)
+    is_native = native.get_library() is not None
     counters.reset()
     assert "primary_pack" not in counters.report(device=False)
     u = lambda *v: np.array(v, np.uint64)  # noqa: E731
     pack_primary([], [], 4)
-    pack_primary([u(), u()], ["a", "b"], 4)  # rows of padding alone: no id
-    pack_primary([u(3, 5, 7, 9, 11), u(), u(5, 2**64 - 1)], ["a", "b", "c"], 4)  # 11 is cut
+    pack_primary([u(), u()], ["a", "b"], 4, 6)  # rows of padding alone: no id, nothing to rank
+    pack_primary([u(3, 5, 7, 9, 11), u(), u(5, 2**64 - 1)], ["a", "b", "c"], 4, 3)  # 11 is cut
+    pack_primary([u(1, 2)], ["d"], 4, 2)
+    # the calls that ranked hashes went through the kernel; `threads` is the widest of them
     assert counters.report(device=False)["primary_pack"] == {
-        "calls": 3, "genomes": 5, "hashes": 6, "distinct_ids": 5}
+        "calls": 4, "native_calls": 2 if is_native else 0, "threads": 3 if is_native else 1,
+        "genomes": 6, "hashes": 8, "distinct_ids": 7}
+    monkeypatch.setenv("DREP_TPU_NO_NATIVE", "1")
+    pack_primary([u(1, 2)], ["d"], 4, 6)
+    assert counters.report(device=False)["primary_pack"]["native_calls"] == (2 if is_native else 0)
     counters.reset()
     assert "primary_pack" not in counters.report(device=False)
+
+
+@pytest.mark.parametrize("processes", [1, 6])
+@pytest.mark.parametrize("route", ["dense", "streaming", "multiround"])
+def test_the_controller_hands_its_processes_down_to_the_primary_pack(route, processes, monkeypatch):
+    """ISSUE 40: every primary route packs at the job's `-p`, capped by the
+    cores: the record's `threads` and the span's `workers=` say so."""
+    import pandas as pd
+
+    from drep_tpu import native
+    from drep_tpu.cluster.controller import _fill_defaults, _primary_clusters
+    from drep_tpu.ingest import GenomeSketches
+    from drep_tpu.ops import minhash
+    from drep_tpu.utils.profiling import counters
+
+    if native.get_library() is None:
+        pytest.skip("native library unavailable (no g++?)")
+    monkeypatch.setattr(minhash, "_usable_cores", lambda: 8)
+    monkeypatch.setattr(minhash, "RANK_HASHES_PER_THREAD", 16)  # 256 hashes and more a pack: six threads may start
+    rng = np.random.default_rng(40)
+    n, s = 24, 64
+    names = [f"g{i}" for i in range(n)]
+    bottom = [np.unique(rng.integers(0, 2**64, size=s, dtype=np.uint64)) for _ in names]
+    gdb = pd.DataFrame({"genome": names, "length": 10**6, "N50": 50_000, "contigs": 10,
+                        "n_kmers": np.arange(n) + 900_000})
+    gs = GenomeSketches(names=names, gdb=gdb, bottom=bottom, scaled=bottom, k=21, sketch_size=s, scale=200)
+    kw = _fill_defaults({"processes": processes, **{
+        "dense": {}, "streaming": {"streaming_primary": True},
+        "multiround": {"multiround_primary_clustering": True, "primary_chunksize": 10},
+    }[route]})
+    seen = []
+    span = counters.span
+    monkeypatch.setattr(counters, "span", lambda name, calls=1, **args: (
+        seen.append(args) if name == "primary/pack" and "hashes" in args else None,
+        span(name, calls, **args))[1])
+    counters.reset()
+    _primary_clusters(gs, pd.DataFrame({"genome": names, "location": names}), kw)
+    booked = counters.report(device=False)["primary_pack"]
+    counters.reset()
+    assert booked["calls"] == booked["native_calls"] == len(seen) == (4 if route == "multiround" else 1)
+    assert booked["threads"] == processes
+    assert all(a["path"] == "native" and a["workers"] == processes and a["hashes"] > 0 for a in seen)
 
 
 _PROFILE_ON_A_POD = """
