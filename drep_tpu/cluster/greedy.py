@@ -33,7 +33,9 @@ from drep_tpu.ops.containment import (
     pack_scaled_sketches,
     rect_from_chunks,
     rect_from_chunks_sharded,
+    replicate_on_mesh,
     self_from_chunks,
+    shard_rows_on_mesh,
 )
 from drep_tpu.ops.minhash import PAD_ID
 from drep_tpu.ops.rangepart import vocab_extent
@@ -53,6 +55,27 @@ def _pad_pack(ids: np.ndarray, counts: np.ndarray, rows: list[int], pad_to: int)
         out_ids[: len(rows)] = ids[rows]
         out_counts[: len(rows)] = counts[rows]
     return out_ids, out_counts
+
+
+def _put_chunks(chunks: list[np.ndarray], booked: dict, side: str, mesh=None, replicated=False):
+    """Chunk tensors onto the device, or onto a mesh (row-sharded, or a copy
+    a device when `replicated`), under the span `secondary/greedy_put` and
+    booked in `booked[side]` by the bytes that cross the link: a replicated
+    put crosses once a device. The span ends when the arrays are there."""
+    import jax
+    import jax.numpy as jnp
+
+    n_dev = 1 if mesh is None else int(mesh.devices.size)
+    nbytes = sum(c.nbytes for c in chunks) * (n_dev if replicated else 1)
+    with counters.span("secondary/greedy_put", bytes=nbytes, devices=n_dev):
+        if mesh is None:
+            out = [jnp.asarray(c) for c in chunks]
+        else:
+            put = replicate_on_mesh if replicated else shard_rows_on_mesh
+            out = [put(c, mesh) for c in chunks]
+        jax.block_until_ready(out)
+    booked[side] += nbytes
+    return out
 
 
 def _ndb_from_rows(ndb_rows: list[dict], pc: int) -> pd.DataFrame:
@@ -148,9 +171,17 @@ def greedy_secondary_cluster(
     entry of the record's `secondary_greedy_calls`, and the spans
     `secondary/pack`, `secondary/greedy_layout` (the chunk geometry, every
     block's and every new representative's repack and pad, the
-    representatives' shipment), `secondary/greedy_wait` (a block's
-    transfer, its tiles against the representatives, its self comparison,
-    the readbacks) and `secondary/greedy_assign`.
+    representatives' shipment), `secondary/greedy_wait` (one a block: its
+    tiles against the representatives, its self comparison, the readbacks;
+    `devices=` says how many served) and `secondary/greedy_assign`. On the
+    matmul route every put of chunk tensors is a `secondary/greedy_put`
+    (`bytes=`, `devices=`) inside the span it belongs to: a block's chunks
+    inside `greedy_wait` (on a mesh once a representative tile and twice
+    for the self comparison, ROADMAP S3), a mesh's representative tiles
+    inside `greedy_layout` (a filled one, once) or `greedy_wait` (the
+    trailing one, once a block). The cluster's entry says who served and
+    what crossed (`mesh_devices`, `block_bytes`, `rep_bytes`,
+    `rep_tiles_replicated`, `partial_tile_ships`).
     """
     s_ani, cov_thresh = kw["S_ani"], kw["cov_thresh"]
     m = len(indices)
@@ -199,8 +230,10 @@ def greedy_secondary_cluster(
     # what the cluster's counter entry sums over its blocks
     # (Counters.add_greedy_call)
     booked = dict.fromkeys(
-        ("blocks", "rep_rows_shipped", "rep_rows_real", "id_slots", "device_calls"), 0
+        ("blocks", "rep_rows_shipped", "rep_rows_real", "id_slots", "device_calls",
+         "block_bytes", "rep_bytes", "rep_tiles_replicated", "partial_tile_ships"), 0
     )
+    n_dev = 1 if mesh is None else int(mesh.devices.size)
 
     if use_matmul:
         import jax.numpy as jnp
@@ -226,8 +259,6 @@ def greedy_secondary_cluster(
                 # device array is not incremental); FILLED rep tiles are
                 # replicated once and cached — only the trailing partial tile
                 # re-crosses the link per block
-                from drep_tpu.ops.containment import replicate_on_mesh
-
                 rep_chunks_host = [np.full((0, w), PAD_ID, np.int32) for w in geom.widths]
                 rep_tiles_cached: list[list] = []  # per filled tile: replicated chunks
         n_shipped = 0  # reps already resident on device / in the host store
@@ -263,6 +294,7 @@ def greedy_secondary_cluster(
                             jnp.concatenate([old, jnp.asarray(nc)]) if old.shape[0] else jnp.asarray(nc)
                             for old, nc in zip(rep_chunks_dev, new_chunks)
                         ]
+                        booked["rep_bytes"] += sum(nc.nbytes for nc in new_chunks)
                     else:
                         rep_chunks_host = [
                             np.concatenate([old, nc])
@@ -272,18 +304,15 @@ def greedy_secondary_cluster(
                         # change again (reps are append-only)
                         while (len(rep_tiles_cached) + 1) * rep_tile <= len(reps):
                             t = len(rep_tiles_cached)
-                            rep_tiles_cached.append([
-                                replicate_on_mesh(
-                                    rc[t * rep_tile : (t + 1) * rep_tile], mesh
-                                )
-                                for rc in rep_chunks_host
-                            ])
+                            rep_tiles_cached.append(_put_chunks(
+                                [rc[t * rep_tile : (t + 1) * rep_tile] for rc in rep_chunks_host],
+                                booked, "rep_bytes", mesh, replicated=True,
+                            ))
+                            booked["rep_tiles_replicated"] += 1
                     booked["id_slots"] += (len(reps) - n_shipped) * shape["widths"]
                     n_shipped = len(reps)
                 r_counts = np.zeros(rep_pad, np.int32)
                 r_counts[: len(reps)] = counts[reps]
-                # the block's chunk tensors go to device ONCE and serve both
-                # the vs-reps tiles and the self comparison
                 blk_chunks = [
                     np.pad(bc, ((0, block - nb), (0, 0)), constant_values=PAD_ID)
                     for bc in geom.rows_chunks(np.array(rows))
@@ -291,12 +320,14 @@ def greedy_secondary_cluster(
                 booked["id_slots"] += block * shape["widths"]
             with counters.span(
                 "secondary/greedy_wait", rows=nb, reps=len(reps), rep_pad=rep_pad,
-                chunks=geom.n_chunks,
+                chunks=geom.n_chunks, devices=n_dev,
             ):
                 # one program call a chunk: each rep tile, then the self comparison
                 booked["device_calls"] += (rep_pad // rep_tile + 1) * geom.n_chunks
                 if mesh is None:
-                    blk_dev = [jnp.asarray(bc) for bc in blk_chunks]
+                    # the block's chunk tensors go to device ONCE and serve both
+                    # the vs-reps tiles and the self comparison
+                    blk_dev = _put_chunks(blk_chunks, booked, "block_bytes")
                 inter = np.empty((block, rep_pad), np.float32)
                 for t0 in range(0, rep_pad, rep_tile):
                     if mesh is not None:
@@ -305,16 +336,23 @@ def greedy_secondary_cluster(
                             tile_chunks = rep_tiles_cached[ti]  # replicated, cached
                         else:
                             # trailing partial tile: host pad, shipped this block
-                            tile_chunks = [
-                                np.pad(
-                                    rc[t0 : t0 + rep_tile],
-                                    ((0, rep_tile - max(min(rc.shape[0] - t0, rep_tile), 0)), (0, 0)),
-                                    constant_values=PAD_ID,
-                                )
-                                for rc in rep_chunks_host
-                            ]
+                            tile_chunks = _put_chunks(
+                                [
+                                    np.pad(
+                                        rc[t0 : t0 + rep_tile],
+                                        ((0, rep_tile - max(min(rc.shape[0] - t0, rep_tile), 0)), (0, 0)),
+                                        constant_values=PAD_ID,
+                                    )
+                                    for rc in rep_chunks_host
+                                ],
+                                booked, "rep_bytes", mesh, replicated=True,
+                            )
+                            booked["partial_tile_ships"] += 1
+                        # on a mesh the block's chunks cross the link again for
+                        # every representative tile (and twice more below)
                         inter[:, t0 : t0 + rep_tile] = rect_from_chunks_sharded(
-                            blk_chunks, tile_chunks, geom.v_chunk, mesh
+                            _put_chunks(blk_chunks, booked, "block_bytes", mesh),
+                            tile_chunks, geom.v_chunk, mesh,
                         )
                     else:
                         tile_chunks = [
@@ -334,7 +372,9 @@ def greedy_secondary_cluster(
                 # rect call built two identical ones per block)
                 if mesh is not None:
                     inter_self = rect_from_chunks_sharded(
-                        blk_chunks, blk_chunks, geom.v_chunk, mesh
+                        _put_chunks(blk_chunks, booked, "block_bytes", mesh),
+                        _put_chunks(blk_chunks, booked, "block_bytes", mesh, replicated=True),
+                        geom.v_chunk, mesh,
                     ).astype(np.float32)
                 else:
                     inter_self = self_from_chunks(blk_dev, geom.v_chunk).astype(np.float32)
@@ -345,9 +385,13 @@ def greedy_secondary_cluster(
                 booked["id_slots"] += (block + rep_pad) * shape["widths"]
             with counters.span(
                 "secondary/greedy_wait", rows=nb, reps=len(reps), rep_pad=rep_pad, chunks=0,
+                devices=1,
             ):
-                # two gather tiles a representative tile, one for the block itself
+                # two gather tiles a representative tile, one for the block itself;
+                # each call ships both its operands (a jitted call on host arrays)
                 booked["device_calls"] += 2 * (rep_pad // rep_tile) + 1
+                booked["block_bytes"] += (2 * (rep_pad // rep_tile) + 2) * b_ids.nbytes
+                booked["rep_bytes"] += 2 * r_ids.nbytes
                 cov_vs_reps = np.zeros((block, rep_pad), np.float32)
                 cov_rev_reps = np.zeros((block, rep_pad), np.float32)
                 for r0 in range(0, rep_pad, block):
@@ -402,6 +446,7 @@ def greedy_secondary_cluster(
         ndb = _ndb_from_rows(ndb_rows, pc)
     counters.add_greedy_call(
         rows=m, block_rows=block, reps=len(reps), extent=vocab_extent(ids),
-        hashes=int(counts.sum()), compared_pairs=len(ndb), **shape, **booked,
+        hashes=int(counts.sum()), compared_pairs=len(ndb), mesh_devices=n_dev,
+        **shape, **booked,
     )
     return ndb, labels

@@ -643,29 +643,40 @@ def replicate_on_mesh(arr: np.ndarray, mesh):
     return put_global(arr, NamedSharding(mesh, P(None, None)))
 
 
+def shard_rows_on_mesh(arr: np.ndarray, mesh):
+    """Device-put a host array with its rows split over the mesh devices —
+    the layout of `rect_from_chunks_sharded`'s A side (the row count must
+    divide the mesh size)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from drep_tpu.parallel.allpairs import put_global
+    from drep_tpu.parallel.mesh import AXIS
+
+    return put_global(arr, NamedSharding(mesh, P(AXIS, None)))
+
+
 def rect_from_chunks_sharded(a_chunks, b_chunks, v_chunk: int, mesh) -> np.ndarray:
     """`rect_from_chunks` with the A rows sharded across a device mesh and
     B replicated — the greedy engine's candidate-block parallelism
     (BASELINE config 5: 100k greedy dereplicate on a multi-chip mesh).
     A's row count must divide the mesh size (callers pad blocks to a
-    device multiple). B chunks may be host arrays (shipped replicated
-    here) or already-replicated device arrays from
-    :func:`replicate_on_mesh` (zero link traffic). The result gathers via
-    the multi-host-safe allgather path, not np.asarray (remote shards
-    have no local buffers on a pod)."""
+    device multiple). Either side's chunks may be host arrays (shipped
+    here: A row-sharded, B replicated) or device arrays already laid out
+    so (:func:`shard_rows_on_mesh`, :func:`replicate_on_mesh`; zero link
+    traffic, and a caller that books its transfers makes them itself). The
+    result gathers via the multi-host-safe allgather path, not np.asarray
+    (remote shards have no local buffers on a pod)."""
     import jax
-    from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from drep_tpu.parallel.allpairs import gather_global, put_global
-    from drep_tpu.parallel.mesh import AXIS
+    from drep_tpu.parallel.allpairs import gather_global
 
     dt = _indicator_dtype(max(a_chunks[0].shape[1], b_chunks[0].shape[1]))
     fn = _rect_sharded_fn(v_chunk, str(np.dtype(dt)), mesh)
-    row_sh = NamedSharding(mesh, P(AXIS, None))
     acc = None
     for a_c, b_c in zip(a_chunks, b_chunks):
+        a_d = a_c if isinstance(a_c, jax.Array) else shard_rows_on_mesh(np.asarray(a_c), mesh)
         b_d = b_c if isinstance(b_c, jax.Array) else replicate_on_mesh(np.asarray(b_c), mesh)
-        part = fn(put_global(np.asarray(a_c), row_sh), b_d)
+        part = fn(a_d, b_d)
         acc = part if acc is None else acc + part
     return gather_global(acc)
 

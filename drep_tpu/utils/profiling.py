@@ -542,6 +542,8 @@ class Counters:
         self, rows: int, blocks: int, block_rows: int, reps: int, rep_tile: int,
         rep_rows_shipped: int, rep_rows_real: int, v_chunk: int, chunks: int, extent: int,
         widths: int, hashes: int, id_slots: int, device_calls: int, compared_pairs: int,
+        mesh_devices: int, rep_tiles_replicated: int, partial_tile_ships: int,
+        block_bytes: int, rep_bytes: int,
     ) -> None:
         """Book one primary cluster the greedy engine served: `rows` genomes
         holding `hashes` real ids over a vocabulary of `extent` went through
@@ -550,27 +552,44 @@ class Counters:
         blocks) padded to whole tiles of `rep_tile` rows (`rep_rows_shipped`:
         the padded rows, summed likewise) and against itself, over `chunks`
         vocabulary chunks of `v_chunk` ids whose id widths add up to
-        `widths`; `id_slots` int32 id slots crossed to the device
-        (`bytes_shipped`) for `device_calls` program calls, and the cluster
-        ended with `reps` representatives after `compared_pairs`
-        genome-against-representative comparisons (its Ndb rows) of the
-        `all_pairs` an all-pairs secondary makes. Off the matmul route
-        `v_chunk` and `chunks` are 0. One entry a cluster (`clusters` 1), in
-        the order met; past SECONDARY_SHAPES_MAX entries the rest is summed
-        into the last one, whose `clusters` says how many it holds."""
+        `widths`; `id_slots` int32 id slots had to reach the device
+        (`bytes_shipped`, each slot counted once) for `device_calls` program
+        calls, and the cluster ended with `reps` representatives after
+        `compared_pairs` genome-against-representative comparisons (its Ndb
+        rows) of the `all_pairs` an all-pairs secondary makes. Off the matmul
+        route `v_chunk` and `chunks` are 0.
+
+        Who served, and what really crossed the link: `mesh_devices` the
+        devices the blocks were sharded over (1 off a mesh), `block_bytes`
+        and `rep_bytes` the bytes of block rows and of representative rows
+        put on a device, each counted as often as it crossed (a replicated
+        put once a device), `rep_tiles_replicated` the filled representative
+        tiles a mesh was handed once, `partial_tile_ships` the trailing tile
+        shipped again with a block. On one device `block_bytes + rep_bytes`
+        is `bytes_shipped`.
+
+        One entry a cluster (`clusters` 1), in the order met; past
+        SECONDARY_SHAPES_MAX entries the rest is summed into the last one,
+        whose `clusters` says how many it holds (its `mesh_devices` the
+        fewest any of them had)."""
         booked = {name: int(value) for name, value in {
             "clusters": 1, "rows": rows, "blocks": blocks, "block_rows": block_rows,
             "reps": reps, "rep_tile": rep_tile, "rep_rows_shipped": rep_rows_shipped,
             "rep_rows_real": rep_rows_real, "v_chunk": v_chunk, "chunks": chunks,
             "extent": extent, "widths": widths, "hashes": hashes, "id_slots": id_slots,
             "device_calls": device_calls, "compared_pairs": compared_pairs,
-            "all_pairs": rows * (rows - 1) // 2, "bytes_shipped": 4 * id_slots}.items()}
+            "all_pairs": rows * (rows - 1) // 2, "bytes_shipped": 4 * id_slots,
+            "mesh_devices": mesh_devices, "rep_tiles_replicated": rep_tiles_replicated,
+            "partial_tile_ships": partial_tile_ships, "block_bytes": block_bytes,
+            "rep_bytes": rep_bytes}.items()}
         if len(self.greedy_calls) < SECONDARY_SHAPES_MAX:
             self.greedy_calls.append(booked)
             return
         rest = self.greedy_calls[-1]
+        fewest = min(rest["mesh_devices"], booked["mesh_devices"])
         for name, value in booked.items():
             rest[name] += value
+        rest["mesh_devices"] = fewest
 
     def add_greedy_batched(self, rows: int, compared_pairs: int) -> None:
         """Book one primary cluster of `rows` genomes that the greedy rule

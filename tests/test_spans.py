@@ -591,7 +591,9 @@ def test_the_greedy_job_s_record_names_its_routes_and_its_wait_span_carries_the_
                                                "all_pairs": 15}
     assert rec["stages"]["secondary_compare"]["pairs"] == call["compared_pairs"] + 5
     (wait,) = [sp for sp in spans if sp["ev"] == "secondary/greedy_wait"]
-    assert wait["args"] == {"rows": 40, "reps": 0, "rep_pad": call["rep_tile"], "chunks": 0}
+    assert wait["args"] == {"rows": 40, "reps": 0, "rep_pad": call["rep_tile"], "chunks": 0,
+                            "devices": 1}
+    assert call["mesh_devices"] == 1 and "secondary/greedy_put" not in rec["phases"]  # the gather route
     assert "secondary/wait" in rec["phases"]  # the batched route's one-shot call keeps its own name
 
 
@@ -601,9 +603,13 @@ def test_greedy_calls_list_a_cluster_each_and_stay_bounded():
     c = Counters()
     booked = dict(rows=300, blocks=3, block_rows=128, reps=5, rep_tile=512, rep_rows_shipped=1536,
                   rep_rows_real=9, v_chunk=262144, chunks=2, extent=400_000, widths=49152,
-                  hashes=6 * 10**6, id_slots=10**7, device_calls=12, compared_pairs=1200)
+                  hashes=6 * 10**6, id_slots=10**7, device_calls=12, compared_pairs=1200,
+                  mesh_devices=4, rep_tiles_replicated=1, partial_tile_ships=2,
+                  block_bytes=3 * 10**8, rep_bytes=10**8)
     for i in range(profiling.SECONDARY_SHAPES_MAX + 5):
-        c.add_greedy_call(**{**booked, "rows": 300 + i})
+        # one of the clusters past the cap fell to one device: the summed entry has to say so
+        fell = i == profiling.SECONDARY_SHAPES_MAX + 2
+        c.add_greedy_call(**{**booked, "rows": 300 + i, "mesh_devices": 1 if fell else 4})
     c.add_greedy_batched(rows=4, compared_pairs=3)
     c.add_greedy_batched(rows=2, compared_pairs=1)
     rep = c.report(device=False)
@@ -612,12 +618,60 @@ def test_greedy_calls_list_a_cluster_each_and_stay_bounded():
     assert calls[0] == {**booked, "clusters": 1, "all_pairs": 300 * 299 // 2, "bytes_shipped": 4 * 10**7}
     assert [e["rows"] for e in calls[:3]] == [300, 301, 302]  # in the order met
     assert calls[-1]["clusters"] == 6 and calls[-1]["compared_pairs"] == 6 * 1200  # the rest, summed
+    assert calls[-1]["block_bytes"] == 6 * 3 * 10**8 and calls[-1]["mesh_devices"] == 1  # the fewest
+    assert calls[-2]["mesh_devices"] == 4
     assert sum(e["clusters"] for e in calls) == profiling.SECONDARY_SHAPES_MAX + 5
     assert rep["secondary_greedy_batched"] == {"clusters": 2, "rows": 6, "compared_pairs": 4,
                                                "all_pairs": 7}
     c.reset()
     rep = c.report(device=False)
     assert "secondary_greedy_calls" not in rep and "secondary_greedy_batched" not in rep
+
+
+@pytest.mark.parametrize("devices", [1, 4])
+def test_greedy_put_and_greedy_wait_partition_what_greedy_wait_covered(monkeypatch, devices):
+    """ISSUE 42: on the engine's matmul route every put of chunk tensors is a
+    `secondary/greedy_put` span inside the span that used to hold it unnamed,
+    off a mesh and on one: the block's inside `greedy_wait`, a mesh's filled
+    representative tile inside `greedy_layout`. So the wait's seconds are what
+    they were, its self seconds and the puts inside it partition them, and
+    the three self-second readers still add up to the engine's spans."""
+    import pandas as pd
+
+    from drep_tpu.cluster.greedy import greedy_secondary_cluster
+    from drep_tpu.ingest import GenomeSketches
+    from drep_tpu.utils.profiling import counters
+
+    # 640 genomes a fifth alike: each founds its own group, so on four devices (blocks of 512)
+    # the representatives fill a tile, which a mesh replicates inside `greedy_layout`
+    rng = np.random.default_rng(42)
+    core = rng.integers(0, 1 << 40, size=60, dtype=np.uint64)
+    scaled = [np.unique(np.concatenate([core, rng.integers(1 << 41, 1 << 62, size=240, dtype=np.uint64)]))
+              for _ in range(640)]
+    names = [f"g{i}" for i in range(640)]
+    gs = GenomeSketches(names=names, gdb=pd.DataFrame({"genome": names, "n_kmers": range(10_640, 10_000, -1)}),
+                        bottom=[s[:100] for s in scaled], scaled=scaled, k=21, sketch_size=100, scale=200)
+    monkeypatch.setenv("DREP_TPU_GREEDY_MATMUL", "1")
+    monkeypatch.setenv("DREP_TPU_EVENTS", "off")
+    counters.reset()
+    greedy_secondary_cluster(gs, None, list(range(640)), pc=1,
+                             kw={"S_ani": 0.95, "cov_thresh": 0.1, "mesh_shape": devices})
+    ph = counters.report(device=False)["phases"]
+    put, wait, layout = (ph["secondary/" + n] for n in ("greedy_put", "greedy_wait", "greedy_layout"))
+    assert put["self_seconds"] == pytest.approx(put["seconds"], abs=2e-4)  # a put holds no span
+    inside_wait = wait["seconds"] - wait["self_seconds"]
+    inside_layout = layout["seconds"] - layout["self_seconds"]
+    # the record rounds each number to a tenth of a millisecond
+    assert inside_wait > 0 and inside_wait + inside_layout == pytest.approx(put["seconds"], abs=5e-4)
+    blocks = -(-640 // (128 * devices))
+    assert wait["calls"] == blocks  # still a span a block
+    if devices == 1:
+        assert abs(inside_layout) < 2e-4 and put["calls"] == blocks  # a block crosses once
+    else:
+        # the trailing tile and the block for the tile, the block twice for itself; then the
+        # filled tile, inside the layout, and the second block: for the tile, twice for itself
+        assert inside_layout > 2e-4 and put["calls"] == 4 + 1 + 3
+
 
 
 # --- stage:evaluate: the job's own columns, or the tables read back (ISSUE 35) ---
