@@ -304,7 +304,7 @@ def sketch_genomes(
     args_snapshot = sketch_args_snapshot(bdb["genome"], k, sketch_size, scale, hash_name)
 
     if wd is not None and wd.has_arrays("sketches") and wd.arguments_match("sketch", args_snapshot):
-        cached = _load(wd, k, sketch_size, scale)
+        cached = _load(wd, k, sketch_size, scale, processes)
         if not (cached.gdb["n_kmers"] == 0).any():
             logger.info("loading cached sketches from workdir")
             return cached
@@ -566,7 +566,7 @@ def read_genomes(
                     f"(not FASTA, empty, or shorter than k): {shown}"
                 )
             if wd.has_arrays("sketches") and wd.arguments_match("sketch", args_snapshot):
-                cached = _load(wd, k, sketch_size, scale)
+                cached = _load(wd, k, sketch_size, scale, processes)
                 if not (cached.gdb["n_kmers"] == 0).any():
                     logger.info(
                         "ingest: peer assembled the whole-run cache first — using it"
@@ -712,8 +712,13 @@ def _save(wd: WorkDirectory, gs: GenomeSketches) -> None:
     wd.store_db(gs.gdb, "Gdb")
 
 
-def _load(wd: WorkDirectory, k: int, sketch_size: int, scale: int) -> GenomeSketches:
-    arrs = wd.get_arrays("sketches")
+def _load(wd: WorkDirectory, k: int, sketch_size: int, scale: int, processes: int = 1) -> GenomeSketches:
+    """The whole-run cache :func:`_save` wrote, its parts read on up to
+    `processes` threads; the record's `sketch_cache_read` says how."""
+    from drep_tpu.utils.profiling import counters
+
+    arrs, read = wd.read_arrays("sketches", workers=processes)
+    counters.add_sketch_cache_read(**read)
     names = [str(x) for x in arrs["names"]]
     bottom = _unpack_ragged(arrs["bottom"], arrs["bottom_offsets"], len(names))
     scaled = _unpack_ragged(arrs["scaled"], arrs["scaled_offsets"], len(names))

@@ -19,12 +19,13 @@ Distance: ``d = -ln(2j / (1+j)) / k`` (the Mash distance), clipped to [0, 1].
 from __future__ import annotations
 
 import functools
-import os
 from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from drep_tpu.utils.hosttools import usable_cores as _usable_cores
 
 PAD_ID = np.int32(2**31 - 1)  # sorts after every real id; never counted
 U16_PAD = np.uint16(0xFFFF)  # pad sentinel of link-compressed uint16 id packs
@@ -96,14 +97,6 @@ def _fill_padded_rows(ids: np.ndarray, ranks: np.ndarray, lens: np.ndarray) -> N
     for row, n in zip(ids, lens):
         row[:n] = ranks[o : o + n]
         o += n
-
-
-def _usable_cores() -> int:
-    """Cores this process may run on (its affinity mask where the platform
-    has one: a container's share, not the machine's count)."""
-    if hasattr(os, "sched_getaffinity"):
-        return len(os.sched_getaffinity(0))
-    return os.cpu_count() or 1
 
 
 # hashes a thread of the rank kernel should have to itself: starting one

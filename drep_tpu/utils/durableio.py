@@ -27,7 +27,16 @@ site drifts off it):
   self-heals instead of crashing with ``BadZipFile``. Payloads written
   before checksums existed (no ``__crc__``/``"crc"``) stay readable —
   legacy-accepted, flagged by the scrubber (tools/scrub_store.py) but
-  never invalidated.
+  never invalidated. One payload has a second reader
+  (:func:`load_npz_member_into`, the workdir array store's parts): where
+  the FILE says it is a plain stored payload (zip method STORED, one
+  member of a fixed-size C-ordered dtype, ``__crc__`` beside it) the
+  member's bytes are ``readinto`` their destination and ``__crc__`` is
+  computed over them there; the zip's own CRC-32 is not read on that
+  path, because ``__crc__`` covers the same bytes and the name, dtype and
+  shape besides. Every other file, and every file when checksums are off,
+  is decoded there as :func:`load_npz_checked` decodes it (zipfile checks
+  its CRC) and verified as there.
 - **Transient-error retries**: ``EIO``/``ESTALE``/``ETIMEDOUT`` on read
   or write retry with bounded exponential backoff
   (``DREP_TPU_IO_RETRIES``, default 3; first delay
@@ -60,6 +69,7 @@ import contextlib
 import errno
 import io
 import json
+import math
 import os
 import time
 import uuid
@@ -270,6 +280,14 @@ def atomic_write_bytes(path: str, data) -> None:
 # -- in-band checksums ------------------------------------------------------
 
 
+def _checksum_header(name: str, dtype: np.dtype, shape: tuple, crc: int) -> int:
+    """`crc` run on over what the in-band checksum covers of an array
+    before its bytes: its member name, dtype and shape."""
+    crc = zlib.crc32(str(name).encode(), crc)
+    crc = zlib.crc32(str(dtype).encode(), crc)
+    return zlib.crc32(str(shape).encode(), crc)
+
+
 def checksum_arrays(arrays: dict[str, np.ndarray]) -> int:
     """crc32 over member names, dtypes, shapes, and raw bytes (sorted by
     name, CRC_KEY excluded) — pinned to the decoded arrays, not the zip
@@ -280,9 +298,7 @@ def checksum_arrays(arrays: dict[str, np.ndarray]) -> int:
         if name == CRC_KEY:
             continue
         a = np.ascontiguousarray(arrays[name])
-        crc = zlib.crc32(str(name).encode(), crc)
-        crc = zlib.crc32(str(a.dtype).encode(), crc)
-        crc = zlib.crc32(str(a.shape).encode(), crc)
+        crc = _checksum_header(name, a.dtype, a.shape, crc)
         try:
             # hash the buffer in place: a.tobytes() would transiently copy
             # the payload, doubling peak memory on the GB-scale sketch cache
@@ -312,26 +328,46 @@ def with_checksum(arrays: dict[str, np.ndarray]) -> dict[str, np.ndarray]:
     return out
 
 
-def verify_npz_payload(loaded: dict[str, np.ndarray], path: str, what: str) -> dict:
-    """Strip + verify the in-band checksum of an already-decoded payload.
-    Payloads with no ``__crc__`` are legacy-accepted (pre-checksum stores
-    must stay resumable); a present-but-wrong crc raises."""
-    if CRC_KEY not in loaded:
-        return loaded
+def _stored_checksum(crc_member, path: str, what: str) -> int:
+    """The value of a payload's ``__crc__`` member."""
     try:
-        stored = int(np.asarray(loaded.pop(CRC_KEY)).ravel()[0])
+        return int(np.asarray(crc_member).ravel()[0])
     except (IndexError, TypeError, ValueError) as e:
         # a rotted/empty __crc__ member is itself corruption — it must
         # classify, never crash (the corruption-never-crashes contract)
         raise CorruptPayloadError(
             f"{what} {path}: unreadable in-band checksum ({e!r})"
         ) from e
-    if crc_enabled() and checksum_arrays(loaded) != stored:
-        raise CorruptPayloadError(
-            f"{what} {path}: in-band checksum mismatch — the payload was "
-            f"corrupted after it was written"
-        )
+
+
+def _checksum_mismatch(path: str, what: str) -> CorruptPayloadError:
+    return CorruptPayloadError(
+        f"{what} {path}: in-band checksum mismatch — the payload was "
+        f"corrupted after it was written"
+    )
+
+
+def verify_npz_payload(loaded: dict[str, np.ndarray], path: str, what: str) -> dict:
+    """Strip + verify the in-band checksum of an already-decoded payload.
+    Payloads with no ``__crc__`` are legacy-accepted (pre-checksum stores
+    must stay resumable); a present-but-wrong crc raises."""
+    if CRC_KEY in loaded:
+        stored = _stored_checksum(loaded.pop(CRC_KEY), path, what)
+        if crc_enabled() and checksum_arrays(loaded) != stored:
+            raise _checksum_mismatch(path, what)
     return loaded
+
+
+def _member_data_offset(f, info) -> int | None:
+    """Where the data of the zip member `info` begins in the open file `f`
+    (its local file header holds the name and extra lengths at 26/28); None
+    where no local header stands at the member's offset."""
+    f.seek(info.header_offset)
+    local = f.read(30)
+    if len(local) != 30 or local[:4] != b"PK\x03\x04":
+        return None
+    return (info.header_offset + 30 + int.from_bytes(local[26:28], "little")
+            + int.from_bytes(local[28:30], "little"))
 
 
 def _flip_bit(path: str) -> None:
@@ -353,14 +389,7 @@ def _flip_bit(path: str) -> None:
             info = max(zf.infolist(), key=lambda i: i.compress_size)
         if info.compress_size > 0:
             with open(path, "rb") as f:
-                f.seek(info.header_offset)
-                hdr = f.read(30)  # local file header: lengths at 26/28
-            name_len = int.from_bytes(hdr[26:28], "little")
-            extra_len = int.from_bytes(hdr[28:30], "little")
-            off = (
-                info.header_offset + 30 + name_len + extra_len
-                + info.compress_size // 2
-            )
+                off = _member_data_offset(f, info) + info.compress_size // 2
     except Exception:  # noqa: BLE001 — not a zip: rot the middle byte
         off = None
     if off is None or off >= size:
@@ -405,18 +434,16 @@ def atomic_savez(
         _flip_bit(path)
 
 
-def read_npz_unverified(path: str, what: str = "payload") -> dict[str, np.ndarray]:
-    """Retried read + full decode with corrupt classification, but NO
-    checksum verification — the returned dict still carries its
-    ``__crc__`` member. The scrubber reads through this so it can
-    classify legacy (crc-less) payloads without a second open; everything
-    else wants :func:`load_npz_checked`."""
+def _read_npz(path: str, what: str, decode: Callable[[Any], Any], buffering: int = -1) -> Any:
+    """THE retried read of an npz: the ``io`` fault site, then
+    ``decode(the open file)``, once an attempt; transient OSErrors retried,
+    anything but an OSError that `decode` raises classified corrupt."""
     from drep_tpu.utils import faults
 
-    def read() -> dict[str, np.ndarray]:
+    def read():
         faults.fire_io("read", path=path)
-        with np.load(path, allow_pickle=False) as z:
-            return {k: z[k] for k in z.files}
+        with open(path, "rb", buffering=buffering) as f:
+            return decode(f)
 
     try:
         return retry_io(read, what=f"read {what}", path=path)
@@ -424,6 +451,20 @@ def read_npz_unverified(path: str, what: str = "payload") -> dict[str, np.ndarra
         raise
     except Exception as e:  # noqa: BLE001 — BadZipFile / EOF / pickle guard
         raise CorruptPayloadError(f"{what} {path}: unreadable ({e!r})") from e
+
+
+def _decode_npz(f) -> dict[str, np.ndarray]:
+    with np.load(f, allow_pickle=False) as z:
+        return {k: z[k] for k in z.files}
+
+
+def read_npz_unverified(path: str, what: str = "payload") -> dict[str, np.ndarray]:
+    """Retried read + full decode with corrupt classification, but NO
+    checksum verification — the returned dict still carries its
+    ``__crc__`` member. The scrubber reads through this so it can
+    classify legacy (crc-less) payloads without a second open; everything
+    else wants :func:`load_npz_checked`."""
+    return _read_npz(path, what, _decode_npz)
 
 
 def load_npz_checked(path: str, what: str = "payload") -> dict[str, np.ndarray]:
@@ -435,6 +476,156 @@ def load_npz_checked(path: str, what: str = "payload") -> dict[str, np.ndarray]:
     survive the retry budget surface as themselves (missing file, real
     permission trouble — answers, not corruption)."""
     return verify_npz_payload(read_npz_unverified(path, what), path, what)
+
+
+# The in-place reader takes a member in pieces of this size and runs the
+# checksum on over each while the cache still holds it. On the chip host
+# (PERF.md section 6, PR 43) 1.04 GB in 16 MiB parts took 0.89 s a part at
+# a time and 0.68 s in pieces of 256 KiB on six threads, 0.81 s in pieces
+# of 1 MiB; one thread loses what the read calls cost (1.26 against 1.56 s)
+READ_PIECE_BYTES = 256 << 10
+
+
+def _npy_header(f):
+    """``(shape, fortran_order, dtype)`` from the `.npy` header at `f`'s
+    position, `f` left at the array's first byte; None for a header version
+    numpy has no public reader of."""
+    from numpy.lib import format as npy
+
+    read = {(1, 0): npy.read_array_header_1_0, (2, 0): npy.read_array_header_2_0}.get(npy.read_magic(f))
+    return None if read is None else read(f)
+
+
+def npz_member_header(path: str, member: str) -> tuple[np.dtype, tuple] | None:
+    """Dtype and shape of the array `member` of the npz at `path`, from its
+    `.npy` header alone: no data read, no fault site fired, nothing raised.
+    None where they cannot be had so; :func:`load_npz_checked` says why."""
+    import zipfile
+
+    try:
+        with zipfile.ZipFile(path) as zf, zf.open(member + ".npy") as m:
+            shape, _, dtype = _npy_header(m)
+        return dtype, shape
+    except Exception:  # noqa: BLE001 — missing, torn, no such member, an odd header
+        return None
+
+
+def _stored_member(f, member: str):
+    """Where the one array of a PLAIN STORED payload lies in the open npz
+    `f`: ``(dtype, shape, offset of its bytes, the __crc__ member)``, or
+    None where the file does not say, beyond doubt, that it is one: a zip
+    holding `member` and ``__crc__`` and nothing else, both stored
+    uncompressed, `member` a C-ordered array of a fixed-size dtype (numbers,
+    strings, dates, records: anything but objects) whose bytes run to the
+    member's end.
+    Only OSErrors leave here: a file that cannot be read this way is the
+    decoding reader's to judge."""
+    import zipfile
+    from numpy.lib import format as npy
+
+    try:
+        with zipfile.ZipFile(f) as zf:
+            infos = {i.filename: i for i in zf.infolist()}
+            if sorted(infos) != sorted((member + ".npy", CRC_KEY + ".npy")) or any(
+                i.compress_type != zipfile.ZIP_STORED or i.flag_bits & 0x1 for i in infos.values()
+            ):
+                return None
+            with zf.open(CRC_KEY + ".npy") as m:
+                crc_member = npy.read_array(m, allow_pickle=False)
+        info = infos[member + ".npy"]
+        data = _member_data_offset(f, info)
+        if data is None:
+            return None
+        f.seek(data)
+        shape, fortran_order, dtype = _npy_header(f)
+        offset = f.tell()
+        nbytes = math.prod(shape) * dtype.itemsize
+        if (
+            fortran_order or dtype.hasobject or nbytes <= 0
+            or offset + nbytes != data + info.file_size
+            or offset + nbytes > os.fstat(f.fileno()).st_size
+        ):
+            return None
+        return dtype, shape, offset, crc_member
+    except OSError:
+        raise
+    except Exception:  # noqa: BLE001 — BadZipFile, a torn header: not this reader's to name
+        return None
+
+
+class PayloadShapeError(CorruptPayloadError):
+    """A sound payload whose array has another `dtype` or `shape` than the
+    array its reader was to fill."""
+
+    def __init__(self, message: str, dtype: np.dtype, shape: tuple):
+        super().__init__(message)
+        self.dtype, self.shape = dtype, shape
+
+
+def load_npz_member_into(path: str, member: str, dest: np.ndarray, what: str = "payload") -> bool:
+    """Read the array `member` of the checked payload at `path` into the
+    C-contiguous array `dest` (:class:`PayloadShapeError` where the file
+    holds another dtype or shape), verified before this returns. Read,
+    verify, place, by the one of two readers the FILE asks for; True where
+    it was the first:
+
+    - **in place**, where the file is a plain stored payload
+      (:func:`_stored_member`): the member's bytes are ``readinto`` `dest`
+      straight from their offset in the file, a piece of `READ_PIECE_BYTES`
+      at a time, and the in-band checksum (:func:`checksum_arrays`' value
+      for ``{member: array}``) is run on over each piece as it arrives. One
+      read, one CRC, no copy, no second array, and nothing that holds the
+      GIL, so a store's parts can be read on several threads. The zip's own
+      CRC-32 of the member is not read: ``__crc__`` covers the same bytes,
+      and the name, dtype and shape besides.
+    - **decoded** from the same open file as :func:`load_npz_checked`
+      decodes it, verified as there, then copied: a compressed payload, one
+      without ``__crc__`` or read with checksums off (the zip's CRC is then
+      the only check there is, and zipfile's reader makes it), an object
+      dtype, anything unparseable; the errors are that reader's.
+
+    Either way the file is opened once an attempt, and the transient-error
+    retries, the ``io`` fault site and :class:`CorruptPayloadError` are
+    :func:`load_npz_checked`'s, per call and on the calling thread."""
+
+    def must_fit(dtype: np.dtype, shape: tuple) -> None:
+        if (dtype, shape) != (dest.dtype, dest.shape):
+            raise PayloadShapeError(
+                f"{what} {path}: holds {dtype}{list(shape)}, its reader expects "
+                f"{dest.dtype}{list(dest.shape)}", dtype, shape)
+
+    def decode(f) -> dict[str, np.ndarray] | None:
+        found = _stored_member(f, member) if crc_enabled() else None
+        if found is None:
+            f.seek(0)
+            return _decode_npz(f)
+        dtype, shape, offset, crc_member = found
+        stored = _stored_checksum(crc_member, path, what)
+        must_fit(dtype, shape)
+        # as bytes through a uint8 view: a buffer of `<U..` or `M8` cannot be cast
+        buf = memoryview(dest.view(np.uint8)).cast("B")
+        crc = _checksum_header(member, dtype, shape, 0)
+        f.seek(offset)
+        for lo in range(0, len(buf), READ_PIECE_BYTES):
+            piece = buf[lo : lo + READ_PIECE_BYTES]
+            got = 0
+            while got < len(piece):
+                n = f.readinto(piece[got:])
+                if not n:
+                    raise CorruptPayloadError(f"{what} {path}: unreadable (truncated under the read)")
+                got += n
+            crc = zlib.crc32(piece, crc)
+        if crc & 0xFFFFFFFF != stored:
+            raise _checksum_mismatch(path, what)
+        return None
+
+    decoded = _read_npz(path, what, decode, buffering=0)
+    if decoded is None:
+        return True
+    payload = verify_npz_payload(decoded, path, what)[member]
+    must_fit(payload.dtype, payload.shape)
+    dest[...] = payload
+    return False
 
 
 def load_npz_or_none(path: str, what: str, convert: Callable[[dict], Any], warn: str) -> Any:

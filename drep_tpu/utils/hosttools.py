@@ -1,4 +1,5 @@
-"""Loader for host-side tools/ modules from library code.
+"""The host as library code sees it: the cores it may use (:func:`usable_cores`)
+and a loader for host-side tools/ modules.
 
 ``tools/`` is deliberately NOT a package (standalone operator scripts),
 but two library components consume ``tools/pod_status.py``'s
@@ -19,6 +20,14 @@ from __future__ import annotations
 import os
 
 _POD_STATUS: list = []
+
+
+def usable_cores() -> int:
+    """Cores this process may run on (its affinity mask where the platform
+    has one: a container's share, not the machine's count)."""
+    if hasattr(os, "sched_getaffinity"):
+        return len(os.sched_getaffinity(0))
+    return os.cpu_count() or 1
 
 
 def pod_status_collect():

@@ -384,6 +384,12 @@ class Counters:
     # ranked them: `native_calls` of the calls went through native/rank.cc,
     # the widest on `threads` threads (ISSUE 40)
     primary_pack: dict[str, int] = field(default_factory=dict)
+    # how the sketch cache was read back (ingest.py::_load, ISSUE 43): the
+    # `members` stored in parts, their `parts`, how many were read in place
+    # (`direct_parts`) and how many through `load_npz_checked`
+    # (`fallback_parts`), their `bytes`, the `threads` of the widest member
+    # and the `seconds` of `WorkDirectory.read_arrays`, summed over the reads
+    sketch_cache_read: dict[str, Any] = field(default_factory=dict)
     # what the dense primary's linkage did (ops/linkage.py::
     # cluster_by_components, ISSUE 37): genomes, the components of the graph
     # of pairs under the cutoff, how many were singletons, how many were
@@ -489,7 +495,8 @@ class Counters:
         fault firing) — and, with event tracing on, stamp WHEN it
         happened into the structured timeline (the counters keep the
         totals; the events keep the order)."""
-        self.faults[kind] = self.faults.get(kind, 0) + int(n)
+        with self._lock:  # the sketch cache's reader threads retry on their own
+            self.faults[kind] = self.faults.get(kind, 0) + int(n)
         telemetry.event("fault", kind=kind, n=int(n))
 
     def add_path(self, name: str) -> None:
@@ -613,6 +620,15 @@ class Counters:
         for name, value in booked.items():
             self.primary_pack[name] = self.primary_pack.get(name, 0) + int(value)
         self.primary_pack["threads"] = max(self.primary_pack.get("threads", 0), int(threads))
+
+    def add_sketch_cache_read(self, threads: int, seconds: float, **did: int) -> None:
+        """Book one `WorkDirectory.read_arrays` of the sketch cache: `did` is
+        what it counted (members, parts, direct_parts, fallback_parts, bytes)."""
+        booked = self.sketch_cache_read
+        for name, value in did.items():
+            booked[name] = booked.get(name, 0) + int(value)
+        booked["threads"] = max(booked.get("threads", 0), int(threads))
+        booked["seconds"] = booked.get("seconds", 0.0) + float(seconds)
 
     def add_primary_linkage(self, tree: str, **did: int) -> None:
         """Book one `cluster_by_components` of the dense primary: `did` is
@@ -946,6 +962,9 @@ class Counters:
             out["secondary_greedy_batched"] = dict(self.greedy_batched)
         if self.primary_pack:
             out["primary_pack"] = dict(self.primary_pack)
+        if self.sketch_cache_read:
+            out["sketch_cache_read"] = {**self.sketch_cache_read,
+                                        "seconds": round(self.sketch_cache_read["seconds"], 4)}
         if self.primary_linkage:
             out["primary_linkage"] = dict(self.primary_linkage)
         if self.stream_slots:
@@ -1025,6 +1044,7 @@ class Counters:
         self.greedy_calls.clear()
         self.greedy_batched.clear()
         self.primary_pack.clear()
+        self.sketch_cache_read.clear()
         self.primary_linkage.clear()
         self.stream_slots.clear()
         self.tables_write.clear()

@@ -155,7 +155,13 @@ class WorkDirectory:
         a checked payload of its own) and the head ``<name>.npz`` records
         the parts' lengths under ``__parts__<key>``. The head is the commit
         point — removed first, published last — so a kill mid-save leaves
-        an absent cache, never a head over another save's parts."""
+        an absent cache, never a head over another save's parts.
+
+        A part written with `compressed=False` is what :meth:`get_arrays`
+        reads in place: a zip of two STORED members, `part` and
+        ``__crc__``. The format is numpy's own and has not changed since
+        the parts came (a cache either side of ISSUE 43 loads on the
+        other)."""
         from drep_tpu.utils.durableio import with_checksum
 
         writer = np.savez_compressed if compressed else np.savez
@@ -186,35 +192,106 @@ class WorkDirectory:
             head[_PARTS_PREFIX + key] = np.asarray(lengths, dtype=np.int64)
         publish(head_loc, head)
 
-    def get_arrays(self, name: str) -> dict[str, np.ndarray]:
-        from drep_tpu.utils.durableio import CorruptPayloadError, load_npz_checked
+    def get_arrays(self, name: str, workers: int = 1) -> dict[str, np.ndarray]:
+        """What :meth:`store_arrays` stored as `name`: :meth:`read_arrays`'
+        arrays, without its account of the read."""
+        return self.read_arrays(name, workers)[0]
 
+    def read_arrays(self, name: str, workers: int = 1) -> tuple[dict[str, np.ndarray], dict[str, Any]]:
+        """What :meth:`store_arrays` stored as `name`, and what reading it
+        did. The head goes through `load_npz_checked`; a member the head
+        lists in parts is read part by part into ONE array allocated for the
+        whole of it, each part by the reader its file asks for
+        (`durableio.load_npz_member_into`): a plain stored part with its
+        ``__crc__``, which is what `compressed=False` writes, straight into
+        its rows and checksummed there; any other (a compressed part, one
+        written or read with checksums off) decoded, verified and copied.
+        Either way no byte is returned that the part's checksum has not
+        covered. A member's parts are read on up to `workers` threads (the
+        job's `-p`), no more than the usable cores or the parts; an error on
+        any of them is raised here, as itself, and no array is returned.
+        The account: the `members` read from parts, their `parts`, how many
+        went `direct_parts` and how many `fallback_parts`, their `bytes`,
+        the `threads` of the widest member, the call's `seconds`."""
+        import time
+
+        from drep_tpu.utils.durableio import load_npz_checked
+
+        t0 = time.perf_counter()
         out = load_npz_checked(self._array_loc(name), what=f"workdir array {name}")
+        read = {"members": 0, "parts": 0, "direct_parts": 0, "fallback_parts": 0, "bytes": 0, "threads": 0}
         for pkey in [k for k in out if k.startswith(_PARTS_PREFIX)]:
             lengths = out.pop(pkey).tolist()
             key = pkey[len(_PARTS_PREFIX) :]
-            whole, lo = None, 0
-            for i, n in enumerate(lengths):
-                loc = self._part_loc(name, key, i)
-                try:
-                    part = load_npz_checked(loc, what=f"workdir array {name} part")["part"]
-                except (FileNotFoundError, KeyError) as e:
+            out[key], direct, threads = self._read_parts(name, key, lengths, workers)
+            read["members"] += 1
+            read["parts"] += len(lengths)
+            read["direct_parts"] += direct
+            read["fallback_parts"] += len(lengths) - direct
+            read["bytes"] += out[key].nbytes
+            read["threads"] = max(read["threads"], threads)
+        read["seconds"] = time.perf_counter() - t0
+        return out, read
+
+    def _read_parts(self, name: str, key: str, lengths: list[int], workers: int) -> tuple[np.ndarray, int, int]:
+        """The member `key` of `name` out of its parts: (the array, the parts
+        read in place, the threads that read). The first part's header says
+        what to allocate; every part is then read into its own rows."""
+        from concurrent.futures import ThreadPoolExecutor
+
+        from drep_tpu.utils.durableio import (
+            CorruptPayloadError, PayloadShapeError, load_npz_checked, load_npz_member_into, npz_member_header,
+        )
+        from drep_tpu.utils.hosttools import usable_cores
+
+        head_loc = self._array_loc(name)
+        what = f"workdir array {name} part"
+
+        def missing(loc: str, e: Exception) -> CorruptPayloadError:
+            return CorruptPayloadError(
+                f"workdir array {name}: part {loc} is missing ({e!r}) — "
+                f"delete {head_loc} to recompute the cache"
+            )
+
+        first = self._part_loc(name, key, 0)
+        header = npz_member_header(first, "part")
+        if header is None:  # unreadable so: the checked reader says why, or reads it
+            try:
+                part = load_npz_checked(first, what=what)["part"]
+            except (FileNotFoundError, KeyError) as e:
+                raise missing(first, e) from e
+            header = part.dtype, part.shape
+        dtype, shape = header
+        starts = np.concatenate([[0], np.cumsum(lengths, dtype=np.int64)]).tolist()
+        whole = np.empty((starts[-1], *shape[1:]), dtype=dtype)  # one allocation, filled part by part
+
+        def read(i: int) -> bool:
+            loc = self._part_loc(name, key, i)
+            try:
+                return load_npz_member_into(loc, "part", whole[starts[i] : starts[i + 1]], what=what)
+            except (FileNotFoundError, KeyError) as e:
+                raise missing(loc, e) from e
+            except PayloadShapeError as e:
+                if e.shape[:1] != (lengths[i],):
                     raise CorruptPayloadError(
-                        f"workdir array {name}: part {loc} is missing ({e!r}) — "
-                        f"delete {self._array_loc(name)} to recompute the cache"
-                    ) from e
-                if len(part) != n:
-                    raise CorruptPayloadError(
-                        f"workdir array {name}: part {loc} holds {len(part)} rows, "
-                        f"its head says {n} — delete {self._array_loc(name)} to "
+                        f"workdir array {name}: part {loc} holds {e.shape[0] if e.shape else 0} rows, "
+                        f"its head says {lengths[i]} — delete {head_loc} to "
                         f"recompute the cache"
-                    )
-                if whole is None:  # one allocation, filled part by part
-                    whole = np.empty((sum(lengths), *part.shape[1:]), dtype=part.dtype)
-                whole[lo : lo + n] = part
-                lo += n
-            out[key] = whole
-        return out
+                    ) from e
+                raise CorruptPayloadError(
+                    f"workdir array {name}: part {loc} holds {e.dtype}{list(e.shape[1:])} rows, "
+                    f"the first part {dtype}{list(shape[1:])} — delete "
+                    f"{head_loc} to recompute the cache"
+                ) from e
+
+        threads = max(1, min(int(workers), usable_cores(), len(lengths)))
+        if threads == 1:
+            direct = sum(read(i) for i in range(len(lengths)))
+        else:
+            with ThreadPoolExecutor(max_workers=threads) as pool:
+                # the first error is raised here; `map` cancels what has not begun
+                direct = sum(pool.map(read, range(len(lengths))))
+        return whole, direct, threads
 
     def has_arrays(self, name: str) -> bool:
         return os.path.exists(self._array_loc(name))

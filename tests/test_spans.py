@@ -337,6 +337,44 @@ def test_primary_pack_says_what_it_ranked(toy_job):
         {"hashes": want["hashes"], "path": "native" if is_native else "numpy", "workers": 1}]
 
 
+def test_a_compare_from_a_planted_cache_reads_it_back_with_no_span_inside_the_stage(tmp_path, monkeypatch):
+    """ISSUE 43: `load_sketches_s` reads the SELF seconds of
+    `stage:ingest_or_cache`, so the cache's reader (and its threads) open no
+    span there; what it did is the record's `sketch_cache_read`."""
+    from benchmark import cells
+    from drep_tpu import controller, workdir
+    from drep_tpu.utils import hosttools
+
+    monkeypatch.setattr(workdir, "ARRAY_PART_BYTES", 1 << 16)
+    monkeypatch.setattr(hosttools, "usable_cores", lambda: 8)
+    gen = cells.load_module(os.path.join(REPO, "benchmark", "generators", "planted_sketches.py"))
+    cfg = cells.read_json(os.path.join(REPO, "benchmark", "configs", "mags_5k.json"))
+    cfg["data"].update({"n": 48, "s_scaled": 2000})
+    wd = gen.prepare(cfg, 43, str(tmp_path))["workdir"]
+    controller.main(["compare", wd, "--skip_plots", "--events", "on", "-p", "6"])
+    telemetry.configure()
+    with open(os.path.join(wd, "log", "perf_counters.json")) as f:
+        rec = json.load(f)
+    arrs = workdir.WorkDirectory(wd).get_arrays("sketches")
+    read = rec["sketch_cache_read"]
+    assert read["members"] == 2 and read["parts"] > 2  # bottom and scaled, in parts of 64 KiB
+    assert read["direct_parts"] + read["fallback_parts"] == read["parts"] and read["fallback_parts"] == 0
+    assert read["bytes"] == arrs["bottom"].nbytes + arrs["scaled"].nbytes
+    assert read["threads"] == 6 and 0 < read["seconds"]
+    ph = rec["phases"]
+    stage = ph["stage:ingest_or_cache"]
+    assert stage["calls"] == 1 and stage["self_seconds"] == stage["seconds"] >= read["seconds"]
+    assert all(p["thread"] == "main" for p in ph.values())  # the reader's threads open none
+    trace_report = _trace_report()
+    spans, unclosed = trace_report.pair_spans(trace_report.load_events(os.path.join(wd, "log"))["events"])
+    assert not unclosed
+    (lo, hi), = [(sp["begin"], sp["end"]) for sp in spans if sp["ev"] == "stage:ingest_or_cache"]
+    assert [sp["ev"] for sp in spans if lo <= sp["begin"] and sp["end"] <= hi] == ["stage:ingest_or_cache"]
+    # the other counters are what they were
+    assert rec["primary_pack"]["calls"] == 1 and rec["primary_pack"]["genomes"] == 48
+    assert "ingest" not in rec and _main_self_sum(ph) == pytest.approx(ph["job"]["seconds"], rel=0.01)
+
+
 def test_primary_pack_counter_sums_its_calls_and_counts_no_padding(monkeypatch):
     from drep_tpu import native
     from drep_tpu.cluster.engines import pack_primary
