@@ -29,7 +29,7 @@ from drep_tpu.cluster.dispatch import (
     register_secondary_batched,
 )
 from drep_tpu.ingest import GenomeSketches
-from drep_tpu.ops.containment import all_vs_all_containment, pack_scaled_sketches
+from drep_tpu.ops.containment import all_vs_all_containment, pack_secondary
 from drep_tpu.ops.minhash import all_vs_all_mash, pack_sketches, rank_route
 from drep_tpu.utils.profiling import counters
 
@@ -298,15 +298,16 @@ def secondary_jax_ani(
     indices: list[int],
     tile: int = 128,
     mesh_shape: int | None = None,
+    processes: int = 1,
     **_,
 ) -> tuple[np.ndarray, np.ndarray]:
     """(symmetric max-containment ani, directional cov) for a genome
     subset. `indices` index into gs.names; matrices are [m, m] in that
-    order."""
-    with counters.span("secondary/pack"):
-        sketches = [gs.scaled[i] for i in indices]
-        names = [gs.names[i] for i in indices]
-        packed = pack_scaled_sketches(sketches, names)
+    order. `processes` (dRep's `-p`) bounds the threads the pack ranks
+    on; results do not depend on it."""
+    packed = pack_secondary(
+        [gs.scaled[i] for i in indices], [gs.names[i] for i in indices], processes
+    )
     return containment_matrices(packed, gs.k, mesh_shape=mesh_shape, tile=tile)
 
 
@@ -371,8 +372,7 @@ def secondary_jax_ani_batched(
             packed_l, k=gs.k, v_pad=v_pad
         )
     if ani_all is None:
-        with counters.span("secondary/pack", calls=len(clusters)):
-            packed = pack_scaled_sketches([gs.scaled[i] for i in flat], names)
+        packed = pack_secondary([gs.scaled[i] for i in flat], names, processes, calls=len(clusters))
         ani_all, cov_all = containment_matrices(
             packed, gs.k, mesh_shape=mesh_shape, tile=tile
         )

@@ -30,7 +30,7 @@ from drep_tpu.ops.containment import (
     cap_gather_tile,
     containment_cov_tile,
     containment_to_ani,
-    pack_scaled_sketches,
+    pack_secondary,
     rect_from_chunks,
     rect_from_chunks_sharded,
     replicate_on_mesh,
@@ -169,7 +169,10 @@ def greedy_secondary_cluster(
     Books the route that served in `secondary_paths` (`greedy_matmul` on a
     TPU or under DREP_TPU_GREEDY_MATMUL, `greedy_gather` off it), one
     entry of the record's `secondary_greedy_calls`, and the spans
-    `secondary/pack`, `secondary/greedy_layout` (the chunk geometry, every
+    `secondary/pack` (the cluster's shared-vocabulary pack, ranked by
+    native/rank.cc on the job's `-p` threads, `kw["processes"]`: args
+    `hashes=`, `path=` native | numpy, `workers=`; also booked in the
+    record's `secondary_pack`), `secondary/greedy_layout` (the chunk geometry, every
     block's and every new representative's repack and pad, the
     representatives' shipment), `secondary/greedy_wait` (one a block: its
     tiles against the representatives, its self comparison, the readbacks;
@@ -187,10 +190,10 @@ def greedy_secondary_cluster(
     m = len(indices)
     order = sorted(range(m), key=lambda t: -int(gs.gdb["n_kmers"].iloc[indices[t]]))
 
-    with counters.span("secondary/pack"):
-        packed = pack_scaled_sketches(
-            [gs.scaled[indices[t]] for t in order], [gs.names[indices[t]] for t in order]
-        )
+    packed = pack_secondary(
+        [gs.scaled[indices[t]] for t in order], [gs.names[indices[t]] for t in order],
+        kw.get("processes", 1),
+    )
     ids, counts = packed.ids, packed.counts
     import jax
 

@@ -47,12 +47,14 @@ from drep_tpu.ops.minhash import (
     _fill_padded_rows,
     _usable_cores,
     pad_packed_rows,
+    rank_route,
+    rank_rows_padded,
 )
 from drep_tpu.utils.profiling import counters
 
 
 def pack_scaled_sketches(
-    sketches: list[np.ndarray], names: list[str], pad_multiple: int = 128
+    sketches: list[np.ndarray], names: list[str], pad_multiple: int = 128, workers: int = 1
 ) -> PackedSketches:
     """Ragged uint64 scaled sketches -> padded int32 id matrix [N, S].
 
@@ -60,21 +62,55 @@ def pack_scaled_sketches(
     lane-friendly AND compile-stable — a linear pad multiple gave every
     batch its own width and thus its own XLA compilation (see
     :func:`_pow2_bucket`).
+
+    An id is a hash's rank in the sorted vocabulary of ALL rows. The ranks
+    come from the primary pack's kernel (native/rank.cc, by
+    :func:`~drep_tpu.ops.minhash.rank_route`) on up to `workers` threads,
+    the width the caller grants (the job's `-p`); the matrix is the same
+    bytes at any width and by the NumPy lines below, which serve without
+    the library and are the tests' oracle.
     """
     if not sketches:
         raise ValueError("no sketches to pack")
+    lens = np.array([len(s) for s in sketches], dtype=np.int64)
+    width = _pow2_bucket(max(int(lens.max()), 1), pad_multiple)
+    path, threads = rank_route(int(lens.sum()), workers)
+    # (rows of another dtype are NumPy's to promote and order, as they were)
+    if path == "native" and all(s.dtype == np.uint64 for s in sketches):
+        # one `np.unique` and one binary search a hash into its result
+        # (every level a cache miss once the vocabulary leaves the cache)
+        # took 3.0 s at 2.6e7 hashes of 1.6M ids on one core (ISSUE 44)
+        ids = rank_rows_padded(sketches, width, threads)
+        return PackedSketches(ids=ids, counts=lens.astype(np.int32), names=list(names))
     flat = np.concatenate(sketches)
     vocab = np.unique(flat)
     if vocab.size >= np.iinfo(np.int32).max:
         raise ValueError("id space overflow: >2^31 distinct sketch hashes")
-    lens = np.array([len(s) for s in sketches], dtype=np.int64)
-    width = _pow2_bucket(max(int(lens.max()), 1), pad_multiple)
     ids = np.full((len(sketches), width), PAD_ID, dtype=np.int32)
     # ONE searchsorted over the concatenation — a per-row SEARCH was a
     # measured hot spot at thousands of clusters/batches per run. The fill
     # below does loop over rows: a slice copy per row costs microseconds
     _fill_padded_rows(ids, np.searchsorted(vocab, flat), lens)
     return PackedSketches(ids=ids, counts=lens.astype(np.int32), names=list(names))
+
+
+def pack_secondary(
+    sketches: list[np.ndarray], names: list[str], processes: int = 1, calls: int = 1
+) -> PackedSketches:
+    """`pack_scaled_sketches` for a secondary compare's shared-vocabulary
+    pack (the per-cluster engine, the greedy engine, the batched route's
+    fallback), under the `secondary/pack` span and booked in the record's
+    `secondary_pack`. The span's args say what the pack ranks (`hashes=`)
+    and how: `path=` native | numpy, on `workers=` threads of the job's
+    `-p` (`processes`); `calls` is the clusters the one pack serves."""
+    hashes = sum(len(s) for s in sketches)
+    path, threads = rank_route(hashes, processes)
+    with counters.span("secondary/pack", calls=calls, hashes=hashes, path=path, workers=threads):
+        packed = pack_scaled_sketches(sketches, names, workers=processes)
+    counters.add_secondary_pack(
+        rows=packed.n, hashes=hashes, native=path == "native", threads=threads
+    )
+    return packed
 
 
 def clusterlocal_pack_workers(processes: int, n_groups: int) -> int:
@@ -94,7 +130,15 @@ def _rank_cluster(group: list[np.ndarray]) -> tuple[np.ndarray, int]:
     # the vocabulary is np.unique(flat), spelled so that the sort is the
     # stable one: it merges the members' already-sorted runs. Of the
     # spellings timed on the chip machine's host (PERF.md section 6,
-    # PR 25) this is the fastest at the CLI's six workers
+    # PR 25) this is the fastest at the CLI's six workers.
+    # NOT native/rank.cc, which ranks the shared-vocabulary pack above
+    # (ROADMAP D16: two spellings, one kernel, one oracle): the clusters
+    # already rank one a pool thread, so the kernel would run on one
+    # thread each, and there it loses at the few rows most clusters hold
+    # and gains little above (chip host, rows of 25,800 hashes, PERF.md
+    # section 6, PR 44: 4 rows 3.0 against these lines' 2.6 ms, 16 rows
+    # 11.5 against 13.3, 32 rows 23.2 against 31.2); and it writes a
+    # padded int32 matrix where this pack narrows to uint16
     srt = np.sort(flat, kind="stable")
     first = np.ones(len(srt), dtype=bool)
     np.not_equal(srt[1:], srt[:-1], out=first[1:])

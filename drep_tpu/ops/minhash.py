@@ -120,6 +120,23 @@ def rank_route(hashes: int, workers: int = 1) -> tuple[str, int]:
     return "native", max(1, min(int(workers), _usable_cores(), hashes // RANK_HASHES_PER_THREAD))
 
 
+def rank_rows_padded(rows: list[np.ndarray], width: int, threads: int) -> np.ndarray:
+    """The padded int32 id matrix [len(rows), width] of uint64 `rows` by
+    native/rank.cc on `threads` threads (the `native` route of
+    :func:`rank_route`): a hash's id is its rank among the distinct hashes
+    of ALL rows, PAD_ID past a row's length. The kernel reads the rows
+    where they lie and writes the whole matrix, padding included; it
+    counts the vocabulary first, and a vocabulary at the int32 limit is
+    refused in NumPy's words."""
+    from drep_tpu.native import rank_rows_native
+
+    ids = np.empty((len(rows), width), dtype=np.int32)
+    limit = np.iinfo(np.int32).max
+    if rank_rows_native(rows, ids, PAD_ID, threads, limit) >= limit:
+        raise ValueError("id space overflow: >2^31 distinct sketch hashes")
+    return ids
+
+
 def pack_sketches(
     sketches: list[np.ndarray], names: list[str], sketch_size: int, workers: int = 1
 ) -> PackedSketches:
@@ -134,14 +151,7 @@ def pack_sketches(
     path, threads = rank_route(int(lens.sum()), workers)
     # (rows of another dtype are NumPy's to promote and order, as they were)
     if path == "native" and all(s.dtype == np.uint64 for s in trimmed):
-        from drep_tpu.native import rank_rows_native
-
-        # the kernel reads the rows where they lie and writes the whole
-        # matrix, padding included; it counts the vocabulary first
-        ids = np.empty((len(trimmed), sketch_size), dtype=np.int32)
-        limit = np.iinfo(np.int32).max
-        if rank_rows_native(trimmed, ids, PAD_ID, threads, limit) >= limit:
-            raise ValueError("id space overflow: >2^31 distinct sketch hashes")
+        ids = rank_rows_padded(trimmed, sketch_size, threads)
         return PackedSketches(ids=ids, counts=lens.astype(np.int32), names=list(names))
     ids = np.full((len(trimmed), sketch_size), PAD_ID, dtype=np.int32)
     flat = np.concatenate(trimmed) if trimmed else np.empty(0, np.uint64)

@@ -196,6 +196,15 @@ class _Phase:
     calls: int = 0
 
 
+def _book_pack(section: dict[str, int], native: bool, threads: int, **counted: int) -> None:
+    """One more rank-map pack into a record section (`primary_pack`,
+    `secondary_pack`): the calls and what they `counted` add up, `threads`
+    keeps the widest call's."""
+    for name, value in {"calls": 1, "native_calls": int(native), **counted}.items():
+        section[name] = section.get(name, 0) + int(value)
+    section["threads"] = max(section.get("threads", 0), int(threads))
+
+
 def _on_main_thread() -> bool:
     return threading.current_thread() is threading.main_thread()
 
@@ -384,6 +393,10 @@ class Counters:
     # ranked them: `native_calls` of the calls went through native/rank.cc,
     # the widest on `threads` threads (ISSUE 40)
     primary_pack: dict[str, int] = field(default_factory=dict)
+    # the same for the secondary's shared-vocabulary packs
+    # (containment.pack_secondary, ISSUE 44): calls, native_calls, rows,
+    # hashes, threads
+    secondary_pack: dict[str, int] = field(default_factory=dict)
     # how the sketch cache was read back (ingest.py::_load, ISSUE 43): the
     # `members` stored in parts, their `parts`, how many were read in place
     # (`direct_parts`) and how many through `load_npz_checked`
@@ -615,11 +628,16 @@ class Counters:
         hashes of `genomes` rows became `distinct_ids` int32 ranks, by the
         native kernel (`native_calls`) or NumPy, on `threads` threads (the
         record keeps the widest of the job's calls)."""
-        booked = {"calls": 1, "native_calls": int(native), "genomes": genomes, "hashes": hashes,
-                  "distinct_ids": distinct_ids}
-        for name, value in booked.items():
-            self.primary_pack[name] = self.primary_pack.get(name, 0) + int(value)
-        self.primary_pack["threads"] = max(self.primary_pack.get("threads", 0), int(threads))
+        _book_pack(self.primary_pack, native, threads,
+                   genomes=genomes, hashes=hashes, distinct_ids=distinct_ids)
+
+    def add_secondary_pack(
+        self, rows: int, hashes: int, native: bool = False, threads: int = 1
+    ) -> None:
+        """Book one shared-vocabulary `pack_scaled_sketches` of the secondary
+        compare (`containment.pack_secondary`): `hashes` scaled hashes of
+        `rows` genomes, ranked as `add_primary_pack` says."""
+        _book_pack(self.secondary_pack, native, threads, rows=rows, hashes=hashes)
 
     def add_sketch_cache_read(self, threads: int, seconds: float, **did: int) -> None:
         """Book one `WorkDirectory.read_arrays` of the sketch cache: `did` is
@@ -962,6 +980,8 @@ class Counters:
             out["secondary_greedy_batched"] = dict(self.greedy_batched)
         if self.primary_pack:
             out["primary_pack"] = dict(self.primary_pack)
+        if self.secondary_pack:
+            out["secondary_pack"] = dict(self.secondary_pack)
         if self.sketch_cache_read:
             out["sketch_cache_read"] = {**self.sketch_cache_read,
                                         "seconds": round(self.sketch_cache_read["seconds"], 4)}
@@ -1044,6 +1064,7 @@ class Counters:
         self.greedy_calls.clear()
         self.greedy_batched.clear()
         self.primary_pack.clear()
+        self.secondary_pack.clear()
         self.sketch_cache_read.clear()
         self.primary_linkage.clear()
         self.stream_slots.clear()

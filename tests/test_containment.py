@@ -154,3 +154,91 @@ def test_shared_vocabulary_pack_equals_double_loop_oracle(rng, case):
     assert packed.counts.dtype == np.int32
     assert packed.counts.tolist() == [len(s) for s in sketches]
     assert packed.names == names
+
+
+# ---- ISSUE 44: the shared-vocabulary pack ranks in native/rank.cc ----------------
+
+
+def _shared_by_16(rng):
+    # 4 groups of 16 rows; every hash of a group is in all 16 of its rows
+    groups = [np.unique(rng.integers(0, 2**64 // 1000, size=300, dtype=np.uint64)) for _ in range(4)]
+    return [g.copy() for g in groups for _ in range(16)]
+
+
+def _disjoint(rng):
+    pool = np.unique(rng.integers(0, 2**63, size=4000, dtype=np.uint64))[:3000]
+    rng.shuffle(pool)
+    return [np.sort(pool[i * 250 : (i + 1) * 250]) for i in range(12)]
+
+
+_SCALED_CASES = {
+    "every_hash_shared_by_16_rows": _shared_by_16,
+    "disjoint_rows": _disjoint,
+    "one_row": lambda rng: [np.unique(rng.integers(0, 2**64 - 1, size=500, dtype=np.uint64))],
+    "an_empty_row_between_full_ones": lambda rng: [
+        *_sketches(rng, n=2, size=200), np.empty(0, np.uint64), *_sketches(rng, n=2, size=200)],
+    # 255, 256 and 257 hashes: the longest row sets the pow2 width, 512
+    "lengths_that_cross_a_pow2_width": lambda rng: [
+        np.unique(rng.integers(0, 2**50, size=4 * n, dtype=np.uint64))[:n] for n in (255, 256, 257, 31)],
+}
+# how the ranks are computed: NumPy's lines (no library), or native/rank.cc on so many threads
+_SCALED_PATHS = {"numpy": None, "native_x1": 1, "native_x2": 2, "native_x6": 6}
+
+
+@pytest.fixture(params=list(_SCALED_PATHS))
+def scaled_pack_workers(request, monkeypatch):
+    """`workers` for `pack_scaled_sketches`, with the path it names made the
+    one that serves: no library for NumPy, six usable cores for native."""
+    from drep_tpu import native
+    from drep_tpu.ops import minhash
+
+    workers = _SCALED_PATHS[request.param]
+    if workers is None:
+        monkeypatch.setattr(native, "get_library", lambda: None)
+        assert minhash.rank_route(10, 6) == ("numpy", 1)
+        return 6
+    if native.get_library() is None:
+        pytest.skip("native library unavailable (no g++?)")
+    monkeypatch.setattr(minhash, "_usable_cores", lambda: 6)
+    monkeypatch.setattr(minhash, "RANK_HASHES_PER_THREAD", 1)  # the toy cases on every thread asked for
+    assert minhash.rank_route(10, workers) == ("native", workers)
+    return workers
+
+
+@pytest.mark.parametrize("case", list(_SCALED_CASES))
+def test_shared_vocabulary_pack_is_byte_equal_to_numpys_by_either_route(case, scaled_pack_workers):
+    """ISSUE 44: the ranks of `pack_scaled_sketches` come from the primary
+    pack's kernel at the caller's thread width, or from NumPy's lines where
+    there is no library: the same bytes as `np.unique` over all rows and a
+    search of each row into it."""
+    from drep_tpu.ops.minhash import PAD_ID
+
+    sketches = _SCALED_CASES[case](np.random.default_rng(44))
+    names = [f"g{i}" for i in range(len(sketches))]
+    vocab = np.unique(np.concatenate(sketches))
+    longest = max(len(s) for s in sketches)
+    want = np.full((len(sketches), max(32, 1 << (longest - 1).bit_length())), PAD_ID, np.int32)
+    for r, s in enumerate(sketches):
+        want[r, : len(s)] = np.searchsorted(vocab, s)
+    packed = pack_scaled_sketches(sketches, names, pad_multiple=32, workers=scaled_pack_workers)
+    assert packed.ids.dtype == np.int32 and packed.counts.dtype == np.int32
+    assert packed.ids.shape == want.shape and packed.ids.flags.c_contiguous
+    assert packed.ids.tobytes() == want.tobytes()
+    assert packed.counts.tolist() == [len(s) for s in sketches]
+    assert packed.names == names and packed.names is not names
+    assert [s.dtype for s in sketches] == [np.uint64] * len(sketches)  # read where they lie, unchanged
+
+
+def test_shared_vocabulary_pack_refuses_a_vocabulary_at_the_limit_by_either_route(
+        monkeypatch, scaled_pack_workers):
+    """The vocabulary's size is checked before a rank is an int32: in
+    NumPy's lines, and as the limit the native kernel is handed."""
+    from drep_tpu.ops.minhash import PAD_ID
+
+    monkeypatch.setattr(np, "iinfo", lambda dtype: type("I", (), {"max": 5})())
+    sk = [np.arange(3, dtype=np.uint64), np.arange(2, 5, dtype=np.uint64)]
+    with pytest.raises(ValueError, match="id space overflow: >2\\^31 distinct sketch hashes"):
+        pack_scaled_sketches(sk, ["a", "b"], pad_multiple=8, workers=scaled_pack_workers)
+    under = pack_scaled_sketches([sk[0], sk[0] + 1], ["a", "b"], pad_multiple=8,
+                                 workers=scaled_pack_workers)
+    assert under.ids[:, :3].tolist() == [[0, 1, 2], [1, 2, 3]] and (under.ids[:, 3:] == PAD_ID).all()

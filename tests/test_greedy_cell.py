@@ -58,7 +58,10 @@ def planted(cell, tmp_path_factory):
 
 @pytest.fixture(scope="module")
 def jobs(cell, planted):
-    """The same planted collection through both routes of the engine."""
+    """The same planted collection through both routes of the engine (the
+    matmul route's job with its event log on)."""
+    from drep_tpu.utils import telemetry
+
     done = {}
     for route in ("greedy_gather", "greedy_matmul"):
         wd = os.path.join(planted["out"], route)
@@ -67,7 +70,8 @@ def jobs(cell, planted):
             if route == "greedy_matmul":
                 # the chip's route: one device (conftest gives the CPU eight), blocks of 128
                 mp.setenv("DREP_TPU_GREEDY_MATMUL", "1")
-                done[route] = _job(cell, wd, "--mesh_shape", "1")
+                done[route] = _job(cell, wd, "--mesh_shape", "1", "--events", "on")
+                telemetry.configure()
             else:
                 done[route] = _job(cell, wd)
     return done
@@ -130,6 +134,33 @@ def test_the_record_says_which_route_served_what(planted, jobs, route):
     by_cluster = ndb.groupby("primary_cluster")["querry"].size()
     assert {c["compared_pairs"] for c in calls} <= set(by_cluster.tolist())
     assert by_cluster.max() == max(c["compared_pairs"] for c in calls)
+
+
+@pytest.mark.parametrize("route", ["greedy_gather", "greedy_matmul"])
+def test_the_pack_spans_and_the_record_say_how_the_engines_clusters_were_ranked(planted, jobs, route):
+    """ISSUE 44: the engine's `secondary/pack` span carries `hashes=`, `path=`
+    and `workers=` (what `rank_route` names for the job's `-p`), one span a
+    cluster, and the record's `secondary_pack` adds them up: native wherever
+    the library is there. The batched route's cluster-local pack books none."""
+    from drep_tpu import native
+    from drep_tpu.ops.minhash import rank_route
+    from tools import trace_report
+
+    data, rec = planted["data"], jobs[route]["record"]
+    sizes = np.bincount(data.primary_labels)
+    engine = np.flatnonzero(sizes > SMALL_CLUSTER_MAX)
+    hashes = [sum(len(data.scaled[g]) for g in np.flatnonzero(data.primary_labels == c)) for c in engine]
+    routes = [rank_route(h, 6) for h in hashes]  # the cell's argv leaves `-p` at the CLI's 6
+    is_native = native.get_library() is not None
+    assert rec["secondary_pack"] == {"calls": len(engine), "native_calls": len(engine) * is_native,
+                      "rows": int(sizes[engine].sum()), "hashes": sum(hashes),
+                      "threads": max(t for _, t in routes)}
+    if route == "greedy_matmul":  # the job whose event log is on
+        spans, _ = trace_report.pair_spans(
+            trace_report.load_events(os.path.join(jobs[route]["wd"], "log"))["events"])
+        ranked = [sp["args"] for sp in spans if sp["ev"] == "secondary/pack" and "path" in sp["args"]]
+        want = [{"hashes": h, "path": p, "workers": t} for h, (p, t) in zip(hashes, routes)]
+        assert sorted(ranked, key=str) == sorted(want, key=str)
 
 
 def test_the_control_fails_the_limits_and_a_swapped_order_fails_the_pair_set(cell, planted):

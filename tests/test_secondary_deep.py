@@ -270,6 +270,30 @@ def test_job_through_the_chunked_call_names_it_and_writes_the_same_tables(cell, 
     assert plain["secondary"] == chunked["secondary"]
 
 
+def test_the_pack_span_and_the_record_say_how_the_cluster_was_ranked(toy_jobs):
+    """ISSUE 44: the per-cluster engine's `secondary/pack` span carries
+    `hashes=`, `path=` and `workers=` (the route `rank_route` names for the
+    job's `-p`), and the record's `secondary_pack` books the call beside
+    `primary_pack`: native wherever the library is there."""
+    from drep_tpu import native
+    from drep_tpu.ops.minhash import rank_route
+    from tools import trace_report
+
+    data = toy_jobs["data"]
+    hashes = sum(len(s) for s in data.scaled)
+    path, threads = rank_route(hashes, 6)  # the cell's argv leaves `-p` at the CLI's 6
+    is_native = native.get_library() is not None
+    assert path == ("native" if is_native else "numpy")
+    want = {"calls": 1, "native_calls": int(is_native), "rows": len(data.names), "hashes": hashes,
+            "threads": threads}
+    for job in ("plain_record", "on_record", "off_record"):
+        assert toy_jobs[job]["secondary_pack"] == want, job
+        assert toy_jobs[job]["secondary_pack"]["native_calls"] == toy_jobs[job]["primary_pack"]["native_calls"]
+    spans, _ = trace_report.pair_spans(trace_report.load_events(os.path.join(toy_jobs["on"], "log"))["events"])
+    ranked = [sp["args"] for sp in spans if sp["ev"] == "secondary/pack" and "hashes" in sp["args"]]
+    assert ranked == [{"hashes": hashes, "path": path, "workers": threads}]
+
+
 @pytest.mark.parametrize("fault", ["groups_merged", "one_hash_count", "pair_missing"])
 def test_comparison_prints_wrong_for_a_wrong_answer(cell, toy_jobs, fault, capsys):
     data = toy_jobs["data"]
