@@ -32,10 +32,6 @@ def build_parser() -> argparse.ArgumentParser:
         comp = p.add_argument_group("GENOME COMPARISON")
         comp.add_argument("--primary_algorithm", default="jax_mash",
                           help="primary (coarse) comparison engine [jax_mash|mash]")
-        comp.add_argument("--primary_estimator", default="auto",
-                          choices=["auto", "sort", "matmul"],
-                          help="jax_mash Jaccard estimator: sort=union-bottom-s "
-                               "(reference Mash), matmul=MXU common-threshold")
         comp.add_argument("--S_algorithm", default="jax_ani",
                           help="secondary (ANI) comparison engine "
                                "[jax_ani|fastANI|ANImf|ANIn|gANI|goANI]")
@@ -158,17 +154,6 @@ def build_parser() -> argparse.ArgumentParser:
                               "consumes the flag within this many seconds the "
                               "process publishes the note best-effort and "
                               "exits 0 anyway (preemption grants no extension)")
-        tpu.add_argument("--ring_monolithic", action="store_true",
-                         help="run the dense all-pairs ring as ONE collective "
-                              "program (the pre-elastic reference) instead of "
-                              "the default host-stepped schedule (one dispatch "
-                              "per ring step, per-step block checkpoints under "
-                              "<wd>/data/dense_ring, individually redoable "
-                              "blocks, pod-death survival; per-step watchdog "
-                              "auto-derived like the streaming tiles, reported "
-                              "as derived_ring_step_timeout_s). Results are "
-                              "bit-identical either way; env "
-                              "DREP_TPU_RING_MONOLITHIC=1 also forces it")
         tpu.add_argument("--io_retries", type=int, default=None,
                          help="transient shared-filesystem I/O errors "
                               "(EIO/ESTALE/ETIMEDOUT) retried per durable "

@@ -69,7 +69,6 @@ CLUSTER_DEFAULTS: dict[str, Any] = {
     "primary_chunksize": 5000,
     "mdb_dense_limit": 2000,
     "mesh_shape": None,
-    "primary_estimator": "auto",
     "streaming_primary": False,
     "streaming_block": 1024,
     "streaming_threshold": 30_000,
@@ -113,12 +112,6 @@ CLUSTER_DEFAULTS: dict[str, Any] = {
     # so neither joins _RESUME_KEYS.
     "io_retries": None,
     "fsync": False,
-    # dense-ring execution: False (default) runs the host-stepped elastic
-    # schedule (parallel/allpairs.py — per-step block checkpoints, redoable
-    # blocks, pod-death survival); True forces the monolithic single
-    # collective program kept as the bit-equality reference. Results are
-    # bit-identical either way, so it never invalidates a workdir.
-    "ring_monolithic": False,
 }
 
 _RESUME_KEYS = [
@@ -127,7 +120,6 @@ _RESUME_KEYS = [
     "cov_thresh",
     "clusterAlg",
     "primary_algorithm",
-    "primary_estimator",
     "S_algorithm",
     "MASH_sketch",
     "scale",
@@ -232,29 +224,25 @@ def _streaming_mdb(edges, names: list[str]) -> pd.DataFrame:
     return pd.DataFrame({"genome1": g1, "genome2": g2, "dist": d, "similarity": 1.0 - d})
 
 
-def _resolve_estimator_for_run(n: int, kw: dict[str, Any]) -> str:
-    """The estimator the run will ACTUALLY use, mirroring
-    `_primary_clusters`' branch order exactly (SkipMash -> multiround ->
-    streaming -> dense engine). Recorded in the resume snapshot; a naive
-    `resolve_primary_estimator(n)` alone would claim 'matmul' for a 40k-
-    genome run that in fact streams with sort tiles, producing spurious
-    boundary warnings on resume."""
+def _primary_route(n: int, kw: dict[str, Any]) -> str:
+    """The route the primary of `n` genomes takes on THIS host: 'skipmash',
+    'multiround_<dense route of a chunk>', 'streaming_sort', 'ring_sort' or
+    'sort'. THE decision: `_primary_clusters` dispatches on this name, the
+    resume snapshot records it as `primary_estimator_resolved` (every
+    route computes the one sort estimator; the key keeps its name for the
+    readers of stored snapshots), and `_ingest_stage` overlaps the tile
+    programs' compile where it says 'streaming_sort'."""
     if kw["SkipMash"] or n == 1:
         return "skipmash"
     if kw["multiround_primary_clustering"] and n > kw["primary_chunksize"]:
-        # per-chunk resolution: chunks are primary_chunksize genomes
-        per_chunk = engines.resolve_primary_estimator(
-            min(n, kw["primary_chunksize"]), kw["mesh_shape"],
-            kw["primary_estimator"], kw["MASH_sketch"],
-        )
-        return f"multiround_{per_chunk}"
+        return "multiround_" + engines.dense_primary_route(
+            kw["primary_chunksize"], kw["mesh_shape"]
+        )[0]
     if kw["streaming_primary"] or (
         kw["primary_algorithm"] == "jax_mash" and n >= kw["streaming_threshold"]
     ):
-        return "streaming_sort"  # streaming always runs sort tiles
-    return engines.resolve_primary_estimator(
-        n, kw["mesh_shape"], kw["primary_estimator"], kw["MASH_sketch"]
-    )
+        return "streaming_sort"
+    return engines.dense_primary_route(n, kw["mesh_shape"])[0]
 
 
 def _primary_clusters(
@@ -268,17 +256,16 @@ def _primary_clusters(
     pairs actually compared — 0 for skipped work, honest across resumes)."""
     logger = get_logger()
     n = len(gs.names)
-    if kw["SkipMash"] or n == 1:
+    route = _primary_route(n, kw)
+    if route == "skipmash":
         # reference --SkipMash: everything lands in one primary cluster
         return np.ones(n, dtype=np.int64), np.zeros((n, n), np.float32), np.empty((0, 4)), None, 0
-    if kw["multiround_primary_clustering"] and n > kw["primary_chunksize"]:
+    if route.startswith("multiround_"):
         from drep_tpu.cluster.multiround import multiround_primary_clustering
 
         labels, pairs_done = multiround_primary_clustering(gs, bdb, kw)
         return labels, None, np.empty((0, 4)), None, pairs_done
-    if kw["streaming_primary"] or (
-        kw["primary_algorithm"] == "jax_mash" and n >= kw["streaming_threshold"]
-    ):
+    if route == "streaming_sort":
         from drep_tpu.parallel.streaming import streaming_primary_clusters
 
         if not kw["streaming_primary"]:
@@ -287,12 +274,6 @@ def _primary_clusters(
                 "to the out-of-core streaming path (pass --streaming_primary to opt "
                 "in explicitly, or raise the threshold to keep the dense path)",
                 n, kw["streaming_threshold"],
-            )
-        if kw["primary_estimator"] not in ("auto", "sort"):
-            logger.warning(
-                "streaming primary always uses the sort (union-bottom-s) tile "
-                "estimator; --primary_estimator %s is ignored on this path",
-                kw["primary_estimator"],
             )
         ckpt = wd.get_dir(os.path.join("data", "streaming_primary")) if wd is not None else None
         packed = engines.pack_primary(gs.bottom, gs.names, gs.sketch_size, kw["processes"])
@@ -331,13 +312,11 @@ def _primary_clusters(
             "--streaming_threshold or pass --streaming_primary) — ignored",
             kw["primary_prune"],
         )
+    # 'ring_sort' | 'sort': the engine takes the mesh or the one device
+    # by engines.dense_primary_route, the function the name came from
     engine = dispatch.get_primary(kw["primary_algorithm"])
     dist, _sim = engine(
-        gs,
-        bdb=bdb,
-        processes=kw["processes"],
-        mesh_shape=kw["mesh_shape"],
-        primary_estimator=kw["primary_estimator"],
+        gs, bdb=bdb, processes=kw["processes"], mesh_shape=kw["mesh_shape"]
     )
     cutoff = 1.0 - kw["P_ani"]
     on_device = kw["clusterAlg"] == "single" and n > 64
@@ -451,7 +430,7 @@ def _secondary_stage(
             multi.append((pc, indices))
 
     # warn_dist shapes only the Mdb retention, never secondary results;
-    # the resolved primary estimator never touches ANI numerics — keep
+    # the primary's route never touches ANI numerics — keep
     # both out of the checkpoint key so neither a warning-threshold
     # change nor a device-count change throws away the whole ANI stage
     sec_snapshot = {
@@ -588,11 +567,11 @@ def _sketch_args(kw: dict[str, Any]) -> dict[str, Any]:
 
 
 @contextlib.contextmanager
-def _ingest_stage(wd: WorkDirectory, genomes, kw: dict[str, Any], resolved: str):
+def _ingest_stage(wd: WorkDirectory, genomes, kw: dict[str, Any], route: str):
     """`stage:ingest_or_cache` (counted, so a run's stage seconds attribute
     the cache-load / ingest wall separately from compute), with the
     streaming tile programs' cold compile hidden behind it where that buys
-    anything. `resolved` is the primary estimator the run resolves to."""
+    anything. `route` is the run's :func:`_primary_route`."""
     warmup_thread = None
     warmup_error: list[BaseException] = []
     if (
@@ -600,7 +579,7 @@ def _ingest_stage(wd: WorkDirectory, genomes, kw: dict[str, Any], resolved: str)
         # ingest pool workers are SPAWNED (ingest.py::read_genomes), so
         # running them while this thread sits inside XLA's multithreaded
         # compiler is safe — spawn children inherit no locks
-        and resolved == "streaming_sort"
+        and route == "streaming_sort"
         # nothing to hide the compile behind when ingest will return
         # without sketching (whole-run cache hit on resumed runs /
         # pre-planted workdirs, or a shard store that already covers
@@ -660,8 +639,8 @@ def read_for_filter(wd: WorkDirectory, bdb: pd.DataFrame, stats_only, **kwargs) 
     are the genomes the quality table already drops. The pass goes to
     :func:`d_cluster_wrapper` as `sketches`."""
     kw = _fill_defaults(kwargs)
-    resolved = _resolve_estimator_for_run(len(bdb) - len(stats_only), kw)
-    with _ingest_stage(wd, bdb["genome"], kw, resolved):
+    route = _primary_route(len(bdb) - len(stats_only), kw)
+    with _ingest_stage(wd, bdb["genome"], kw, route):
         return read_genomes(bdb, wd=wd, stats_only=stats_only, **_sketch_args(kw))
 
 
@@ -691,35 +670,32 @@ def d_cluster_wrapper(
     # its per-step block tiles under the workdir (lazily — the directory
     # is only created when a mesh ring actually runs), making the dense
     # primary/secondary rings kill-resumable and pod-death elastic.
-    # --ring_monolithic False maps to None so DREP_TPU_RING_MONOLITHIC
-    # can still force the reference program for an A/B check.
-    configure_ring(
-        monolithic=True if kw["ring_monolithic"] else None,
-        checkpoint_base=os.path.join(wd.location, "data", "dense_ring"),
-    )
+    configure_ring(checkpoint_base=os.path.join(wd.location, "data", "dense_ring"))
     snapshot = {k: kw.get(k) for k in _RESUME_KEYS if k != "genomes"}
     # normalize: CLI passes 0.25 explicitly, library callers omit it — the
     # effective value must snapshot identically from both entry points
     snapshot["warn_dist"] = _warn_dist(kw)
     snapshot["genomes"] = sorted(bdb["genome"])
 
-    # the concrete estimator 'auto' resolves to HERE (it depends on N and on
-    # this host's device count). Stored for boundary detection, excluded
-    # from the match keys — a changed resolution must warn, not recompute
-    # (the families agree within estimator variance; SURVEY.md §7 step 3).
-    snapshot["primary_estimator_resolved"] = _resolve_estimator_for_run(len(bdb), kw)
+    # the route the primary takes HERE (it depends on N and on this host's
+    # device count). Stored for boundary detection, excluded from the match
+    # keys — a changed route must warn, not recompute: the routes compute
+    # the one estimator, and a ring's bytes differ from a streaming run's
+    # by float32 rounding.
+    route = _primary_route(len(bdb), kw)
+    snapshot["primary_estimator_resolved"] = route
     match_keys = [k for k in snapshot if k != "primary_estimator_resolved"]
 
     if wd.hasDb("Cdb") and wd.arguments_match("cluster", snapshot, keys=match_keys):
         stored = wd.get_arguments("cluster") or {}
         stored_resolved = stored.get("primary_estimator_resolved")
-        if stored_resolved is not None and stored_resolved != snapshot["primary_estimator_resolved"]:
+        if stored_resolved is not None and stored_resolved != route:
             logger.warning(
-                "resuming a workdir whose primary estimator resolved to %r, but this "
-                "run would resolve to %r (N or device count crossed an auto-selection "
-                "boundary). The cached Mdb is kept — its per-pair values differ from a "
-                "fresh run within estimator variance; delete Cdb/Mdb to recompute.",
-                stored_resolved, snapshot["primary_estimator_resolved"],
+                "resuming a workdir whose primary took the route %r, but this run "
+                "would take %r (N, the device count or a flag crossed a route "
+                "boundary). The cached tables are kept: they are that route's; "
+                "delete Cdb/Mdb to recompute on this one.",
+                stored_resolved, route,
             )
         logger.info("resuming: Cdb present with matching cluster arguments — skipping recompute")
         return wd.get_db("Cdb")
@@ -730,7 +706,7 @@ def d_cluster_wrapper(
         with counters.stage("ingest_or_cache"):
             gs = sketches.keep(bdb["genome"])
     else:
-        with _ingest_stage(wd, bdb["genome"], kw, snapshot["primary_estimator_resolved"]):
+        with _ingest_stage(wd, bdb["genome"], kw, route):
             gs = sketch_genomes(bdb, wd=wd, **_sketch_args(kw))
     n = len(gs.names)
     logger.info("clustering %d genomes (primary=%s, secondary=%s)", n, kw["primary_algorithm"], kw["S_algorithm"])

@@ -7,7 +7,9 @@ registered lazily there.
 Both engines pick their execution layout automatically: single-device tiled
 loops on one chip, ring-sharded ``shard_map`` all-pairs (parallel/allpairs)
 when the mesh has more than one device and the problem is big enough to
-amortize the collectives.
+amortize the collectives. The primary computes ONE estimator on every
+layout (union-bottom-s, the reference Mash): the layouts differ in
+execution, and `dense_primary_route` names the one a run takes.
 
 Every dense path is TRIANGLE-ONLY (ISSUE 1): Mash distance and the raw
 MinHash/FracMinHash intersection size are symmetric, so each engine
@@ -67,37 +69,15 @@ def _mesh_or_none(mesh_shape: int | None, n: int, local_only: bool = False):
     return None
 
 
-# below this the MXU estimator's host chunk-prep outweighs its matmul win
-MATMUL_MIN_GENOMES = 512
-
-
-def resolve_primary_estimator(
-    n: int,
-    mesh_shape: int | None,
-    estimator: str,
-    sketch_width: int,
-) -> str:
-    """The concrete estimator :func:`mash_distance_matrix` will run for `n`
-    genomes on THIS host ('ring_sort' | 'pallas_sort' | 'matmul' | 'sort').
-
-    Recorded into the cluster resume snapshot: 'auto' silently switches
-    family with N (and with device count), and the families agree only in
-    expectation — per-pair Mdb values differ within estimator variance. A
-    resumed workdir whose stored resolution differs gets a loud warning
-    (cluster/controller.py) instead of silently mixing numerics. NB:
-    'pallas_sort' and 'sort' are the SAME estimator (bit-equal values,
-    different execution) — the boundary warning keys on numerics, so the
-    two share the 'sort' family tag below.
-    """
-    from drep_tpu.ops.pallas_mash import pallas_mash_supported
-
-    if _mesh_or_none(mesh_shape, n) is not None:
-        return "ring_sort"
-    if estimator in ("auto", "sort") and pallas_mash_supported(sketch_width):
-        return "sort"  # pallas execution, identical numerics to the jnp sort
-    if estimator == "matmul" or (estimator == "auto" and n >= MATMUL_MIN_GENOMES):
-        return "matmul"
-    return "sort"
+def dense_primary_route(n: int, mesh_shape: int | None):
+    """(route name, mesh | None) of a dense primary over `n` genomes on
+    THIS host: 'ring_sort' on the mesh the ring will run on, else 'sort'.
+    The name is what the cluster snapshot records
+    (controller._primary_route); :func:`mash_distance_matrix` runs what it
+    says. Every dense route computes the one estimator (union-bottom-s,
+    the reference Mash) — they differ in execution alone."""
+    mesh = _mesh_or_none(mesh_shape, n)
+    return ("ring_sort" if mesh is not None else "sort"), mesh
 
 
 def mash_distance_matrix(
@@ -105,7 +85,6 @@ def mash_distance_matrix(
     k: int,
     mesh_shape: int | None = None,
     tile: int = 256,
-    estimator: str = "auto",
 ) -> np.ndarray:
     """[N, N] Mash distance with automatic single-chip / mesh selection.
 
@@ -115,48 +94,27 @@ def mash_distance_matrix(
     All dispatch targets are triangle-only: the mesh ring runs the
     half-ring schedule (ceil((D+1)/2) of D steps + host mirror), the
     Pallas path its wrapped symmetric grid, the sort tiles an upper-
-    triangle walk, and the MXU estimator canonical (bi <= bj) blocks —
-    each exactly equal to its full-grid twin at ~half the tile work.
+    triangle walk — each exactly equal to its full-grid twin at ~half the
+    tile work.
 
-    `estimator`: 'auto' (mesh ring if multi-device, else MXU matmul for
-    large N, else sort tiles), 'sort' (union-bottom-s, the reference Mash
-    estimator), or 'matmul' (common-threshold MXU estimator — same
-    unbiased family, ~2.5x faster single-chip; see ops/minhash_matmul.py).
+    A mesh -> the ring; one device -> the Pallas kernel where
+    `pallas_mash_supported` (a TPU, the sketch within the kernel's VMEM
+    width); else the jnp sort tiles (`all_vs_all_mash`: the tests'
+    reference, and what a CPU runs). One estimator at any N: the three
+    differ in execution, never in numerics family.
     """
-    if estimator not in ("auto", "sort", "matmul"):
-        raise ValueError(f"unknown mash estimator {estimator!r}")
-    mesh = _mesh_or_none(mesh_shape, packed.n)
-    # the ring path computes the sort (union-bottom-s) estimator, so it
-    # serves both 'auto' and an explicit 'sort' request on a mesh
-    if mesh is not None:
-        if estimator == "matmul":
-            from drep_tpu.utils.logger import get_logger
-
-            get_logger().warning(
-                "primary_estimator='matmul' is single-chip only — using the "
-                "mesh ring (sort estimator) to honor the %d-device mesh",
-                mesh.devices.size,
-            )
+    route, mesh = dense_primary_route(packed.n, mesh_shape)
+    if route == "ring_sort":
         from drep_tpu.parallel.allpairs import sharded_mash_allpairs
 
         return sharded_mash_allpairs(packed, k=k, mesh=mesh)
     from drep_tpu.ops.pallas_mash import all_vs_all_mash_pallas, pallas_mash_supported
 
-    if estimator in ("auto", "sort") and pallas_mash_supported(packed.sketch_size):
-        # single-chip TPU: the VMEM-resident Pallas kernel computes the
-        # reference-faithful sort estimator, and was faster end to end
-        # than the MXU matmul family in an earlier chip run (not
-        # re-measured — ROADMAP D3)
+    if pallas_mash_supported(packed.sketch_size):
         dist, _jac = all_vs_all_mash_pallas(packed, k=k)
         return dist
-    # the tile loops below ship, dispatch and read back tile by tile: the
+    # the tile loop ships, dispatches and reads back tile by tile: the
     # whole call is the host waiting on the device
-    if estimator == "matmul" or (estimator == "auto" and packed.n >= MATMUL_MIN_GENOMES):
-        from drep_tpu.ops.minhash_matmul import all_vs_all_mash_matmul
-
-        with counters.span("primary/wait"):
-            dist, _jac = all_vs_all_mash_matmul(packed, k=k)
-        return dist
     with counters.span("primary/wait"):
         dist, _jac = all_vs_all_mash(packed, k=k, tile=tile)
     return dist
@@ -188,7 +146,6 @@ def primary_jax_mash(
     gs: GenomeSketches,
     tile: int = 256,
     mesh_shape: int | None = None,
-    primary_estimator: str = "auto",
     processes: int = 1,
     **_,
 ) -> tuple[np.ndarray, np.ndarray]:
@@ -198,9 +155,7 @@ def primary_jax_mash(
     (the Mdb convention).
     """
     packed = pack_primary(gs.bottom, gs.names, gs.sketch_size, processes)
-    dist = mash_distance_matrix(
-        packed, gs.k, mesh_shape=mesh_shape, tile=tile, estimator=primary_estimator
-    )
+    dist = mash_distance_matrix(packed, gs.k, mesh_shape=mesh_shape, tile=tile)
     return dist, 1.0 - dist
 
 

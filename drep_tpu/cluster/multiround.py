@@ -33,7 +33,6 @@ def _cluster_chunk(
     cutoff: float,
     method: str,
     mesh_shape: int | None,
-    estimator: str = "auto",
     processes: int = 1,
 ) -> np.ndarray:
     from drep_tpu.cluster.engines import mash_distance_matrix, pack_primary
@@ -41,7 +40,7 @@ def _cluster_chunk(
     packed = pack_primary(
         [gs.bottom[i] for i in idx], [gs.names[i] for i in idx], gs.sketch_size, processes
     )
-    dist = mash_distance_matrix(packed, gs.k, mesh_shape=mesh_shape, estimator=estimator)
+    dist = mash_distance_matrix(packed, gs.k, mesh_shape=mesh_shape)
     labels, _ = cluster_hierarchical(dist, cutoff, method=method)
     return labels
 
@@ -56,7 +55,6 @@ def multiround_primary_clustering(
     cutoff = 1.0 - kw["P_ani"]
     method = kw["clusterAlg"]
     mesh_shape = kw.get("mesh_shape")
-    estimator = kw.get("primary_estimator", "auto")
     processes = kw.get("processes", 1)
     nk = gs.gdb["n_kmers"].to_numpy()
 
@@ -67,7 +65,7 @@ def multiround_primary_clustering(
     for c0 in range(0, n, chunk):
         idx = list(range(c0, min(c0 + chunk, n)))
         pairs_compared += len(idx) * (len(idx) - 1) // 2
-        labels = _cluster_chunk(gs, idx, cutoff, method, mesh_shape, estimator, processes)
+        labels = _cluster_chunk(gs, idx, cutoff, method, mesh_shape, processes)
         # one grouping pass — a per-label membership scan is
         # O(clusters * chunk), ~170M Python iterations at the 100k scale
         groups: dict[int, list[int]] = {}
@@ -83,7 +81,7 @@ def multiround_primary_clustering(
 
     # round 2: cluster the representatives
     pairs_compared += len(reps) * (len(reps) - 1) // 2
-    rep_labels = _cluster_chunk(gs, reps, cutoff, method, mesh_shape, estimator, processes)
+    rep_labels = _cluster_chunk(gs, reps, cutoff, method, mesh_shape, processes)
     label_of_rep = {rep: int(rep_labels[t]) for t, rep in enumerate(reps)}
 
     raw = np.array([label_of_rep[int(rep_of_genome[i])] for i in range(n)], dtype=np.int64)

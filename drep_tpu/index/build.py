@@ -117,11 +117,13 @@ def _params_from_workdir(wd) -> dict:
     resolved = snap.get("primary_estimator_resolved")
     if resolved is not None and resolved != "streaming_sort":
         get_logger().warning(
-            "index build: the source run's primary estimator resolved to %r; "
-            "incremental updates always compare with the streaming sort "
-            "estimator, so snapshot edges and update edges agree within "
-            "estimator variance (run the source with --streaming_primary "
-            "for exact numerics)", resolved,
+            "index build: the source run's primary took the route %r; "
+            "incremental updates always compare with the streaming tiles, "
+            "which take the distance's logarithm in float32 on the device: "
+            "snapshot edges and update edges are the same estimator and "
+            "differ by that rounding (up to 2e-6 against the dense routes' "
+            "4e-9 on the chip; run the source with --streaming_primary for "
+            "equal bytes)", resolved,
         )
     return resolve_params(
         P_ani=snap.get("P_ani"), S_ani=snap.get("S_ani"),

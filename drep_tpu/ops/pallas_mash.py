@@ -258,11 +258,8 @@ def _unwrap_symmetric(compact: np.ndarray, tile: int) -> np.ndarray:
 
 def all_vs_all_mash_pallas(packed, k: int = 21) -> tuple[np.ndarray, np.ndarray]:
     """Full [N, N] (distance, jaccard) for one packed sketch set — the
-    single-chip TPU primary engine (faster end to end than the MXU
-    common-threshold estimator in an earlier chip run, not re-measured —
-    ROADMAP D3 — AND it computes the reference-faithful union-bottom-s
-    estimator, not an alternative family). Same output
-    contract as ops/minhash.py::all_vs_all_mash."""
+    single-chip TPU primary engine: the reference-faithful union-bottom-s
+    estimator. Same output contract as ops/minhash.py::all_vs_all_mash."""
     from drep_tpu.utils.profiling import counters
 
     n = packed.n
@@ -331,7 +328,10 @@ def shared_counts_to_distance(
 
 def pallas_mash_supported(sketch_width: int) -> bool:
     """True when the compiled kernel path applies: on-TPU and the padded
-    width fits the VMEM budget."""
+    width fits the VMEM budget. A wider sketch on a TPU (`-ms` over
+    PALLAS_MAX_WIDTH; no deployment sketches wider than 1,000) takes the
+    jnp sort tiles (ops/minhash.all_vs_all_mash) at any N: the same
+    estimator, its merge temporaries in HBM."""
     return (
         not _use_interpret()
         and max(128, next_pow2(sketch_width)) <= PALLAS_MAX_WIDTH

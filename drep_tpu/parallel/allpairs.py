@@ -45,8 +45,8 @@ HeartbeatManager death verdict between steps makes the survivors abandon
 the (now unusable) full-pod collective, re-deal every missing block
 across the live set, and assemble a distance matrix bit-identical to a
 healthy run from the shared shard store. The monolithic single-program
-ring is kept behind ``monolithic=True`` / ``--ring_monolithic`` /
-``DREP_TPU_RING_MONOLITHIC=1`` as the bit-equality reference.
+ring is kept behind the ``monolithic=True`` argument as the bit-equality
+reference the tests compare against; no flag or knob selects it.
 
 Every step runs the one shard_map program (:func:`_ring_step_fn`: the
 tile, then the ``lax.ppermute`` hop). A step that FAULTS at run time
@@ -70,17 +70,13 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from drep_tpu.ops.containment import ani_cov_from_intersections, containment_inter_tile
 from drep_tpu.ops.minhash import PackedSketches, mash_distance_tile, pad_packed_rows
 from drep_tpu.parallel.mesh import AXIS, make_mesh
-from drep_tpu.utils import envknobs, telemetry
+from drep_tpu.utils import telemetry
 from drep_tpu.utils.logger import get_logger
 from drep_tpu.utils.profiling import counters
 
 # the stage a ring's spans are booked to, by the kind of its tile
 # ("primary/wait", "secondary/assemble", ...)
 _STAGE_OF_KIND = {"mash": "primary", "containment": "secondary"}
-
-# monolithic-reference opt-in: explicit argument > configure_ring() >
-# env var > step-wise default
-RING_MONOLITHIC_ENV = "DREP_TPU_RING_MONOLITHIC"
 
 # per-ring-step AutoTimeout warmup: exclude exactly the FIRST step's wait
 # from the rolling median — it absorbs whatever is still cold after the
@@ -93,30 +89,18 @@ RING_STEP_WARMUP = 1
 # controller from the CLI flags (same pattern as faulttol's
 # configure_defaults): engines call ring_allpairs deep inside replicated
 # control flow and cannot thread a workdir down to it.
-_RING_CONFIG: dict = {"monolithic": None, "checkpoint_base": None}
+_RING_CONFIG: dict = {"checkpoint_base": None}
 
 
-def configure_ring(
-    monolithic: bool | None = None,
-    checkpoint_base: str | None = None,
-) -> None:
-    """Install run-wide ring defaults: `monolithic` forces the single
-    collective reference program; `checkpoint_base` roots the step-wise
-    ring's per-call block shard stores (one subdirectory per distinct
-    input fingerprint, created lazily when a ring actually runs).
+def configure_ring(checkpoint_base: str | None = None) -> None:
+    """Install the run-wide ring default: `checkpoint_base` roots the
+    step-wise ring's per-call block shard stores (one subdirectory per
+    distinct input fingerprint, created lazily when a ring actually runs).
 
-    This REPLACES the whole config — an omitted argument resets that knob
-    to its default (None), it does not preserve the previous value; a
-    bare ``configure_ring()`` is the full reset (tests rely on it). To
-    flip one knob mid-run, pass all."""
-    _RING_CONFIG["monolithic"] = monolithic
+    This REPLACES the config — an omitted argument resets it to its
+    default (None), it does not preserve the previous value; a bare
+    ``configure_ring()`` is the full reset (tests rely on it)."""
     _RING_CONFIG["checkpoint_base"] = checkpoint_base
-
-
-def ring_monolithic_default() -> bool:
-    if _RING_CONFIG["monolithic"] is not None:
-        return bool(_RING_CONFIG["monolithic"])
-    return envknobs.env_bool(RING_MONOLITHIC_ENV)
 
 
 def half_ring_steps(n_devices: int) -> int:
@@ -462,7 +446,7 @@ def ring_allpairs(
     k: int,
     mesh=None,
     full_grid: bool = False,
-    monolithic: bool | None = None,
+    monolithic: bool = False,
     checkpoint_dir: str | None = None,
     ft_config=None,
 ) -> tuple[np.ndarray, ...]:
@@ -477,19 +461,16 @@ def ring_allpairs(
 
     Execution is HOST-STEPPED by default (one dispatch per ring step,
     per-step block tiles checkpointable and individually redoable — the
-    elastic dense engine, module docstring); ``monolithic=True`` (or the
-    run-wide flag / env) forces the original single collective program,
-    kept as the bit-equality reference. `checkpoint_dir` overrides the
-    configured per-call block store location (None + no configured base =
-    in-memory only).
+    elastic dense engine, module docstring); ``monolithic=True`` runs the
+    original single collective program, kept as the bit-equality
+    reference. `checkpoint_dir` overrides the configured per-call block
+    store location (None + no configured base = in-memory only).
     """
     if mesh is None:
         mesh = make_mesh()
     n_devices = mesh.devices.size
     half = not full_grid
     n = packed.n
-    if monolithic is None:
-        monolithic = ring_monolithic_default()
     if not monolithic:
         # honest accounting: the step-wise path reports the block tiles
         # THIS process actually computed this call — a full store resume
@@ -1286,7 +1267,7 @@ def sharded_mash_allpairs(
     k: int = 21,
     mesh=None,
     full_grid: bool = False,
-    monolithic: bool | None = None,
+    monolithic: bool = False,
     checkpoint_dir: str | None = None,
     ft_config=None,
 ) -> np.ndarray:
@@ -1307,7 +1288,7 @@ def sharded_containment_allpairs(
     k: int = 21,
     mesh=None,
     full_grid: bool = False,
-    monolithic: bool | None = None,
+    monolithic: bool = False,
     checkpoint_dir: str | None = None,
     ft_config=None,
 ) -> tuple[np.ndarray, np.ndarray]:

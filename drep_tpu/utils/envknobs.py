@@ -88,12 +88,6 @@ _declare(
     "Mid-run join request on a NEW process: 'auto' derives an id from the "
     "pod's notes, an integer pins one. Empty = not a joiner.",
 )
-# -- dense ring --------------------------------------------------------------
-_declare(
-    "DREP_TPU_RING_MONOLITHIC", "bool", False,
-    "Run the dense ring as the single fori_loop program (the pre-PR-4 "
-    "reference) instead of host-stepped redoable units.",
-)
 # -- single-chip kernels -----------------------------------------------------
 _declare(
     "DREP_TPU_INDICATOR_DTYPE", "str", None,

@@ -106,9 +106,10 @@ def test_cli_subprocess_compare(tmp_path, genome_paths):
 
 
 def test_resume_warns_on_estimator_boundary(tmp_path, genome_paths):
-    """A resumed workdir whose 'auto' primary estimator resolved differently
-    (N or device count crossed a selection boundary) must still resume —
-    but with a loud warning, never a silent numerics mix."""
+    """A resumed workdir whose primary took another route than this run
+    would (N, the device count or a flag crossed a route boundary: a ring's
+    tables are not a streaming run's byte for byte) must still resume —
+    but with a loud warning that names both routes."""
     import json
 
     wd = str(tmp_path / "wd")
@@ -117,9 +118,8 @@ def test_resume_warns_on_estimator_boundary(tmp_path, genome_paths):
     with open(loc) as f:
         args = json.load(f)
     assert "primary_estimator_resolved" in args
-    args["primary_estimator_resolved"] = (
-        "matmul" if args["primary_estimator_resolved"] != "matmul" else "sort"
-    )
+    was = args["primary_estimator_resolved"]
+    args["primary_estimator_resolved"] = "ring_sort" if was != "ring_sort" else "sort"
     # snapshots carry an in-band checksum (utils/durableio.py); a hand
     # edit must drop the now-stale crc — a crc-less snapshot is
     # legacy-accepted, a mismatched one is (correctly) treated as rot
@@ -131,7 +131,8 @@ def test_resume_warns_on_estimator_boundary(tmp_path, genome_paths):
     # stream) — assert via the workdir log file the file handler writes
     with open(os.path.join(wd, "log", "logger.log")) as f:
         log = f.read()
-    assert "estimator resolved" in log
+    assert f"primary took the route {args['primary_estimator_resolved']!r}" in log
+    assert f"would take {was!r}" in log
     assert "skipping recompute" in log  # resumed, not recomputed
     assert len(cdb) == len(genome_paths)
 

@@ -3,9 +3,9 @@ references, on the 8-device virtual CPU mesh.
 
 Every dense compare engine exploits output symmetry: the half-ring
 (parallel/allpairs.py), the blocked upper-triangle matmuls
-(ops/minhash_matmul.py, ops/containment.py), and the tiled searchsorted
-fallback. Each triangular path must be EXACTLY equal (same float32 bits)
-to its full-grid twin — the mirrored blocks are transposed copies of
+(ops/containment.py), and the tiled searchsorted fallback. Each
+triangular path must be EXACTLY equal (same float32 bits) to its full-grid
+twin — the mirrored blocks are transposed copies of
 bit-identical symmetric payloads — and the profiling counters must prove
 the triangular schedule engaged (tiles_computed well under tiles_total).
 """
@@ -22,7 +22,6 @@ from drep_tpu.ops.containment import (
     pack_scaled_sketches,
 )
 from drep_tpu.ops.minhash import all_vs_all_mash, pack_sketches
-from drep_tpu.ops.minhash_matmul import all_vs_all_mash_matmul
 from drep_tpu.parallel.allpairs import (
     half_ring_steps,
     sharded_containment_allpairs,
@@ -180,6 +179,40 @@ def test_stepwise_ring_equals_monolithic_bit_exact(rng, n_dev):
         )
 
 
+def test_the_reference_ring_is_reached_by_argument_alone(rng, monkeypatch):
+    """The monolithic ring is the tests' reference, an argument of the
+    function: the environment variable that once selected it steers
+    nothing now — a step-wise ring under it books the step-wise
+    schedule's counters and returns the same bytes."""
+    from drep_tpu.parallel.allpairs import configure_ring, ring_tiles_computed
+
+    configure_ring()  # hermetic: no store base leaked from earlier tests
+    n_dev = 4
+    mesh = make_mesh(n_dev)
+    packed = pack_sketches(_sketch_set(rng, 21, 64), [f"g{i}" for i in range(21)], 64)
+
+    def steps_booked():
+        ph = counters.phases.get(("ring_step", True))
+        return ph.calls if ph else 0
+
+    def ring(**how):
+        s0, t0 = steps_booked(), _tile_diff("primary_compare")[0]
+        out = sharded_mash_allpairs(packed, k=21, mesh=mesh, **how)
+        return out, steps_booked() - s0, _tile_diff("primary_compare")[0] - t0
+
+    plain, plain_steps, plain_tiles = ring()
+    # drep-lint: allow[env-knob] — the retired name, set to show that nothing reads it
+    monkeypatch.setenv("DREP_TPU_RING_MONOLITHIC", "1")
+    under, under_steps, under_tiles = ring()
+    assert under.tobytes() == plain.tobytes()
+    # a `ring_step` span a host-dispatched step; the one program books none
+    assert under_steps == plain_steps == half_ring_steps(n_dev)
+    assert under_tiles == plain_tiles == ring_tiles_computed(n_dev, half=True)
+    mono, mono_steps, _ = ring(monolithic=True)
+    assert mono_steps == 0
+    assert mono.tobytes() == plain.tobytes()
+
+
 # the edge shapes of the ring's blocks: one row a device, an empty shard
 # (a device whose whole block is padding), a single genome
 @pytest.mark.parametrize("n_genomes", ["n_dev", "n_dev-1", "1"])
@@ -236,18 +269,6 @@ def test_ring_step_autotimeout_excludes_first_step_only():
     for _ in range(5):
         auto_default.note(0.01)
     assert auto_default.derived() is None
-
-
-@pytest.mark.parametrize("n", [20, 300])  # spans the _TRI_BLOCK boundary
-def test_mash_matmul_triangular_equals_full(rng, n):
-    s = 48
-    packed = pack_sketches(_sketch_set(rng, n, s), [f"g{i}" for i in range(n)], s)
-    d_tri, j_tri = all_vs_all_mash_matmul(packed, k=21, chunk_entries=512)
-    d_full, j_full = all_vs_all_mash_matmul(
-        packed, k=21, chunk_entries=512, triangular=False
-    )
-    np.testing.assert_array_equal(d_tri, d_full)
-    np.testing.assert_array_equal(j_tri, j_full)
 
 
 def test_containment_matmul_triangular_equals_full(rng):
