@@ -396,8 +396,17 @@ def _secondary_stage(
     """The secondary (ANI) stage over every primary cluster: ({genome:
     "P_S" secondary name}, Ndb parts in cluster order, {primary cluster:
     its linkage and names} for Clustering_files)."""
+    import jax
+
     from drep_tpu.cluster.secondary_ckpt import SecondaryCheckpoint
-    from drep_tpu.parallel.faulttol import pod_dead, pod_epoch, pod_live, retrying_call
+    from drep_tpu.parallel.faulttol import (
+        drain_at_boundary,
+        pod_dead,
+        pod_epoch,
+        pod_live,
+        retrying_call,
+    )
+    from drep_tpu.utils import faults
 
     secondary_names: dict[str, str] = {}
     greedy = kw["greedy_secondary_clustering"]
@@ -448,6 +457,18 @@ def _secondary_stage(
             sec_snapshot, primary, gs.names,
         )
     results: dict[int, tuple[pd.DataFrame, np.ndarray, np.ndarray]] = {}
+    alone = jax.process_count() == 1
+
+    def publish(pc: int) -> None:
+        """Save cluster `pc`'s checkpoint; a one-process job with a drain
+        pending leaves right after it (a pod's members stay: their peers
+        hold the same loop)."""
+        with counters.span("secondary/checkpoint"):
+            ckpt.save(pc, *results[pc])
+        faults.fire("secondary_checkpoint")
+        if alone and ckpt.dir is not None:
+            drain_at_boundary("secondary", clusters_published=len(results), last_cluster=pc)
+
     small: list[tuple[int, list[int]]] = []
     for pc, indices in multi:
         m = len(indices)
@@ -455,6 +476,7 @@ def _secondary_stage(
             cached = ckpt.load(pc)
         if cached is not None:
             results[pc] = cached  # resumed: 0 pairs counted
+            counters.add_resume(clusters_resumed=1)
         elif batched_fn is not None and m <= SMALL_CLUSTER_MAX:
             small.append((pc, indices))  # one device call for many
         elif greedy:
@@ -463,9 +485,9 @@ def _secondary_stage(
             with counters.stage("secondary_compare"):
                 ndb, labels = greedy_secondary_cluster(gs, bdb, indices, pc, kw)
             counters.stages["secondary_compare"].pairs += len(ndb)  # actual comparisons made
+            counters.add_resume(clusters_computed=1)
             results[pc] = (ndb, labels, np.empty((0, 4)))
-            with counters.span("secondary/checkpoint"):
-                ckpt.save(pc, *results[pc])
+            publish(pc)
         else:
             with counters.stage("secondary_compare", pairs=m * (m - 1) // 2):
                 # a transient device failure on one big cluster must
@@ -484,8 +506,8 @@ def _secondary_stage(
                     config=ft_cfg,
                     local_only=True,
                 )
-            with counters.span("secondary/checkpoint"):
-                ckpt.save(pc, *results[pc])
+            counters.add_resume(clusters_computed=1)
+            publish(pc)
 
     # flush the small clusters in row-bounded batches
     batches: list[list[tuple[int, list[int]]]] = []
@@ -519,6 +541,7 @@ def _secondary_stage(
                 # (engines._mesh_or_none local_only): retryable on pods
                 local_only=True,
             )
+        counters.add_resume(clusters_computed=len(batch))
         with counters.stage("secondary_postprocess"):
             for (pc, indices), (ani, cov) in zip(batch, outs, strict=True):
                 if greedy:
@@ -529,8 +552,7 @@ def _secondary_stage(
                     results[pc] = (ndb, labels, np.empty((0, 4)))
                 else:
                     results[pc] = _secondary_postprocess(gs, indices, pc, kw, ani, cov)
-                with counters.span("secondary/checkpoint"):
-                    ckpt.save(pc, *results[pc])
+                publish(pc)
 
     if pod_live() is not None and ckpt.dir is not None:
         # the pod lost member(s) somewhere before/inside the secondary
@@ -717,12 +739,17 @@ def d_cluster_wrapper(
     # counters.add below keeps the stage's totals (counters.stage cannot
     # wrap this site — pairs_done is only known after the call); the span
     # is the container of the primary/* phases
-    with counters.span("stage:primary_compare"):
-        primary, pdist, plink, sparse_mdb, pairs_done = _primary_clusters(
-            gs, bdb, kw, wd=wd, ft_cfg=ft_cfg
-        )
+    from drep_tpu.parallel.faulttol import PodDrained, pod_dead, pod_epoch, pod_live
+
+    try:
+        with counters.span("stage:primary_compare"):
+            primary, pdist, plink, sparse_mdb, pairs_done = _primary_clusters(
+                gs, bdb, kw, wd=wd, ft_cfg=ft_cfg
+            )
+    except PodDrained as drained:  # the stage as far as it came, in the attempt's record
+        counters.add("primary_compare", pairs=drained.pairs, seconds=_time.perf_counter() - t0)
+        raise
     counters.add("primary_compare", pairs=pairs_done, seconds=_time.perf_counter() - t0)
-    from drep_tpu.parallel.faulttol import pod_dead, pod_epoch, pod_live
 
     if pod_live() is not None:
         # the elastic streaming stage lost pod member(s) and completed on

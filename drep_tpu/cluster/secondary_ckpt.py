@@ -53,18 +53,24 @@ class SecondaryCheckpoint:
         if not os.path.exists(loc):
             return None
         from drep_tpu.utils import durableio
+        from drep_tpu.utils.profiling import counters
 
         def convert(z):
             cols = [str(c) for c in z["ndb_columns"]]
             ndb = pd.DataFrame({c: z[f"ndb_col_{c}"] for c in cols})
             return ndb, z["labels"], z["link"]
 
-        result = durableio.load_npz_or_none(
-            loc, what="secondary checkpoint", convert=convert,
-            warn="secondary checkpoint: unreadable %s — recomputing",
-        )
+        # a stopped job's checkpoint, read back: a span of its own inside the
+        # caller's `secondary/checkpoint`, which a fresh job never opens
+        size = os.path.getsize(loc)
+        with counters.span("secondary/resume_load", pc=pc, clusters=1, bytes=size):
+            result = durableio.load_npz_or_none(
+                loc, what="secondary checkpoint", convert=convert,
+                warn="secondary checkpoint: unreadable %s — recomputing",
+            )
         if result is not None:
             self.n_resumed += 1  # only after the payload fully validates
+            counters.add_resume(checkpoint_bytes=size)
         return result
 
     def save(self, pc: int, ndb: pd.DataFrame, labels: np.ndarray, link: np.ndarray) -> None:

@@ -163,6 +163,10 @@ class PodDrained(Exception):
     Deliberately NOT a FaultTolError: a drain is a clean, expected exit,
     and nothing may swallow it as a retryable dispatch failure."""
 
+    # pairs the primary had compared when it left: the stage's counter is
+    # booked by the caller, who learns them only from a return otherwise
+    pairs = 0
+
 
 class WatchdogTimeout(FaultTolError):
     """A single dispatch exceeded the per-dispatch watchdog."""
@@ -315,12 +319,14 @@ def configure_defaults(config: FaultTolConfig) -> None:
 # notice that lands between stages must still drain the next one.
 
 _DRAIN_EVENT = threading.Event()
+_DRAIN_AT: list[float] = []  # time.monotonic() of the pending request, once
 
 
 def request_drain() -> None:
     """Flag this process for graceful departure at the next safe
     boundary (idempotent)."""
     if not _DRAIN_EVENT.is_set():
+        _DRAIN_AT[:] = [time.monotonic()]
         get_logger().warning(
             "elastic pod: drain requested — this process will finish its "
             "in-flight work unit, publish a planned-departure note, and "
@@ -336,6 +342,26 @@ def drain_requested() -> bool:
 def clear_drain() -> None:
     """Reset the drain flag (tests; a long-lived service re-arming)."""
     _DRAIN_EVENT.clear()
+
+
+def drain_at_boundary(stage: str, **where) -> None:
+    """A ONE-process job's safe boundary (a streaming stripe's shard
+    published, a primary cluster's secondary checkpoint published): with a
+    drain pending, enter the boundary in the job's record
+    (``Counters.note_drain``) and raise :class:`PodDrained`. There is no
+    peer to tell, so no departure note: the stores keep the finished work
+    and the same command on the same work directory goes on from here. With
+    nothing pending this is one flag test. Pod members keep their own
+    boundaries (the elastic loops), which also tell the peers."""
+    if not _DRAIN_EVENT.is_set():
+        return
+    from drep_tpu.utils.profiling import counters
+
+    counters.note_drain(stage, requested_monotonic_s=_DRAIN_AT[0], **where)
+    raise PodDrained(
+        f"{stage}: drained at a safe boundary ({where}); the finished work is "
+        f"in the work directory's stores"
+    )
 
 
 def _drain_force_exit(grace_s: float) -> None:

@@ -514,7 +514,12 @@ def streaming_mash_edges(
     """
     import jax
 
-    from drep_tpu.parallel.faulttol import TileExecutor, heartbeat_cadence_s
+    from drep_tpu.parallel.faulttol import (
+        PodDrained,
+        TileExecutor,
+        drain_at_boundary,
+        heartbeat_cadence_s,
+    )
     from drep_tpu.utils import faults as _faults
 
     logger = get_logger()
@@ -781,6 +786,11 @@ def streaming_mash_edges(
             # the elastic chaos tests SIGKILL a pod member here — at a
             # stripe boundary, with its finished shards already durable
             _faults.fire("process_death")
+            if pc == 1 and checkpoint_dir is not None:
+                # every earlier stripe's shard is published: a one-process job
+                # with a drain pending leaves here (a pod member at its own
+                # loop's boundaries, where it also tells its peers)
+                drain_at_boundary("primary", stripes_published=len(all_ii), next_stripe=bi)
             return _compute_stripe_tiles(bi, epoch)
 
     def _publish_shard(bi: int, epoch: int, s_ii, s_jj, s_dd, **note) -> None:
@@ -926,6 +936,39 @@ def streaming_mash_edges(
             _publish_shard(bi, epoch, s_ii, s_jj, s_dd)
         return s_ii, s_jj, s_dd
 
+    def _book_walk() -> None:
+        """The walk's own accounting, at its end or where a drain ends it."""
+        if tiles_full:
+            counters.add_tiles(
+                "primary_compare", computed=tiles_done, total=tiles_full,
+                skipped=tiles_skipped,
+            )
+        counters.add_resume(tiles_computed=tiles_done)
+        if prune is not None:
+            # the headline pruning gauge: fraction of the triangle/rect
+            # SCHEDULE the candidate bitmap removed this call (resumed
+            # stripes contribute to neither side — honest across resumes)
+            sched = tiles_done + tiles_skipped
+            counters.set_gauge(
+                "skip_fraction", round(tiles_skipped / sched, 4) if sched else 0.0
+            )
+        if tiles_done:
+            # how many local devices the round-robin actually reached: a
+            # multi-chip run whose tiles all landed on one chip must not
+            # read like one that used the host's four
+            counters.set_gauge(
+                "streaming_devices_used", float(sum(1 for d in ft.dispatched if d))
+            )
+            counters.add_stream_slots(
+                stripes_computed, turns, ft.dispatched, slot_pairs, slot_put_bytes, slot_wait_s
+            )
+        derived = ft.derived_timeout_s()
+        if derived is not None:
+            # the watchdog deadline the run actually derived from its own
+            # tile latencies (--dispatch_timeout left at 0) — reported so
+            # an operator can pin an explicit value from evidence
+            counters.set_gauge("derived_dispatch_timeout_s", round(derived, 3))
+
     try:
         if not elastic:
             n_resumed = 0
@@ -933,12 +976,21 @@ def streaming_mash_edges(
                 if stripe_owner(bi, n_blocks, pc) != pid:
                     continue  # another process owns this row stripe
                 found = _find_shard(checkpoint_dir, bi) if resume else None
-                loaded = _load_shard(found) if found is not None else None
+                loaded = None
+                if found is not None:
+                    # a stopped job's shard: found, verified and read, no tile dispatched
+                    size = os.path.getsize(found)
+                    with counters.span("primary/resume_load", bi=bi, shards=1, bytes=size):
+                        loaded = _load_shard(found)
                 if loaded is not None:
                     all_ii.append(loaded[0])
                     all_jj.append(loaded[1])
                     all_dd.append(loaded[2])
                     n_resumed += 1
+                    counters.add_resume(
+                        stripes_resumed=1, shard_bytes=size,
+                        tiles_resumed=n_blocks - max(bi, first_col_block),
+                    )
                     continue
                 s_ii, s_jj, s_dd = _compute_stripe(bi)
                 all_ii.append(s_ii)
@@ -975,35 +1027,7 @@ def streaming_mash_edges(
                 "(of %d local devices) — see fault_tolerance counters",
                 ft.quarantined(), len(devices),
             )
-        if tiles_full:
-            counters.add_tiles(
-                "primary_compare", computed=tiles_done, total=tiles_full,
-                skipped=tiles_skipped,
-            )
-        if prune is not None:
-            # the headline pruning gauge: fraction of the triangle/rect
-            # SCHEDULE the candidate bitmap removed this call (resumed
-            # stripes contribute to neither side — honest across resumes)
-            sched = tiles_done + tiles_skipped
-            counters.set_gauge(
-                "skip_fraction", round(tiles_skipped / sched, 4) if sched else 0.0
-            )
-        if tiles_done:
-            # how many local devices the round-robin actually reached: a
-            # multi-chip run whose tiles all landed on one chip must not
-            # read like one that used the host's four
-            counters.set_gauge(
-                "streaming_devices_used", float(sum(1 for d in ft.dispatched if d))
-            )
-            counters.add_stream_slots(
-                stripes_computed, turns, ft.dispatched, slot_pairs, slot_put_bytes, slot_wait_s
-            )
-        derived = ft.derived_timeout_s()
-        if derived is not None:
-            # the watchdog deadline the run actually derived from its own
-            # tile latencies (--dispatch_timeout left at 0) — reported so
-            # an operator can pin an explicit value from evidence
-            counters.set_gauge("derived_dispatch_timeout_s", round(derived, 3))
+        _book_walk()
         with counters.span("primary/assemble"):
             ii = np.concatenate(all_ii) if all_ii else np.empty(0, np.int64)
             jj = np.concatenate(all_jj) if all_jj else np.empty(0, np.int64)
@@ -1011,6 +1035,11 @@ def streaming_mash_edges(
         if pc > 1 and not elastic:
             ii, jj, dd, pairs_computed = _allgather_edges(ii, jj, dd, pairs_computed)
         return ii, jj, dd, pairs_computed
+    except PodDrained as drained:
+        # the attempt's record holds what it did up to the boundary
+        drained.pairs = pairs_computed
+        _book_walk()
+        raise
     finally:
         if hb is not None:
             hb.close()

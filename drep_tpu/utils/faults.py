@@ -46,7 +46,10 @@ SIGKILL: no cleanup, no atexit, heartbeats simply stop). The ``drain``
 mode at the same two sites is the GRACEFUL counterpart: it flags the
 process for a planned departure (faulttol.request_drain — the SIGTERM
 path minus the signal), consumed at that very boundary: departure note
-published, PodDrained raised, exit 0.
+published, PodDrained raised, exit 0. A one-process job consumes it too,
+with no note (faulttol.drain_at_boundary): at ``process_death``, and at
+``secondary_checkpoint``, fired after each primary cluster's secondary
+checkpoint is published.
 
 Zero overhead when unset: the spec parses once (lazily, from the env);
 every :func:`fire` call thereafter is a no-op behind one falsy check.
@@ -69,6 +72,10 @@ SITES = (
     "ring_dispatch",  # ring step/recovery dispatch waits, parallel/allpairs.py
     "ring_step",  # per-ring-step host boundary, parallel/allpairs.py (kill)
     "secondary_batch",  # secondary engine calls, cluster/controller.py
+    "secondary_checkpoint",  # after each primary cluster's secondary
+    # checkpoint is published, cluster/controller.py (drain: a one-process
+    # job leaves there, exit 0, and the rerun looks every published
+    # cluster up; skip=N lets N clusters publish first)
     "shard_write",  # atomic shard publish, utils/durableio.py (torn)
     "allgather",  # multi-host edge allgather, parallel/streaming.py
     "barrier",  # checkpoint-dir open barrier, utils/ckptmeta.py
@@ -149,6 +156,8 @@ IO_MODES = ("io_error", "stale_read", "enospc", "corrupt")
 # it); dup = deliver the reply line twice (request-id echo must dedupe).
 WIRE_MODES = ("reset", "stall", "slow", "short_read", "garble", "dup")
 MODES = ("raise", "hang", "sleep", "torn", "kill", "drain") + IO_MODES + WIRE_MODES
+# where a drain request is looked at right after the fire point
+DRAIN_SITES = ("process_death", "ring_step", "secondary_checkpoint")
 
 
 class InjectedFault(RuntimeError):
@@ -229,14 +238,14 @@ def _parse(spec: str) -> dict[str, list[_Rule]]:
                 f"shard_write:torn for torn publishes, or "
                 f"process_death/ring_step:kill for deaths"
             )
-        if mode == "drain" and site not in ("process_death", "ring_step"):
-            # the drain request is consumed at the elastic loops' safe
-            # boundaries, which are exactly the death sites' fire points —
-            # anywhere else the flag would be set but never honored and
-            # the chaos run would claim coverage while testing nothing
+        if mode == "drain" and site not in DRAIN_SITES:
+            # the drain request is consumed at the safe boundaries, which
+            # are exactly these sites' fire points — anywhere else the flag
+            # would be set but never honored and the chaos run would claim
+            # coverage while testing nothing
             raise FaultSpecError(
                 f"mode 'drain' fires only at the safe-boundary sites "
-                f"process_death/ring_step (got site {site!r})"
+                f"{'/'.join(DRAIN_SITES)} (got site {site!r})"
             )
         if mode in WIRE_MODES and site != "wire":
             # the proxy is the only consumer: router_leg:garble would

@@ -440,6 +440,20 @@ class Counters:
     # and its `rows`; `warnings`, the lines by kind; their `bytes`; and the
     # `distinct` texts rendered for them (names encoded, values formatted)
     evaluate: dict[str, Any] = field(default_factory=dict)
+    # what this job found in the stores an earlier, stopped job of the same
+    # work directory left, beside what it computed itself (ISSUE 47):
+    # `stripes_resumed`, `tiles_resumed`, `shard_bytes` (streaming shards read
+    # back and the upper-triangle tiles they stand for) against
+    # `tiles_computed`; `clusters_resumed`, `checkpoint_bytes` (secondary
+    # checkpoints read back) against `clusters_computed` (primary clusters
+    # that went through a device call, published or not). A fresh job reads
+    # zeros on the resumed side
+    resume: dict[str, int] = field(default_factory=dict)
+    # the safe boundary at which a one-process job honoured a drain request
+    # (faulttol.drain_at_boundary): `stage`, where in it, and when the
+    # request was made on time.monotonic()'s clock; empty for a job that ran
+    # to its end
+    drain: dict[str, Any] = field(default_factory=dict)
     # the programs this job built (ISSUE 36), keyed (function, the innermost
     # span open on the thread that built it): _BUILT_FIELDS. Booked by the
     # jax.monitoring listeners (:func:`listen_for_compiles`)
@@ -733,6 +747,19 @@ class Counters:
         for name, value in booked.items():
             self.filter[name] = self.filter.get(name, 0) + int(value)
 
+    def add_resume(self, **counts: int) -> None:
+        """Add to the record's `resume`: what was read back from a stopped
+        job's stores and what was computed here."""
+        for name, value in counts.items():
+            self.resume[name] = self.resume.get(name, 0) + int(value)
+
+    def note_drain(self, stage: str, **where: Any) -> None:
+        """This job leaves at a safe boundary of `stage`: the record's
+        `drain`, with the seconds the `job` span had run by then."""
+        stack = self._stack()
+        at = time.perf_counter() - stack[0]._t0 if stack else None
+        self.drain = {"stage": stage, "after_s": None if at is None else round(at, 4), **where}
+
     def add_evaluate_table(self, table: str, source: str, rows: int) -> None:
         """Book where `stage:evaluate` took the pair table `table` (`mdb`,
         `ndb`) from, and its rows."""
@@ -1003,6 +1030,10 @@ class Counters:
         if self.evaluate:
             out["evaluate"] = {name: dict(ent) if isinstance(ent, dict) else ent
                                for name, ent in self.evaluate.items()}
+        if self.resume:
+            out["resume"] = dict(self.resume)
+        if self.drain:
+            out["drain"] = dict(self.drain)
         phases = self._phases_report()
         if phases:
             out["phases"] = phases
@@ -1072,6 +1103,8 @@ class Counters:
         self.ingest.clear()
         self.filter.clear()
         self.evaluate.clear()
+        self.resume.clear()
+        self.drain = {}
         with self._lock:
             self.phases.clear()  # a span open now stays open and books when it closes
             self.built.clear()

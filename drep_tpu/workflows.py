@@ -7,6 +7,8 @@ compare = cluster -> evaluate -> analyze (no filter/choose).
 
 from __future__ import annotations
 
+import contextlib
+
 import pandas as pd
 
 from drep_tpu.choose import d_choose_wrapper
@@ -104,6 +106,23 @@ def _finish_counters(wd: WorkDirectory) -> None:
     counters.finish_job()
 
 
+@contextlib.contextmanager
+def _record_a_drain(wd: WorkDirectory):
+    """A job that leaves at a safe boundary (`PodDrained`) writes its record
+    like one that ends: its spans and counters up to there, and `drain`, the
+    boundary (profiling.Counters.note_drain). Inside the `job` span."""
+    from drep_tpu.parallel.faulttol import PodDrained
+    from drep_tpu.utils.profiling import counters
+
+    try:
+        yield
+    except PodDrained as drained:
+        if not counters.drain:  # a pod member's boundary: its loop told the peers
+            counters.note_drain("pod", message=str(drained))
+        _finish_counters(wd)
+        raise
+
+
 def compare_wrapper(wd_loc: str, genomes: list[str] | None = None, **kwargs) -> pd.DataFrame:
     """`compare`: cluster + evaluate + analyze. Returns Cdb."""
     from drep_tpu.utils import telemetry
@@ -114,7 +133,7 @@ def compare_wrapper(wd_loc: str, genomes: list[str] | None = None, **kwargs) -> 
     wd = _bring_up(wd_loc, "compare", events=kwargs.pop("events", None))
     with trace(_trace_dir(wd_loc, kwargs.pop("profile", None))), counters.span("job"):
         bdb = _load_bdb(wd, genomes or [])
-        with counters.span("stage:cluster"):
+        with _record_a_drain(wd), counters.span("stage:cluster"):
             cdb = d_cluster_wrapper(wd, bdb, **kwargs)
         # per-genome stats for downstream stages come from the ingest pass's Gdb
         # (one FASTA read per genome, not a second parse)
@@ -620,7 +639,7 @@ def dereplicate_wrapper(wd_loc: str, genomes: list[str] | None = None, **kwargs)
         filtered, sketches = d_filter_wrapper(
             wd, bdb, genomeInfo=kwargs.pop("genomeInfo", None), **kwargs
         )
-        with counters.span("stage:cluster"):
+        with _record_a_drain(wd), counters.span("stage:cluster"):
             d_cluster_wrapper(wd, filtered, sketches=sketches, **kwargs)
         with counters.span("stage:choose"):
             wdb = d_choose_wrapper(wd, filtered, **kwargs)

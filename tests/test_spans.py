@@ -635,6 +635,83 @@ def test_the_greedy_job_s_record_names_its_routes_and_its_wait_span_carries_the_
     assert "secondary/wait" in rec["phases"]  # the batched route's one-shot call keeps its own name
 
 
+# --- the same toy job, stopped with notice and run again (ISSUE 47) ----------
+
+
+@pytest.fixture(scope="module")
+def stopped_job(tmp_path_factory):
+    """`greedy_job`'s collection again: a first attempt that drains after the
+    engine cluster's checkpoint (the shard store whole, one of two
+    checkpoints published), then the same command to the end with the event
+    log on. Both attempts' records and the second's spans."""
+    from benchmark import cells
+    from drep_tpu import controller
+    from drep_tpu.parallel import faulttol
+    from drep_tpu.utils import faults
+
+    gen = cells.load_module(os.path.join(REPO, "benchmark", "generators", "planted_release.py"))
+    cfg = cells.read_json(os.path.join(REPO, "benchmark", "configs", "gtdb_release_6k.json"))
+    cfg["data"].update({"n": 70, "s_scaled": 1900, "clusters": [
+        {"size": 40, "count": 1, "groups": [28, 12]}, {"size": 6, "count": 1, "groups": [6]},
+        {"size": 1, "count": 24, "groups": [1]}]})
+    wd = gen.prepare(cfg, 34, str(tmp_path_factory.mktemp("spans_stopped")))["workdir"]
+    argv = ["compare", wd, "--greedy_secondary_clustering", "--streaming_primary", "--skip_plots",
+            "--streaming_block", "32", "--events", "on"]
+    records = []
+    for fault in ("secondary_checkpoint:drain", None):
+        faults.configure(fault)
+        try:
+            controller.main(argv)
+        except SystemExit as e:
+            assert e.code == 0 and fault
+        finally:
+            faulttol.clear_drain()
+            faults.configure(None)
+            telemetry.configure()
+        with open(os.path.join(wd, "log", "perf_counters.json")) as f:
+            records.append(json.load(f))
+    trace_report = _trace_report()
+    spans, _unclosed = trace_report.pair_spans(
+        trace_report.load_events(os.path.join(wd, "log"))["events"])
+    return {"drained": records[0], "resumed": records[1], "spans": spans}
+
+
+def test_a_fresh_job_books_no_resume_load_span_and_a_drained_one_writes_its_record(greedy_job,
+                                                                                  stopped_job):
+    for fresh in (greedy_job["record"], stopped_job["drained"]):
+        assert not [name for name in fresh["phases"] if name.endswith("resume_load")]
+        assert "stripes_resumed" not in fresh["resume"] and "clusters_resumed" not in fresh["resume"]
+    drained = stopped_job["drained"]
+    assert drained["drain"] == {**drained["drain"], "stage": "secondary", "clusters_published": 1}
+    # the attempt's spans and counters up to the boundary, the root span as far as it came
+    ph = drained["phases"]
+    # the store's open, the engine cluster's look-up and its save: the boundary
+    assert ph["stage:secondary"]["calls"] == 1 and ph["secondary/checkpoint"]["calls"] == 3
+    assert "stage:evaluate" not in ph and "stage:assembly_io" not in ph
+    assert _main_self_sum(ph) == pytest.approx(ph["job"]["seconds"], rel=0.01)
+    assert drained["resume"] == {"tiles_computed": 6, "clusters_computed": 1}
+    assert "secondary_greedy_calls" in drained and "secondary_greedy_batched" not in drained
+
+
+def test_the_resume_load_spans_lie_inside_the_spans_that_held_their_time(stopped_job):
+    ph, spans = stopped_job["resumed"]["phases"], stopped_job["spans"]
+    # three stripes' shards read back inside the primary's stage, no tile dispatched
+    assert ph["primary/resume_load"]["calls"] == 3 and "primary/wait" not in ph and "stripe" not in ph
+    assert all(_inside(spans, "primary/resume_load", "stage:primary_compare"))
+    # the engine cluster's checkpoint read back inside a look-up's `secondary/checkpoint`
+    assert ph["secondary/resume_load"]["calls"] == 1
+    assert _inside(spans, "secondary/resume_load", "secondary/checkpoint") == [True]
+    assert ph["secondary/checkpoint"]["seconds"] >= ph["secondary/resume_load"]["seconds"]
+    assert ph["secondary/checkpoint"]["self_seconds"] <= (
+        ph["secondary/checkpoint"]["seconds"] - ph["secondary/resume_load"]["seconds"] + 1e-3)
+    loads = [sp for sp in spans if sp["ev"].endswith("resume_load")]
+    assert all(sp["args"].get("bytes", 0) > 0 for sp in loads)
+    assert stopped_job["resumed"]["resume"] == {
+        **stopped_job["resumed"]["resume"], "stripes_resumed": 3, "tiles_resumed": 6,
+        "tiles_computed": 0, "clusters_resumed": 1, "clusters_computed": 1}
+    assert _main_self_sum(ph) == pytest.approx(ph["job"]["seconds"], rel=0.01)
+
+
 def test_greedy_calls_list_a_cluster_each_and_stay_bounded():
     from drep_tpu.utils import profiling
 
