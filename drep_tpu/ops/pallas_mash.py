@@ -211,9 +211,9 @@ def _mash_shared_grid_symmetric(a_rev, na, b, nb, *, s_orig: int, r_iter: int, i
     """Self-comparison: shared counts are symmetric in (A, B), so the
     (T, T//2+1) wrapped grid — cell (i, jj) computes tile (i, (i+jj)%T) —
     covers every unordered tile pair at ~2x less kernel work (for even T
-    the last column double-covers half, the unwrap just overwrites).
+    the last column double-covers half, the assemble reads one copy).
     Output is the compact wrapped matrix [n, (T//2+1)*TILE];
-    :func:`_unwrap_symmetric` scatters it on host."""
+    :func:`_assemble_symmetric` turns it into distances on host."""
     n, s2 = a_rev.shape
     t = n // TILE
     th = t // 2 + 1
@@ -239,27 +239,60 @@ def _mash_shared_grid_symmetric(a_rev, na, b, nb, *, s_orig: int, r_iter: int, i
     )(a_rev, na, b, nb)
 
 
-def _unwrap_symmetric(compact: np.ndarray, tile: int) -> np.ndarray:
-    """[na, th*tile] wrapped-compact tiles -> full symmetric [na, na]."""
-    na = compact.shape[0]
-    t = na // tile
+def _assemble_symmetric(
+    compact: np.ndarray,
+    counts: np.ndarray,
+    s_orig: int,
+    k: int,
+    tile: int,
+    jaccard: bool = False,
+) -> tuple[np.ndarray, np.ndarray | None, int, int]:
+    """[rows, th*tile] wrapped-compact shared counts -> the symmetric
+    [n, n] float32 distances (n = len(counts), rows = n padded to a
+    multiple of `tile`), a tile at a time: wrapped cell (i, jj) holds tile
+    (i, (i+jj)%t), goes through THE transform on its own and lands in
+    `dist` with its transpose. A whole-matrix transform first-touches some
+    twenty n^2 temporaries, each from a fresh mmap (2.4 s at n = 5,000 on
+    the chip host); a tile's are allocator-recycled and stay in cache, and
+    the values are the same bit for bit (an elementwise formula). For even
+    t the last wrapped column holds each of its tile pairs twice: the
+    first copy (i < t/2) is skipped, so every cell is written once. The
+    Jaccard matrix only for a caller that reads it. Returns (dist, jaccard
+    or None, tiles transformed, cells written); the diagonals are the
+    caller's."""
+    n = len(counts)
+    t = compact.shape[0] // tile
     th = compact.shape[1] // tile
-    out = np.empty((na, na), dtype=compact.dtype)
+    dist = np.empty((n, n), np.float32)
+    jac = np.empty((n, n), np.float32) if jaccard else None
+    tiles = cells = 0
     for i in range(t):
-        rows = slice(i * tile, (i + 1) * tile)
+        rows = slice(i * tile, min((i + 1) * tile, n))
         for jj in range(th):
+            if 2 * jj == t and 2 * i < t:
+                continue  # tile (i+jj, i) of the same column is its twin
             j = (i + jj) % t
-            cols = slice(j * tile, (j + 1) * tile)
-            blk = compact[rows, jj * tile : (jj + 1) * tile]
-            out[rows, cols] = blk
-            out[cols, rows] = blk.T
-    return out
+            cols = slice(j * tile, min((j + 1) * tile, n))
+            blk = compact[rows, jj * tile : jj * tile + (cols.stop - cols.start)]
+            d, jb = shared_counts_to_distance(blk, counts[rows], counts[cols], s_orig, k)
+            for out, val in ((dist, d), (jac, jb)):
+                if out is not None:
+                    out[rows, cols] = val
+                    if i != j:
+                        out[cols, rows] = val.T
+            tiles += 1
+            cells += blk.size * (1 if i == j else 2)
+    return dist, jac, tiles, cells
 
 
-def all_vs_all_mash_pallas(packed, k: int = 21) -> tuple[np.ndarray, np.ndarray]:
+def all_vs_all_mash_pallas(
+    packed, k: int = 21, jaccard: bool = False
+) -> tuple[np.ndarray, np.ndarray | None]:
     """Full [N, N] (distance, jaccard) for one packed sketch set — the
     single-chip TPU primary engine: the reference-faithful union-bottom-s
-    estimator. Same output contract as ops/minhash.py::all_vs_all_mash."""
+    estimator. Same output contract as ops/minhash.py::all_vs_all_mash,
+    but the Jaccard matrix is None unless `jaccard` asks for it (the one
+    production caller, engines.mash_distance_matrix, drops it)."""
     from drep_tpu.utils.profiling import counters
 
     n = packed.n
@@ -290,12 +323,15 @@ def all_vs_all_mash_pallas(packed, k: int = 21) -> tuple[np.ndarray, np.ndarray]
         )
     with counters.span("primary/wait", rows=rows):
         compact = np.asarray(pending)
-    with counters.span("primary/assemble"):
-        shared = _unwrap_symmetric(compact, TILE)[:n, :n]
-        dist, j = shared_counts_to_distance(shared, counts, counts, width, k)
+    with counters.span("primary/assemble") as span:
+        dist, jac, tiles, cells = _assemble_symmetric(
+            compact, counts, width, k, TILE, jaccard=jaccard
+        )
+        span.note(tiles=tiles, cells=cells)
         np.fill_diagonal(dist, 0.0)
-        np.fill_diagonal(j, 1.0)
-    return dist, j
+        if jac is not None:
+            np.fill_diagonal(jac, 1.0)
+    return dist, jac
 
 
 def shared_counts_to_distance(

@@ -68,9 +68,73 @@ def test_all_vs_all_pallas_symmetric_grid(rng):
     n, s = 10, 64
     packed = pack_sketches(_sketch_set(rng, n, s), [f"g{i}" for i in range(n)], s)
     want_d, want_j = all_vs_all_mash(packed, k=21, tile=8)
-    got_d, got_j = all_vs_all_mash_pallas(packed, k=21)
+    got_d, got_j = all_vs_all_mash_pallas(packed, k=21, jaccard=True)
     np.testing.assert_allclose(got_d, want_d, atol=1e-7)
     np.testing.assert_allclose(got_j, want_j, atol=1e-7)
+
+
+def _unwrap_symmetric(compact: np.ndarray, tile: int) -> np.ndarray:
+    """[na, th*tile] wrapped-compact tiles -> full symmetric [na, na]: the
+    whole-matrix unwrap `all_vs_all_mash_pallas` made before ISSUE 48, kept
+    here as the oracle of `_assemble_symmetric`."""
+    na = compact.shape[0]
+    t = na // tile
+    th = compact.shape[1] // tile
+    out = np.empty((na, na), dtype=compact.dtype)
+    for i in range(t):
+        rows = slice(i * tile, (i + 1) * tile)
+        for jj in range(th):
+            j = (i + jj) % t
+            cols = slice(j * tile, (j + 1) * tile)
+            blk = compact[rows, jj * tile : (jj + 1) * tile]
+            out[rows, cols] = blk
+            out[cols, rows] = blk.T
+    return out
+
+
+@pytest.mark.parametrize("counts_kind", ["full", "some_short", "one_zero"])
+@pytest.mark.parametrize("ragged_n", [False, True])
+@pytest.mark.parametrize("t", [1, 2, 3, 4, 5])
+def test_tilewise_assemble_is_bit_equal_to_the_whole_matrix_transform(rng, t, ragged_n, counts_kind):
+    """ISSUE 48: the dense grid's counts become distances a tile at a time.
+    Every float32 must be the one the whole-matrix unwrap + transform gave:
+    odd and even t (an even t's last wrapped column holds its tile pairs
+    twice), n on a tile edge and not, short and empty sketches."""
+    from drep_tpu.ops.pallas_mash import _assemble_symmetric, shared_counts_to_distance
+
+    tile, width, k = 8, 16, 21
+    rows = t * tile
+    n = rows - 3 if ragged_n else rows
+    counts = np.full(n, width, np.int32)
+    if counts_kind != "full":
+        counts[::3] = rng.integers(1, width, size=len(counts[::3]))
+    if counts_kind == "one_zero":
+        counts[n // 2] = 0
+    padded = np.zeros(rows, np.int32)
+    padded[:n] = counts
+    # a symmetric matrix of counts, as the kernel gives: shared <= min(|A|, |B|)
+    full = np.triu(rng.integers(0, width + 1, size=(rows, rows)))
+    full = np.minimum(full + np.triu(full, 1).T, np.minimum(padded[:, None], padded[None, :]))
+    th = t // 2 + 1
+    compact = np.empty((rows, th * tile), np.int32)
+    for i in range(t):
+        for jj in range(th):
+            j = (i + jj) % t
+            compact[i * tile : (i + 1) * tile, jj * tile : (jj + 1) * tile] = (
+                full[i * tile : (i + 1) * tile, j * tile : (j + 1) * tile])
+    shared = _unwrap_symmetric(compact, tile)
+    np.testing.assert_array_equal(shared, full)  # the oracle reads the layout the kernel writes
+    want_d, want_j = shared_counts_to_distance(shared[:n, :n], counts, counts, width, k)
+
+    got_d, got_j, tiles, cells = _assemble_symmetric(compact, counts, width, k, tile, jaccard=True)
+    assert got_d.dtype == got_j.dtype == np.float32
+    np.testing.assert_array_equal(got_d.view(np.uint32), want_d.view(np.uint32))
+    np.testing.assert_array_equal(got_j.view(np.uint32), want_j.view(np.uint32))
+    # every unordered tile pair once, every cell once
+    assert (tiles, cells) == (t * (t + 1) // 2, n * n)
+    only_d, no_j, *_ = _assemble_symmetric(compact, counts, width, k, tile)
+    assert no_j is None
+    np.testing.assert_array_equal(only_d.view(np.uint32), want_d.view(np.uint32))
 
 
 def test_pallas_mash_rectangular_blocks(rng):
@@ -102,7 +166,7 @@ def test_rows_per_iter_batching_equals_default(rng, monkeypatch, r_iter):
     packed = pack_sketches(_sketch_set(rng, n, s), [f"g{i}" for i in range(n)], s)
     want_d, want_j = all_vs_all_mash(packed, k=21, tile=8)
     monkeypatch.setenv("DREP_TPU_MASH_ROWS_PER_ITER", str(r_iter))
-    got_d, got_j = all_vs_all_mash_pallas(packed, k=21)
+    got_d, got_j = all_vs_all_mash_pallas(packed, k=21, jaccard=True)
     np.testing.assert_allclose(got_d, want_d, atol=1e-7)
     np.testing.assert_allclose(got_j, want_j, atol=1e-7)
 

@@ -444,6 +444,39 @@ def test_the_controller_hands_its_processes_down_to_the_primary_pack(route, proc
     assert all(a["path"] == "native" and a["workers"] == processes and a["hashes"] > 0 for a in seen)
 
 
+def test_the_dense_grid_assemble_says_what_it_transformed(monkeypatch):
+    """ISSUE 48: on the Pallas route `primary/assemble` turns the wrapped
+    grid's counts into distances a tile at a time and says so: `tiles=`
+    the grid's tile pairs (an even t's twice-covered ones once), `cells=`
+    the whole matrix once. `mash_distance_matrix` reads no Jaccard matrix,
+    so it asks for none."""
+    from drep_tpu.cluster.engines import mash_distance_matrix
+    from drep_tpu.ops import minhash, pallas_mash
+    from drep_tpu.utils.profiling import counters
+
+    rng = np.random.default_rng(48)
+    n, s = pallas_mash.TILE + 2, 64  # two tile rows: t = 2, the last wrapped column covered twice
+    bottom = [np.unique(rng.integers(0, 2**62, size=s, dtype=np.uint64)) for _ in range(n)]
+    bottom[3] = bottom[3][: s // 2]
+    packed = minhash.pack_sketches(bottom, [f"g{i}" for i in range(n)], s)
+    monkeypatch.setattr(pallas_mash, "pallas_mash_supported", lambda width: True)  # interpreted here
+    returned, opened = [], []
+    grid_call, span = pallas_mash.all_vs_all_mash_pallas, counters.span
+    monkeypatch.setattr(pallas_mash, "all_vs_all_mash_pallas", lambda *a, **kw: (
+        returned.append(grid_call(*a, **kw)), returned[-1])[1])
+    monkeypatch.setattr(counters, "span", lambda name, calls=1, **args: (
+        sp := span(name, calls, **args), opened.append(sp))[0])
+    counters.reset()
+    dist = mash_distance_matrix(packed, 21, mesh_shape=1)
+    grid = counters.report(device=False)["stages"]["primary_compare"]
+    counters.reset()
+    assert [sp._args for sp in opened if sp.name == "primary/assemble"] == [{"tiles": 3, "cells": n * n}]
+    assert (grid["tiles_computed"], grid["tiles_total"]) == (4, 4)  # the grid ran 2 x 2: one pair twice
+    assert [jac for _dist, jac in returned] == [None]
+    want, _ = minhash.all_vs_all_mash(packed, k=21, tile=64)
+    np.testing.assert_allclose(dist, want, atol=1e-7)
+
+
 _PROFILE_ON_A_POD = """
 import os, sys
 sys.path.insert(0, {repo!r})
