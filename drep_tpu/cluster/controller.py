@@ -351,10 +351,10 @@ def _secondary_postprocess(
     kw: dict[str, Any],
     ani: np.ndarray,
     cov: np.ndarray,
-) -> tuple[pd.DataFrame, np.ndarray, np.ndarray]:
+) -> tuple[pairs.NdbColumns, np.ndarray, np.ndarray]:
     """(ani, cov) for one primary cluster -> (Ndb rows, labels 1.., linkage)."""
     names = [gs.names[i] for i in indices]
-    ndb = pairs.directional_ndb(names, ani, cov, pc)
+    ndb = pairs.directional_ndb_columns(names, ani, cov, pc)
     dist = 1.0 - pairs.gated_symmetric_ani(ani, cov, kw["cov_thresh"])
     labels, link = cluster_hierarchical(dist, 1.0 - kw["S_ani"], method=kw["clusterAlg"])
     return ndb, labels, link
@@ -366,7 +366,7 @@ def _secondary_for_cluster(
     indices: list[int],
     pc: int,
     kw: dict[str, Any],
-) -> tuple[pd.DataFrame, np.ndarray, np.ndarray]:
+) -> tuple[pairs.NdbColumns, np.ndarray, np.ndarray]:
     """One primary cluster -> (Ndb rows, secondary labels 1.., linkage)."""
     engine = dispatch.get_secondary(kw["S_algorithm"])
     ani, cov = engine(gs, indices, bdb=bdb, processes=kw["processes"], mesh_shape=kw["mesh_shape"])
@@ -374,13 +374,24 @@ def _secondary_for_cluster(
         return _secondary_postprocess(gs, indices, pc, kw, ani, cov)
 
 
-# the incremental genome index (drep_tpu/index/update.py) re-runs the
-# secondary stage for exactly the primary clusters its update touched —
-# through THIS implementation, so a re-scored cluster's (Ndb rows, labels)
-# are bit-identical to what a from-scratch run computes for the same
-# member set. `kw` needs S_algorithm/S_ani/cov_thresh/clusterAlg/
-# processes/mesh_shape (fill via CLUSTER_DEFAULTS).
-secondary_for_cluster = _secondary_for_cluster
+def secondary_for_cluster(
+    gs: GenomeSketches,
+    bdb: pd.DataFrame,
+    indices: list[int],
+    pc: int,
+    kw: dict[str, Any],
+) -> tuple[pd.DataFrame, np.ndarray, np.ndarray]:
+    """One primary cluster -> (Ndb frame, secondary labels 1.., linkage).
+
+    The incremental genome index (drep_tpu/index/update.py) re-runs the
+    secondary stage for exactly the primary clusters its update touched —
+    through THIS implementation, so a re-scored cluster's (Ndb rows, labels)
+    are bit-identical to what a from-scratch run computes for the same
+    member set. `kw` needs S_algorithm/S_ani/cov_thresh/clusterAlg/
+    processes/mesh_shape (fill via CLUSTER_DEFAULTS). A caller with one
+    cluster reads a frame; the stage keeps columns."""
+    ndb, labels, link = _secondary_for_cluster(gs, bdb, indices, pc, kw)
+    return ndb.frame(), labels, link
 
 
 def _secondary_stage(
@@ -392,9 +403,10 @@ def _secondary_stage(
     snapshot: dict[str, Any],
     primary: np.ndarray,
     n_primary: int,
-) -> tuple[dict[str, str], list[pd.DataFrame], dict[int, dict[str, Any]]]:
+) -> tuple[dict[str, str], list[pairs.NdbColumns], dict[int, dict[str, Any]]]:
     """The secondary (ANI) stage over every primary cluster: ({genome:
-    "P_S" secondary name}, Ndb parts in cluster order, {primary cluster:
+    "P_S" secondary name}, each cluster's Ndb rows as columns, in cluster
+    order (pairs.assemble_ndb makes the table of them), {primary cluster:
     its linkage and names} for Clustering_files)."""
     import jax
 
@@ -456,7 +468,7 @@ def _secondary_stage(
             wd.get_dir(os.path.join("data", "secondary_checkpoints")),
             sec_snapshot, primary, gs.names,
         )
-    results: dict[int, tuple[pd.DataFrame, np.ndarray, np.ndarray]] = {}
+    results: dict[int, tuple[pairs.NdbColumns, np.ndarray, np.ndarray]] = {}
     alone = jax.process_count() == 1
 
     def publish(pc: int) -> None:
@@ -570,7 +582,7 @@ def _secondary_stage(
                 ckpt.dir,
                 {"pod_epochs": pod_epoch() + 1, "dead_processes": pod_dead()},
             )
-    ndb_parts: list[pd.DataFrame] = []
+    ndb_parts: list[pairs.NdbColumns] = []
     files: dict[int, dict[str, Any]] = {}
     for pc, indices in multi:  # assemble in cluster order (deterministic)
         ndb, labels, link = results[pc]
@@ -790,7 +802,8 @@ def d_cluster_wrapper(
         "secondary": {},
     }
 
-    ndb_parts: list[pd.DataFrame] = []
+    ndb_parts: list[pairs.NdbColumns] = []
+    tertiary_ndb: pd.DataFrame | None = None
     secondary_names: dict[str, str] = {}
     if kw["SkipSecondary"]:
         for i, g in enumerate(gs.names):
@@ -804,12 +817,6 @@ def d_cluster_wrapper(
             secondary_names, ndb_parts, clustering_files["secondary"] = _secondary_stage(
                 gs, bdb, kw, ft_cfg, wd, snapshot, primary, n_primary
             )
-
-    ndb = (
-        pd.concat(ndb_parts, ignore_index=True)
-        if ndb_parts
-        else schemas.empty("Ndb")
-    )
 
     cdb = pd.DataFrame(
         {
@@ -832,12 +839,14 @@ def d_cluster_wrapper(
             from drep_tpu.cluster.tertiary import run_tertiary_clustering
 
             cdb, tertiary_ndb = run_tertiary_clustering(gs, bdb, cdb, kw)
-            if len(tertiary_ndb):
-                ndb = pd.concat([ndb, tertiary_ndb], ignore_index=True)
 
     # counted: CSV serialization of a 50k-scale Ndb is real wall that must
-    # not hide in the uncounted remainder of a run
+    # not hide in the uncounted remainder of a run; nor its assembly, the
+    # job's one Ndb frame (no cluster's rows were a frame before this)
     with counters.stage("assembly_io"):
+        ndb = pairs.assemble_ndb(ndb_parts) if ndb_parts else schemas.empty("Ndb")
+        if tertiary_ndb is not None and len(tertiary_ndb):
+            ndb = pd.concat([ndb, tertiary_ndb], ignore_index=True)
         wd.store_db(schemas.validate(ndb, "Ndb"), "Ndb")
         wd.store_db(schemas.validate(cdb, "Cdb"), "Cdb")
 

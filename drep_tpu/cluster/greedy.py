@@ -23,7 +23,7 @@ from typing import Any
 import numpy as np
 import pandas as pd
 
-from drep_tpu.cluster.pairs import NDB_COLUMNS
+from drep_tpu.cluster.pairs import NdbColumns, empty_ndb_columns
 from drep_tpu.ingest import GenomeSketches
 from drep_tpu.ops.containment import (
     VocabChunkGeometry,
@@ -78,15 +78,15 @@ def _put_chunks(chunks: list[np.ndarray], booked: dict, side: str, mesh=None, re
     return out
 
 
-def _ndb_from_rows(ndb_rows: list[dict], pc: int) -> pd.DataFrame:
-    """THE greedy Ndb assembly, shared by both comparison sources."""
-    if ndb_rows:
-        ndb = pd.DataFrame(
-            {key: np.concatenate([r[key] for r in ndb_rows]) for key in ndb_rows[0]}
-        )
-        ndb["primary_cluster"] = pc
-        return ndb
-    return pd.DataFrame(columns=NDB_COLUMNS)
+def _ndb_from_rows(ndb_rows: list[dict], pc: int, names: list[str]) -> NdbColumns:
+    """THE greedy Ndb assembly, shared by both comparison sources: each
+    visited genome's rows against the representatives it met, the two name
+    columns as positions in `names`."""
+    if not ndb_rows:
+        return empty_ndb_columns()
+    cols = {key: np.concatenate([r[key] for r in ndb_rows]) for key in ndb_rows[0]}
+    cols["primary_cluster"] = np.full(len(cols["ani"]), pc, dtype=np.int64)
+    return NdbColumns(cols, names)
 
 
 def greedy_assign_from_matrices(
@@ -96,7 +96,7 @@ def greedy_assign_from_matrices(
     kw: dict[str, Any],
     ani: np.ndarray,
     cov: np.ndarray,
-) -> tuple[pd.DataFrame, np.ndarray]:
+) -> tuple[NdbColumns, np.ndarray]:
     """Greedy representative assignment from PRECOMPUTED (ani, cov)
     matrices — the small-cluster path when `--greedy_secondary_clustering`
     is on. Semantics identical to :func:`greedy_secondary_cluster`
@@ -119,7 +119,7 @@ def greedy_assign_from_matrices(
     return ndb, labels
 
 
-def _assign_from_matrices(gs, indices, pc, kw, ani, cov) -> tuple[pd.DataFrame, np.ndarray]:
+def _assign_from_matrices(gs, indices, pc, kw, ani, cov) -> tuple[NdbColumns, np.ndarray]:
     s_ani, cov_thresh = kw["S_ani"], kw["cov_thresh"]
     m = len(indices)
     n_kmers = [int(gs.gdb["n_kmers"].iloc[i]) for i in indices]
@@ -136,8 +136,8 @@ def _assign_from_matrices(gs, indices, pc, kw, ani, cov) -> tuple[pd.DataFrame, 
             ani_row = ani[t, r].astype(np.float64)
             ndb_rows.append(
                 {
-                    "reference": np.array([names[x] for x in reps]),
-                    "querry": np.repeat(names[t], len(reps)),
+                    "reference": r,
+                    "querry": np.full(len(r), t),
                     "ani": ani_row,
                     "alignment_coverage": cov_row,
                     "ref_coverage": cov_rev,
@@ -150,7 +150,7 @@ def _assign_from_matrices(gs, indices, pc, kw, ani, cov) -> tuple[pd.DataFrame, 
                 continue
         reps.append(t)
         labels[t] = len(reps)
-    return _ndb_from_rows(ndb_rows, pc), labels
+    return _ndb_from_rows(ndb_rows, pc, names), labels
 
 
 def greedy_secondary_cluster(
@@ -160,7 +160,7 @@ def greedy_secondary_cluster(
     pc: int,
     kw: dict[str, Any],
     block: int = 128,
-) -> tuple[pd.DataFrame, np.ndarray]:
+) -> tuple[NdbColumns, np.ndarray]:
     """Returns (Ndb rows for the comparisons performed, labels 1..R).
 
     Genomes are visited largest-first (most k-mers), the reference's
@@ -229,7 +229,6 @@ def greedy_secondary_cluster(
     labels_ordered = np.zeros(m, dtype=np.int64)
     reps: list[int] = []  # positions (in `order` space) of representatives
     ndb_rows: list[dict] = []
-    name_arr = np.array(packed.names)  # invariant across blocks
     # what the cluster's counter entry sums over its blocks
     # (Counters.add_greedy_call)
     booked = dict.fromkeys(
@@ -424,8 +423,8 @@ def greedy_secondary_cluster(
                     rep_pos_arr = np.array(reps, dtype=np.int64)
                     ndb_rows.append(
                         {
-                            "reference": name_arr[rep_pos_arr],
-                            "querry": np.repeat(name_arr[pos], len(ani_row)),
+                            "reference": rep_pos_arr,
+                            "querry": np.full(len(ani_row), pos),
                             "ani": ani_row.astype(np.float64),
                             "alignment_coverage": cov_row.astype(np.float64),
                             "ref_coverage": cov_rev.astype(np.float64),
@@ -446,7 +445,7 @@ def greedy_secondary_cluster(
         labels = np.zeros(m, dtype=np.int64)
         for t in range(m):
             labels[order[t]] = labels_ordered[t]
-        ndb = _ndb_from_rows(ndb_rows, pc)
+        ndb = _ndb_from_rows(ndb_rows, pc, packed.names)
     counters.add_greedy_call(
         rows=m, block_rows=block, reps=len(reps), extent=vocab_extent(ids),
         hashes=int(counts.sum()), compared_pairs=len(ndb), mesh_devices=n_dev,

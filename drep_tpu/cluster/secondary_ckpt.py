@@ -17,8 +17,8 @@ import os
 from typing import Any
 
 import numpy as np
-import pandas as pd
 
+from drep_tpu.cluster.pairs import NdbColumns
 from drep_tpu.utils.ckptmeta import content_fingerprint, open_checkpoint_dir
 from drep_tpu.utils.logger import get_logger
 
@@ -46,7 +46,7 @@ class SecondaryCheckpoint:
         return os.path.join(self.dir, f"pc_{pc:06d}.npz")
 
     def load(self, pc: int):
-        """(ndb, labels, link) for a finished cluster, or None."""
+        """(Ndb columns, labels, link) for a finished cluster, or None."""
         if self.dir is None:
             return None
         loc = self._loc(pc)
@@ -56,9 +56,10 @@ class SecondaryCheckpoint:
         from drep_tpu.utils.profiling import counters
 
         def convert(z):
+            # the names come back as the payload's unicode arrays: the
+            # columns form without a name list
             cols = [str(c) for c in z["ndb_columns"]]
-            ndb = pd.DataFrame({c: z[f"ndb_col_{c}"] for c in cols})
-            return ndb, z["labels"], z["link"]
+            return NdbColumns({c: z[f"ndb_col_{c}"] for c in cols}), z["labels"], z["link"]
 
         # a stopped job's checkpoint, read back: a span of its own inside the
         # caller's `secondary/checkpoint`, which a fresh job never opens
@@ -73,20 +74,17 @@ class SecondaryCheckpoint:
             counters.add_resume(checkpoint_bytes=size)
         return result
 
-    def save(self, pc: int, ndb: pd.DataFrame, labels: np.ndarray, link: np.ndarray) -> None:
+    def save(self, pc: int, ndb: NdbColumns, labels: np.ndarray, link: np.ndarray) -> None:
         if self.dir is None:
             return
         loc = self._loc(pc)
         arrays: dict[str, np.ndarray] = {
             "labels": np.asarray(labels),
             "link": np.asarray(link),
-            "ndb_columns": np.array(list(ndb.columns), dtype=str),
+            "ndb_columns": np.array(list(ndb.cols), dtype=str),
         }
-        for c in ndb.columns:
-            col = ndb[c].to_numpy()
-            if col.dtype == object:
-                col = col.astype(str)  # unicode arrays need no pickle
-            arrays[f"ndb_col_{c}"] = col
+        for c in ndb.cols:
+            arrays[f"ndb_col_{c}"] = ndb.stored(c)
         from drep_tpu.utils.ckptmeta import atomic_savez
 
         # uncompressed: thousands of small per-cluster files per run made

@@ -66,7 +66,9 @@ def test_the_mesh_route_equals_the_one_device_route_and_the_reference(planted, m
     assert (call["mesh_devices"], call["block_rows"]) == (devices, 128 * devices)
     assert call["blocks"] == -(-m // (128 * devices)) and call["reps"] == len(CLUSTERS[cluster])
     # the one-device route: same labels, same rows in the same order, same values
-    one_ndb, one_labels = p["one"]
+    # the engine hands the stage columns; the rows are read here as a frame
+    one_ndb, one_labels = p["one"][0].frame(), p["one"][1]
+    ndb = ndb.frame()
     np.testing.assert_array_equal(labels, one_labels)
     assert list(ndb["querry"]) == list(one_ndb["querry"])
     assert list(ndb["reference"]) == list(one_ndb["reference"])

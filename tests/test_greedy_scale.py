@@ -57,7 +57,7 @@ def test_greedy_recovers_planted_clusters(synthetic):
 
     # comparisons recorded: every genome vs every rep existing when visited
     assert len(ndb) > 0
-    assert set(ndb.columns) >= {"reference", "querry", "ani", "alignment_coverage", "primary_cluster"}
+    assert set(ndb.frame().columns) >= {"reference", "querry", "ani", "alignment_coverage", "primary_cluster"}
 
     # generous ceiling: the vectorized path runs in a few seconds on CPU;
     # a Python pair-loop regression would take minutes
@@ -82,9 +82,9 @@ def test_greedy_mesh_sharded_equals_single_device(synthetic, monkeypatch):
     np.testing.assert_array_equal(got_labels, want_labels)
     assert len(got_ndb) == len(want_ndb)
     for col in ("reference", "querry"):
-        assert list(got_ndb[col]) == list(want_ndb[col])
+        assert list(got_ndb.column(col)) == list(want_ndb.column(col))
     for col in ("ani", "alignment_coverage", "ref_coverage", "querry_coverage"):
-        np.testing.assert_allclose(got_ndb[col], want_ndb[col], atol=1e-6, err_msg=col)
+        np.testing.assert_allclose(got_ndb.column(col), want_ndb.column(col), atol=1e-6, err_msg=col)
 
     # attribution recorded: the span of the device work, the route, the cluster's entry
     from drep_tpu.utils.profiling import counters
@@ -112,7 +112,7 @@ def test_greedy_matmul_single_device_equals_gather(synthetic, monkeypatch):
     np.testing.assert_array_equal(got_labels, want_labels)
     assert len(got_ndb) == len(want_ndb)
     for col in ("ani", "alignment_coverage", "ref_coverage", "querry_coverage"):
-        np.testing.assert_allclose(got_ndb[col], want_ndb[col], atol=1e-6, err_msg=col)
+        np.testing.assert_allclose(got_ndb.column(col), want_ndb.column(col), atol=1e-6, err_msg=col)
 
 
 def test_greedy_from_matrices_equals_engine(synthetic):
@@ -134,9 +134,9 @@ def test_greedy_from_matrices_equals_engine(synthetic):
         np.testing.assert_array_equal(got_labels, want_labels, err_msg=str((lo, hi)))
         assert len(got_ndb) == len(want_ndb)
         for col in ("reference", "querry"):
-            assert list(got_ndb[col]) == list(want_ndb[col])
+            assert list(got_ndb.column(col)) == list(want_ndb.column(col))
         for col in ("ani", "alignment_coverage", "ref_coverage", "querry_coverage"):
-            np.testing.assert_allclose(got_ndb[col], want_ndb[col], atol=1e-6, err_msg=col)
+            np.testing.assert_allclose(got_ndb.column(col), want_ndb.column(col), atol=1e-6, err_msg=col)
 
 
 def test_greedy_small_clusters_ride_the_batched_path(synthetic, monkeypatch):

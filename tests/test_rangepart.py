@@ -300,5 +300,5 @@ def test_greedy_matmul_path_equals_gather_path(rng, monkeypatch):
         monkeypatch.undo()
     np.testing.assert_array_equal(labels_g, labels_m)
     pd.testing.assert_frame_equal(
-        ndb_g.reset_index(drop=True), ndb_m.reset_index(drop=True)
+        ndb_g.frame().reset_index(drop=True), ndb_m.frame().reset_index(drop=True)
     )
