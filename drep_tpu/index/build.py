@@ -28,7 +28,7 @@ import pandas as pd
 
 from drep_tpu.errors import UserInputError
 from drep_tpu.index.store import IndexStore, LoadedIndex, empty_index
-from drep_tpu.index.update import publish_generation, recluster, sketch_batch, _admit_batch, _rect_edges
+from drep_tpu.index.update import publish_generation, rect_compare, recluster, sketch_batch, _admit_batch
 from drep_tpu.utils.logger import get_logger
 
 # the scoring weights an index pins at build (choose.py SCORE_DEFAULTS
@@ -241,9 +241,12 @@ def build_from_workdir(index_loc: str, wd_loc: str) -> dict:
     cdb_idx = pd.DataFrame(
         {"genome": idx.names, "secondary_cluster": idx.secondary_names()}
     )
-    sdb_full, wdb = score_and_pick(
-        cdb_idx, stats, ndb, None, S_ani=params["S_ani"], **params["weights"]
-    )
+    from drep_tpu.utils.profiling import counters
+
+    with counters.span("index/score", members=idx.n):
+        sdb_full, wdb = score_and_pick(
+            cdb_idx, stats, ndb, None, S_ani=params["S_ani"], **params["weights"]
+        )
     by_score = sdb_full.set_index("genome")["score"]
     idx.score = np.array([float(by_score[g]) for g in idx.names], np.float64)
     idx.winners = wdb[["cluster", "genome", "score"]]
@@ -277,15 +280,13 @@ def build_from_paths(
         )
     params = resolve_params(**kwargs)
     idx = empty_index(params, location=store.location)
-    batch, results = sketch_batch(idx, genome_paths, processes=processes)
+    with counters.span("index/sketch", genomes=len(genome_paths)):
+        batch, results = sketch_batch(idx, genome_paths, processes=processes)
     if not len(batch):
         raise UserInputError("no genomes survived the length filter — nothing to index")
-    _admit_batch(idx, batch, results, 0)
-    with counters.stage("index_rect_compare"):
-        ii, jj, dd, pairs = _rect_edges(idx, 0, store.pending_dir(0))
-    counters.stages["index_rect_compare"].pairs += pairs
-    order = np.lexsort((jj, ii))
-    ii, jj, dd = ii[order], jj[order], dd[order]
+    with counters.span("index/admit", genomes=len(batch)):
+        _admit_batch(idx, batch, results, 0)
+    ii, jj, dd, _pairs = rect_compare(idx, 0, store.pending_dir(0))
     idx.edges = (ii, jj, dd)
     summary = recluster(idx, 0, processes=processes)
     publish_generation(store, idx, 0, 0, idx.edges)

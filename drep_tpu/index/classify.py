@@ -310,8 +310,11 @@ def classify_batch(
             ii, jj, dd = fast
     if ii is None:
         # in-memory rectangular compare: checkpoint_dir None => no writes
-        # drep-lint: allow[reader-purity] — ckpt_dir=None gates the streaming engine storeless: no shard publishes, no heartbeat notes, no meta stamps (byte-for-byte pinned by test_index/test_serve digest assertions)
-        ii, jj, dd, _pairs = _rect_edges(scratch, n_old, None, prune_cfg=prune_cfg)
+        from drep_tpu.utils.profiling import counters
+
+        with counters.span("index/rect_compare", genomes=scratch.n, min_col=n_old):
+            # drep-lint: allow[reader-purity] — ckpt_dir=None gates the streaming engine storeless: no shard publishes, no heartbeat notes, no meta stamps (byte-for-byte pinned by test_index/test_serve digest assertions)
+            ii, jj, dd, _pairs = _rect_edges(scratch, n_old, None, prune_cfg=prune_cfg)
     # canonical (ii, jj) order — the update path's convention: the
     # streaming federated path assembles the same edge SET from
     # per-partition compares, and identical ordering pins identical

@@ -89,7 +89,7 @@ import pandas as pd
 
 from drep_tpu.errors import UserInputError
 from drep_tpu.index import meta as fedmeta
-from drep_tpu.index.store import IndexStore, LoadedIndex, empty_index, load_index
+from drep_tpu.index.store import IndexStore, LoadedIndex, empty_index, load_index, read_payload
 from drep_tpu.index.update import (
     _admit_batch,
     _retention,
@@ -992,12 +992,11 @@ class FederatedResident:
         """Names/locations/stats + intra edges for one partition —
         O(n_p) metadata, NO sketch payloads (those load lazily on first
         consult)."""
-        from drep_tpu.utils import durableio
 
         pdir = os.path.join(self.location, slot.dir)
         manifest = self._partition_manifest(slot)
-        state = durableio.load_npz_checked(
-            os.path.join(pdir, manifest["state"]), what="partition state"
+        state = read_payload(
+            os.path.join(pdir, manifest["state"]), "partition state"
         )
         sel = np.nonzero(self.part_of == slot.pid)[0]
         locs = self.local_of[sel]
@@ -1020,8 +1019,8 @@ class FederatedResident:
         for e in manifest["edge_shards"]:
             if int(e["lo"]) >= slot.n:
                 continue  # published ahead of the meta: truncated out
-            z = durableio.load_npz_checked(
-                os.path.join(pdir, e["file"]), what="partition edge shard"
+            z = read_payload(
+                os.path.join(pdir, e["file"]), "partition edge shard"
             )
             ii, jj, dd = (
                 z["ii"].astype(np.int64), z["jj"].astype(np.int64),
@@ -1041,7 +1040,6 @@ class FederatedResident:
 
     def _load_sketches(self, slot: _PartitionSlot) -> None:
         from drep_tpu.ingest import unpack_ragged
-        from drep_tpu.utils import durableio
 
         pdir = os.path.join(self.location, slot.dir)
         manifest = self._partition_manifest(slot)
@@ -1056,8 +1054,8 @@ class FederatedResident:
             if lo >= slot.n:
                 continue
             hi = min(int(e["hi"]), slot.n)
-            z = durableio.load_npz_checked(
-                os.path.join(pdir, e["file"]), what="partition sketch shard"
+            z = read_payload(
+                os.path.join(pdir, e["file"]), "partition sketch shard"
             )
             m = int(e["hi"]) - lo
             bot = unpack_ragged(z["bottom"], z["bottom_offsets"], m)
@@ -1791,15 +1789,18 @@ def write_params_handoff(
 ) -> None:
     """The router -> partition-pod handoff (ISSUE 14 satellite): the
     routed batch's ALREADY-COMPUTED sketches plus the federation's
-    PINNED params, serialized as one durable npz — so a ``--fed_pods``
+    PINNED params, serialized as one durable payload — so a ``--fed_pods``
     pod neither re-sketches its batch nor needs the CLI bootstrap to
     express the meta's params (which it cannot: generation-0
     materialization now parallelizes as pods too). The in-process path
-    passes the same (batch, results) directly (``presketched``)."""
+    passes the same (batch, results) directly (``presketched``). Written by
+    the index store's own writer (``store.write_payload``): the head at
+    `path`, a member over ``workdir.ARRAY_PART_BYTES`` in parts beside it,
+    uncompressed as a sketch shard is and for its reason."""
     import json
 
+    from drep_tpu.index.store import write_payload
     from drep_tpu.ingest import pack_ragged
-    from drep_tpu.utils.ckptmeta import atomic_savez
 
     names = list(batch["genome"])
     payload: dict[str, np.ndarray] = {
@@ -1813,11 +1814,10 @@ def write_params_handoff(
         payload[key], payload[f"{key}_offsets"] = pack_ragged(
             [results[g][key] for g in names]
         )
-    os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
-    atomic_savez(path, **payload)
+    write_payload(path, compressed=False, **payload)
 
 
-def read_params_handoff(path: str) -> dict:
+def read_params_handoff(path: str, workers: int = 1) -> dict:
     """Read a :func:`write_params_handoff` file back into
     {"params", "batch", "results"} — the exact shapes ``sketch_batch``
     produces, so the consuming update is bit-identical to an in-process
@@ -1825,9 +1825,8 @@ def read_params_handoff(path: str) -> dict:
     import json
 
     from drep_tpu.ingest import unpack_ragged
-    from drep_tpu.utils.durableio import load_npz_checked
 
-    z = load_npz_checked(path, what="params handoff")
+    z = read_payload(path, "params handoff", workers)
     names = [str(x) for x in z["names"]]
     bottom = unpack_ragged(z["bottom"], z["bottom_offsets"], len(names))
     scaled = unpack_ragged(z["scaled"], z["scaled_offsets"], len(names))
@@ -1878,14 +1877,12 @@ def _partition_names(part_dir: str, lo: int = 0) -> list[str]:
     whose range reaches there — the resume skip-detection's tail probe.
     Deliberately NOT a full partition load: only the rare resume
     branches pay it, and only for the tail shards they compare."""
-    from drep_tpu.utils import durableio
-
     store = IndexStore(part_dir)
     names: list[str] = []
     for e in store.read_manifest()["sketch_shards"]:
         if int(e["hi"]) <= lo:
             continue
-        z = durableio.load_npz_checked(store.abspath(e["file"]), what="sketch shard")
+        z = read_payload(store.abspath(e["file"]), "sketch shard")
         names.extend(
             str(x) for i, x in enumerate(z["names"], start=int(e["lo"])) if i >= lo
         )

@@ -83,7 +83,7 @@ from drep_tpu.index.federation import (
     _partition_generation,
     load_federated,
 )
-from drep_tpu.index.store import IndexStore, LoadedIndex, build_manifest, load_index
+from drep_tpu.index.store import IndexStore, LoadedIndex, build_manifest, load_index, unreferenced
 from drep_tpu.utils.logger import get_logger
 
 _STAT_COLS = ("length", "N50", "contigs", "n_kmers")
@@ -326,11 +326,9 @@ def _gc_unreferenced(part_dir: str) -> None:
         fam = os.path.join(part_dir, sub)
         if not os.path.isdir(fam):
             continue
-        for f in os.listdir(fam):
-            if (f.startswith(prefix) and f.endswith(".npz")
-                    and f not in referenced):
-                with contextlib.suppress(OSError):
-                    os.remove(os.path.join(fam, f))
+        for loc in unreferenced(fam, prefix, referenced):  # a shard's parts go with its head
+            with contextlib.suppress(OSError):
+                os.remove(loc)
     shutil.rmtree(os.path.join(part_dir, "pending"), ignore_errors=True)
 
 

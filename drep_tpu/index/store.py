@@ -16,7 +16,28 @@ Layout (all paths relative to the index directory)::
     sketches/sketch_g%06d.npz     -- one per admitted batch [lo, hi):
                                      names/locations/stats + the raw
                                      uint64 bottom & scaled sketches in
-                                     the ingest ragged layout.
+                                     the ingest ragged layout. The HEAD
+                                     of its shard: a member over
+                                     workdir.ARRAY_PART_BYTES (16 MiB;
+                                     `scaled` from ~100 genomes at
+                                     20,000 hashes, `bottom` from 2,000)
+                                     is not in it but in
+    sketches/sketch_g%06d.<member>.NNNN.npz
+                                  -- rows-first slices of that member, at
+                                     most ARRAY_PART_BYTES each, every
+                                     one a checked payload of its own;
+                                     the head holds their lengths under
+                                     ``__parts__<member>`` and is written
+                                     LAST, so the manifest's shard list
+                                     names heads only and a shard with no
+                                     head does not exist. A shard written
+                                     before the parts came is a head with
+                                     every member in it and loads as it
+                                     always did. Every npz family below
+                                     goes through the same writer
+                                     (`write_payload`), so an edge shard
+                                     or a state whose member passes the
+                                     bound is cut the same way.
     edges/edges_g%06d.npz         -- one per admitted batch: the retained
                                      sparse edge graph rows with
                                      lo <= jj < hi (ii < jj, dist <= keep),
@@ -45,7 +66,8 @@ Self-heal matrix (update-time; classify is read-only and refuses):
 
 - sketch shard corrupt/missing  -> re-sketch its range from the
   names/locations held redundantly in state (refusing loudly if the
-  FASTA content changed since indexing).
+  FASTA content changed since indexing). A missing or torn PART is its
+  shard torn: the same path, and the rewrite replaces head and parts.
 - edge shard corrupt/missing    -> recompute its [lo, hi) column range
   through the same rectangular tile schedule that produced it (pairwise
   distances are pack-independent, so the healed shard is identical).
@@ -138,6 +160,72 @@ def empty_index(params: dict, location: str | None = None) -> LoadedIndex:
     )
 
 
+_PART_REMEDY = "`drep-tpu index update <index>` (no genomes needed) heals a shard from the store's redundancy"
+
+
+def write_payload(path: str, compressed: bool = True, **arrays: np.ndarray) -> dict[str, int]:
+    """THE npz writer of the index store and of the params hand-off: each
+    file an ``atomic_savez`` (in-band checksum, atomic publish), none past
+    ``workdir.ARRAY_PART_BYTES``. A member over the bound is cut into part
+    files beside the head by the workdir array store's own cutter
+    (``workdir.store_parted``); the head is written last. Books what it
+    wrote in the record's `index` section and returns it."""
+    from drep_tpu import workdir
+    from drep_tpu.utils.ckptmeta import atomic_savez
+    from drep_tpu.utils.profiling import counters
+
+    os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
+    wrote = workdir.store_parted(
+        path, arrays, lambda loc, payload: atomic_savez(loc, compressed=compressed, **payload),
+        workdir.ARRAY_PART_BYTES,
+    )
+    counters.add_index(files_published=wrote["files"], bytes_published=wrote["bytes"],
+                       parts_written=wrote["parts"])
+    return wrote
+
+
+def fill_payload(path: str, head: dict[str, np.ndarray], what: str, workers: int = 1) -> dict[str, np.ndarray]:
+    """The decoded head of `path` with every member it lists in parts read
+    into one preallocated array (``workdir.fill_parted``: in place where the
+    part is a plain stored payload, checksummed either way). A one-file
+    payload comes back as it is. A missing or torn part is
+    `CorruptPayloadError`: its shard is torn."""
+    from drep_tpu import workdir
+    from drep_tpu.utils.profiling import counters
+
+    workdir.fill_parted(path, head, what, _PART_REMEDY, workers)
+    counters.add_index(bytes_loaded=payload_bytes(path))
+    return head
+
+
+def read_payload(path: str, what: str, workers: int = 1) -> dict[str, np.ndarray]:
+    """What :func:`write_payload` wrote at `path`, checked: the head through
+    `load_npz_checked`, then :func:`fill_payload`. `FileNotFoundError` where
+    there is no head."""
+    from drep_tpu.utils.durableio import load_npz_checked
+
+    return fill_payload(path, load_npz_checked(path, what=what), what, workers)
+
+
+def payload_bytes(path: str) -> int:
+    """The bytes on disk of the payload at `path`: its head and its parts."""
+    from drep_tpu import workdir
+
+    return sum(os.path.getsize(loc) for loc in (path, *workdir.part_locs(path)))
+
+
+def unreferenced(family_dir: str, prefix: str, keep: set[str]) -> list[str]:
+    """The files of one npz family (`prefix`) under `family_dir` whose head
+    is not in `keep` (basenames): superseded heads, and every part with its
+    head, an orphan of a killed write included."""
+    from drep_tpu import workdir
+
+    return [
+        os.path.join(family_dir, f) for f in sorted(os.listdir(family_dir))
+        if f.startswith(prefix) and f.endswith(".npz") and workdir.head_of(f) not in keep
+    ]
+
+
 class IndexStore:
     """Path bookkeeping + shard (de)serialization for one index dir."""
 
@@ -217,8 +305,6 @@ class IndexStore:
     # ---- shard serialization --------------------------------------------
     def write_sketch_shard(self, rel: str, names, locations, gdb_rows: pd.DataFrame,
                            bottom, scaled, admitted_gen) -> None:
-        from drep_tpu.utils.ckptmeta import atomic_savez
-
         # admitted_gen: one int for an ordinary per-generation append
         # shard, or a per-genome array for a folded shard (compaction /
         # split children span many admitting generations in one payload)
@@ -234,19 +320,16 @@ class IndexStore:
             payload[c] = gdb_rows[c].to_numpy().astype(np.int64)
         for key, arrs in (("bottom", bottom), ("scaled", scaled)):
             payload[key], payload[f"{key}_offsets"] = pack_ragged(list(arrs))
-        os.makedirs(os.path.dirname(self.abspath(rel)), exist_ok=True)
         # uncompressed like the workdir sketch cache: uniform 64-bit
-        # hashes are incompressible and zlib was a measured hot spot
-        atomic_savez(self.abspath(rel), compressed=False, **payload)
+        # hashes are incompressible and zlib was a measured hot spot (and a
+        # stored part is what the loader reads in place)
+        write_payload(self.abspath(rel), compressed=False, **payload)
 
     def write_edge_shard(self, rel: str, ii, jj, dd) -> None:
-        from drep_tpu.utils.ckptmeta import atomic_savez
-
         # canonical (ii, jj) order: a healed recompute must reproduce the
         # original payload exactly, whatever tile order produced it
         order = np.lexsort((jj, ii))
-        os.makedirs(os.path.dirname(self.abspath(rel)), exist_ok=True)
-        atomic_savez(
+        write_payload(
             self.abspath(rel),
             ii=np.asarray(ii, np.int64)[order],
             jj=np.asarray(jj, np.int64)[order],
@@ -254,10 +337,7 @@ class IndexStore:
         )
 
     def write_state(self, rel: str, idx: LoadedIndex) -> None:
-        from drep_tpu.utils.ckptmeta import atomic_savez
-
-        os.makedirs(os.path.dirname(self.abspath(rel)), exist_ok=True)
-        atomic_savez(
+        write_payload(
             self.abspath(rel),
             names=np.array(idx.names, dtype=str),
             locations=np.array(idx.locations, dtype=str),
@@ -283,12 +363,10 @@ class IndexStore:
         import contextlib
 
         state_dir = os.path.join(self.location, "state")
-        keep = os.path.basename(keep_rel)
         if os.path.isdir(state_dir):
-            for f in os.listdir(state_dir):
-                if f != keep and f.startswith("state_g") and f.endswith(".npz"):
-                    with contextlib.suppress(OSError):
-                        os.remove(os.path.join(state_dir, f))
+            for loc in unreferenced(state_dir, "state_g", {os.path.basename(keep_rel)}):
+                with contextlib.suppress(OSError):
+                    os.remove(loc)
         shutil.rmtree(os.path.join(self.location, "pending"), ignore_errors=True)
 
 
@@ -333,8 +411,10 @@ def _recompute_edge_range(
     return ii[sel], jj[sel], dd[sel]
 
 
-def load_index(location: str, heal: bool = False) -> LoadedIndex:
-    """Read the whole index at its manifest generation.
+def load_index(location: str, heal: bool = False, workers: int = 1) -> LoadedIndex:
+    """Read the whole index at its manifest generation (the span
+    `index/load`; a shard's parts are read on up to `workers` threads, and
+    checksummed as they are placed, so no span tells the verify apart).
 
     `heal=True` (the `index update` path) repairs corrupt/missing shards
     per the module-docstring heal matrix, rewriting them in place and
@@ -354,6 +434,15 @@ def load_index(location: str, heal: bool = False) -> LoadedIndex:
         from drep_tpu.index.federation import load_federated
 
         return load_federated(location, heal=heal)
+    from drep_tpu.utils.profiling import counters
+
+    with counters.span("index/load", heal=bool(heal)) as span:
+        idx = _load_plain(location, heal, workers)
+        span.note(genomes=idx.n, generation=idx.generation, healed=len(idx.healed))
+    return idx
+
+
+def _load_plain(location: str, heal: bool, workers: int) -> LoadedIndex:
     from drep_tpu.utils import durableio
 
     logger = get_logger()
@@ -369,12 +458,14 @@ def load_index(location: str, heal: bool = False) -> LoadedIndex:
         it); read-only mode surfaces an actionable refusal instead."""
         path = store.abspath(rel)
         if heal:
+            # a lost or torn part is raised inside `convert`: the shard is
+            # torn, booked as one heal, its head removed, its range recomputed
             return durableio.load_npz_or_none(
-                path, what=what, convert=lambda z: z,
+                path, what=what, convert=lambda z: fill_payload(path, z, what, workers),
                 warn=f"index {what}: corrupt %s — healing via recompute",
             )
         try:
-            return durableio.load_npz_checked(path, what=what)
+            return read_payload(path, what, workers)
         except FileNotFoundError:
             return None
         except durableio.CorruptPayloadError as e:

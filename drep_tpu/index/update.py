@@ -109,6 +109,30 @@ def _rect_edges(
     return ii[sel], jj[sel], dd[sel], pairs
 
 
+def rect_compare(
+    idx: LoadedIndex, n_old: int, checkpoint_dir: str | None, prune_cfg: dict | None = None
+) -> tuple[np.ndarray, np.ndarray, np.ndarray, int]:
+    """:func:`_rect_edges` as the verbs run it: inside the stage
+    `index_rect_compare` and the span `index/rect_compare`, its pairs, tiles
+    and new edges booked in the record's `index`, the edges in canonical
+    (ii, jj) order."""
+    from drep_tpu.utils.profiling import counters
+
+    def tiles_done() -> int:
+        walked = counters.stages.get("primary_compare")
+        return walked.tiles_computed if walked else 0
+
+    tiles_before = tiles_done()
+    with counters.stage("index_rect_compare"), counters.span(
+        "index/rect_compare", genomes=idx.n, min_col=n_old
+    ):
+        ii, jj, dd, pairs = _rect_edges(idx, n_old, checkpoint_dir, prune_cfg=prune_cfg)
+    counters.stages["index_rect_compare"].pairs += pairs
+    counters.add_index(pairs_compared=pairs, tiles=tiles_done() - tiles_before, new_edges=len(ii))
+    order = np.lexsort((jj, ii))
+    return ii[order], jj[order], dd[order], pairs
+
+
 def _primary_partition(idx: LoadedIndex, n_old: int) -> tuple[np.ndarray, list[list[int]], int]:
     """The union primary partition, re-clustering ONLY dirty components.
 
@@ -245,7 +269,10 @@ def recluster(idx: LoadedIndex, n_old: int, processes: int = 1) -> dict:
             by_label.setdefault(int(old_primary[i]), []).append(i)
         old_groups = {frozenset(v): True for v in by_label.values()}
 
-    labels, groups, reclustered_comps = _primary_partition(idx, n_old)
+    from drep_tpu.utils.profiling import counters
+
+    with counters.span("index/partition", genomes=idx.n, edges=len(idx.edges[0])):
+        labels, groups, reclustered_comps = _primary_partition(idx, n_old)
     n = idx.n
     suffix = np.zeros(n, np.int64)
     score = np.zeros(n, np.float64)
@@ -267,7 +294,7 @@ def recluster(idx: LoadedIndex, n_old: int, processes: int = 1) -> dict:
     # N per batch (the serving tier's per-query recluster floor). The
     # argmax/tie rule is pick_winners' exactly (score desc, genome asc;
     # output ordered by cluster name ascending), oracle-pinned in tests.
-    reused = recomputed = 0
+    reused = recomputed = members_recomputed = secondary_calls = singletons_scored = 0
     win_rows: list[tuple[str, str, float]] = []  # (cluster, genome, score)
     old_win: dict[str, tuple[str, float]] = {}
     if old_groups:
@@ -294,6 +321,7 @@ def recluster(idx: LoadedIndex, n_old: int, processes: int = 1) -> dict:
                 win_rows.append((f"{pc}_{s_val}", won[0], won[1]))
             continue
         recomputed += 1
+        members_recomputed += len(members)
         if frozen:
             held = [i for i in members if i in frozen]
             if held:
@@ -309,15 +337,20 @@ def recluster(idx: LoadedIndex, n_old: int, processes: int = 1) -> dict:
         if len(members) == 1:
             i = members[0]
             suffix[i] = 1  # the pipeline's singleton convention ("pc_1")
-            score[i] = _score_cluster(
-                idx, members, [f"{pc}_1"], pd.DataFrame({"querry": [], "reference": [], "ani": []})
-            )[0]
+            singletons_scored += 1
+            with counters.span("index/score", members=1):
+                score[i] = _score_cluster(
+                    idx, members, [f"{pc}_1"], pd.DataFrame({"querry": [], "reference": [], "ani": []})
+                )[0]
             win_rows.append((f"{pc}_1", idx.names[i], float(score[i])))
             continue
-        ndb, labs, _link = secondary_for_cluster(gs, bdb, list(members), pc, kw)
+        secondary_calls += 1
+        with counters.span("index/secondary", members=len(members)):
+            ndb, labs, _link = secondary_for_cluster(gs, bdb, list(members), pc, kw)
         suffix[members] = labs
         sec_names = [f"{pc}_{int(l)}" for l in labs]
-        score[members] = _score_cluster(idx, list(members), sec_names, ndb)
+        with counters.span("index/score", members=len(members)):
+            score[members] = _score_cluster(idx, list(members), sec_names, ndb)
         by_s = {}
         for i, lab in zip(members, labs):
             by_s.setdefault(int(lab), []).append(i)
@@ -335,6 +368,11 @@ def recluster(idx: LoadedIndex, n_old: int, processes: int = 1) -> dict:
             "genome": [r[1] for r in win_rows],
             "score": np.array([r[2] for r in win_rows], np.float64),
         }
+    )
+    counters.add_index(
+        components_reclustered=reclustered_comps, clusters_reused=reused,
+        clusters_recomputed=recomputed, members_recomputed=members_recomputed,
+        secondary_calls=secondary_calls, singletons_scored=singletons_scored,
     )
     return {
         "primary_clusters": int(labels.max()) if n else 0,
@@ -412,28 +450,35 @@ def publish_generation(
     Shared by `index update` and the fresh `index build` (whose batch is
     the whole initial set at generation 0)."""
     from drep_tpu.utils import faults
+    from drep_tpu.utils.profiling import counters
 
-    store.ensure_dirs()
-    sk_rel = store.sketch_shard_name(gen_new)
-    ed_rel = store.edge_shard_name(gen_new)
-    st_rel = store.state_name(gen_new)
-    store.write_sketch_shard(
-        sk_rel, idx.names[n_old:], idx.locations[n_old:], idx.gdb.iloc[n_old:],
-        idx.bottom[n_old:], idx.scaled[n_old:], gen_new,
-    )
-    ii, jj, dd = new_edges
-    store.write_edge_shard(ed_rel, ii, jj, dd)
-    store.write_state(st_rel, idx)
-    idx.generation = gen_new
-    idx.sketch_shards = idx.sketch_shards + [
-        {"file": sk_rel, "lo": n_old, "hi": idx.n, "generation": gen_new}
-    ]
-    idx.edge_shards = idx.edge_shards + [
-        {"file": ed_rel, "lo": n_old, "hi": idx.n, "generation": gen_new}
-    ]
-    faults.fire("index_update")  # pre-publish point (skip=1 targets it)
-    store.publish_manifest(build_manifest(idx, st_rel))
-    store.gc_states(st_rel)
+    with counters.span("index/publish", generation=gen_new, genomes=idx.n - n_old):
+        store.ensure_dirs()
+        sk_rel = store.sketch_shard_name(gen_new)
+        ed_rel = store.edge_shard_name(gen_new)
+        st_rel = store.state_name(gen_new)
+        with counters.span("index/publish_sketch"):
+            store.write_sketch_shard(
+                sk_rel, idx.names[n_old:], idx.locations[n_old:], idx.gdb.iloc[n_old:],
+                idx.bottom[n_old:], idx.scaled[n_old:], gen_new,
+            )
+        ii, jj, dd = new_edges
+        with counters.span("index/publish_edges", edges=len(ii)):
+            store.write_edge_shard(ed_rel, ii, jj, dd)
+        with counters.span("index/publish_state"):
+            store.write_state(st_rel, idx)
+        idx.generation = gen_new
+        idx.sketch_shards = idx.sketch_shards + [
+            {"file": sk_rel, "lo": n_old, "hi": idx.n, "generation": gen_new}
+        ]
+        idx.edge_shards = idx.edge_shards + [
+            {"file": ed_rel, "lo": n_old, "hi": idx.n, "generation": gen_new}
+        ]
+        faults.fire("index_update")  # pre-publish point (skip=1 targets it)
+        with counters.span("index/publish_manifest"):
+            store.publish_manifest(build_manifest(idx, st_rel))
+            store.gc_states(st_rel)
+    counters.add_index(admitted=idx.n - n_old, generation=gen_new, n_old=n_old)
 
 
 def materialize_generation0(
@@ -456,12 +501,10 @@ def materialize_generation0(
             f"length filter — nothing to materialize"
         )
     idx = empty_index(dict(params), location=store.location)
-    _admit_batch(idx, batch, results, 0)
-    with counters.stage("index_rect_compare"):
-        ii, jj, dd, pairs = _rect_edges(idx, 0, store.pending_dir(0))
-    counters.stages["index_rect_compare"].pairs += pairs
-    order = np.lexsort((jj, ii))
-    idx.edges = (ii[order], jj[order], dd[order])
+    with counters.span("index/admit", genomes=len(batch)):
+        _admit_batch(idx, batch, results, 0)
+    ii, jj, dd, pairs = rect_compare(idx, 0, store.pending_dir(0))
+    idx.edges = (ii, jj, dd)
     summary = recluster(idx, 0, processes=processes)
     publish_generation(store, idx, 0, 0, idx.edges)
     summary.update(
@@ -529,7 +572,8 @@ def index_update(
     if params_file:
         from drep_tpu.index.federation import read_params_handoff
 
-        handoff = read_params_handoff(params_file)
+        with counters.span("index/handoff_read"):
+            handoff = read_params_handoff(params_file, workers=processes)
         handoff_params = handoff["params"]
         presketched = (handoff["batch"], handoff["results"])
         if not store.exists():
@@ -539,7 +583,7 @@ def index_update(
             return materialize_generation0(
                 store, handoff_params, *presketched, processes=processes
             )
-    idx = load_index(index_loc, heal=True)
+    idx = load_index(index_loc, heal=True, workers=processes)
     if handoff_params is not None and dict(idx.params) != dict(handoff_params):
         raise UserInputError(
             f"params handoff {params_file} pins different params than the "
@@ -560,7 +604,8 @@ def index_update(
                 f"admitted (resume the interrupted update instead)"
             )
     elif genome_paths:
-        batch, results = sketch_batch(idx, genome_paths, processes=processes)
+        with counters.span("index/sketch", genomes=len(genome_paths)):
+            batch, results = sketch_batch(idx, genome_paths, processes=processes)
     if batch is None or not len(batch):
         # heal-only pass: rotted state recomputes (all components dirty),
         # healed shards were already rewritten by load_index — the
@@ -574,20 +619,15 @@ def index_update(
             logger.info("index heal pass: repaired %s", idx.healed)
         return summary
 
-    n_old = _admit_batch(idx, batch, results, gen_new)
+    with counters.span("index/admit", genomes=len(batch)):
+        n_old = _admit_batch(idx, batch, results, gen_new)
     prune_cfg = {
         "primary_prune": primary_prune,
         "prune_bands": prune_bands,
         "prune_min_shared": prune_min_shared,
         "prune_join_chunk": prune_join_chunk,
     }
-    with counters.stage("index_rect_compare"):
-        ii, jj, dd, pairs = _rect_edges(
-            idx, n_old, store.pending_dir(gen_new), prune_cfg=prune_cfg
-        )
-    counters.stages["index_rect_compare"].pairs += pairs
-    order = np.lexsort((jj, ii))
-    ii, jj, dd = ii[order], jj[order], dd[order]
+    ii, jj, dd, pairs = rect_compare(idx, n_old, store.pending_dir(gen_new), prune_cfg=prune_cfg)
     idx.edges = (
         np.concatenate([idx.edges[0], ii]),
         np.concatenate([idx.edges[1], jj]),

@@ -449,6 +449,14 @@ class Counters:
     # that went through a device call, published or not). A fresh job reads
     # zeros on the resumed side
     resume: dict[str, int] = field(default_factory=dict)
+    # what an index verb did (drep_tpu/index/, ISSUE 50): `n_old` genomes
+    # loaded, `admitted`, the `generation` it published; the rectangle's
+    # `pairs_compared`, `tiles` and `new_edges`; `components_reclustered`,
+    # `clusters_reused`, `clusters_recomputed` with their `members_recomputed`,
+    # the `secondary_calls` and `singletons_scored` that took; `bytes_loaded`
+    # (files read back), `bytes_published`, `files_published` and the
+    # `parts_written` among them (index/store.py::write_payload)
+    index: dict[str, int] = field(default_factory=dict)
     # the safe boundary at which a one-process job honoured a drain request
     # (faulttol.drain_at_boundary): `stage`, where in it, and when the
     # request was made on time.monotonic()'s clock; empty for a job that ran
@@ -753,6 +761,12 @@ class Counters:
         for name, value in counts.items():
             self.resume[name] = self.resume.get(name, 0) + int(value)
 
+    def add_index(self, **counts: int) -> None:
+        """Add to the record's `index`: what an index verb read, compared,
+        reclustered and published (drep_tpu/index/)."""
+        for name, value in counts.items():
+            self.index[name] = self.index.get(name, 0) + int(value)
+
     def note_drain(self, stage: str, **where: Any) -> None:
         """This job leaves at a safe boundary of `stage`: the record's
         `drain`, with the seconds the `job` span had run by then."""
@@ -1032,6 +1046,8 @@ class Counters:
                                for name, ent in self.evaluate.items()}
         if self.resume:
             out["resume"] = dict(self.resume)
+        if self.index:
+            out["index"] = dict(self.index)
         if self.drain:
             out["drain"] = dict(self.drain)
         phases = self._phases_report()
@@ -1104,6 +1120,7 @@ class Counters:
         self.filter.clear()
         self.evaluate.clear()
         self.resume.clear()
+        self.index.clear()
         self.drain = {}
         with self._lock:
             self.phases.clear()  # a span open now stays open and books when it closes

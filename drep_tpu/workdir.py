@@ -19,6 +19,7 @@ import contextlib
 import glob
 import json
 import os
+import re
 from typing import Any
 
 import numpy as np
@@ -56,6 +57,150 @@ def _atomic_write(loc: str, write_fn) -> None:
     from drep_tpu.utils.ckptmeta import atomic_write
 
     atomic_write(loc, write_fn, keep_suffix=True)
+
+
+def part_loc(head_loc: str, key: str, i: int) -> str:
+    """Part `i` of the member `key` of the payload whose head is `head_loc`
+    (``<name>.npz`` -> ``<name>.<key>.NNNN.npz``, beside it)."""
+    return f"{head_loc[: -len('.npz')]}.{key}.{i:04d}.npz"
+
+
+def part_locs(head_loc: str) -> list[str]:
+    """Every part file on disk beside `head_loc`, whichever save wrote it."""
+    return glob.glob(f"{glob.escape(head_loc[: -len('.npz')])}.*.{'[0-9]' * 4}.npz")
+
+
+_PART_NAME = re.compile(r"^(?P<head>.+)\.[A-Za-z_]\w*\.\d{4}\.npz$")
+
+
+def head_of(filename: str) -> str:
+    """The head file a part file belongs to; any other name is its own head.
+    What lists a store's payloads by name (gc, the scrubber's superseded
+    class) keeps or drops a part with its head."""
+    m = _PART_NAME.match(filename)
+    return m.group("head") + ".npz" if m else filename
+
+
+def store_parted(head_loc: str, arrays: dict[str, np.ndarray], publish, part_bytes: int) -> dict[str, int]:
+    """Publish `arrays` as the payload `head_loc` with no file past
+    `part_bytes`: an array larger than that, or that would take the head past
+    it, is cut along its first axis into
+    parts (``part_loc``; each ``publish(loc, {"part": rows})``, a checked
+    payload of its own) and the head records the parts' lengths under
+    ``__parts__<key>``. The head is the commit point, removed first and
+    published last, so a kill mid-save leaves an absent payload, never a head
+    over another save's parts. Returns what was written: `files`, `parts`,
+    `bytes` (on disk)."""
+    for loc in (head_loc, *part_locs(head_loc)):  # the head first
+        with contextlib.suppress(FileNotFoundError):
+            os.remove(loc)
+    head: dict[str, np.ndarray] = {}
+    wrote = {"files": 0, "parts": 0, "bytes": 0}
+
+    def put(loc: str, payload: dict) -> None:
+        publish(loc, payload)
+        wrote["files"] += 1
+        wrote["bytes"] += os.path.getsize(loc)
+
+    in_head = 0
+    for key, arr in arrays.items():
+        arr = np.asarray(arr)
+        # the head holds what fits it TOGETHER: a member that would take the
+        # head past the bound goes to parts like one that is past it alone
+        if arr.ndim == 0 or len(arr) < 2 or in_head + arr.nbytes <= part_bytes:
+            head[key] = arr
+            in_head += arr.nbytes
+            continue
+        rows = max(1, part_bytes // (arr.nbytes // len(arr)))
+        lengths = []
+        for i, lo in enumerate(range(0, len(arr), rows)):
+            part = arr[lo : lo + rows]
+            put(part_loc(head_loc, key, i), {"part": part})
+            lengths.append(len(part))
+        wrote["parts"] += len(lengths)
+        head[_PARTS_PREFIX + key] = np.asarray(lengths, dtype=np.int64)
+    put(head_loc, head)
+    return wrote
+
+
+def fill_parted(head_loc: str, out: dict[str, np.ndarray], what: str, remedy: str,
+                workers: int = 1) -> dict[str, int]:
+    """Replace every ``__parts__<key>`` entry of the decoded head `out`
+    (``load_npz_checked(head_loc)``) by its member, read part by part into
+    ONE array allocated for the whole of it. A head with no such entry (a
+    one-file payload) is left as it is. Returns the account of the read:
+    `members` read from parts, their `parts`, `direct_parts`,
+    `fallback_parts`, `bytes`, the `threads` of the widest member. A part
+    that is missing, torn or of another shape than its head says raises
+    `CorruptPayloadError` naming the part, `what` and the `remedy`."""
+    read = {"members": 0, "parts": 0, "direct_parts": 0, "fallback_parts": 0, "bytes": 0, "threads": 0}
+    for pkey in [k for k in out if k.startswith(_PARTS_PREFIX)]:
+        lengths = out.pop(pkey).tolist()
+        key = pkey[len(_PARTS_PREFIX) :]
+        out[key], direct, threads = _read_parts(head_loc, key, lengths, what, remedy, workers)
+        read["members"] += 1
+        read["parts"] += len(lengths)
+        read["direct_parts"] += direct
+        read["fallback_parts"] += len(lengths) - direct
+        read["bytes"] += out[key].nbytes
+        read["threads"] = max(read["threads"], threads)
+    return read
+
+
+def _read_parts(head_loc: str, key: str, lengths: list[int], what: str, remedy: str,
+                workers: int) -> tuple[np.ndarray, int, int]:
+    """The member `key` out of its parts: (the array, the parts read in
+    place, the threads that read). The first part's header says what to
+    allocate; every part is then read into its own rows."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    from drep_tpu.utils.durableio import (
+        CorruptPayloadError, PayloadShapeError, load_npz_checked, load_npz_member_into, npz_member_header,
+    )
+    from drep_tpu.utils.hosttools import usable_cores
+
+    part_what = f"{what} part"
+
+    def missing(loc: str, e: Exception) -> CorruptPayloadError:
+        return CorruptPayloadError(f"{what}: part {loc} is missing ({e!r}) — {remedy}")
+
+    first = part_loc(head_loc, key, 0)
+    header = npz_member_header(first, "part")
+    if header is None:  # unreadable so: the checked reader says why, or reads it
+        try:
+            part = load_npz_checked(first, what=part_what)["part"]
+        except (FileNotFoundError, KeyError) as e:
+            raise missing(first, e) from e
+        header = part.dtype, part.shape
+    dtype, shape = header
+    starts = np.concatenate([[0], np.cumsum(lengths, dtype=np.int64)]).tolist()
+    whole = np.empty((starts[-1], *shape[1:]), dtype=dtype)  # one allocation, filled part by part
+
+    def read(i: int) -> bool:
+        loc = part_loc(head_loc, key, i)
+        try:
+            return load_npz_member_into(loc, "part", whole[starts[i] : starts[i + 1]], what=part_what)
+        except (FileNotFoundError, KeyError) as e:
+            raise missing(loc, e) from e
+        except PayloadShapeError as e:
+            if e.shape[:1] != (lengths[i],):
+                raise CorruptPayloadError(
+                    f"{what}: part {loc} holds {e.shape[0] if e.shape else 0} rows, "
+                    f"its head says {lengths[i]} — {remedy}"
+                ) from e
+            raise CorruptPayloadError(
+                f"{what}: part {loc} holds {e.dtype}{list(e.shape[1:])} rows, "
+                f"the first part {dtype}{list(shape[1:])} — {remedy}"
+            ) from e
+
+    threads = max(1, min(int(workers), usable_cores(), len(lengths)))
+    if threads == 1:
+        direct = sum(read(i) for i in range(len(lengths)))
+    else:
+        with ThreadPoolExecutor(max_workers=threads) as pool:
+            # the first error is raised here; `map` cancels what has not begun
+            direct = sum(pool.map(read, range(len(lengths))))
+    return whole, direct, threads
 
 
 def _json_default(o: Any):
@@ -137,9 +282,6 @@ class WorkDirectory:
     def _array_loc(self, name: str) -> str:
         return os.path.join(self.location, "data", "arrays", f"{name}.npz")
 
-    def _part_loc(self, name: str, key: str, i: int) -> str:
-        return os.path.join(self.location, "data", "arrays", f"{name}.{key}.{i:04d}.npz")
-
     def store_arrays(self, name: str, compressed: bool = True, **arrays: np.ndarray) -> None:
         """`compressed=False` for high-entropy payloads (the MinHash sketch
         cache: uniform 64-bit hashes are incompressible, and zlib over the
@@ -170,27 +312,7 @@ class WorkDirectory:
             payload = with_checksum(payload)
             _atomic_write(loc, lambda tmp: writer(tmp, **payload))
 
-        head_loc = self._array_loc(name)
-        stale_parts = glob.glob(
-            os.path.join(glob.escape(os.path.dirname(head_loc)), f"{name}.*.{'[0-9]' * 4}.npz")
-        )
-        for loc in (head_loc, *stale_parts):  # the head first
-            with contextlib.suppress(FileNotFoundError):
-                os.remove(loc)
-        head: dict[str, np.ndarray] = {}
-        for key, arr in arrays.items():
-            arr = np.asarray(arr)
-            if arr.ndim == 0 or len(arr) < 2 or arr.nbytes <= ARRAY_PART_BYTES:
-                head[key] = arr
-                continue
-            rows = max(1, ARRAY_PART_BYTES // (arr.nbytes // len(arr)))
-            lengths = []
-            for i, lo in enumerate(range(0, len(arr), rows)):
-                part = arr[lo : lo + rows]
-                publish(self._part_loc(name, key, i), {"part": part})
-                lengths.append(len(part))
-            head[_PARTS_PREFIX + key] = np.asarray(lengths, dtype=np.int64)
-        publish(head_loc, head)
+        store_parted(self._array_loc(name), arrays, publish, ARRAY_PART_BYTES)
 
     def get_arrays(self, name: str, workers: int = 1) -> dict[str, np.ndarray]:
         """What :meth:`store_arrays` stored as `name`: :meth:`read_arrays`'
@@ -218,80 +340,13 @@ class WorkDirectory:
         from drep_tpu.utils.durableio import load_npz_checked
 
         t0 = time.perf_counter()
-        out = load_npz_checked(self._array_loc(name), what=f"workdir array {name}")
-        read = {"members": 0, "parts": 0, "direct_parts": 0, "fallback_parts": 0, "bytes": 0, "threads": 0}
-        for pkey in [k for k in out if k.startswith(_PARTS_PREFIX)]:
-            lengths = out.pop(pkey).tolist()
-            key = pkey[len(_PARTS_PREFIX) :]
-            out[key], direct, threads = self._read_parts(name, key, lengths, workers)
-            read["members"] += 1
-            read["parts"] += len(lengths)
-            read["direct_parts"] += direct
-            read["fallback_parts"] += len(lengths) - direct
-            read["bytes"] += out[key].nbytes
-            read["threads"] = max(read["threads"], threads)
+        head_loc = self._array_loc(name)
+        out = load_npz_checked(head_loc, what=f"workdir array {name}")
+        read: dict[str, Any] = fill_parted(
+            head_loc, out, f"workdir array {name}", f"delete {head_loc} to recompute the cache", workers
+        )
         read["seconds"] = time.perf_counter() - t0
         return out, read
-
-    def _read_parts(self, name: str, key: str, lengths: list[int], workers: int) -> tuple[np.ndarray, int, int]:
-        """The member `key` of `name` out of its parts: (the array, the parts
-        read in place, the threads that read). The first part's header says
-        what to allocate; every part is then read into its own rows."""
-        from concurrent.futures import ThreadPoolExecutor
-
-        from drep_tpu.utils.durableio import (
-            CorruptPayloadError, PayloadShapeError, load_npz_checked, load_npz_member_into, npz_member_header,
-        )
-        from drep_tpu.utils.hosttools import usable_cores
-
-        head_loc = self._array_loc(name)
-        what = f"workdir array {name} part"
-
-        def missing(loc: str, e: Exception) -> CorruptPayloadError:
-            return CorruptPayloadError(
-                f"workdir array {name}: part {loc} is missing ({e!r}) — "
-                f"delete {head_loc} to recompute the cache"
-            )
-
-        first = self._part_loc(name, key, 0)
-        header = npz_member_header(first, "part")
-        if header is None:  # unreadable so: the checked reader says why, or reads it
-            try:
-                part = load_npz_checked(first, what=what)["part"]
-            except (FileNotFoundError, KeyError) as e:
-                raise missing(first, e) from e
-            header = part.dtype, part.shape
-        dtype, shape = header
-        starts = np.concatenate([[0], np.cumsum(lengths, dtype=np.int64)]).tolist()
-        whole = np.empty((starts[-1], *shape[1:]), dtype=dtype)  # one allocation, filled part by part
-
-        def read(i: int) -> bool:
-            loc = self._part_loc(name, key, i)
-            try:
-                return load_npz_member_into(loc, "part", whole[starts[i] : starts[i + 1]], what=what)
-            except (FileNotFoundError, KeyError) as e:
-                raise missing(loc, e) from e
-            except PayloadShapeError as e:
-                if e.shape[:1] != (lengths[i],):
-                    raise CorruptPayloadError(
-                        f"workdir array {name}: part {loc} holds {e.shape[0] if e.shape else 0} rows, "
-                        f"its head says {lengths[i]} — delete {head_loc} to "
-                        f"recompute the cache"
-                    ) from e
-                raise CorruptPayloadError(
-                    f"workdir array {name}: part {loc} holds {e.dtype}{list(e.shape[1:])} rows, "
-                    f"the first part {dtype}{list(shape[1:])} — delete "
-                    f"{head_loc} to recompute the cache"
-                ) from e
-
-        threads = max(1, min(int(workers), usable_cores(), len(lengths)))
-        if threads == 1:
-            direct = sum(read(i) for i in range(len(lengths)))
-        else:
-            with ThreadPoolExecutor(max_workers=threads) as pool:
-                # the first error is raised here; `map` cancels what has not begun
-                direct = sum(pool.map(read, range(len(lengths))))
-        return whole, direct, threads
 
     def has_arrays(self, name: str) -> bool:
         return os.path.exists(self._array_loc(name))

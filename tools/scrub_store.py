@@ -20,7 +20,9 @@ Verified payload families (everything else is left alone):
   blocks (``blk_*.npz``), secondary per-cluster results (``pc_*.npz``),
   ingest sketch shards, workdir arrays, and every genome-index family
   (``sketch_g*.npz``, ``edges_g*.npz``, ``state_g*.npz`` — sketches,
-  edge graph, labels/winner table; drep_tpu/index/store.py). Zero-byte,
+  edge graph, labels/winner table; drep_tpu/index/store.py — a shard's
+  heads and its ``sketch_g*.<member>.NNNN.npz`` part files alike, each a
+  checked payload of its own). Zero-byte,
   truncated, unparseable, or checksum-mismatched shards are DAMAGE.
 - ``meta.json``, the genome-index ``manifest.json``, the FEDERATED
   index's ``federation.json`` meta-manifest (drep_tpu/index/meta.py),
@@ -77,6 +79,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 from drep_tpu.utils import durableio  # noqa: E402
+from drep_tpu.workdir import head_of  # noqa: E402
 
 import re  # noqa: E402
 
@@ -167,9 +170,11 @@ def _maintenance_map(root: str) -> dict[str, str]:
                 fam_dir = os.path.join(dirpath, sub)
                 if not os.path.isdir(fam_dir):
                     continue
+                # a shard's part files (index/store.py) are kept or
+                # superseded with their head
                 for f in os.listdir(fam_dir):
                     if (f.startswith(prefix) and f.endswith(".npz")
-                            and f not in keep):
+                            and head_of(f) not in keep):
                         out[os.path.join(fam_dir, f)] = "superseded"
     return out
 
