@@ -360,38 +360,27 @@ def _secondary_postprocess(
     return ndb, labels, link
 
 
-def _secondary_for_cluster(
-    gs: GenomeSketches,
-    bdb: pd.DataFrame,
-    indices: list[int],
-    pc: int,
-    kw: dict[str, Any],
-) -> tuple[pairs.NdbColumns, np.ndarray, np.ndarray]:
-    """One primary cluster -> (Ndb rows, secondary labels 1.., linkage)."""
-    engine = dispatch.get_secondary(kw["S_algorithm"])
-    ani, cov = engine(gs, indices, bdb=bdb, processes=kw["processes"], mesh_shape=kw["mesh_shape"])
-    with counters.span("secondary/post"):
-        return _secondary_postprocess(gs, indices, pc, kw, ani, cov)
-
-
 def secondary_for_cluster(
     gs: GenomeSketches,
     bdb: pd.DataFrame,
     indices: list[int],
     pc: int,
     kw: dict[str, Any],
-) -> tuple[pd.DataFrame, np.ndarray, np.ndarray]:
-    """One primary cluster -> (Ndb frame, secondary labels 1.., linkage).
+) -> tuple[pairs.NdbColumns, np.ndarray, np.ndarray]:
+    """One primary cluster -> (Ndb rows, secondary labels 1.., linkage).
 
-    The incremental genome index (drep_tpu/index/update.py) re-runs the
-    secondary stage for exactly the primary clusters its update touched —
-    through THIS implementation, so a re-scored cluster's (Ndb rows, labels)
-    are bit-identical to what a from-scratch run computes for the same
+    The secondary stage's per-cluster route, and the incremental genome
+    index's (drep_tpu/index/update.py), which re-runs the secondary for
+    exactly the primary clusters its update touched — through THIS
+    implementation, so a re-scored cluster's (Ndb rows, labels) are
+    bit-identical to what a from-scratch run computes for the same
     member set. `kw` needs S_algorithm/S_ani/cov_thresh/clusterAlg/
-    processes/mesh_shape (fill via CLUSTER_DEFAULTS). A caller with one
-    cluster reads a frame; the stage keeps columns."""
-    ndb, labels, link = _secondary_for_cluster(gs, bdb, indices, pc, kw)
-    return ndb.frame(), labels, link
+    processes/mesh_shape (fill via CLUSTER_DEFAULTS). Both callers keep
+    the rows as columns and build one frame of many clusters'."""
+    engine = dispatch.get_secondary(kw["S_algorithm"])
+    ani, cov = engine(gs, indices, bdb=bdb, processes=kw["processes"], mesh_shape=kw["mesh_shape"])
+    with counters.span("secondary/post"):
+        return _secondary_postprocess(gs, indices, pc, kw, ani, cov)
 
 
 def _secondary_stage(
@@ -511,7 +500,7 @@ def _secondary_stage(
                 # a per-process retry cannot desync the pod — a
                 # mid-batch failure retries instead of killing the run
                 results[pc] = retrying_call(
-                    lambda indices=indices, pc=pc: _secondary_for_cluster(
+                    lambda indices=indices, pc=pc: secondary_for_cluster(
                         gs, bdb, indices, pc, kw
                     ),
                     site="secondary_batch",

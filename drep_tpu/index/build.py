@@ -247,6 +247,7 @@ def build_from_workdir(index_loc: str, wd_loc: str) -> dict:
         sdb_full, wdb = score_and_pick(
             cdb_idx, stats, ndb, None, S_ani=params["S_ani"], **params["weights"]
         )
+    counters.add_index(score_calls=1)
     by_score = sdb_full.set_index("genome")["score"]
     idx.score = np.array([float(by_score[g]) for g in idx.names], np.float64)
     idx.winners = wdb[["cluster", "genome", "score"]]

@@ -20,10 +20,12 @@ approximately, because every quantity the pipeline computes decomposes:
   ops/linkage code the streaming primary runs.
 - **secondary clustering + scoring** depend only on a primary cluster's
   member set (cluster-local ANI; row-local scores; centrality only to
-  co-members) — recomputed through cluster/controller.py's
-  ``secondary_for_cluster`` and choose.py's ``score_and_pick`` for
-  exactly the clusters whose member set changed, reused verbatim
-  (member-set-keyed) for the rest.
+  co-members) — recomputed for exactly the clusters whose member set
+  changed, reused verbatim (member-set-keyed) for the rest: the secondary
+  a cluster at a time through cluster/controller.py's
+  ``secondary_for_cluster``, the scores and winners of ALL the changed
+  clusters through ONE call of choose.py's ``score_and_pick`` (row-local,
+  so the call over their union gives each cluster's own rows).
 
 Crash story: the rectangular compare checkpoints per-stripe shards under
 ``<index>/pending/`` (the streaming store format), all new shards are
@@ -46,6 +48,13 @@ from drep_tpu.index.store import IndexStore, LoadedIndex, build_manifest, load_i
 from drep_tpu.utils.logger import get_logger
 
 _STAT_COLS = ("length", "N50", "contigs", "n_kmers")
+# the Ndb rows one score_and_pick call may gather: past it the changed
+# clusters gathered so far go through a call of their own, cut at cluster
+# boundaries — the frames' peak is this or the largest cluster, whatever the
+# number of clusters an `index build -g` or a rebuild recomputes at once
+SCORE_ROWS_MAX = 4_000_000
+# all compute_centrality reads of an Ndb (the index keeps no pair table)
+_SCORE_NDB_COLS = ("querry", "reference", "ani")
 
 
 def _genome_sketches(idx: LoadedIndex):
@@ -216,23 +225,80 @@ def _primary_partition(idx: LoadedIndex, n_old: int) -> tuple[np.ndarray, list[l
     return labels, groups, reclustered
 
 
-def _score_cluster(
-    idx: LoadedIndex, members: list[int], sec_names: list[str], ndb: pd.DataFrame
-) -> np.ndarray:
-    """Choose-stage scores for one primary cluster's members — through the
-    same score_and_pick core the batch pipeline runs (row-local, so the
-    subset call equals the full run's rows)."""
-    from drep_tpu.choose import score_and_pick
+def _score_column(ndb, c: str) -> np.ndarray:
+    """Column `c` of one cluster's Ndb rows (pairs.NdbColumns) for the frame
+    score_and_pick reads: a name column as objects that SHARE the cluster's
+    own name strings — `ndb.column(c)` is a `<U` array, of which a frame
+    makes a new str (and the group-bys a new hash) a row."""
+    from drep_tpu.cluster import pairs
 
-    names = [idx.names[i] for i in members]
-    cdb_sub = pd.DataFrame({"genome": names, "secondary_cluster": sec_names})
-    stats_sub = idx.gdb.iloc[members][["genome", "length", "N50"]]
-    w = idx.params["weights"]
-    sdb_full, _ = score_and_pick(
-        cdb_sub, stats_sub, ndb, None, S_ani=idx.params["S_ani"], **w
-    )
-    by = sdb_full.set_index("genome")["score"]
-    return np.array([float(by[g]) for g in names], np.float64)
+    if ndb.names is None or c not in pairs.NAME_COLUMNS:
+        return ndb.column(c)
+    return ndb.names.astype(object)[ndb.cols[c]]
+
+
+class _ScoreBatch:
+    """The recomputed clusters gathered for ONE call of score_and_pick, the
+    choose core the batch pipeline runs over all its clusters: scores are
+    row-local (own stats + centrality to co-members), so the call over a
+    union of clusters gives exactly the rows and winners a call a cluster
+    would, at one set of frames where that built one a cluster. A flush
+    fills `score[members]` and appends the clusters' winners (pick_winners:
+    score desc, genome asc) to `win_rows`."""
+
+    def __init__(self, idx: LoadedIndex, score: np.ndarray, win_rows: list) -> None:
+        self.idx, self.score, self.win_rows = idx, score, win_rows
+        self.calls = 0
+        self._clear()
+
+    def _clear(self) -> None:
+        self.members: list[int] = []
+        self.sec_names: list[str] = []
+        self.ndbs: list = []  # pairs.NdbColumns, one a cluster of two or more
+        self.clusters = self.rows = 0
+
+    def add(self, pc: int, members: list[int], labels, ndb=None) -> None:
+        """One cluster: its members, their secondary labels and its Ndb rows
+        (pairs.NdbColumns; a singleton has one Cdb row and none). What was
+        gathered is scored first where these rows would pass SCORE_ROWS_MAX."""
+        if ndb is not None:
+            if self.rows and self.rows + len(ndb) > SCORE_ROWS_MAX:
+                self.flush()
+            self.ndbs.append(ndb)
+            self.rows += len(ndb)
+        self.members.extend(members)
+        self.sec_names.extend(f"{pc}_{int(l)}" for l in labels)
+        self.clusters += 1
+
+    def flush(self) -> None:
+        if not self.members:
+            return
+        from drep_tpu.choose import score_and_pick
+        from drep_tpu.utils.profiling import counters
+
+        idx = self.idx
+        self.calls += 1
+        with counters.span(
+            "index/score", members=len(self.members), clusters=self.clusters, pairs=self.rows
+        ):
+            cdb = pd.DataFrame(
+                {"genome": [idx.names[i] for i in self.members], "secondary_cluster": self.sec_names}
+            )
+            stats = idx.gdb.iloc[self.members][["genome", "length", "N50"]]
+            ndb = pd.DataFrame(
+                {
+                    c: np.concatenate([_score_column(p, c) for p in self.ndbs])
+                    if self.ndbs else np.empty(0)
+                    for c in _SCORE_NDB_COLS
+                }
+            )
+            sdb_full, wdb = score_and_pick(
+                cdb, stats, ndb, None, S_ani=idx.params["S_ani"], **idx.params["weights"]
+            )
+            # the left merge on unique names keeps Cdb's row order
+            self.score[self.members] = sdb_full["score"].to_numpy(np.float64)
+            self.win_rows.extend(zip(wdb["cluster"], wdb["genome"], wdb["score"]))
+        self._clear()
 
 
 def recluster(idx: LoadedIndex, n_old: int, processes: int = 1) -> dict:
@@ -289,11 +355,13 @@ def recluster(idx: LoadedIndex, n_old: int, processes: int = 1) -> dict:
     # incremental verdict assembly (ISSUE 13 satellite): only a touched
     # cluster's winner can change, so the winner table is SPLICED — reused
     # clusters keep their old winner row verbatim (identical member sets
-    # have identical scores), recomputed clusters pick locally — instead
-    # of re-running choose.pick_winners + the score pandas path over all
-    # N per batch (the serving tier's per-query recluster floor). The
-    # argmax/tie rule is pick_winners' exactly (score desc, genome asc;
-    # output ordered by cluster name ascending), oracle-pinned in tests.
+    # have identical scores), recomputed clusters take theirs from the one
+    # score_and_pick call that scores them (ISSUE 51) — instead of
+    # re-running choose.pick_winners + the score pandas path over all
+    # N per batch (the serving tier's per-query recluster floor). `_pick`,
+    # a reused cluster's fallback, is pick_winners' argmax/tie rule exactly
+    # (score desc, genome asc; output ordered by cluster name ascending),
+    # oracle-pinned in tests.
     reused = recomputed = members_recomputed = secondary_calls = singletons_scored = 0
     win_rows: list[tuple[str, str, float]] = []  # (cluster, genome, score)
     old_win: dict[str, tuple[str, float]] = {}
@@ -303,6 +371,8 @@ def recluster(idx: LoadedIndex, n_old: int, processes: int = 1) -> dict:
 
     def _pick(cands: list[tuple[str, float]]) -> tuple[str, float]:
         return min(cands, key=lambda t: (-t[1], t[0]))
+
+    batch = _ScoreBatch(idx, score, win_rows)
 
     for pc, members in enumerate(groups, start=1):
         fs = frozenset(members)
@@ -335,28 +405,16 @@ def recluster(idx: LoadedIndex, n_old: int, processes: int = 1) -> dict:
                 if not members:
                     continue  # whole cluster unavailable: no winner row
         if len(members) == 1:
-            i = members[0]
-            suffix[i] = 1  # the pipeline's singleton convention ("pc_1")
+            suffix[members[0]] = 1  # the pipeline's singleton convention ("pc_1")
             singletons_scored += 1
-            with counters.span("index/score", members=1):
-                score[i] = _score_cluster(
-                    idx, members, [f"{pc}_1"], pd.DataFrame({"querry": [], "reference": [], "ani": []})
-                )[0]
-            win_rows.append((f"{pc}_1", idx.names[i], float(score[i])))
+            batch.add(pc, members, (1,))
             continue
         secondary_calls += 1
         with counters.span("index/secondary", members=len(members)):
             ndb, labs, _link = secondary_for_cluster(gs, bdb, list(members), pc, kw)
         suffix[members] = labs
-        sec_names = [f"{pc}_{int(l)}" for l in labs]
-        with counters.span("index/score", members=len(members)):
-            score[members] = _score_cluster(idx, list(members), sec_names, ndb)
-        by_s = {}
-        for i, lab in zip(members, labs):
-            by_s.setdefault(int(lab), []).append(i)
-        for s_val, mem in sorted(by_s.items()):
-            won = _pick([(idx.names[i], float(score[i])) for i in mem])
-            win_rows.append((f"{pc}_{s_val}", won[0], won[1]))
+        batch.add(pc, members, labs, ndb)
+    batch.flush()
 
     idx.primary = labels
     idx.suffix = suffix
@@ -373,6 +431,7 @@ def recluster(idx: LoadedIndex, n_old: int, processes: int = 1) -> dict:
         components_reclustered=reclustered_comps, clusters_reused=reused,
         clusters_recomputed=recomputed, members_recomputed=members_recomputed,
         secondary_calls=secondary_calls, singletons_scored=singletons_scored,
+        score_calls=batch.calls,
     )
     return {
         "primary_clusters": int(labels.max()) if n else 0,

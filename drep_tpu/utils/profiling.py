@@ -453,7 +453,8 @@ class Counters:
     # loaded, `admitted`, the `generation` it published; the rectangle's
     # `pairs_compared`, `tiles` and `new_edges`; `components_reclustered`,
     # `clusters_reused`, `clusters_recomputed` with their `members_recomputed`,
-    # the `secondary_calls` and `singletons_scored` that took; `bytes_loaded`
+    # the `secondary_calls` and `singletons_scored` that took and the
+    # `score_calls` (score_and_pick calls: one over all of them); `bytes_loaded`
     # (files read back), `bytes_published`, `files_published` and the
     # `parts_written` among them (index/store.py::write_payload)
     index: dict[str, int] = field(default_factory=dict)

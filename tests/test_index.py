@@ -372,3 +372,114 @@ def test_index_update_fault_site_spec_validation():
         with pytest.raises(faults.FaultSpecError):
             faults.configure(bad)
     faults.configure(None)
+
+
+def _per_cluster_reference(idx, before, n_old, frozen):
+    """The route `recluster` took before ISSUE 51, written out for the state
+    `recluster` left in `idx`: a changed cluster's own secondary, its frame,
+    its own `score_and_pick` call, the scores read back by name, the winner
+    by score descending then name; an unchanged cluster carried. Returns
+    (suffix, score, winners frame, the members scored of each changed cluster)."""
+    from drep_tpu.choose import score_and_pick
+    from drep_tpu.cluster.controller import secondary_for_cluster
+    from drep_tpu.index import update
+
+    old_primary, old_suffix, old_score = before
+    old_groups = {frozenset(np.nonzero(old_primary == l)[0].tolist()) for l in np.unique(old_primary[:n_old])}
+    gs = update._genome_sketches(idx)
+    bdb = pd.DataFrame({"genome": idx.names, "location": idx.locations})
+    kw = {k: idx.params[k] for k in ("S_algorithm", "S_ani", "cov_thresh", "clusterAlg")}
+    kw.update(processes=1, mesh_shape=None)
+    suffix, score = np.zeros(idx.n, np.int64), np.zeros(idx.n, np.float64)
+    win, scored = [], []
+    for pc in range(1, int(idx.primary.max()) + 1):
+        members = np.nonzero(idx.primary == pc)[0].tolist()
+        if frozenset(members) in old_groups:
+            suffix[members], score[members] = old_suffix[members], old_score[members]
+            labs = old_suffix[members]
+        else:
+            held = [i for i in members if i in frozen]
+            suffix[held], score[held] = 0, old_score[held]
+            members = [i for i in members if i not in frozen]
+            scored.append(len(members))
+            if len(members) == 1:
+                ndb, labs = pd.DataFrame({"querry": [], "reference": [], "ani": []}), np.array([1])
+            else:
+                cols, labs, _ = secondary_for_cluster(gs, bdb, members, pc, kw)
+                ndb = cols.frame()
+            names = [idx.names[i] for i in members]
+            cdb = pd.DataFrame({"genome": names, "secondary_cluster": [f"{pc}_{int(l)}" for l in labs]})
+            sdb, _ = score_and_pick(cdb, idx.gdb.iloc[members][["genome", "length", "N50"]], ndb, None,
+                                    S_ani=idx.params["S_ani"], **idx.params["weights"])
+            by = sdb.set_index("genome")["score"]
+            suffix[members], score[members] = labs, [float(by[g]) for g in names]
+        for s_val in sorted(set(int(l) for l in labs)):
+            cands = [(idx.names[i], float(score[i])) for i, l in zip(members, labs) if int(l) == s_val]
+            win.append((f"{pc}_{s_val}", *min(cands, key=lambda t: (-t[1], t[0]))))
+    win.sort(key=lambda r: r[0])
+    winners = pd.DataFrame({"cluster": [r[0] for r in win], "genome": [r[1] for r in win],
+                            "score": np.array([r[2] for r in win], np.float64)})
+    return suffix, score, winners, scored
+
+
+# groups planted, the genomes built from, the batch admitted in memory (in
+# this order), frozen rows, update.SCORE_ROWS_MAX (None: as shipped), whether
+# everything is recomputed (n_old = 0, what `index build -g` runs), and the
+# score_and_pick calls and changed clusters that makes
+ONE_CALL_CASES = {
+    "update_joins_founds_and_adds_a_singleton": ([3, 2, 2, 1], (0, 1, 3, 4), (2, 5, 6, 7), (), None, False, 1, 3),
+    "build_n_old_0": ([3, 2, 2, 1], tuple(range(8)), (), (), None, True, 1, 4),
+    "frozen_member_held_in_a_split_cluster": ([4, 2], (0, 1, 2, 4, 5), (3,), (1,), None, False, 1, 1),
+    "exact_tie_in_a_cluster_of_two": ([2, 2], (0, 1), (3, 2), (), None, False, 1, 1),
+    "flush_in_two_calls": ([3, 2, 2, 1], (0, 1, 3, 4), (2, 5, 6, 7), (), 6, False, 2, 3),
+    "flush_in_three_calls": ([3, 2, 2, 1], tuple(range(8)), (), (), 2, True, 3, 4),
+}
+
+
+@pytest.mark.parametrize("case", list(ONE_CALL_CASES))
+def test_one_score_call_over_the_changed_clusters_equals_a_call_a_cluster(tmp_path, monkeypatch, case):
+    """ISSUE 51: `recluster` scores the clusters it recomputes in ONE
+    `score_and_pick` call (or one a flush of `SCORE_ROWS_MAX` Ndb rows) and
+    takes their winners from it; scores, suffixes and winners are bit-equal
+    to the per-cluster route it replaces."""
+    from drep_tpu import choose
+    from drep_tpu.index import update
+
+    groups, first, batch, frozen, rows_max, everything, calls, changed = ONE_CALL_CASES[case]
+    paths = lib.write_genome_set(str(tmp_path / "g"), groups, seed=11)
+    loc = str(tmp_path / "idx")
+    build_from_paths(loc, [paths[i] for i in first], length=0)
+    idx = load_index(loc)
+    n_old = idx.n
+    if batch:
+        bdb, results = update.sketch_batch(idx, [paths[i] for i in batch])
+        update._admit_batch(idx, bdb, results, idx.generation + 1)
+        new = update.rect_compare(idx, n_old, None)[:3]
+        idx.edges = tuple(np.concatenate([a, b]) for a, b in zip(idx.edges, new))
+    if frozen:
+        idx.frozen_rows = np.array(frozen)
+    before = (idx.primary, idx.suffix, idx.score)
+    if rows_max is not None:
+        monkeypatch.setattr(update, "SCORE_ROWS_MAX", rows_max)
+    seen = []
+    real = choose.score_and_pick
+    monkeypatch.setattr(choose, "score_and_pick", lambda cdb, *a, **k: (seen.append(len(cdb)), real(cdb, *a, **k))[1])
+    summary = update.recluster(idx, 0 if everything else n_old)
+    monkeypatch.setattr(choose, "score_and_pick", real)
+
+    want_suffix, want_score, want_winners, scored = _per_cluster_reference(
+        idx, before, 0 if everything else n_old, set(frozen))
+    assert len(seen) == calls and len(scored) == changed == summary["clusters_recomputed"]
+    assert sum(seen) == sum(scored)  # every changed cluster's available members, once
+    assert np.array_equal(idx.suffix, want_suffix)
+    assert np.array_equal(idx.score, want_score)  # bit-equal, not close
+    pd.testing.assert_frame_equal(idx.winners, want_winners, check_exact=True)
+    if frozen:
+        assert idx.suffix[frozen[0]] == 0 and idx.score[frozen[0]] == before[2][frozen[0]]
+        assert idx.names[frozen[0]] not in set(idx.winners["genome"])
+    if case == "exact_tie_in_a_cluster_of_two":
+        a, b = n_old, n_old + 1  # admitted as (g03, g02): the later row has the smaller name
+        assert idx.score[a] == idx.score[b] and idx.suffix[a] == idx.suffix[b]
+        assert (idx.names[a], idx.names[b]) == ("g03.fasta", "g02.fasta")
+        pc = int(idx.primary[a])
+        assert list(idx.winners.loc[idx.winners["cluster"] == f"{pc}_1", "genome"]) == ["g02.fasta"]

@@ -18,7 +18,7 @@ PUBLISH_SPANS = {"index/publish_sketch", "index/publish_edges", "index/publish_s
                  "index/publish_manifest"}
 INDEX_COUNTERS = {"n_old", "admitted", "generation", "pairs_compared", "tiles", "new_edges",
                   "components_reclustered", "clusters_reused", "clusters_recomputed", "members_recomputed",
-                  "secondary_calls", "singletons_scored", "bytes_loaded", "bytes_published",
+                  "secondary_calls", "singletons_scored", "score_calls", "bytes_loaded", "bytes_published",
                   "files_published", "parts_written"}
 
 
@@ -64,8 +64,9 @@ def test_the_index_spans_partition_the_updates_job(verbs):
     # the shared code books its own spans inside the index's
     assert ph["primary/pack"]["seconds"] <= ph["index/rect_compare"]["seconds"] + 1e-3
     assert ph["stage:index_rect_compare"]["calls"] == 1
-    # one span a cluster whose member set changed: a joined cluster, a founded one, a new singleton
-    assert ph["index/secondary"]["calls"] == 2 and ph["index/score"]["calls"] == 3
+    # a joined cluster, a founded one, a new singleton: one secondary span a cluster of two or
+    # more, and ONE score span over the three (ISSUE 51: it was one a cluster)
+    assert ph["index/secondary"]["calls"] == 2 and ph["index/score"]["calls"] == 1
 
 
 def test_index_build_opens_the_same_spans_where_the_same_functions_run(verbs):
@@ -74,6 +75,7 @@ def test_index_build_opens_the_same_spans_where_the_same_functions_run(verbs):
     did = verbs["records"]["build"]["index"]
     assert did["generation"] == 0 and did["admitted"] == 4 and did["n_old"] == 0
     assert did["clusters_recomputed"] == 2 and did["clusters_reused"] == 0 and did["tiles"] == 1
+    assert did["score_calls"] == 1 == ph["index/score"]["calls"]
 
 
 def test_the_records_index_section_counts_what_the_update_did(verbs):
@@ -84,6 +86,8 @@ def test_the_records_index_section_counts_what_the_update_did(verbs):
     # g02 joins the first cluster, g05 and g06 found one, g07 is alone: three changed, one reused
     assert (did["clusters_recomputed"], did["clusters_reused"], did["components_reclustered"]) == (3, 1, 3)
     assert (did["members_recomputed"], did["secondary_calls"], did["singletons_scored"]) == (6, 2, 1)
+    # the three changed clusters went through one score_and_pick call
+    assert did["score_calls"] == 1
     assert did["new_edges"] >= 2 and did["tiles"] == 1
     # sketch shard, edge shard, state: three files and no part at this size
     assert (did["files_published"], did["parts_written"]) == (3, 0)

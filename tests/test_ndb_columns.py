@@ -106,7 +106,7 @@ def test_the_assembled_ndb_is_the_concat_of_a_frame_a_cluster(tmp_path, case, te
 @pytest.mark.parametrize("m", [2, 3, 40])
 @pytest.mark.parametrize("masked", [False, True])
 def test_directional_ndb_still_gives_the_frame(m, masked):
-    """tertiary.py and index/update.py keep receiving a frame, the parent's."""
+    """tertiary.py keeps receiving a frame, the parent's."""
     ani, cov = parent.planted_matrices(m, seed=m)
     mask = (np.arange(m)[:, None] + np.arange(m)[None, :]) % 2 == 1 if masked else None
     got = pairs.directional_ndb(_names(5, m), ani, cov, 5, pair_mask=mask)
@@ -115,9 +115,10 @@ def test_directional_ndb_still_gives_the_frame(m, masked):
     assert len(got) == (int(mask.sum()) if masked else m * (m - 1))
 
 
-def test_secondary_for_cluster_still_gives_the_frame(sketches, bdb):
-    """The index's entry point: (frame, labels, linkage), the frame the
-    parent built of the engine's matrices."""
+def test_secondary_for_cluster_gives_the_frames_columns(sketches, bdb):
+    """The stage's and the index's entry point: (columns, labels, linkage),
+    their `.frame()` the frame the parent built of the engine's matrices
+    (the index scores its changed clusters from the columns of all of them)."""
     from drep_tpu.cluster import controller, dispatch
 
     kw = controller._fill_defaults({})
@@ -125,9 +126,9 @@ def test_secondary_for_cluster_still_gives_the_frame(sketches, bdb):
     ndb, labels, link = controller.secondary_for_cluster(sketches, bdb, indices, 3, kw)
     ani, cov = dispatch.get_secondary(kw["S_algorithm"])(
         sketches, indices, bdb=bdb, processes=kw["processes"], mesh_shape=kw["mesh_shape"])
-    assert isinstance(ndb, pd.DataFrame)
+    assert isinstance(ndb, pairs.NdbColumns) and not hasattr(controller, "_secondary_for_cluster")
     pd.testing.assert_frame_equal(
-        ndb, parent.directional_frame([sketches.names[i] for i in indices], ani, cov, 3))
+        ndb.frame(), parent.directional_frame([sketches.names[i] for i in indices], ani, cov, 3))
     assert len(labels) == 3 and link.shape[1] == 4
 
 

@@ -160,7 +160,7 @@ def test_pipeline_resumes_secondary(tmp_path, genome_paths, monkeypatch):
     import drep_tpu.cluster.controller as ctl
     from drep_tpu.cluster import dispatch
 
-    monkeypatch.setattr(ctl, "_secondary_for_cluster", boom)
+    monkeypatch.setattr(ctl, "secondary_for_cluster", boom)
     # the small-cluster batched path must not recompute either
     monkeypatch.setitem(dispatch.SECONDARY_BATCHED, "jax_ani", boom)
     cdb = compare_wrapper(wd_loc, genome_paths, skip_plots=True)
