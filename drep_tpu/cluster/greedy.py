@@ -176,7 +176,10 @@ def greedy_secondary_cluster(
     block's and every new representative's repack and pad, the
     representatives' shipment), `secondary/greedy_wait` (one a block: its
     tiles against the representatives, its self comparison, the readbacks;
-    `devices=` says how many served) and `secondary/greedy_assign`. On the
+    `devices=` says how many served), `secondary/greedy_assign`, and since
+    ISSUE 52 `secondary/greedy_pad` (a block's rows copied into a padded
+    block on the host) and `secondary/greedy_extent` (the cluster's
+    vocabulary extent, computed for the counter's entry alone). On the
     matmul route every put of chunk tensors is a `secondary/greedy_put`
     (`bytes=`, `devices=`) inside the span it belongs to: a block's chunks
     inside `greedy_wait` (on a mesh once a representative tile and twice
@@ -273,7 +276,8 @@ def greedy_secondary_cluster(
     for b0 in range(0, m, block):
         rows = list(range(b0, min(b0 + block, m)))
         nb = len(rows)
-        b_ids, b_counts = _pad_pack(ids, counts, rows, block)
+        with counters.span("secondary/greedy_pad", rows=nb, pad_to=block):
+            b_ids, b_counts = _pad_pack(ids, counts, rows, block)
         rep_pad = max(-(-len(reps) // rep_tile) * rep_tile, rep_tile)
         booked["blocks"] += 1
         booked["rep_rows_shipped"] += rep_pad
@@ -446,8 +450,11 @@ def greedy_secondary_cluster(
         for t in range(m):
             labels[order[t]] = labels_ordered[t]
         ndb = _ndb_from_rows(ndb_rows, pc, packed.names)
+    # the record's own cost: a mask and a copy of every real id for one number
+    with counters.span("secondary/greedy_extent", rows=m):
+        extent = vocab_extent(ids)
     counters.add_greedy_call(
-        rows=m, block_rows=block, reps=len(reps), extent=vocab_extent(ids),
+        rows=m, block_rows=block, reps=len(reps), extent=extent,
         hashes=int(counts.sum()), compared_pairs=len(ndb), mesh_devices=n_dev,
         **shape, **booked,
     )

@@ -308,10 +308,10 @@ def classify_batch(
         fast = rect_edges_device(resident, queries, n_old)
         if fast is not None:
             ii, jj, dd = fast
+    from drep_tpu.utils.profiling import counters
+
     if ii is None:
         # in-memory rectangular compare: checkpoint_dir None => no writes
-        from drep_tpu.utils.profiling import counters
-
         with counters.span("index/rect_compare", genomes=scratch.n, min_col=n_old):
             # drep-lint: allow[reader-purity] — ckpt_dir=None gates the streaming engine storeless: no shard publishes, no heartbeat notes, no meta stamps (byte-for-byte pinned by test_index/test_serve digest assertions)
             ii, jj, dd, _pairs = _rect_edges(scratch, n_old, None, prune_cfg=prune_cfg)
@@ -320,8 +320,9 @@ def classify_batch(
     # per-partition compares, and identical ordering pins identical
     # tie-breaks (nearest-neighbor argmin, linkage merge order) so the
     # two paths' verdicts can be compared byte-for-byte
-    order = np.lexsort((jj, ii))
-    ii, jj, dd = ii[order], jj[order], dd[order]
+    with counters.span("index/rect_sort", edges=len(ii)):
+        order = np.lexsort((jj, ii))
+        ii, jj, dd = ii[order], jj[order], dd[order]
     if joint:
         scratch.edges = (
             np.concatenate([scratch.edges[0], ii]),

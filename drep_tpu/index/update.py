@@ -94,10 +94,13 @@ def _rect_edges(
     to the unpruned compare's."""
     from drep_tpu.ops.minhash import pack_sketches
     from drep_tpu.parallel.streaming import streaming_mash_edges
+    from drep_tpu.utils.profiling import counters
 
     p = idx.params
     _, keep = _retention(p)
-    packed = pack_sketches(idx.bottom, idx.names, int(p["sketch_size"]))
+    # the union's pack: every genome's hashes ranked again, whatever the batch
+    with counters.span("index/rect_pack", genomes=idx.n):
+        packed = pack_sketches(idx.bottom, idx.names, int(p["sketch_size"]))
     prune = None
     if prune_cfg and prune_cfg.get("primary_prune", "off") == "lsh":
         from drep_tpu.ops.lsh import build_candidates
@@ -138,8 +141,10 @@ def rect_compare(
         ii, jj, dd, pairs = _rect_edges(idx, n_old, checkpoint_dir, prune_cfg=prune_cfg)
     counters.stages["index_rect_compare"].pairs += pairs
     counters.add_index(pairs_compared=pairs, tiles=tiles_done() - tiles_before, new_edges=len(ii))
-    order = np.lexsort((jj, ii))
-    return ii[order], jj[order], dd[order], pairs
+    with counters.span("index/rect_sort", edges=len(ii)):
+        order = np.lexsort((jj, ii))
+        ii, jj, dd = ii[order], jj[order], dd[order]
+    return ii, jj, dd, pairs
 
 
 def _primary_partition(idx: LoadedIndex, n_old: int) -> tuple[np.ndarray, list[list[int]], int]:

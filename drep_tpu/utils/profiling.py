@@ -10,7 +10,8 @@ written to ``<wd>/log/perf_counters.json`` at the end of every run.
 ``Counters.span(name)`` is THE front door for a span (ISSUE 24): one
 context manager, three sinks. It always accumulates into the record's
 ``phases`` section (seconds, self seconds = duration less what child spans
-cover, calls, per thread); when ``jax`` is already imported it enters
+cover, calls, per thread, and what the host spent meanwhile: `Host accounting`
+below); when ``jax`` is already imported it enters
 ``jax.profiler.TraceAnnotation("drep:<name>")``, so inside a profiler session
 the span lands on ``/host:CPU`` on the profiler's own clock, beside the device
 trace; and under ``--events on`` it writes the JSONL ``B``/``E`` lines
@@ -19,6 +20,48 @@ keyword arguments.
 
 ``trace(dir)`` wraps a job in ``jax.profiler.trace`` with the options the
 benchmark harness uses (``--profile`` on the CLI).
+
+Host accounting (ISSUE 52). A span's seconds say how long; what the host was
+doing is read at the span's two boundaries (:func:`_read_host`) and booked
+with the arithmetic that gives ``self_seconds``, so the main thread's
+``self_*`` values over all bare-named phases add up to the ``job`` span's own
+deltas. Beside ``seconds`` / ``self_seconds`` / ``calls`` / ``thread`` an
+entry of ``phases`` holds, as plain numbers:
+
+- ``cpu_s``, ``self_cpu_s``: user + kernel seconds of ALL threads of the
+  process (``RUSAGE_SELF``). Over the span's seconds: the cores kept busy;
+  above 1 the worker threads scaled, far under 1 the process waited.
+- ``sys_s``, ``self_sys_s``: the kernel's part of it (page faults, file
+  creation and rename, ``mmap``). The kernel splits user from kernel time by
+  tick samples: right over a phase's sum, coarse for one short span.
+- ``self_minor_faults``, ``self_major_faults``: pages first touched (times
+  ``resource.getpagesize()`` = bytes) and pages read back from disk, process-wide.
+- ``self_thread_cpu_s``: the opening thread's own CPU (``RUSAGE_THREAD``);
+  ``self_seconds`` less it is what the thread spent off its CPU: a device
+  wait, a file, worker threads, the GIL, or descheduled.
+- ``self_invol_switches``, ``self_vol_switches``: the opening thread was
+  preempted / went to sleep itself. A sandboxed kernel keeps neither these
+  nor the faults (the chip host's reads the faults as 0 for ever and the
+  switches nearly so): a job with no fault has no source for them, not a
+  reading of zero.
+- ``gc_s``, ``gc_collections``: seconds inside the cyclic collector and its
+  runs (``gc.callbacks``), booked to the innermost span open on the
+  collecting thread: self values by construction.
+
+The process-wide values are the process's, whoever opened the span: a span
+on another thread sees the same CPU as the main thread's span open beside it,
+so a ``<name>@other`` phase carries the thread's fields and the collector's
+alone. The ingest pool's workers are processes and are in none of it (they
+carry their own ``busy_seconds``; ``RUSAGE_CHILDREN`` moves only when a
+child is reaped).
+
+A boundary reuses its thread's last read while that is younger than
+``HOST_READ_EVERY_S``: a read is two system calls, and a job crosses 10^4
+boundaries. What accrued since the last real read is booked to the span
+innermost at the next one, so phases whose spans are all shorter than the
+constant are right in their sum over the loop that alternates them, and
+share it by their seconds, not span by span. The record's writer reads
+afresh, so the open spans count as far as they have come.
 
 What a process pays before its first warm job (ISSUE 36) is in two sections
 of the same record. ``compile``: the programs this job traced, lowered,
@@ -34,8 +77,10 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import gc
 import json
 import os
+import resource
 import sys
 import threading
 import time
@@ -189,11 +234,97 @@ class _Stage:
     tiles_skipped: int = 0
 
 
+# what one read of the host holds (:func:`_read_host`), in this order: the
+# first four are the whole process's, the rest the reading thread's
+_HOST_FIELDS = ("cpu_s", "sys_s", "minor_faults", "major_faults",
+                "thread_cpu_s", "invol_switches", "vol_switches")
+_NO_HOST = (0,) * len(_HOST_FIELDS)
+# the args a span's E line may carry of it, whole deltas, beside the span's own
+HOST_ARGS = (*_HOST_FIELDS, "gc_s", "gc_collections")
+# a span boundary reuses its thread's last read of the host while that is
+# younger than this (module docstring). On a chip host one read is two system
+# calls of 6 us each (12.5-14 us measured, ISSUE 52; the dense job crosses
+# 9,146 boundaries in 8.4 s), so at most 100 reads a second stay under 0.15%
+# of any job; reading at every boundary was 1.4% of that one
+HOST_READ_EVERY_S = 0.01
+
+
+class _ThreadHost:
+    """One thread's side of the host accounting, shared by every Counters:
+    its last read of the host (`read`, taken at `read_at` on perf_counter's
+    clock), its innermost open span (`span`), and where a collection under
+    way began (`gc_t0`)."""
+
+    __slots__ = ("read", "read_at", "span", "gc_t0")
+
+    def __init__(self) -> None:
+        self.read: tuple = _NO_HOST
+        self.read_at = float("-inf")
+        self.span: _Span | None = None
+        self.gc_t0: float | None = None
+
+    def take(self, now: float) -> tuple:
+        """A new read for a boundary at `now`. A boundary reuses `read`
+        while `now - read_at` is under HOST_READ_EVERY_S: the same object,
+        so a span that saw no new read books nothing."""
+        self.read_at = now
+        self.read = read = _read_host()
+        return read
+
+
+_THREADS = threading.local()
+
+
+def _thread_host() -> _ThreadHost:
+    th = getattr(_THREADS, "host", None)
+    if th is None:
+        th = _THREADS.host = _ThreadHost()
+    return th
+
+
 @dataclass
 class _Phase:
     seconds: float = 0.0
     self_seconds: float = 0.0
     calls: int = 0
+    # _HOST_FIELDS over the phase's spans: their whole deltas, and what no
+    # child span covers
+    host: list = field(default_factory=lambda: list(_NO_HOST))
+    self_host: list = field(default_factory=lambda: list(_NO_HOST))
+    gc_s: float = 0.0
+    gc_collections: int = 0
+
+
+def _plus(a, b) -> list:
+    return [x + y for x, y in zip(a, b)]
+
+
+def _minus(a, b) -> list:
+    return [x - y for x, y in zip(a, b)]
+
+
+def _read_host() -> tuple:
+    """_HOST_FIELDS now. Off the main thread the process's part reads zero:
+    it is booked once, by the main thread's spans."""
+    t = resource.getrusage(resource.RUSAGE_THREAD)
+    thread = (t.ru_utime + t.ru_stime, t.ru_nivcsw, t.ru_nvcsw)
+    if not _on_main_thread():
+        return (0.0, 0.0, 0, 0, *thread)
+    p = resource.getrusage(resource.RUSAGE_SELF)
+    return (p.ru_utime + p.ru_stime, p.ru_stime, p.ru_minflt, p.ru_majflt, *thread)
+
+
+def _on_gc(phase: str, _info: dict) -> None:
+    """`gc.callbacks`: a collection's seconds go to the innermost span open
+    on the thread that collects."""
+    th = _thread_host()
+    if phase == "start":
+        th.gc_t0 = time.perf_counter()
+        return
+    span, t0 = th.span, th.gc_t0
+    if span is not None and t0 is not None:
+        span._gc_s += time.perf_counter() - t0
+        span._gc_n += 1
 
 
 def _book_pack(section: dict[str, int], native: bool, threads: int, **counted: int) -> None:
@@ -211,10 +342,12 @@ def _on_main_thread() -> bool:
 
 class _Span:
     """One open span of :meth:`Counters.span`. Its frame sits on the
-    opening thread's stack; on exit its duration is booked to its phase and
-    credited to the parent frame, whose self time is what no child covers."""
+    opening thread's stack; on exit its duration and what the host spent
+    meanwhile are booked to its phase and credited to the parent frame,
+    whose self values are what no child covers."""
 
-    __slots__ = ("_counters", "name", "_calls", "_args", "_sinks", "_t0", "_child")
+    __slots__ = ("_counters", "name", "_calls", "_args", "_sinks", "_t0", "_child",
+                 "_stack", "_thread", "_host0", "_host_child", "_gc_s", "_gc_n", "_outer")
 
     def __init__(self, counters: "Counters", name: str, calls: int, args: dict) -> None:
         self._counters = counters
@@ -230,8 +363,14 @@ class _Span:
         if prof is not None:
             self._sinks.append(prof.TraceAnnotation("drep:" + self.name, **self._args))
         self._child = 0.0
-        self._counters._stack().append(self)
-        self._t0 = time.perf_counter()
+        self._host_child: list | None = None
+        self._gc_s, self._gc_n = 0.0, 0
+        self._stack, self._thread = stack, th = self._counters._frames()
+        self._outer = th.span
+        th.span = self
+        stack.append(self)
+        self._t0 = now = time.perf_counter()
+        self._host0 = th.read if now - th.read_at < HOST_READ_EVERY_S else th.take(now)
         for sink in self._sinks:
             sink.__enter__()
         return self
@@ -243,15 +382,35 @@ class _Span:
         self._args.update(args)
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        th = self._thread
+        now = time.perf_counter()
+        host1 = th.read if now - th.read_at < HOST_READ_EVERY_S else th.take(now)
+        # no new read since the span opened: nothing to book, for it or inside it
+        host = None if host1 is self._host0 else _minus(host1, self._host0)
+        if (host is not None or self._gc_n) and telemetry.enabled():
+            self.note(**self._host_args(host))
         for sink in reversed(self._sinks):
             sink.__exit__(exc_type, exc, tb)
         dur = time.perf_counter() - self._t0
-        stack = self._counters._stack()
+        th.span = self._outer
+        stack = self._stack
         stack.pop()
         if stack:
-            stack[-1]._child += dur
-        self._counters._book(self.name, dur, dur - self._child, self._calls)
+            parent = stack[-1]
+            parent._child += dur
+            if host is not None:
+                covered = parent._host_child
+                parent._host_child = host if covered is None else _plus(covered, host)
+        self._counters._book(self.name, dur, dur - self._child, self._calls,
+                             host, self._host_child, self._gc_s, self._gc_n)
         return False
+
+    def _host_args(self, host: list | None) -> dict:
+        """The span's whole deltas as its E line carries them: what moved."""
+        args = {name: round(v, 6) for name, v in zip(_HOST_FIELDS, host or ()) if v}
+        if self._gc_n:
+            args.update(gc_s=round(self._gc_s, 6), gc_collections=self._gc_n)
+        return args
 
 
 class Histogram:
@@ -479,18 +638,33 @@ class Counters:
         `calls` books one span round a loop as that many units of work."""
         return _Span(self, name, calls, args)
 
-    def _stack(self) -> list:
-        stack = getattr(self._open, "stack", None)
-        if stack is None:
-            stack = self._open.stack = []
-        return stack
+    def _frames(self) -> tuple[list, _ThreadHost]:
+        """The calling thread's open spans of these counters, outermost
+        first, and its side of the host accounting."""
+        frames = getattr(self._open, "frames", None)
+        if frames is None:
+            frames = self._open.frames = ([], _thread_host())
+        return frames
 
-    def _book(self, name: str, seconds: float, self_seconds: float, calls: int) -> None:
+    def _stack(self) -> list:
+        return self._frames()[0]
+
+    def _book(self, name: str, seconds: float, self_seconds: float, calls: int,
+              host: list | None, host_child: list | None, gc_s: float, gc_collections: int) -> None:
+        """One closed span into its phase. `host`: its whole _HOST_FIELDS
+        deltas (None: no read of the host fell inside it), `host_child`
+        what its child spans covered of them."""
         with self._lock:
             ph = self.phases.setdefault((name, _on_main_thread()), _Phase())
             ph.seconds += seconds
             ph.self_seconds += self_seconds
             ph.calls += calls
+            if host is not None:
+                ph.host = _plus(ph.host, host)
+                ph.self_host = _plus(ph.self_host, host if host_child is None else _minus(host, host_child))
+            if gc_collections:
+                ph.gc_s += gc_s
+                ph.gc_collections += gc_collections
 
     @contextlib.contextmanager
     def stage(self, name: str, pairs: int = 0) -> Iterator[None]:
@@ -1059,31 +1233,47 @@ class Counters:
         return out
 
     def _phases_report(self) -> dict[str, dict[str, Any]]:
-        """``{name: {"seconds", "self_seconds", "calls", "thread"}}``. A
-        span of another thread than the main one is kept apart under
-        ``<name>@other``. The calling thread's OPEN spans are counted as
-        far as they have come: the record is written inside ``job``."""
+        """``{name: {"seconds", "self_seconds", "calls", "thread", ...}}``
+        with the host's fields of the module docstring. A span of another
+        thread than the main one is kept apart under ``<name>@other`` and
+        carries the thread's fields and the collector's alone. The calling
+        thread's OPEN spans are counted as far as they have come, on a fresh
+        read of the host: the record is written inside ``job``."""
         now = time.perf_counter()
+        host_now = _thread_host().take(now)
         with self._lock:
-            acc = {k: [p.seconds, p.self_seconds, p.calls] for k, p in self.phases.items()}
+            acc = {k: _Phase(p.seconds, p.self_seconds, p.calls, list(p.host), list(p.self_host),
+                             p.gc_s, p.gc_collections) for k, p in self.phases.items()}
         main = _on_main_thread()
-        inner = 0.0  # duration so far of the open span one level in
+        inner, host_inner = 0.0, _NO_HOST  # so far, of the open span one level in
         for sp in reversed(self._stack()):
             dur = now - sp._t0
-            a = acc.setdefault((sp.name, main), [0.0, 0.0, 0])
-            a[0] += dur
-            a[1] += dur - sp._child - inner
-            a[2] += sp._calls
-            inner = dur
-        return {
-            name if on_main else name + "@other": {
-                "seconds": round(sec, 4),
-                "self_seconds": round(self_sec, 4),
-                "calls": calls,
-                "thread": "main" if on_main else "other",
-            }
-            for (name, on_main), (sec, self_sec, calls) in sorted(acc.items())
-        }
+            host = _minus(host_now, sp._host0)
+            a = acc.setdefault((sp.name, main), _Phase())
+            a.seconds += dur
+            a.self_seconds += dur - sp._child - inner
+            a.calls += sp._calls
+            a.host = _plus(a.host, host)
+            a.self_host = _plus(a.self_host, _minus(_minus(host, sp._host_child or _NO_HOST), host_inner))
+            a.gc_s += sp._gc_s
+            a.gc_collections += sp._gc_n
+            inner, host_inner = dur, host
+        out = {}
+        for (name, on_main), p in sorted(acc.items()):
+            whole, own = dict(zip(_HOST_FIELDS, p.host)), dict(zip(_HOST_FIELDS, p.self_host))
+            ent = {"seconds": round(p.seconds, 4), "self_seconds": round(p.self_seconds, 4),
+                   "calls": p.calls, "thread": "main" if on_main else "other"}
+            if on_main:
+                ent.update(cpu_s=round(whole["cpu_s"], 4), self_cpu_s=round(own["cpu_s"], 4),
+                           sys_s=round(whole["sys_s"], 4), self_sys_s=round(own["sys_s"], 4),
+                           self_minor_faults=int(own["minor_faults"]),
+                           self_major_faults=int(own["major_faults"]))
+            ent.update(self_thread_cpu_s=round(own["thread_cpu_s"], 4),
+                       self_invol_switches=int(own["invol_switches"]),
+                       self_vol_switches=int(own["vol_switches"]),
+                       gc_s=round(p.gc_s, 4), gc_collections=p.gc_collections)
+            out[name if on_main else name + "@other"] = ent
+        return out
 
     def write(self, log_dir: str, device: bool = True) -> str:
         # atomic (utils/durableio.py): a SIGKILL mid-write must not leave
@@ -1129,6 +1319,7 @@ class Counters:
 
 
 counters = Counters()  # the process-global instance used by the pipeline
+gc.callbacks.append(_on_gc)
 
 _listening = False
 

@@ -69,6 +69,19 @@ def test_the_index_spans_partition_the_updates_job(verbs):
     assert ph["index/secondary"]["calls"] == 2 and ph["index/score"]["calls"] == 1
 
 
+@pytest.mark.parametrize("verb", ["build", "update"])
+def test_the_rectangles_pack_and_its_edges_sort_have_names_of_their_own(verbs, verb):
+    """ISSUE 52: the union's `pack_sketches` was self time of
+    `index/rect_compare`, the edges' lexsort of the verb's `job`."""
+    ph = verbs["records"][verb]["phases"]
+    rect, pack, order = ph["index/rect_compare"], ph["index/rect_pack"], ph["index/rect_sort"]
+    assert pack["calls"] == order["calls"] == rect["calls"] == 1
+    assert pack["thread"] == order["thread"] == "main"
+    # the pack lies inside the rectangle's span: what the span keeps for itself excludes it
+    assert rect["self_seconds"] <= rect["seconds"] - pack["seconds"] + 1e-3
+    assert "cpu_s" in pack and "self_minor_faults" in order  # every entry carries the host's fields
+
+
 def test_index_build_opens_the_same_spans_where_the_same_functions_run(verbs):
     ph = verbs["records"]["build"]["phases"]
     assert (UPDATE_SPANS - {"index/load"}) | PUBLISH_SPANS <= set(ph) and "index/load" not in ph

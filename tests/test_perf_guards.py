@@ -297,6 +297,40 @@ def test_events_overhead_is_counted_and_zero_files_when_off(rng, tmp_path, monke
     assert len(lines) <= 12 * stripes + 16 < tiles, by_kind
 
 
+def test_host_reads_are_counted_a_span_boundary_on_the_warm_tile_pass(rng, monkeypatch):
+    """The host-accounting guard (ISSUE 52), by count: spans are booked in
+    every job, so what a boundary may cost is pinned on the 528-tile warm
+    pass. A boundary makes at most ONE read of the host (`_read_host`: two
+    `getrusage` calls), never one a tile, and reuses a read younger than
+    `HOST_READ_EVERY_S`: the reads of a pass stay under its boundaries and
+    under the rate the constant allows."""
+    import time
+
+    from drep_tpu.parallel.streaming import streaming_mash_edges
+    from drep_tpu.utils import faults, profiling
+    from drep_tpu.utils.profiling import counters
+
+    packed = _tile_pass_inputs(rng)
+    stripes, tiles = 32, 528
+    faults.configure(None)
+    streaming_mash_edges(packed, k=21, cutoff=0.2, block=8)  # warm the jits
+    reads: list[int] = []
+    read_host = profiling._read_host
+    monkeypatch.setattr(profiling, "_read_host", lambda: (reads.append(1), read_host())[1])
+    counters.reset()
+    t0 = time.perf_counter()
+    with counters.span("job"):
+        streaming_mash_edges(packed, k=21, cutoff=0.2, block=8)
+    elapsed = time.perf_counter() - t0
+    boundaries = 2 * sum(p.calls for p in counters.phases.values())
+    assert 2 * (1 + stripes) <= boundaries <= 2 * (14 * stripes + 16) < tiles * 2, boundaries
+    assert 1 <= len(reads) <= boundaries, (len(reads), boundaries)
+    assert len(reads) <= elapsed / profiling.HOST_READ_EVERY_S + 2, (len(reads), elapsed)
+    job = counters.report(device=False)["phases"]["job"]
+    assert 0 < job["cpu_s"] and job["sys_s"] <= job["cpu_s"]
+    counters.reset()
+
+
 def test_the_compile_instant_is_one_a_program_and_none_a_call(rng, tmp_path):
     """ISSUE 36: under --events on a program built is one `compile` instant;
     the calls of a program already built write none."""

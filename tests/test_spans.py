@@ -12,6 +12,7 @@ import glob
 import importlib.util
 import json
 import os
+import resource
 import subprocess
 import sys
 import threading
@@ -20,7 +21,7 @@ import time
 import numpy as np
 import pytest
 
-from drep_tpu.utils import telemetry
+from drep_tpu.utils import profiling, telemetry
 from drep_tpu.utils.profiling import Counters
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -140,6 +141,167 @@ def test_stage_keeps_its_signature_and_is_a_phase():
     assert set(rep["phases"]) == {"stage:secondary_compare", "secondary/wait"}
 
 
+# --- what the host spent inside a span (ISSUE 52) ---------------------------
+
+
+# a sandboxed kernel (the chip host's) keeps neither page faults nor context switches: a process
+# that has come this far has faulted and slept where they are kept at all
+_NOW = resource.getrusage(resource.RUSAGE_SELF)
+COUNTS_FAULTS = _NOW.ru_minflt > 0
+COUNTS_SWITCHES = _NOW.ru_nvcsw + _NOW.ru_nivcsw > 0
+
+
+def _burn(seconds: float) -> float:
+    """CPU with the GIL released (hashlib drops it for a large buffer), for
+    about `seconds`; returns the calling thread's own CPU seconds of it."""
+    import hashlib
+
+    block = bytes(1 << 20)
+    t0, cpu0 = time.perf_counter(), time.thread_time()
+    while time.perf_counter() - t0 < seconds:
+        hashlib.sha1(block).digest()
+    return time.thread_time() - cpu0
+
+
+def _touch_fresh(nbytes: int):
+    """`nbytes` of anonymous memory touched for the first time, a 4 KiB
+    page a fault whatever the host does with huge pages."""
+    import mmap
+
+    m = mmap.mmap(-1, nbytes)
+    m.madvise(mmap.MADV_NOHUGEPAGE)
+    np.frombuffer(m, np.uint8)[:: resource.getpagesize()] = 1
+    return m
+
+
+def test_a_span_round_a_thread_pool_reads_the_cores_it_kept_busy():
+    from concurrent.futures import ThreadPoolExecutor
+
+    c = Counters()
+    with c.span("job"):
+        with c.span("secondary/pack", workers=4), ThreadPoolExecutor(4) as pool:
+            burned = sum(pool.map(_burn, [0.3] * 4))  # the workers open no spans
+    ph = c.report(device=False)["phases"]["secondary/pack"]
+    # the process's CPU inside the span is the workers' own, with no span in a worker: over
+    # the span's seconds it is the scaling they reached (the sandbox lends its cores in
+    # bursts: four threads get one core or four, and the span says which)
+    assert burned > 0.25 and ph["seconds"] > 0.25
+    assert ph["cpu_s"] == pytest.approx(burned + ph["self_thread_cpu_s"], rel=0.1, abs=0.03)
+    # the opening thread waited: off its CPU, asleep by its own will
+    assert ph["cpu_s"] > 4 * ph["self_thread_cpu_s"]
+    assert ph["self_seconds"] - ph["self_thread_cpu_s"] > 0.2
+    assert ph["self_vol_switches"] >= 1 or not COUNTS_SWITCHES
+
+
+@pytest.mark.skipif(not COUNTS_FAULTS, reason="this kernel counts no page faults")
+def test_a_child_span_that_touches_fresh_memory_books_the_faults_and_its_parent_does_not():
+    c = Counters()
+    with c.span("job"):
+        with c.span("stage:ingest_or_cache"):
+            time.sleep(0.005)  # the child opens on a read of its own
+            with c.span("load"):
+                kept = _touch_fresh(64 << 20)
+            time.sleep(0.005)
+    ph = c.report(device=False)["phases"]
+    assert ph["load"]["self_minor_faults"] >= 16384
+    assert ph["stage:ingest_or_cache"]["self_minor_faults"] < ph["load"]["self_minor_faults"] / 10
+    assert ph["load"]["sys_s"] > 0 and ph["load"]["self_major_faults"] == 0
+    kept.close()
+
+
+@pytest.mark.parametrize("at", range(len(profiling._HOST_FIELDS)), ids=profiling._HOST_FIELDS)
+def test_the_main_threads_self_values_add_up_to_the_jobs_own(at):
+    c = Counters()
+    with c.span("job"):
+        with c.span("a"):
+            _burn(0.01)
+            with c.span("b"):
+                kept = _touch_fresh(4 << 20)
+            for _ in range(300):  # shorter than a read lasts: right in the loop's sum
+                with c.span("tiny"):
+                    pass
+        with c.span("stage:cluster"):
+            time.sleep(0.004)
+            with c.span("writing"):  # the record is written from inside `job`
+                inside = c._phases_report()
+    kept.close()
+    # the record's fields by _HOST_FIELDS' order; `job`'s whole where the record keeps it
+    own = ("self_cpu_s", "self_sys_s", "self_minor_faults", "self_major_faults",
+           "self_thread_cpu_s", "self_invol_switches", "self_vol_switches")[at]
+    whole = {"self_cpu_s": "cpu_s", "self_sys_s": "sys_s"}.get(own)
+    for ph in (inside, c._phases_report()):
+        assert set(ph) == {"job", "a", "b", "tiny", "stage:cluster", "writing"}
+        if whole:
+            assert sum(p[own] for p in ph.values()) == pytest.approx(ph["job"][whole], abs=6e-4)
+    # closed, each phase holds its spans' whole deltas: the parts add up to the root's
+    phases = c.phases
+    assert sum(p.self_host[at] for p in phases.values()) == pytest.approx(
+        phases[("job", True)].host[at], abs=1e-9)
+    assert all(p.self_host[at] >= -1e-9 for p in phases.values())
+    assert _main_self_sum(inside) == pytest.approx(inside["job"]["seconds"], rel=0.01)
+
+
+def test_gc_collect_inside_a_span_is_booked_there_and_not_in_its_sibling():
+    import gc
+
+    c = Counters()
+    with c.span("job"):
+        with c.span("choose/score"):
+            cycles = [[] for _ in range(20000)]
+            for x in cycles:
+                x.append(x)
+            del cycles, x
+            gc.collect()
+        gc.disable()  # none may start by itself inside the sibling
+        try:
+            with c.span("choose/copy"):
+                time.sleep(0.001)
+        finally:
+            gc.enable()
+    ph = c.report(device=False)["phases"]
+    assert ph["choose/score"]["gc_collections"] >= 1 and ph["choose/score"]["gc_s"] > 0
+    assert ph["choose/score"]["gc_s"] <= ph["choose/score"]["seconds"]
+    assert ph["choose/copy"]["gc_collections"] == 0 and ph["choose/copy"]["gc_s"] == 0
+    assert ph["job"]["gc_collections"] == 0  # self by construction: the child's is not the parent's
+
+
+def test_a_span_on_another_thread_carries_the_threads_fields_alone():
+    c = Counters()
+
+    def work():
+        with c.span("primary/wait"):
+            _burn(0.05)
+
+    with c.span("job"):
+        t = threading.Thread(target=work)
+        t.start()
+        t.join(timeout=30)
+        assert not t.is_alive()
+    ph = c.report(device=False)["phases"]
+    other = ph["primary/wait@other"]
+    assert not {"cpu_s", "self_cpu_s", "sys_s", "self_sys_s", "self_minor_faults"} & set(other)
+    assert other["self_thread_cpu_s"] > 0.02 and "gc_s" in other and "self_invol_switches" in other
+    # the process's CPU of that time is booked once, where the main thread was
+    assert ph["job"]["cpu_s"] >= other["self_thread_cpu_s"] * 0.9
+    assert ph["job"]["self_thread_cpu_s"] < other["self_thread_cpu_s"]
+
+
+def test_a_boundary_reuses_a_read_younger_than_the_constant(monkeypatch):
+    reads = []
+    read_host = profiling._read_host
+    monkeypatch.setattr(profiling, "_read_host", lambda: (reads.append(1), read_host())[1])
+    c = Counters()
+    t0 = time.perf_counter()
+    with c.span("job"):
+        for _ in range(2000):
+            with c.span("tiny"):
+                pass
+    elapsed = time.perf_counter() - t0
+    assert 1 <= len(reads) <= elapsed / profiling.HOST_READ_EVERY_S + 2 < 2 * 2001
+    c.report(device=False)  # the record's writer reads afresh
+    assert len(reads) <= elapsed / profiling.HOST_READ_EVERY_S + 3
+
+
 def test_secondary_calls_group_by_shape_and_stay_bounded():
     from drep_tpu.utils import profiling
 
@@ -237,6 +399,7 @@ def test_toy_compare_record_holds_every_span_its_path_reaches(toy_job):
     reached = {
         "job", "tables_io", "mdb_build", "stage:cluster", "stage:ingest_or_cache",
         "stage:primary_compare", "primary/pack", "primary/wait", "primary/linkage",
+        "primary/similarity",  # ISSUE 52: `1 - dist`, a second N x N matrix first touched
         "stage:secondary", "stage:secondary_compare", "stage:secondary_postprocess",
         "secondary/pack", "secondary/wait", "secondary/post", "secondary/checkpoint",
         "stage:assembly_io", "stage:evaluate",
@@ -245,6 +408,7 @@ def test_toy_compare_record_holds_every_span_its_path_reaches(toy_job):
     assert all(p["thread"] == "main" for p in ph.values())
     # acceptance: the main thread's self seconds add up to the job within 1%
     assert _main_self_sum(ph) == pytest.approx(ph["job"]["seconds"], rel=0.01)
+    assert ph["primary/similarity"]["calls"] == 1
     # the stage totals read as before, and the stage spans agree with them
     assert set(rec["stages"]) == {"ingest_or_cache", "primary_compare", "secondary_compare",
                                   "secondary_postprocess", "assembly_io"}
@@ -256,6 +420,24 @@ def test_toy_compare_record_holds_every_span_its_path_reaches(toy_job):
     assert sum(c["useful_pairs"] for c in calls) == rec["stages"]["secondary_compare"]["pairs"] == 4
     assert [(c["calls"], c["clusters"], c["rows"], c["rows_pad"]) for c in calls] == [(1, 2, 5, 64)]
     assert all(c["rows"] <= c["rows_pad"] and c["width"] >= 128 and c["v_pad"] > 0 for c in calls)
+
+
+@pytest.mark.parametrize("own,whole", [("self_seconds", "seconds"), ("self_cpu_s", "cpu_s"),
+                                       ("self_sys_s", "sys_s")])
+def test_every_phase_of_the_toy_jobs_record_carries_the_hosts_fields(toy_job, own, whole):
+    """ISSUE 52: written from inside `job`, the record holds the fields in
+    every entry, and the main thread's self values add up to `job`'s."""
+    ph = toy_job["record"]["phases"]
+    threads = {"self_thread_cpu_s", "self_invol_switches", "self_vol_switches", "gc_s", "gc_collections"}
+    process = {"cpu_s", "self_cpu_s", "sys_s", "self_sys_s", "self_minor_faults", "self_major_faults"}
+    for name, p in ph.items():
+        want = threads | (process if p["thread"] == "main" else set())
+        assert set(p) == {"seconds", "self_seconds", "calls", "thread"} | want, name
+        assert name.endswith("@other") == (p["thread"] == "other")
+    mains = [p for p in ph.values() if p["thread"] == "main"]
+    # each entry is rounded to 1e-4
+    assert sum(p[own] for p in mains) == pytest.approx(ph["job"][whole], abs=1e-4 * len(mains))
+    assert ph["job"]["cpu_s"] > 0 and (sum(p["self_minor_faults"] for p in mains) > 0 or not COUNTS_FAULTS)
 
 
 def test_profile_of_the_toy_job_puts_the_spans_on_the_host_plane(toy_job):
@@ -640,6 +822,10 @@ def _inside(spans, name: str, stage: str) -> list[bool]:
     ("secondary/greedy_wait", ("stage:secondary_compare",), 1),
     # a block and the cluster's rows in the engine, then the batched route's one cluster
     ("secondary/greedy_assign", ("stage:secondary_compare", "stage:secondary_postprocess"), 3),
+    # ISSUE 52, what `stage:secondary_compare` kept for itself: a block's host pad, and the
+    # cluster's vocabulary extent for the counter's entry
+    ("secondary/greedy_pad", ("stage:secondary_compare",), 1),
+    ("secondary/greedy_extent", ("stage:secondary_compare",), 1),
 ])
 def test_the_greedy_spans_nest_in_their_stages(greedy_job, name, stages, calls):
     ph, spans = greedy_job["record"]["phases"], greedy_job["spans"]

@@ -15,7 +15,7 @@ from benchmark import cells, greedy_jobs, stream4_jobs
 from drep_tpu.ops.minhash import PAD_ID, PackedSketches
 from drep_tpu.parallel import streaming
 from drep_tpu.utils import telemetry
-from drep_tpu.utils.profiling import Counters
+from drep_tpu.utils.profiling import HOST_ARGS, Counters
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TABLE = [(24, 1), (12, 2), (6, 4), (3, 8), (2, 16), (1, 128)]  # 256 genomes, 4 stripes of 64
@@ -83,7 +83,7 @@ def test_the_slots_of_four_devices_add_up(job4):
 def test_the_spans_carry_devices_bytes_and_tiles(job4):
     by_name: dict = {}
     for e in job4["ends"]:
-        by_name.setdefault(e["ev"], []).append({k: v for k, v in e["args"].items() if k != "dur"})
+        by_name.setdefault(e["ev"], []).append({k: v for k, v in e["args"].items() if k != "dur" and k not in HOST_ARGS})
     put = by_name["primary/put"]
     assert put == [{"devices": 4, "bytes": 4 * (256 * 1000 * 4 + 256 * 4)}]
     want = [{"bi": bi, "tiles": 4 - bi} for bi in range(4)]

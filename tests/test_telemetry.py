@@ -78,6 +78,47 @@ def test_events_are_valid_jsonl_with_core_keys(tmp_path):
     assert len({r["run"] for r in lines}) == 1
 
 
+@pytest.mark.parametrize("enabled", [True, False], ids=["events_on", "events_off"])
+def test_a_span_s_closing_line_carries_what_the_host_spent_inside_it(tmp_path, enabled):
+    """ISSUE 52: the span's whole deltas ride on its E line as args (the
+    path of `_Span.note`), those that moved; with events off no file exists
+    and the record holds the same numbers."""
+    import gc
+    import hashlib
+
+    from drep_tpu.utils.profiling import HOST_ARGS, Counters
+
+    telemetry.configure(log_dir=str(tmp_path), enabled=enabled, pid=0)
+    c = Counters()
+    with c.span("job"):
+        with c.span("tables_io", rows=3) as sp:
+            cpu0 = time.thread_time()
+            while time.thread_time() - cpu0 < 0.02:  # by its own CPU: the sandbox's cores come and go
+                hashlib.sha1(bytes(1 << 16)).digest()
+            gc.collect()
+            sp.note(bytes=7)
+        with c.span("quick"):
+            pass  # shorter than a read lasts: nothing moved, nothing rides
+    telemetry.close()
+    booked = c.report(device=False)["phases"]["tables_io"]
+    assert booked["cpu_s"] >= 0.015 and booked["gc_collections"] >= 1
+    if not enabled:
+        assert os.listdir(tmp_path) == [], "events off must create ZERO files"
+        return
+    recs, tail = _lines(tmp_path / "events.p0.jsonl")
+    assert tail == b""
+    ends = {r["ev"]: r["args"] for r in recs if r["ph"] == "E"}
+    begins = {r["ev"]: r.get("args") for r in recs if r["ph"] == "B"}
+    assert begins["tables_io"] == {"rows": 3}  # the B line is the entry's
+    closing = ends["tables_io"]
+    assert closing["rows"] == 3 and closing["bytes"] == 7 and closing["dur"] >= 0.02
+    assert closing["cpu_s"] == pytest.approx(booked["cpu_s"], abs=1e-3)
+    assert closing["thread_cpu_s"] >= 0.015 and closing["gc_collections"] == booked["gc_collections"]
+    assert closing["gc_s"] == pytest.approx(booked["gc_s"], abs=1e-3)
+    assert set(closing) - {"rows", "bytes", "dur"} <= set(HOST_ARGS)
+    assert not set(ends["quick"]) & set(HOST_ARGS)
+
+
 def test_run_id_constant_across_resume(tmp_path):
     telemetry.configure(log_dir=str(tmp_path), enabled=True, pid=0)
     telemetry.event("first")

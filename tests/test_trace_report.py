@@ -14,6 +14,7 @@ import hashlib
 import importlib.util
 import json
 import os
+import resource
 import socket
 import subprocess
 import sys
@@ -123,6 +124,77 @@ def test_traced_run_produces_loadable_chrome_trace_and_report(
     assert rc == 0
     with open(os.path.join(log, "trace.json")) as f:
         assert json.load(f)["traceEvents"]
+
+
+@pytest.mark.parametrize("source", ["record", "event_log"])
+def test_the_phases_table_says_what_the_host_spent_from_a_record_or_an_event_log(
+    tmp_path, trace_report, source, capsys
+):
+    """ISSUE 52, the operator's use of the new fields: one line a phase,
+    largest self seconds first, with cores, kernel seconds, memory first
+    touched, off-CPU seconds and collector seconds; from the record beside
+    the logs, or rebuilt from the E lines where a job left none."""
+    from drep_tpu.utils import telemetry
+    from drep_tpu.utils.profiling import Counters
+
+    log = str(tmp_path / "log")
+    telemetry.configure(log_dir=log, enabled=True, pid=0)
+    c = Counters()
+    with c.span("job"):
+        with c.span("stage:cluster"):
+            with c.span("secondary/pack", workers=2):
+                cpu0 = time.thread_time()
+                while time.thread_time() - cpu0 < 0.03:  # by its own CPU, whatever the cores lent
+                    hashlib.sha1(bytes(1 << 16)).digest()
+            time.sleep(0.01)
+        if source == "record":
+            c.write(log, device=False)
+    telemetry.close()
+    evs = trace_report.load_events(log)["events"]
+    rebuilt = trace_report.phases_from_events(evs)
+    assert set(rebuilt) == {"job", "stage:cluster", "secondary/pack"}
+    pack, stage = rebuilt["secondary/pack"], rebuilt["stage:cluster"]
+    assert pack["calls"] == 1 and pack["cpu_s"] >= 0.02
+    assert pack["self_cpu_s"] == pytest.approx(pack["cpu_s"])
+    # self values by nesting: the stage's own is what the pack does not cover
+    assert stage["self_seconds"] == pytest.approx(stage["seconds"] - pack["seconds"], abs=1e-6)
+    assert stage["self_cpu_s"] == pytest.approx(stage["cpu_s"] - pack["cpu_s"], abs=1e-6)
+    assert 0.005 <= stage["self_seconds"] < pack["seconds"]
+    if source == "record":
+        with open(os.path.join(log, "perf_counters.json")) as f:
+            cdoc = json.load(f)
+        rep = trace_report.text_report(evs, cdoc)
+        assert "(perf_counters.json)" in rep
+        # the record alone, by its path
+        assert trace_report.main([os.path.join(log, "perf_counters.json")]) == 0
+        alone = capsys.readouterr().out
+        assert "secondary/pack" in alone and "cores" in alone
+    else:
+        rep = trace_report.text_report(evs)
+        assert "(rebuilt from the event log)" in rep
+    table = rep[rep.index("phases, largest self seconds first"):].splitlines()
+    assert table[1].split() == ["phase", "calls", "seconds", "self", "cores", "sys", "MiB", "new",
+                                "off-CPU", "gc"]
+    rows = [ln.split() for ln in table[2:] if ln.strip()]
+    assert [r[0] for r in rows][0] == "secondary/pack"  # the largest self seconds first
+    assert {r[0] for r in rows} == {"job", "stage:cluster", "secondary/pack"}
+    cores = float(rows[0][4])
+    assert 0.0 < cores < 1.6  # one thread burning: one core at most
+
+
+def test_the_phases_table_leaves_first_touched_memory_out_where_the_kernel_counts_no_faults(trace_report):
+    """The chip host's kernel reports no page fault, ever: zero in every
+    phase is no source, and the column says so; one phase that faulted makes
+    a quiet one's zero a reading."""
+    ph = {"job": {"seconds": 2.0, "self_seconds": 0.5, "calls": 1, "cpu_s": 3.0, "self_sys_s": 0.1,
+                  "self_minor_faults": 0, "self_thread_cpu_s": 0.4, "gc_s": 0.0},
+          "load": {"seconds": 1.5, "self_seconds": 1.5, "calls": 1, "cpu_s": 2.5, "self_sys_s": 0.9,
+                   "self_minor_faults": 0, "self_thread_cpu_s": 0.5, "gc_s": 0.0}}
+    rows = [ln.split() for ln in trace_report.phases_table(ph).splitlines()[1:]]
+    assert [r[0] for r in rows] == ["load", "job"] and [r[6] for r in rows] == ["-", "-"]
+    ph["load"]["self_minor_faults"] = 512
+    rows = [ln.split() for ln in trace_report.phases_table(ph).splitlines()[1:]]
+    assert [r[6] for r in rows] == [f"{512 * resource.getpagesize() / 2**20:.1f}", "0.0"]
 
 
 def test_trace_report_surfaces_unclosed_spans_as_crash_evidence(

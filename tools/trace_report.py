@@ -17,11 +17,19 @@ written by drep_tpu/utils/telemetry.py under ``<wd>/log``) into:
   order) — cross-checked against ``perf_counters.json``'s
   ``epoch_history`` when one sits beside the logs.
 
+- a **phases table** (stdout, last): every span name with its seconds,
+  self seconds and what the host spent inside it (ISSUE 52: cores kept
+  busy, kernel seconds, memory first touched, seconds the opening thread
+  was off its CPU, collector seconds), largest self seconds first — from
+  the ``perf_counters.json`` beside the logs, else rebuilt from the E
+  lines' args; given a ``perf_counters.json`` alone, that table is all.
+
 Usage::
 
     python tools/trace_report.py <wd>/log                # report + trace.json
     python tools/trace_report.py <wd>/log --chrome /tmp/t.json
     python tools/trace_report.py <wd>/log --no-chrome    # report only
+    python tools/trace_report.py <wd>/log/perf_counters.json   # the phases table of a record
 
 Crash evidence is first-class: a torn final line (SIGKILL mid-write) is
 expected and reported as such, never an error; an event file that simply
@@ -35,12 +43,14 @@ import argparse
 import glob
 import json
 import os
+import resource
 import sys
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 from drep_tpu.utils.durableio import atomic_write_bytes  # noqa: E402
+from drep_tpu.utils.profiling import HOST_ARGS  # noqa: E402
 
 EVENTS_GLOB = "events.p*.jsonl"
 
@@ -93,7 +103,9 @@ def load_events(log_dir: str) -> dict:
 def pair_spans(events: list[dict]) -> tuple[list[dict], list[dict]]:
     """Match B/E records per (pid, name) nesting stack. Returns (spans,
     unclosed_B_records); each span dict carries pid/ev/args, begin/end
-    wall stamps, and the monotonic duration (the E record's ``dur``)."""
+    wall stamps, the monotonic duration (the E record's ``dur``) and, apart
+    from the span's own args, what the host spent inside it (``host``: the
+    E record's ``profiling.HOST_ARGS``, whole deltas, those that moved)."""
     stacks: dict[tuple[int, str], list[dict]] = {}
     spans: list[dict] = []
     for rec in events:
@@ -108,6 +120,7 @@ def pair_spans(events: list[dict]) -> tuple[list[dict], list[dict]]:
         begin = stack.pop() if stack else None
         args = dict(rec.get("args") or {})
         dur = args.pop("dur", None)
+        host = {name: args.pop(name) for name in HOST_ARGS if name in args}
         if dur is None and begin is not None:
             dur = max(0.0, rec.get("mono", 0.0) - begin.get("mono", 0.0))
         begin_wall = (
@@ -124,11 +137,80 @@ def pair_spans(events: list[dict]) -> tuple[list[dict], list[dict]]:
                 "begin": begin_wall,
                 "end": rec.get("wall", 0.0),
                 "dur": float(dur or 0.0),
+                "host": host,
             }
         )
     unclosed = [b for stack in stacks.values() for b in stack]
     unclosed.sort(key=lambda r: r.get("wall", 0.0))
     return spans, unclosed
+
+
+def phases_from_events(events: list[dict]) -> dict[str, dict]:
+    """The record's ``phases`` section rebuilt from an event log, for a job
+    that left no record: per name the spans' seconds, calls and the E lines'
+    host deltas, with self values by nesting (a span's less what the spans
+    closed inside it cover). The log names no thread: a worker thread's span
+    nests under whatever its process had open."""
+    phases: dict[str, dict] = {}
+    open_spans: dict[int, list[dict]] = {}  # per process, outermost first
+    for rec in events:
+        ph = rec.get("ph")
+        if ph not in ("B", "E"):
+            continue
+        stack = open_spans.setdefault(rec.get("pid", 0), [])
+        if ph == "B":
+            stack.append({"ev": rec["ev"], "mono": rec.get("mono", 0.0), "child": {}})
+            continue
+        at = next((i for i in range(len(stack) - 1, -1, -1) if stack[i]["ev"] == rec["ev"]), None)
+        if at is None:
+            continue  # an E with no B: the log began inside the span
+        frame = stack.pop(at)
+        args = rec.get("args") or {}
+        whole = {name: args[name] for name in HOST_ARGS if name in args}
+        whole["seconds"] = args.get("dur", max(0.0, rec.get("mono", 0.0) - frame["mono"]))
+        if at > 0:
+            covered = stack[at - 1]["child"]
+            for name, v in whole.items():
+                if not name.startswith("gc_"):  # the collector's are self values already
+                    covered[name] = covered.get(name, 0) + v
+        ent = phases.setdefault(rec["ev"], {"calls": 0})
+        ent["calls"] += 1
+        for name, v in whole.items():
+            if name in ("seconds", "cpu_s", "sys_s") or name.startswith("gc_"):
+                ent[name] = ent.get(name, 0) + v
+            if not name.startswith("gc_"):
+                ent["self_" + name] = ent.get("self_" + name, 0) + v - frame["child"].get(name, 0)
+    return phases
+
+
+def phases_table(phases: dict[str, dict]) -> str:
+    """One line a phase, largest self seconds first: calls, seconds, self
+    seconds, cores (the process's CPU over the span's seconds: above 1 its
+    worker threads scaled, far under 1 it waited), the kernel's self
+    seconds, MiB first touched (4 KiB faults; a floor under transparent
+    huge pages; ``-`` where the kernel counts none in any phase), seconds
+    the opening thread was off its CPU, collector
+    seconds. A worker thread's phase (``@other``) has the thread's alone."""
+    page_mib = resource.getpagesize() / 2**20
+    # a sandboxed kernel counts no page faults: zero in every phase is no source, not 0.0 MiB
+    counts_faults = any(p.get("self_minor_faults") for p in phases.values())
+    head = (f"  {'phase':<34} {'calls':>7} {'seconds':>9} {'self':>9} {'cores':>6} "
+            f"{'sys':>8} {'MiB new':>9} {'off-CPU':>8} {'gc':>7}")
+    lines = [head]
+
+    def cell(value, width: int, digits: int) -> str:
+        return f"{'-':>{width}}" if value is None else f"{value:>{width}.{digits}f}"
+
+    for name, p in sorted(phases.items(), key=lambda kv: -kv[1].get("self_seconds", 0.0)):
+        seconds, own = p.get("seconds", 0.0), p.get("self_seconds", 0.0)
+        cores = p["cpu_s"] / seconds if "cpu_s" in p and seconds > 0 else None
+        faults = p.get("self_minor_faults") if counts_faults else None
+        off_cpu = own - p["self_thread_cpu_s"] if "self_thread_cpu_s" in p else None
+        lines.append(
+            f"  {name:<34} {p.get('calls', 0):>7} {seconds:>9.3f} {own:>9.3f} {cell(cores, 6, 2)} "
+            f"{cell(p.get('self_sys_s'), 8, 3)} {cell(None if faults is None else faults * page_mib, 9, 1)} "
+            f"{cell(off_cpu, 8, 3)} {cell(p.get('gc_s'), 7, 3)}")
+    return "\n".join(lines)
 
 
 def membership_timeline(events: list[dict]) -> list[dict]:
@@ -440,12 +522,21 @@ def text_report(events: list[dict], counters_doc: dict | None = None) -> str:
                 f"  p{b.get('pid', 0)}: {b['ev']} {b.get('args') or {}} "
                 f"(+{b.get('wall', t_lo) - t_lo:.3f}s)"
             )
+
+    # -- where the host's time went ----------------------------------------
+    recorded = (counters_doc or {}).get("phases")
+    phases = recorded or phases_from_events(events)
+    if phases:
+        lines.append("\nphases, largest self seconds first ("
+                     + ("perf_counters.json" if recorded else "rebuilt from the event log") + "):")
+        lines.append(phases_table(phases))
     return "\n".join(lines) + "\n"
 
 
 def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
-    ap.add_argument("log_dir", help="directory holding events.p*.jsonl (e.g. <wd>/log)")
+    ap.add_argument("log_dir", help="directory holding events.p*.jsonl (e.g. <wd>/log), or a "
+                                    "perf_counters.json for its phases table alone")
     ap.add_argument("--chrome", default=None,
                     help="write the Chrome trace-event JSON here "
                          "(default <log_dir>/trace.json)")
@@ -456,6 +547,12 @@ def main(argv: list[str] | None = None) -> int:
                          "timeline against (default: one beside the logs)")
     args = ap.parse_args(argv)
 
+    if os.path.isfile(args.log_dir):
+        with open(args.log_dir, encoding="utf-8") as f:
+            phases = json.load(f).get("phases") or {}
+        print(f"phases of {args.log_dir}, largest self seconds first:")
+        print(phases_table(phases))
+        return 0
     # a workdir was given instead of its log dir: follow the layout
     log_dir = args.log_dir
     if not glob.glob(os.path.join(log_dir, EVENTS_GLOB)) and os.path.isdir(
