@@ -6,6 +6,9 @@ shard files every INGEST_SHARD completions, and a rerun loads them and
 sketches only the remainder.
 """
 
+import os
+import zipfile
+
 import numpy as np
 import pandas as pd
 import pytest
@@ -32,6 +35,13 @@ def counting_sketch(monkeypatch):
     return calls
 
 
+def _assert_same_sketches(got, want):
+    assert got.names == want.names
+    for a, b in zip(got.bottom + got.scaled, want.bottom + want.scaled):
+        np.testing.assert_array_equal(a, b)
+    pd.testing.assert_frame_equal(got.gdb, want.gdb)
+
+
 def test_killed_ingest_resumes_from_shards(tmp_path, genome_paths, counting_sketch, monkeypatch):
     monkeypatch.setattr(ingest_mod, "INGEST_SHARD", 2)  # flush every 2 genomes
     wd = WorkDirectory(str(tmp_path / "wd"))
@@ -50,16 +60,10 @@ def test_killed_ingest_resumes_from_shards(tmp_path, genome_paths, counting_sket
 
     # results identical to a fresh, uninterrupted run
     wd2 = WorkDirectory(str(tmp_path / "wd2"))
-    fresh = sketch_genomes(bdb, wd=wd2)
-    for a, b in zip(gs.bottom, fresh.bottom):
-        np.testing.assert_array_equal(a, b)
-    for a, b in zip(gs.scaled, fresh.scaled):
-        np.testing.assert_array_equal(a, b)
-    pd.testing.assert_frame_equal(gs.gdb, fresh.gdb)
+    _assert_same_sketches(gs, sketch_genomes(bdb, wd=wd2))
 
     # the assembled cache supersedes the shards (disk footprint)
     import glob
-    import os
 
     assert not glob.glob(os.path.join(str(tmp_path / "wd"), "data", "sketch_shards", "*.npz"))
 
@@ -87,12 +91,7 @@ def test_pooled_ingest_matches_serial(genome_paths):
     bdb = make_bdb(genome_paths)
     serial = sketch_genomes(bdb)
     pooled = sketch_genomes(bdb, processes=2)
-    assert pooled.names == serial.names
-    for a, b in zip(pooled.bottom, serial.bottom):
-        np.testing.assert_array_equal(a, b)
-    for a, b in zip(pooled.scaled, serial.scaled):
-        np.testing.assert_array_equal(a, b)
-    pd.testing.assert_frame_equal(pooled.gdb, serial.gdb)
+    _assert_same_sketches(pooled, serial)
 
 
 def test_missing_genome_file_fails_fast():
@@ -113,7 +112,6 @@ def test_non_fasta_input_is_an_error(tmp_path):
 
 def test_cli_reports_clean_error_for_bad_input(tmp_path):
     """CLI: user-input errors end as one `!!!` line + exit 1, no traceback."""
-    import os
     import subprocess
     import sys
     from pathlib import Path
@@ -141,8 +139,6 @@ def test_sketch_cache_will_hit_sees_shard_complete_store(
     rebuilds from shards with zero sketching work, so there is no ingest
     to hide the streaming compile behind (and the warmup's throwaway
     execution would just race the first real tiles)."""
-    import os
-
     from drep_tpu.ingest import (
         DEFAULT_SCALE,
         DEFAULT_SKETCH_SIZE,
@@ -230,6 +226,126 @@ def test_sketch_cache_will_hit_rejects_zero_kmer_stale_cache(tmp_path, genome_pa
     assert not sketch_cache_will_hit(wd, *key)
 
 
+# ---- the shard's format: stored since ISSUE 53, deflated before it --------
+
+
+def _open_store_and_sketch(wd_path, bdb, indices, k=21, sketch_size=1000, scale=200):
+    """The shard store of `wd_path` opened under the matching meta, and the
+    genomes `indices` sketched as the batch a flush would hold."""
+    from drep_tpu.ingest import _SKETCH_SHARD_SUBDIR, _sketch_shard_meta, sketch_args_snapshot
+    from drep_tpu.utils.ckptmeta import open_checkpoint_dir
+
+    wd = WorkDirectory(wd_path)
+    shard_dir = wd.get_dir(_SKETCH_SHARD_SUBDIR)
+    snap = sketch_args_snapshot(bdb["genome"], k, sketch_size, scale, "splitmix64")
+    open_checkpoint_dir(shard_dir, _sketch_shard_meta(snap), clear_suffixes=(".npz",))
+    batch = {}
+    for i in indices:
+        row = bdb.iloc[i]
+        name, res = ingest_mod._sketch_one(
+            (row.genome, row.location, k, sketch_size, scale, "splitmix64")
+        )
+        batch[name] = res
+    return shard_dir, batch
+
+
+def _save_deflated(path, batch, monkeypatch):
+    """The batch as a tree before ISSUE 53 wrote it: the same payload
+    through `atomic_savez(compressed=True)`."""
+    from drep_tpu.utils import ckptmeta
+
+    real = ckptmeta.atomic_savez
+    with monkeypatch.context() as m:
+        m.setattr(ckptmeta, "atomic_savez",
+                  lambda path, compressed=True, **arrays: real(path, compressed=True, **arrays))
+        ingest_mod._save_sketch_shard(path, batch)
+
+
+def _compress_types(path):
+    with zipfile.ZipFile(path) as zf:
+        return {info.filename: info.compress_type for info in zf.infolist()}
+
+
+@pytest.mark.parametrize("fmt", ["stored", "deflated"])
+def test_a_shard_of_either_format_loads_bit_equal_and_is_not_sketched_again(
+    fmt, tmp_path, genome_paths, counting_sketch, monkeypatch
+):
+    """`_load_sketch_shard` reads stored and deflated members alike, so a
+    work directory whose shards an older tree wrote compressed resumes on
+    this one: no format version, no migration."""
+    bdb = make_bdb(genome_paths)
+    shard_dir, batch = _open_store_and_sketch(str(tmp_path / "wd"), bdb, [0, 1, 3])
+    path = os.path.join(shard_dir, "shard_planted.npz")
+    if fmt == "stored":
+        ingest_mod._save_sketch_shard(path, batch)
+    else:
+        _save_deflated(path, batch, monkeypatch)
+    kinds = set(_compress_types(path).values())
+    assert kinds == {zipfile.ZIP_STORED if fmt == "stored" else zipfile.ZIP_DEFLATED}
+
+    loaded = ingest_mod._load_sketch_shard(path)
+    assert list(loaded) == list(batch)
+    for g, res in batch.items():
+        # what a shard keeps of a result: the run's own seconds and byte
+        # counts are not resumed
+        assert set(loaded[g]) == {*ingest_mod._SHARD_SCALARS, "bottom", "scaled"}
+        for key in ingest_mod._SHARD_SCALARS:
+            assert loaded[g][key] == res[key]
+        for key in ("bottom", "scaled"):
+            assert loaded[g][key].dtype == res[key].dtype == np.uint64
+            np.testing.assert_array_equal(loaded[g][key], res[key])
+
+    counting_sketch["n"] = 0
+    gs = sketch_genomes(bdb, wd=WorkDirectory(str(tmp_path / "wd")))
+    assert counting_sketch["n"] == 2  # the two genomes no shard held
+    _assert_same_sketches(gs, sketch_genomes(bdb))
+
+
+def test_the_writer_leaves_stored_members_and_the_checksum(tmp_path, genome_paths):
+    """Uniform 64-bit hashes do not deflate: every member of the shard the
+    writer publishes is ZIP_STORED, the in-band `__crc__` among them, and
+    no tmp is left beside it."""
+    from drep_tpu.utils.durableio import CRC_KEY
+
+    shard_dir, batch = _open_store_and_sketch(str(tmp_path / "wd"), make_bdb(genome_paths), range(5))
+    path = os.path.join(shard_dir, "shard_planted.npz")
+    ingest_mod._save_sketch_shard(path, batch)
+    kinds = _compress_types(path)
+    assert set(kinds) == {f"{key}.npy" for key in (
+        "names", *ingest_mod._SHARD_SCALARS, "bottom", "bottom_offsets",
+        "scaled", "scaled_offsets", CRC_KEY)}
+    assert set(kinds.values()) == {zipfile.ZIP_STORED}
+    hashes = sum(len(r["bottom"]) + len(r["scaled"]) for r in batch.values())
+    assert 8 * hashes < os.path.getsize(path) < 8 * hashes + 8192  # the arrays and their headers
+    assert sorted(os.listdir(shard_dir)) == ["meta.json", "shard_planted.npz"]
+
+
+def test_a_flipped_byte_in_a_stored_shard_is_quarantined_and_its_genomes_sketched_again(
+    tmp_path, genome_paths, counting_sketch
+):
+    """A stored member has no deflate stream to break: the zip's and the
+    payload's checksums alone say that the bytes rotted. The shard is
+    healed (counted, removed), its genomes sketched again, the healthy
+    shard beside it resumed."""
+    from drep_tpu.utils.durableio import _flip_bit
+    from drep_tpu.utils.profiling import counters
+
+    bdb = make_bdb(genome_paths)
+    shard_dir, batch = _open_store_and_sketch(str(tmp_path / "wd"), bdb, range(5))
+    names = list(batch)
+    rotted, healthy = (os.path.join(shard_dir, f"shard_{x}.npz") for x in "ab")
+    ingest_mod._save_sketch_shard(rotted, {g: batch[g] for g in names[:3]})
+    ingest_mod._save_sketch_shard(healthy, {g: batch[g] for g in names[3:]})
+    _flip_bit(rotted)
+
+    counters.reset()
+    counting_sketch["n"] = 0
+    gs = sketch_genomes(bdb, wd=WorkDirectory(str(tmp_path / "wd")))
+    assert counting_sketch["n"] == 3  # the rotted shard's genomes, no others
+    assert counters.faults.get("corrupt_shards_healed") == 1, counters.faults
+    _assert_same_sketches(gs, sketch_genomes(bdb))
+
+
 # ---- per-process sharded ingest (faked 2-process pod, single process) ----
 
 
@@ -247,31 +363,11 @@ def fake_pod_pid1(monkeypatch):
     monkeypatch.setattr(multihost_utils, "sync_global_devices", lambda *_a, **_k: None)
 
 
-def _plant_peer_shards(wd_path, bdb, indices, k=21, sketch_size=1000, scale=200):
+def _plant_peer_shards(wd_path, bdb, indices):
     """Simulate the pid-0 peer: sketch `indices` and write them as shards
     with the matching meta (real single-process calls, before any fakes)."""
-    import os
-
-    from drep_tpu.ingest import (
-        _SKETCH_SHARD_SUBDIR,
-        _save_sketch_shard,
-        _sketch_shard_meta,
-        sketch_args_snapshot,
-    )
-    from drep_tpu.utils.ckptmeta import open_checkpoint_dir
-
-    wd = WorkDirectory(wd_path)
-    shard_dir = wd.get_dir(_SKETCH_SHARD_SUBDIR)
-    snap = sketch_args_snapshot(bdb["genome"], k, sketch_size, scale, "splitmix64")
-    open_checkpoint_dir(shard_dir, _sketch_shard_meta(snap), clear_suffixes=(".npz",))
-    batch = {}
-    for i in indices:
-        row = bdb.iloc[i]
-        name, res = ingest_mod._sketch_one(
-            (row.genome, row.location, k, sketch_size, scale, "splitmix64")
-        )
-        batch[name] = res
-    _save_sketch_shard(os.path.join(shard_dir, "shard_peer.npz"), batch)
+    shard_dir, batch = _open_store_and_sketch(wd_path, bdb, indices)
+    ingest_mod._save_sketch_shard(os.path.join(shard_dir, "shard_peer.npz"), batch)
     return shard_dir
 
 
@@ -280,8 +376,6 @@ def test_sharded_ingest_assembles_peer_stripes(tmp_path, genome_paths, counting_
     stripe (odd indices), assemble the even indices from the peer's
     shards, and signal assembly with its marker instead of writing the
     cache (that is pid 0's job)."""
-    import os
-
     bdb = make_bdb(genome_paths)  # 5 genomes: pid1 owns indices 1, 3
     shard_dir = _plant_peer_shards(str(tmp_path / "wd"), bdb, [0, 2, 4])
     counting_sketch["n"] = 0  # planting went through the counted wrapper
@@ -299,7 +393,6 @@ def test_sharded_ingest_poison_marker_fails_fast(tmp_path, genome_paths, fake_po
     """A peer's unparseable-input poison marker must surface as the real
     UserInputError in every process's barrier, not a timeout."""
     import json
-    import os
     import time
 
     bdb = make_bdb(genome_paths)

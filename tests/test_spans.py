@@ -1123,6 +1123,32 @@ def test_a_dereplicate_job_writes_the_widb_and_the_warnings_of_the_parents_spell
         assert f.read() == widb.to_csv(index=False).encode()
 
 
+def test_the_shard_flush_span_carries_its_genomes_and_the_bytes_it_published(tmp_path, genome_paths, monkeypatch):
+    """ISSUE 53: `ingest/shard_flush` notes the published shard's size, so
+    an event log shows the flush's MB/s; the shard itself is gone by the
+    job's end (the cache supersedes it), so the writer is watched."""
+    import drep_tpu.ingest as ingest_mod
+    from drep_tpu.workflows import compare_wrapper
+
+    published = []
+    real = ingest_mod._save_sketch_shard
+
+    def watched(path, batch):
+        real(path, batch)
+        published.append((len(batch), os.path.getsize(path)))
+
+    monkeypatch.setattr(ingest_mod, "_save_sketch_shard", watched)
+    monkeypatch.setattr(ingest_mod, "INGEST_SHARD", 2)  # 5 genomes: two full shards and the forced one
+    wd = str(tmp_path / "wd")
+    compare_wrapper(wd, genome_paths, skip_plots=True, events="on")
+    telemetry.configure()
+    trace_report = _trace_report()
+    spans, _ = trace_report.pair_spans(trace_report.load_events(os.path.join(wd, "log"))["events"])
+    flushes = [sp["args"] for sp in spans if sp["ev"] == "ingest/shard_flush"]
+    assert [(a["genomes"], a["bytes"]) for a in flushes] == published
+    assert [n for n, _ in published] == [2, 2, 1] and all(size > 0 for _, size in published)
+
+
 def test_the_evaluate_spans_carry_their_source_and_what_they_wrote(tmp_path, genome_paths):
     from drep_tpu.workflows import compare_wrapper
 
