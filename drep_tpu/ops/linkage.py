@@ -343,3 +343,63 @@ def sparse_average_linkage(
         for node in members[cid]:
             labels[node] = cid
     return _renumber_first_appearance(labels), approx_merges
+
+
+def sparse_linkage_account(
+    n: int,
+    ii: np.ndarray,
+    jj: np.ndarray,
+    dd: np.ndarray,
+    labels: np.ndarray,
+    cutoff: float,
+    uncertified_merges: int,
+) -> dict[str, int]:
+    """What a linkage over the retained edges (ii, jj, dd) did, in
+    :func:`cluster_by_components`' terms, for the job's record: one pass over
+    the components of the graph of edges at or under `cutoff`. The edges are
+    distinct pairs of two different genomes, as the streaming walk's upper
+    triangle gives them (a pair given twice would count twice).
+
+    `genomes`, `components`, `singletons`, `cliques` and `largest` mean what
+    they mean there (a clique: a component of two or more whose every pair is
+    an edge under the cutoff; whatever the merge order it is one cluster).
+    `loose_components` are the others, the ones average linkage has to cut,
+    `rows_loose` the genomes in them. `edges_retained` counts the distinct
+    pairs handed in, `edges_under_cutoff` those at or under the cutoff,
+    `edges_between_clusters` those whose ends `labels` puts in different
+    clusters; `merges` is the merges the partition took (genomes less
+    clusters), `uncertified_merges` the accepted merges that averaged over an
+    unobserved pair (:func:`sparse_average_linkage`'s second value): 0
+    certifies the partition equal to full-matrix UPGMA's.
+    """
+    from scipy.sparse import coo_matrix
+    from scipy.sparse.csgraph import connected_components
+
+    keys = ("genomes", "components", "singletons", "cliques", "loose_components", "rows_loose",
+            "largest", "edges_retained", "edges_under_cutoff", "edges_between_clusters", "merges")
+    if n == 0:
+        return {**dict.fromkeys(keys, 0), "uncertified_merges": int(uncertified_merges)}
+    ii, jj = np.asarray(ii), np.asarray(jj)
+    under = np.asarray(dd) <= cutoff
+    ui, uj = ii[under], jj[under]
+    graph = coo_matrix((np.ones(len(ui), dtype=np.int8), (ui, uj)), shape=(n, n))
+    n_comp, comp = connected_components(graph, directed=False)
+    size = np.bincount(comp, minlength=n_comp)
+    inside = np.bincount(comp[ui], minlength=n_comp)  # an edge lies in one component
+    loose = inside != size * (size - 1) // 2
+    labels = np.asarray(labels)
+    singletons = int((size == 1).sum())
+    return {
+        "genomes": int(n),
+        "components": int(n_comp),
+        "singletons": singletons,
+        "cliques": int(n_comp - singletons - loose.sum()),
+        "loose_components": int(loose.sum()),
+        "rows_loose": int(size[loose].sum()),
+        "largest": int(size.max()),
+        "edges_retained": int(len(ii)),
+        "edges_under_cutoff": int(len(ui)),
+        "edges_between_clusters": int(np.count_nonzero(labels[ii] != labels[jj])),
+        "merges": int(n - len(np.unique(labels))),
+        "uncertified_merges": int(uncertified_merges),
+    }

@@ -1488,24 +1488,33 @@ def streaming_primary_clusters(
         packed, k, keep, block=block, checkpoint_dir=checkpoint_dir,
         ft_config=ft_config, prune=prune,
     )
-    if cluster_alg == "single":
-        with counters.span("primary/linkage"):
+    from drep_tpu.ops.linkage import sparse_average_linkage, sparse_linkage_account
+
+    with counters.span("primary/linkage", genomes=packed.n, tree="skipped") as sp:
+        if cluster_alg == "single":
             in_cluster = dd <= cutoff
             labels = connected_components(packed.n, ii[in_cluster], jj[in_cluster])
-    else:
-        from drep_tpu.ops.linkage import sparse_average_linkage
-
-        with counters.span("primary/linkage"):
+            approx_merges = 0
+        else:
             labels, approx_merges = sparse_average_linkage(
                 packed.n, ii, jj, dd, cutoff, keep
             )
-        if approx_merges:
-            get_logger().warning(
-                "streaming average linkage: %d accepted merges involved pairs "
-                "beyond the %.3f retention bound (entered the averages at that "
-                "lower bound) — the partition may over-merge relative to "
-                "full-matrix UPGMA; raise --warn_dist to widen retention if "
-                "this matters",
-                approx_merges, keep,
-            )
+        # the record says what the linkage met, as the dense route's does:
+        # a cell holds `uncertified_merges` to 0, a user reads the warning
+        t0 = time.perf_counter()
+        did = sparse_linkage_account(packed.n, ii, jj, dd, labels, cutoff, approx_merges)
+        counters.add_primary_linkage(tree="skipped", **did)
+        sp.note(
+            account_s=round(time.perf_counter() - t0, 4),
+            **{name: did[name] for name in ("components", "loose_components", "largest")},
+        )
+    if approx_merges:
+        get_logger().warning(
+            "streaming average linkage: %d accepted merges involved pairs "
+            "beyond the %.3f retention bound (entered the averages at that "
+            "lower bound) — the partition may over-merge relative to "
+            "full-matrix UPGMA; raise --warn_dist to widen retention if "
+            "this matters",
+            approx_merges, keep,
+        )
     return labels, (ii, jj, dd), pairs_computed

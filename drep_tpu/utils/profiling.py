@@ -846,9 +846,12 @@ class Counters:
         booked["seconds"] = booked.get("seconds", 0.0) + float(seconds)
 
     def add_primary_linkage(self, tree: str, **did: int) -> None:
-        """Book one `cluster_by_components` of the dense primary: `did` is
-        what it returned beside the labels, `tree` whether the job also built
-        the whole tree for the dendrogram."""
+        """Book the primary's linkage: `did` is what `cluster_by_components`
+        returned beside the labels (the dense routes) or what
+        `sparse_linkage_account` counted over the retained edges (the
+        streaming route, whose `uncertified_merges` of 0 certifies the
+        partition equal to full-matrix UPGMA's), `tree` whether the job also
+        built the whole tree for the dendrogram."""
         self.primary_linkage = {**{name: int(value) for name, value in did.items()}, "tree": tree}
 
     def add_stream_slots(
