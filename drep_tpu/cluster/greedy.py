@@ -18,6 +18,7 @@ off-TPU they run as searchsorted gather tiles (gathers are fine there).
 
 from __future__ import annotations
 
+from itertools import accumulate
 from typing import Any
 
 import numpy as np
@@ -55,6 +56,24 @@ def _pad_pack(ids: np.ndarray, counts: np.ndarray, rows: list[int], pad_to: int)
         out_ids[: len(rows)] = ids[rows]
         out_counts[: len(rows)] = counts[rows]
     return out_ids, out_counts
+
+
+def _rep_tile_rows(n_reps: int, base_block: int) -> list[int]:
+    """Row counts of the representative tiles a block's program calls run
+    against on the matmul route, from the representatives that exist when
+    the block is visited: none for none (the block is compared with itself
+    alone), a tile of `4 * base_block` rows for every one the
+    representatives fill, then the trailing ones padded to the smallest of
+    `base_block`, `2 * base_block`, `4 * base_block` that holds them. Three
+    shapes a width set at most, so representatives that accumulate inside a
+    bucket compile nothing; the floor is `base_block` and no lower, so two
+    jobs whose blocks meet 26 and 30 representatives run the same programs."""
+    rep_tile = 4 * base_block
+    full, rest = divmod(n_reps, rep_tile)
+    tiles = [rep_tile] * full
+    if rest:
+        tiles.append(next(b for b in (base_block, 2 * base_block, rep_tile) if b >= rest))
+    return tiles
 
 
 def _put_chunks(chunks: list[np.ndarray], booked: dict, side: str, mesh=None, replicated=False):
@@ -176,7 +195,8 @@ def greedy_secondary_cluster(
     block's and every new representative's repack and pad, the
     representatives' shipment), `secondary/greedy_wait` (one a block: its
     tiles against the representatives, its self comparison, the readbacks;
-    `devices=` says how many served), `secondary/greedy_assign`, and since
+    `devices=` says how many served, `rep_pad=` the representative rows it
+    was computed against), `secondary/greedy_assign`, and since
     ISSUE 52 `secondary/greedy_pad` (a block's rows copied into a padded
     block on the host) and `secondary/greedy_extent` (the cluster's
     vocabulary extent, computed for the counter's entry alone). On the
@@ -188,6 +208,18 @@ def greedy_secondary_cluster(
     trailing one, once a block). The cluster's entry says who served and
     what crossed (`mesh_devices`, `block_bytes`, `rep_bytes`,
     `rep_tiles_replicated`, `partial_tile_ships`).
+
+    The representative side of a block's program calls on the matmul route
+    is sized by the representatives that exist when the block is visited
+    (:func:`_rep_tile_rows`; ISSUE 55): a block that meets none (the first
+    of every cluster) makes no call against representatives and is compared
+    with itself alone (`blocks_without_reps` in the entry), a tile the
+    representatives fill is `rep_tile` = 4 x 128 rows (on a mesh replicated
+    once and cached), and the trailing tile is padded to 128, 256 or 512
+    rows: on one chip a `jnp.pad` of the resident rows, on a mesh the host
+    pad and one replicated put a block. `rep_rows_shipped` sums the rows
+    really computed against. The gather route keeps whole tiles of one
+    block, at least one.
     """
     s_ani, cov_thresh = kw["S_ani"], kw["cov_thresh"]
     m = len(indices)
@@ -235,8 +267,9 @@ def greedy_secondary_cluster(
     # what the cluster's counter entry sums over its blocks
     # (Counters.add_greedy_call)
     booked = dict.fromkeys(
-        ("blocks", "rep_rows_shipped", "rep_rows_real", "id_slots", "device_calls",
-         "block_bytes", "rep_bytes", "rep_tiles_replicated", "partial_tile_ships"), 0
+        ("blocks", "blocks_without_reps", "rep_rows_shipped", "rep_rows_real", "id_slots",
+         "device_calls", "block_bytes", "rep_bytes", "rep_tiles_replicated",
+         "partial_tile_ships"), 0
     )
     n_dev = 1 if mesh is None else int(mesh.devices.size)
 
@@ -247,9 +280,13 @@ def greedy_secondary_cluster(
         # repacks in O(rows), and the append-only representative set lives
         # as device-resident per-chunk tensors that only receive NEW rows
         # (host->device traffic O(total reps), not O(reps x blocks)).
-        # The rep side is consumed in FIXED row tiles: stable jit shapes
-        # (no recompile as reps grow) and a bounded [tile, v_chunk]
-        # indicator regardless of how many representatives accumulate.
+        # The rep side is consumed in row tiles of a few FIXED sizes
+        # (_rep_tile_rows: `rep_tile` rows at most, the trailing tile in a
+        # bucket, none while there are no representatives): stable jit
+        # shapes (no recompile as reps grow inside a bucket), a bounded
+        # [tile, v_chunk] indicator however many representatives
+        # accumulate, and an indicator build (a scatter whose cost is the id
+        # slots, padding included) sized to what the block really meets.
         # The tile rides the UNSCALED block: under a mesh the candidate
         # block grows by D but the replicated rep side should not.
         rep_tile = 4 * base_block
@@ -278,12 +315,17 @@ def greedy_secondary_cluster(
         nb = len(rows)
         with counters.span("secondary/greedy_pad", rows=nb, pad_to=block):
             b_ids, b_counts = _pad_pack(ids, counts, rows, block)
-        rep_pad = max(-(-len(reps) // rep_tile) * rep_tile, rep_tile)
+        if use_matmul:
+            tiles = _rep_tile_rows(len(reps), base_block)
+        else:
+            tiles = [rep_tile] * max(-(-len(reps) // rep_tile), 1)
+        rep_pad = sum(tiles)
         booked["blocks"] += 1
+        booked["blocks_without_reps"] += not tiles
         booked["rep_rows_shipped"] += rep_pad
         booked["rep_rows_real"] += len(reps)
 
-        # block vs existing reps (padded to a block multiple for shape reuse);
+        # block vs existing reps (padded to whole tiles for shape reuse);
         # both coverage directions — the gate, like the default all-pairs
         # path, requires cov >= cov_thresh in BOTH, and the ANI estimate is
         # max-containment (see ops/containment.py module docstring).
@@ -329,13 +371,15 @@ def greedy_secondary_cluster(
                 chunks=geom.n_chunks, devices=n_dev,
             ):
                 # one program call a chunk: each rep tile, then the self comparison
-                booked["device_calls"] += (rep_pad // rep_tile + 1) * geom.n_chunks
+                booked["device_calls"] += (len(tiles) + 1) * geom.n_chunks
                 if mesh is None:
                     # the block's chunk tensors go to device ONCE and serve both
                     # the vs-reps tiles and the self comparison
                     blk_dev = _put_chunks(blk_chunks, booked, "block_bytes")
                 inter = np.empty((block, rep_pad), np.float32)
-                for t0 in range(0, rep_pad, rep_tile):
+                for t0, tile_rows in zip(accumulate(tiles, initial=0), tiles):
+                    # the tile's own rows of every chunk tensor, padded to the tile
+                    pad = ((0, tile_rows - min(len(reps) - t0, tile_rows)), (0, 0))
                     if mesh is not None:
                         ti = t0 // rep_tile
                         if ti < len(rep_tiles_cached):
@@ -344,11 +388,7 @@ def greedy_secondary_cluster(
                             # trailing partial tile: host pad, shipped this block
                             tile_chunks = _put_chunks(
                                 [
-                                    np.pad(
-                                        rc[t0 : t0 + rep_tile],
-                                        ((0, rep_tile - max(min(rc.shape[0] - t0, rep_tile), 0)), (0, 0)),
-                                        constant_values=PAD_ID,
-                                    )
+                                    np.pad(rc[t0 : t0 + tile_rows], pad, constant_values=PAD_ID)
                                     for rc in rep_chunks_host
                                 ],
                                 booked, "rep_bytes", mesh, replicated=True,
@@ -356,20 +396,16 @@ def greedy_secondary_cluster(
                             booked["partial_tile_ships"] += 1
                         # on a mesh the block's chunks cross the link again for
                         # every representative tile (and twice more below)
-                        inter[:, t0 : t0 + rep_tile] = rect_from_chunks_sharded(
+                        inter[:, t0 : t0 + tile_rows] = rect_from_chunks_sharded(
                             _put_chunks(blk_chunks, booked, "block_bytes", mesh),
                             tile_chunks, geom.v_chunk, mesh,
                         )
                     else:
                         tile_chunks = [
-                            jnp.pad(
-                                rc[t0 : t0 + rep_tile],
-                                ((0, rep_tile - max(min(rc.shape[0] - t0, rep_tile), 0)), (0, 0)),
-                                constant_values=PAD_ID,
-                            )
+                            jnp.pad(rc[t0 : t0 + tile_rows], pad, constant_values=PAD_ID)
                             for rc in rep_chunks_dev
                         ]
-                        inter[:, t0 : t0 + rep_tile] = rect_from_chunks(
+                        inter[:, t0 : t0 + tile_rows] = rect_from_chunks(
                             blk_dev, tile_chunks, geom.v_chunk
                         )
                 cov_vs_reps = _cov_from_inter(inter, b_counts[:, None])

@@ -756,8 +756,8 @@ class Counters:
             ent[name] += int(value)
 
     def add_greedy_call(
-        self, rows: int, blocks: int, block_rows: int, reps: int, rep_tile: int,
-        rep_rows_shipped: int, rep_rows_real: int, v_chunk: int, chunks: int, extent: int,
+        self, rows: int, blocks: int, blocks_without_reps: int, block_rows: int, reps: int,
+        rep_tile: int, rep_rows_shipped: int, rep_rows_real: int, v_chunk: int, chunks: int, extent: int,
         widths: int, hashes: int, id_slots: int, device_calls: int, compared_pairs: int,
         mesh_devices: int, rep_tiles_replicated: int, partial_tile_ships: int,
         block_bytes: int, rep_bytes: int,
@@ -766,15 +766,20 @@ class Counters:
         holding `hashes` real ids over a vocabulary of `extent` went through
         `blocks` blocks of `block_rows` rows, each against the
         representatives that existed then (`rep_rows_real`, summed over the
-        blocks) padded to whole tiles of `rep_tile` rows (`rep_rows_shipped`:
-        the padded rows, summed likewise) and against itself, over `chunks`
+        blocks) padded to whole tiles of at most `rep_tile` rows
+        (`rep_rows_shipped`: the rows really computed against, padding
+        included, summed likewise) and against itself, over `chunks`
         vocabulary chunks of `v_chunk` ids whose id widths add up to
         `widths`; `id_slots` int32 id slots had to reach the device
         (`bytes_shipped`, each slot counted once) for `device_calls` program
         calls, and the cluster ended with `reps` representatives after
         `compared_pairs` genome-against-representative comparisons (its Ndb
         rows) of the `all_pairs` an all-pairs secondary makes. Off the matmul
-        route `v_chunk` and `chunks` are 0.
+        route `v_chunk` and `chunks` are 0. On the matmul route the trailing
+        tile is sized to the representatives met (`greedy._rep_tile_rows`,
+        ISSUE 55), and `blocks_without_reps` counts the blocks that met none
+        and so made no call against representatives: each adds 0 to
+        `rep_rows_shipped` and its self comparison alone to `device_calls`.
 
         Who served, and what really crossed the link: `mesh_devices` the
         devices the blocks were sharded over (1 off a mesh), `block_bytes`
@@ -790,7 +795,8 @@ class Counters:
         whose `clusters` says how many it holds (its `mesh_devices` the
         fewest any of them had)."""
         booked = {name: int(value) for name, value in {
-            "clusters": 1, "rows": rows, "blocks": blocks, "block_rows": block_rows,
+            "clusters": 1, "rows": rows, "blocks": blocks,
+            "blocks_without_reps": blocks_without_reps, "block_rows": block_rows,
             "reps": reps, "rep_tile": rep_tile, "rep_rows_shipped": rep_rows_shipped,
             "rep_rows_real": rep_rows_real, "v_chunk": v_chunk, "chunks": chunks,
             "extent": extent, "widths": widths, "hashes": hashes, "id_slots": id_slots,

@@ -119,12 +119,30 @@ def test_the_record_says_which_route_served_what(planted, jobs, route):
     for c in calls:
         assert c["clusters"] == 1 and c["blocks"] == -(-c["rows"] // c["block_rows"])
         assert c["all_pairs"] == c["rows"] * (c["rows"] - 1) // 2 > c["compared_pairs"] > 0
-        assert c["rep_rows_shipped"] >= c["blocks"] * c["rep_tile"] > c["rep_rows_real"]
+        if route == "greedy_matmul":
+            # sized tiles (ISSUE 55): a cluster's first block meets no representative and makes
+            # no call against any, every later one meets a handful in one tile of 128 rows
+            assert c["blocks_without_reps"] == 1 and c["rep_tile"] == 512
+            assert c["rep_rows_shipped"] == (c["blocks"] - 1) * 128 >= c["rep_rows_real"]
+            assert c["device_calls"] == (2 * c["blocks"] - 1) * c["chunks"]
+        else:
+            assert c["blocks_without_reps"] == 0
+            assert c["rep_rows_shipped"] >= c["blocks"] * c["rep_tile"] > c["rep_rows_real"]
         assert 0 < c["hashes"] <= c["id_slots"] and c["bytes_shipped"] == 4 * c["id_slots"]
         assert c["extent"] > 0 and c["device_calls"] >= c["blocks"]
         assert (c["v_chunk"] > 0 and c["chunks"] > 0) == (route == "greedy_matmul")
     # a span a block of the engine
     assert rec["phases"]["secondary/greedy_wait"]["calls"] == sum(c["blocks"] for c in calls)
+    if route == "greedy_matmul":  # the job whose event log is on: the span says what it computed against
+        from tools import trace_report
+
+        spans, _ = trace_report.pair_spans(
+            trace_report.load_events(os.path.join(jobs[route]["wd"], "log"))["events"])
+        waits = [sp["args"] for sp in spans if sp["ev"] == "secondary/greedy_wait"]
+        assert sum(w["rep_pad"] for w in waits) == sum(c["rep_rows_shipped"] for c in calls)
+        assert sum(w["reps"] for w in waits) == sum(c["rep_rows_real"] for c in calls)
+        assert {w["rep_pad"] for w in waits if w["reps"] == 0} == {0}
+        assert {w["rep_pad"] for w in waits if w["reps"] > 0} == {128}
     # the pairs booked are the Ndb's rows: the engine's clusters and the batched route's
     batched = rec["secondary_greedy_batched"]
     assert (batched["clusters"], batched["rows"]) == (len(small), small.sum())

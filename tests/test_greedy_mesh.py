@@ -16,6 +16,8 @@ from benchmark import reference_greedy as rg
 from drep_tpu.cluster.greedy import greedy_secondary_cluster
 from drep_tpu.ingest import GenomeSketches
 from drep_tpu.utils.profiling import counters
+from tests._greedy_testlib import (
+    assert_same_answers, fixed_tiles, run_engine, sized_tiles, tile_cluster)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 KW = {"S_ani": 0.95, "cov_thresh": 0.1}
@@ -116,8 +118,12 @@ def test_the_record_says_who_served_and_what_crossed_the_link(strangers, monkeyp
     assert (call["mesh_devices"], call["block_rows"], call["blocks"], call["rep_tile"]) == (
         devices, block, blocks, 512)
     assert call["chunks"] == 1 and call["reps"] == 640
-    # the representatives before each block: 0, block, 2 x block ...: one tile each time
-    assert call["rep_rows_shipped"] == 512 * blocks
+    # the representatives before each block: 0, block, 2 x block ...: no tile for none, then
+    # the trailing tile at its bucket (128 -> 128, 256 -> 256, 384 -> 512), a filled one whole
+    met = [b * block for b in range(blocks)]
+    assert call["rep_rows_shipped"] == {1: 128 + 256 + 512 + 512, 4: 512}[devices]
+    assert call["blocks_without_reps"] == 1 and call["rep_rows_real"] == sum(met)
+    assert call["device_calls"] == sum(len(sized_tiles(n)) + 1 for n in met)
     assert call["bytes_shipped"] == 4 * (blocks * block + shipped_reps) * call["widths"]
     puts = rec["phases"]["secondary/greedy_put"]["calls"]
     if devices == 1:
@@ -128,12 +134,53 @@ def test_the_record_says_who_served_and_what_crossed_the_link(strangers, monkeyp
         assert (call["rep_tiles_replicated"], call["partial_tile_ships"]) == (0, 0)
         assert puts == blocks
     else:
-        # block 1 meets a tile of padding (shipped: the trailing tile), founds 512, which fill
-        # tile 0 (replicated once); block 2 meets that tile from the cache. A block crosses once
-        # a tile, then once row-sharded and once a device for the self comparison
-        assert (call["rep_tiles_replicated"], call["partial_tile_ships"]) == (1, 1)
-        assert call["rep_bytes"] == 4 * tile_ids * devices * 2
-        assert call["block_bytes"] == 4 * tile_ids * blocks * (1 + 1 + devices)
-        assert puts == 2 + blocks * 3
+        # block 1 meets no representative (no tile, nothing shipped for one), founds 512, which
+        # fill tile 0 (replicated once); block 2 meets that tile from the cache. A block crosses
+        # once a tile, then once row-sharded and once a device for the self comparison
+        assert (call["rep_tiles_replicated"], call["partial_tile_ships"]) == (1, 0)
+        assert call["rep_bytes"] == 4 * tile_ids * devices
+        assert call["block_bytes"] == 4 * tile_ids * ((1 + devices) + (1 + 1 + devices))
+        assert puts == 2 + 1 + 3
     assert rec["phases"]["secondary/greedy_wait"]["calls"] == blocks
     assert len(ndb) == call["compared_pairs"] == 640 * 639 // 2  # every genome against all before it
+
+
+# the representatives the last MESH block (512 rows on four devices) meets -> each block's founders
+MESH_TILE_CASES = {1: [1, 0], 129: [129, 0], 512: [512, 0], 513: [512, 1, 0]}
+
+
+@pytest.mark.parametrize("meets", list(MESH_TILE_CASES))
+def test_on_a_mesh_the_trailing_tile_is_shipped_at_its_bucket_and_a_filled_one_once(monkeypatch, meets):
+    """ISSUE 55 on the CPU's four virtual devices: the sized tiles give the
+    one-device route's and the fixed 512-row tile's Ndb and labels bit for
+    bit; a tile the representatives fill is still replicated once and read
+    from the cache, the trailing one crosses at 128, 256 or 512 rows a
+    block, and a block that meets no representative ships no tile."""
+    import drep_tpu.cluster.greedy as greedy_mod
+
+    founders = MESH_TILE_CASES[meets]
+    gs = tile_cluster(founders, block=512)
+    met = [sum(founders[:b]) for b in range(len(founders))]
+    assert met[-1] == meets
+    monkeypatch.setenv("DREP_TPU_GREEDY_MATMUL", "1")
+    ndb, labels, rec = run_engine(gs, 4)
+    one_ndb, one_labels, _ = run_engine(gs, 1)
+    monkeypatch.setattr(greedy_mod, "_rep_tile_rows", fixed_tiles)
+    fixed_ndb, fixed_labels, fixed_rec = run_engine(gs, 4)
+    assert_same_answers(ndb, labels, one_ndb, one_labels)
+    assert_same_answers(ndb, labels, fixed_ndb, fixed_labels)
+    (call,), (fixed_call,) = rec["secondary_greedy_calls"], fixed_rec["secondary_greedy_calls"]
+
+    tiles = [sized_tiles(n) for n in met]
+    assert (call["mesh_devices"], call["block_rows"], call["rep_tile"]) == (4, 512, 512)
+    assert call["rep_rows_shipped"] == sum(map(sum, tiles)) and call["blocks_without_reps"] == 1
+    assert call["device_calls"] == sum(len(t) + 1 for t in tiles)
+    # filled tiles: replicated once when the 512th representative is founded; every other tile
+    # of a block is the trailing one, shipped with that block at its own rows, once a device
+    filled = meets // 512
+    trailing = [t[-1] for n, t in zip(met, tiles) if n % 512]
+    assert (call["rep_tiles_replicated"], call["partial_tile_ships"]) == (filled, len(trailing))
+    assert call["rep_bytes"] == 4 * call["widths"] * 4 * (512 * filled + sum(trailing))
+    # the rule before shipped a tile of 512 with every block that had no filled tile to meet
+    assert fixed_call["rep_rows_shipped"] == sum(max(-(-n // 512), 1) * 512 for n in met)
+    assert fixed_call["rep_bytes"] >= call["rep_bytes"] + 4 * call["widths"] * 4 * 512  # block 1's

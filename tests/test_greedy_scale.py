@@ -188,3 +188,62 @@ def test_greedy_small_clusters_ride_the_batched_path(synthetic, monkeypatch):
     assert calls["batched"] >= 1  # small clusters batched
     assert calls["engine"] == 0  # no per-cluster greedy fan-out
     assert cdb["secondary_cluster"].nunique() >= 20
+
+
+# --- ISSUE 55: the representative tile sized to the representatives a block meets ---
+
+# the representatives the LAST block meets -> the founders of each block before it
+TILE_CASES = {0: [5], 1: [1, 0], 127: [127, 0], 128: [128, 0], 129: [128, 1, 0],
+              512: [128] * 4 + [0], 513: [128] * 4 + [1, 0]}
+
+
+@pytest.mark.parametrize("meets", list(TILE_CASES))
+def test_the_representative_tile_is_sized_to_the_representatives_a_block_meets(monkeypatch, meets):
+    """Blocks that meet 0, 1, 127, 128, 129, 512 and 513 representatives: the
+    sized tiles give the gather route's and the fixed 512-row tile's Ndb and
+    labels bit for bit, and the cluster's entry counts what the rule says."""
+    import drep_tpu.cluster.greedy as greedy_mod
+    from tests._greedy_testlib import (
+        assert_same_answers, fixed_tiles, run_engine, sized_tiles, tile_cluster)
+
+    founders = TILE_CASES[meets]
+    gs = tile_cluster(founders)
+    met = [sum(founders[:b]) for b in range(len(founders))]
+    assert met[-1] == meets
+    gather_ndb, gather_labels, gather_rec = run_engine(gs)
+    monkeypatch.setenv("DREP_TPU_GREEDY_MATMUL", "1")
+    ndb, labels, rec = run_engine(gs)
+    monkeypatch.setattr(greedy_mod, "_rep_tile_rows", fixed_tiles)
+    fixed_ndb, fixed_labels, fixed_rec = run_engine(gs)
+
+    assert len(set(labels)) == sum(founders) and len(ndb) > 0  # every stranger founds a group
+    assert_same_answers(ndb, labels, gather_ndb, gather_labels)
+    assert_same_answers(ndb, labels, fixed_ndb, fixed_labels)
+
+    (call,), (fixed_call,), (gather_call,) = (r["secondary_greedy_calls"] for r in (rec, fixed_rec, gather_rec))
+    tiles = [sized_tiles(n) for n in met]
+    assert call["chunks"] == 1 and call["rep_tile"] == 512  # the largest tile
+    assert call["blocks"] == len(founders) and call["rep_rows_real"] == sum(met)
+    assert call["rep_rows_shipped"] == sum(map(sum, tiles))
+    assert call["device_calls"] == sum(len(t) + 1 for t in tiles)
+    assert call["blocks_without_reps"] == 1  # the first block of a cluster
+    # the rule before: whole tiles of 512, one at the least, and a call for every block
+    assert fixed_call["rep_rows_shipped"] == sum(max(-(-n // 512), 1) * 512 for n in met)
+    assert fixed_call["device_calls"] == sum(max(-(-n // 512), 1) + 1 for n in met)
+    assert fixed_call["blocks_without_reps"] == 0 == gather_call["blocks_without_reps"]
+    assert gather_call["rep_rows_shipped"] == sum(max(-(-n // 128), 1) * 128 for n in met)
+    # what reached the device does not depend on the tiles: every row once
+    assert call["id_slots"] == fixed_call["id_slots"] and call["bytes_shipped"] == fixed_call["bytes_shipped"]
+    assert rec["phases"]["secondary/greedy_wait"]["calls"] == len(founders)  # still a span a block
+
+
+@pytest.mark.parametrize("n_reps,tiles", [
+    (0, []), (1, [128]), (127, [128]), (128, [128]), (129, [256]), (256, [256]), (257, [512]),
+    (512, [512]), (513, [512, 128]), (1024, [512, 512]), (1300, [512, 512, 512]),
+])
+def test_the_tile_rule_is_one_function_of_the_representatives_and_the_unscaled_block(n_reps, tiles):
+    from drep_tpu.cluster.greedy import _rep_tile_rows
+    from tests._greedy_testlib import sized_tiles
+
+    assert _rep_tile_rows(n_reps, 128) == tiles == sized_tiles(n_reps)
+    assert _rep_tile_rows(n_reps, 64) == [t // 2 for t in _rep_tile_rows(2 * n_reps, 128)]

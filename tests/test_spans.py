@@ -935,7 +935,8 @@ def test_greedy_calls_list_a_cluster_each_and_stay_bounded():
     from drep_tpu.utils import profiling
 
     c = Counters()
-    booked = dict(rows=300, blocks=3, block_rows=128, reps=5, rep_tile=512, rep_rows_shipped=1536,
+    booked = dict(rows=300, blocks=3, blocks_without_reps=1, block_rows=128, reps=5, rep_tile=512,
+                  rep_rows_shipped=256,
                   rep_rows_real=9, v_chunk=262144, chunks=2, extent=400_000, widths=49152,
                   hashes=6 * 10**6, id_slots=10**7, device_calls=12, compared_pairs=1200,
                   mesh_devices=4, rep_tiles_replicated=1, partial_tile_ships=2,
@@ -1002,9 +1003,9 @@ def test_greedy_put_and_greedy_wait_partition_what_greedy_wait_covered(monkeypat
     if devices == 1:
         assert abs(inside_layout) < 2e-4 and put["calls"] == blocks  # a block crosses once
     else:
-        # the trailing tile and the block for the tile, the block twice for itself; then the
-        # filled tile, inside the layout, and the second block: for the tile, twice for itself
-        assert inside_layout > 2e-4 and put["calls"] == 4 + 1 + 3
+        # the first block meets no representative: twice for itself, no tile (ISSUE 55); then
+        # the filled tile, inside the layout, and the second block: for the tile, twice for itself
+        assert inside_layout > 2e-4 and put["calls"] == 2 + 1 + 3
 
 
 
